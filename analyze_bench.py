@@ -1,22 +1,16 @@
-"""Summarize BENCH artifacts and report cross-run regressions.
+"""Report cross-run regressions over BENCH artifacts.
 
-Two jobs, one loader:
-
-* ``python analyze_bench.py [path]`` — the round-4 A/B tables over
-  BENCH_TPU_MEASURED.json (remat x fused ResNet50 matrix, LSTM sweeps,
-  headline-vs-north-star status), unchanged;
-* ``python analyze_bench.py --regressions [paths...]`` — the cross-run
-  regression reporter: every BENCH_*.json stream in the repo (JSONL
-  appended run over run by tier1.sh, plus the measured cache) is loaded,
-  records are aligned per config/variant IN FILE ORDER, and the latest
-  record of each series is compared against the median of its
-  predecessors. A headline drifting past ``--tolerance`` percent in the
-  bad direction (direction inferred from the unit: ms/seconds regress
-  UP, throughput regresses DOWN; goodput fractions regress DOWN) is
-  flagged; ``--gate`` turns flags into a nonzero exit so a perf
-  regression fails the run the same way a broken test does. Cached and
-  failed records never count; preflight and live records never mix
-  (they live in different variant series).
+``python analyze_bench.py [--gate] [paths...]`` — every BENCH_*.json stream
+in the repo (JSONL appended run over run by tier1.sh) is loaded, records
+are aligned per config/variant IN FILE ORDER, and the latest record of each
+series is compared against the median of its predecessors. A headline
+drifting past ``--tolerance`` percent in the bad direction (direction
+inferred from the unit: ms/seconds regress UP, throughput regresses DOWN;
+goodput fractions regress DOWN) is flagged; ``--gate`` turns flags into a
+nonzero exit so a regression fails the run the same way a broken test
+does. Failed records never count; preflight and chip records never mix
+(``preflight``, ``platform`` and ``device_kind`` are variant fields, so they
+live in different series).
 """
 
 import argparse
@@ -25,19 +19,19 @@ import json
 import os
 import sys
 
-#: record fields that distinguish A/B variants of one config (mirrors
-#: bench.py's _VARIANT_FIELDS; duplicated here so the analyzer stays a
-#: zero-import host tool usable away from the repo)
+#: record fields that distinguish A/B variants of one config, and the
+#: device stamp every bench record carries
 VARIANT_FIELDS = ("batch", "hw", "remat", "fused_conv", "hidden", "masked",
                   "seq", "fused_kernel", "d_model", "n_layers",
                   "fused_attention", "vocab", "dim", "n_chips",
-                  "flash_block", "preflight", "device")
+                  "flash_block", "preflight", "platform", "device_kind",
+                  "device_count")
 
 #: units where a LARGER value is the regression (latencies, walls)
 LOWER_IS_BETTER_UNITS = ("ms", "s/iter", "seconds", "sec/")
 
 
-def load(path="BENCH_TPU_MEASURED.json"):
+def load(path):
     """Records from one artifact: a JSON doc with results[], a JSON
     list, or a JSONL stream (BENCH_smoke.json) — event lines and
     non-record lines are dropped either way."""
@@ -84,7 +78,6 @@ def series_key(rec):
 def _usable(rec):
     return (rec.get("config") or rec.get("metric")) \
         and "FAILED" not in str(rec.get("metric", "")) \
-        and not rec.get("cached") \
         and isinstance(rec.get("value"), (int, float))
 
 
@@ -205,90 +198,24 @@ def report_regressions(paths, tolerance_pct=25.0, gate=False):
 
 
 def default_artifacts():
-    """Every BENCH_*.json next to this script, measured cache last so
-    live-TPU records form the series tail only where they belong."""
+    """Every BENCH_*.json next to this script."""
     here = os.path.dirname(os.path.abspath(__file__))
-    paths = sorted(p for p in glob.glob(os.path.join(here, "BENCH_*.json"))
-                   if not p.endswith("BENCH_TPU_MEASURED.json"))
-    measured = os.path.join(here, "BENCH_TPU_MEASURED.json")
-    if os.path.exists(measured):
-        paths.append(measured)
-    return paths
-
-
-# ---- the round-4 A/B tables (unchanged behavior) -----------------------
-
-def tables(path):
-    recs = load(path)
-    print(f"{len(recs)} records from {path}\n")
-
-    rn = [r for r in recs if r.get("config") == "resnet50"
-          or "resnet50" in str(r.get("metric", ""))]
-    if rn:
-        print("== ResNet50 (north star mfu >= 0.35, target 0.4) ==")
-        print(f"{'remat':>6} {'fused':>6} {'batch':>6} {'mfu':>8} "
-              f"{'samples/s':>10} {'step ms':>8} {'cached':>7}")
-        for r in rn:
-            print(f"{str(r.get('remat', '-')):>6} "
-                  f"{str(r.get('fused_conv', '-')):>6} "
-                  f"{fmt(r.get('batch')):>6} {fmt(r.get('mfu')):>8} "
-                  f"{fmt(r.get('value')):>10} "
-                  f"{fmt(r.get('step_time_ms')):>8} "
-                  f"{str(r.get('cached', False)):>7}")
-        best = max((r.get("mfu") or 0) for r in rn
-                   if not r.get("cached") and not r.get("preflight")) \
-            if any(not r.get("cached") and not r.get("preflight")
-                   for r in rn) else None
-        if best is not None:
-            status = ("NORTH STAR MET" if best >= 0.4 else
-                      "bar met" if best >= 0.35 else "below bar")
-            print(f"best fresh-TPU mfu: {best:.4f} ({status})")
-        print()
-
-    ls = [r for r in recs if r.get("config") == "lstm"
-          or "lstm" in str(r.get("metric", ""))]
-    if ls:
-        print("== GravesLSTM (fused-vs-scan A/Bs) ==")
-        print(f"{'hidden':>7} {'masked':>7} {'fused':>6} {'tokens/s':>12} "
-              f"{'cached':>7}")
-        for r in ls:
-            print(f"{fmt(r.get('hidden')):>7} "
-                  f"{str(r.get('masked', '-')):>7} "
-                  f"{str(r.get('fused_kernel', '-')):>6} "
-                  f"{fmt(r.get('value')):>12} "
-                  f"{str(r.get('cached', False)):>7}")
-        print()
-
-    other = [r for r in recs if r.get("config") not in ("resnet50", "lstm")]
-    if other:
-        print("== other configs ==")
-        for r in other:
-            print(f"{r.get('config', '?'):>12}: {fmt(r.get('value'))} "
-                  f"{r.get('unit', '')} "
-                  f"mfu={fmt(r.get('mfu'))} "
-                  f"cached={r.get('cached', False)}")
+    return sorted(glob.glob(os.path.join(here, "BENCH_*.json")))
 
 
 def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("paths", nargs="*",
-                   help="artifacts to analyze (default: the measured "
-                        "cache for tables; every BENCH_*.json for "
-                        "--regressions)")
-    p.add_argument("--regressions", action="store_true",
-                   help="cross-run regression report instead of tables")
+                   help="artifacts to analyze (default: every "
+                        "BENCH_*.json next to this script)")
     p.add_argument("--gate", action="store_true",
                    help="exit 1 when any headline regressed past "
-                        "tolerance (implies --regressions)")
+                        "tolerance")
     p.add_argument("--tolerance", type=float, default=25.0,
                    help="regression tolerance band, percent (default 25)")
     args = p.parse_args(argv)
-    if args.regressions or args.gate:
-        paths = args.paths or default_artifacts()
-        return report_regressions(paths, tolerance_pct=args.tolerance,
-                                  gate=args.gate)
-    tables(args.paths[0] if args.paths else "BENCH_TPU_MEASURED.json")
-    return 0
+    return report_regressions(args.paths or default_artifacts(),
+                              tolerance_pct=args.tolerance, gate=args.gate)
 
 
 if __name__ == "__main__":

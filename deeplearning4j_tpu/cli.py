@@ -33,16 +33,7 @@ def _build_parser():
         description="TPU-native dl4j: train / serve UI / bench")
     sub = p.add_subparsers(dest="command", required=True)
 
-    def add_compile_cache(sp):
-        sp.add_argument(
-            "--compile-cache", metavar="DIR",
-            help="persistent XLA compilation cache directory "
-                 "(utils/compile_cache): every jit in the process reuses "
-                 "on-disk compilations across restarts; defaults to "
-                 "$DL4J_TPU_COMPILE_CACHE when set")
-
     t = sub.add_parser("train", help="data-parallel training over the mesh")
-    add_compile_cache(t)
     src = t.add_mutually_exclusive_group(required=True)
     src.add_argument("--model-path", help="checkpoint zip to resume")
     src.add_argument("--zoo", help="zoo model name (e.g. lenet)")
@@ -76,7 +67,6 @@ def _build_parser():
         help="production inference server: continuous batching over "
              "AOT-warmed shape buckets, bounded admission queue with "
              "load shedding, /serving status on the dashboard port")
-    add_compile_cache(sv)
     sv.add_argument("--warm-manifest", metavar="PATH",
                     help="warm AOT manifest (utils/compile_cache "
                          "WarmManifest zip): when PATH exists, warmup "
@@ -118,7 +108,6 @@ def _build_parser():
              "from one checkpoint + warm manifest behind one admission/"
              "routing front with elastic worker replacement; /fleet "
              "status on the dashboard port")
-    add_compile_cache(fl)
     flsrc = fl.add_mutually_exclusive_group(required=True)
     flsrc.add_argument("--model-path", help="checkpoint zip every worker "
                                             "serves")
@@ -155,7 +144,6 @@ def _build_parser():
                          "print the front + worker status, and exit")
 
     e = sub.add_parser("eval", help="evaluate a checkpoint on a dataset")
-    add_compile_cache(e)
     esrc = e.add_mutually_exclusive_group(required=True)
     esrc.add_argument("--model-path", help="checkpoint zip")
     esrc.add_argument("--zoo", help="zoo model name (fresh init)")
@@ -409,16 +397,12 @@ def _load_xy(args):
     y = np.load(args.labels)
     return x, y
 
-def _enable_compile_cache(args):
-    """Point jax's persistent compile cache at --compile-cache (or
-    $DL4J_TPU_COMPILE_CACHE) BEFORE any jax work compiles — the
-    instant-restart tier every CLI verb shares."""
+def _enable_compile_cache():
+    """Turn jax's persistent compile cache on BEFORE any jax work compiles
+    — the instant-restart tier every CLI verb shares
+    ($JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache)."""
     from deeplearning4j_tpu.utils import compile_cache as _cc
-    cache_dir = _cc.enable_persistent_cache(
-        getattr(args, "compile_cache", None))
-    if cache_dir:
-        print(f"persistent compile cache: {cache_dir}")
-    return cache_dir
+    print(f"persistent compile cache: {_cc.enable_persistent_cache()}")
 
 
 def _cmd_train(args):
@@ -428,7 +412,7 @@ def _cmd_train(args):
         DistributedMultiLayer, ParameterAveragingTrainingMaster,
         SharedTrainingMaster)
 
-    _enable_compile_cache(args)
+    _enable_compile_cache()
 
     # CLI training is the preemptable long-running entry point: a SIGTERM
     # (scheduler eviction) leaves a flight-recorder dump behind
@@ -502,7 +486,7 @@ def _cmd_serve(args):
     from deeplearning4j_tpu.ui import UIServer
 
     telemetry.enable()  # SLO gauges/counters are the point of a server
-    _enable_compile_cache(args)
+    _enable_compile_cache()
     net = _load_model(args)
     input_spec = _serve_input_spec(args, net)
     buckets = None
@@ -604,7 +588,7 @@ def _cmd_fleet(args):
     from deeplearning4j_tpu.ui import UIServer
 
     telemetry.enable()
-    _enable_compile_cache(args)
+    _enable_compile_cache()
     if args.model_path is None:
         # zoo mode: workers init the model themselves (same seed = same
         # params); a checkpoint is the production path
@@ -618,7 +602,6 @@ def _cmd_fleet(args):
         args.workers, model_path=args.model_path, zoo=args.zoo,
         name=args.name, buckets=buckets, input_shape=input_shape,
         warm_manifest=args.warm_manifest or None,
-        compile_cache=getattr(args, "compile_cache", None),
         max_queue=args.max_queue, max_batch=args.max_batch,
         deadline_ms=args.deadline_ms)
     router = fleet.FleetRouter(
@@ -719,7 +702,7 @@ def _cmd_bench(args):
 def _cmd_eval(args):
     """(reference role: Evaluation printed from MultiLayerNetwork.evaluate /
     the examples' eval.stats() tail — here as a CLI verb)."""
-    _enable_compile_cache(args)
+    _enable_compile_cache()
     net = _load_model(args)
     x, y = _load_xy(args)
     preds = []

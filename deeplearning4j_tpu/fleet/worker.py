@@ -25,10 +25,11 @@ Run as a subprocess (what :class:`FleetSupervisor` spawns)::
         --warm-manifest wm.zip --buckets 1,8 --port 0 --worker-id w0
 
 The process prints ONE machine-readable ready line after warmup —
-``{"fleet_worker_ready": true, "port": <bound>, "aot": {...}, ...}`` —
-carrying the actually-bound port (``--port 0`` never collides) and the
-warmup counters, so the spawner can assert a replacement warm-started
-with zero compiles without a single extra round trip.
+``{"fleet_worker_ready": true, "port": <bound>, "platform": "cpu",
+"aot": {...}, ...}`` — carrying the actually-bound port (``--port 0``
+never collides), the backend the worker holds, and the warmup counters,
+so the spawner can assert a replacement warm-started with zero compiles
+without a single extra round trip.
 """
 
 from __future__ import annotations
@@ -349,10 +350,15 @@ class FleetWorker:
         """The machine-readable ready line ``main()`` prints: bound port
         + warmup counters, so a spawner can counter-assert a warm start
         (manifest hits only, zero compiles) from the line alone."""
+        import jax
+
         stats = self.engine.stats()
         from deeplearning4j_tpu.utils import compile_cache as _cc
         return {"fleet_worker_ready": True, "worker_id": self.worker_id,
                 "pid": os.getpid(), "port": self.port,
+                # the launchers pin workers to the CPU by default: say so,
+                # so nobody reads a fleet run as a chip run
+                "platform": jax.devices()[0].platform,
                 "model": self.engine.name, "buckets": stats["buckets"],
                 "seq_buckets": stats.get("seq_buckets"),
                 "warmup_s": stats["warmup_s"], "aot": stats["aot"],
@@ -394,8 +400,6 @@ def _build_parser():
                    help="serving warm manifest: warmup deserializes "
                         "every covered bucket instead of compiling "
                         "(the zero-compile replacement contract)")
-    p.add_argument("--compile-cache", metavar="DIR",
-                   help="persistent XLA compilation cache directory")
     return p
 
 
@@ -410,7 +414,7 @@ def main(argv=None):
     from deeplearning4j_tpu.utils import compile_cache as _cc
 
     telemetry.enable()  # the supervisor/router read this worker's counters
-    _cc.enable_persistent_cache(args.compile_cache)
+    _cc.enable_persistent_cache()
     net = _load_model(args)
     buckets = ([int(b) for b in args.buckets.split(",") if b.strip()]
                if args.buckets else None)

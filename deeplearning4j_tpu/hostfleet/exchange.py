@@ -11,13 +11,11 @@ backend):
   ``ParameterAveragingTrainingMaster``'s driver-side average, SURVEY
   §2.5): each host runs ``dispatches_per_round`` local sharded steps,
   then params + updater state are averaged across hosts at the ROUND
-  boundary. This is also the CPU-preflight transport: jax 0.4.37's CPU
-  client joins ``jax.distributed`` and enumerates global devices, but
-  raises ``Multiprocess computations aren't implemented on the CPU
-  backend`` on any cross-process dispatch — so the tier-1 chaos gate
-  proves the elastic machinery (watchdog, teardown, re-form, reshard,
-  resume) over this transport, and the gspmd leg is an accelerator-window
-  claim.
+  boundary. This is also the transport of the CPU gates: the tier-1 chaos
+  gate proves the elastic machinery (watchdog, teardown, re-form, reshard,
+  resume) over it, because its fixed reduction order makes the digests
+  reproducible and a dead contributor times out instead of wedging. The
+  gspmd leg has not run under a gate, on the CPU or on a chip.
 
 The server lives IN THE SUPERVISOR process (the Spark-driver analog) and
 is deliberately jax-free: workers send a flat leaf list (host numpy

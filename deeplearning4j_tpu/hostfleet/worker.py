@@ -11,8 +11,9 @@ exchange at every round boundary. Line protocol on stdout (the
 supervisor's contract):
 
 * ready: ``{"hostfleet_ready": true, "process": i, "generation": g,
-  "clock": {mono, unix}, ...}`` — the clock pair seeds the supervisor's
-  per-host clock-offset estimate (cluster timeline alignment);
+  "platform": "cpu", "clock": {mono, unix}, ...}`` — the clock pair seeds
+  the supervisor's per-host clock-offset estimate (cluster timeline
+  alignment); ``platform`` is the backend the host actually holds;
 * round: ``{"round": r, "iteration": n, "process": i, "trace": doc}``
   after each completed round (exchange + heartbeat + snapshot done) —
   the ``hostfleet.round`` trace doc (steps/exchange/heartbeat/checkpoint
@@ -200,9 +201,12 @@ def main(argv=None):
 
     mode = args.exchange
     if mode == "auto":
-        # jax 0.4.37's CPU client coordinates + enumerates across
-        # processes but cannot EXECUTE a multi-process computation — the
-        # round exchange moves to the host there
+        # multi-process CPU runs (the tier-1 gates) use the host-mediated
+        # exchange: its fixed process-id reduction order is what their
+        # digest-parity references lean on, and a dead contributor times
+        # out there instead of wedging a collective. (jax 0.9.0's CPU
+        # client CAN execute a multi-process computation; ``--exchange
+        # gspmd`` selects that path.)
         mode = ("hostavg" if (jax.process_count() > 1
                               and jax.default_backend() == "cpu")
                 else "gspmd")
@@ -301,6 +305,9 @@ def main(argv=None):
            "mode": mode, "resumed": bool(args.resume),
            "start_round": start_round,
            "local_devices": len(jax.local_devices()),
+           # the launcher pins hosts to the CPU by default: say so, so
+           # nobody reads a hostfleet run as a chip run
+           "platform": jax.local_devices()[0].platform,
            "layout": trainer.layout,
            "clock": _timeline.clock_pair()})
 

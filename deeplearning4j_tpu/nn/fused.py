@@ -73,15 +73,14 @@ class _ManifestDispatch:
     manifest (zero compiles on a warm restart) or live-compiles through
     ``compile_cache.aot_compile`` — which serializes the result back into
     the manifest, so saving the bundle after a cold run makes the next
-    restart warm. Any signature the AOT path cannot serve (serialization
-    unsupported on this backend, arg-convention mismatch) falls back to
-    the plain jit permanently — correctness never depends on the cache."""
+    restart warm. A lowering failure or a call the executable rejects
+    raises: nothing here falls back to the plain jit."""
 
     def __init__(self, jitted, manifest, kind):
         self._jit = jitted
         self._manifest = manifest
         self._kind = kind
-        self._by_sig = {}  # signature -> executable | False (jit fallback)
+        self._by_sig = {}  # signature -> executable
 
     def _cache_size(self):
         # recompile telemetry (devices.note_jit_cache) keys off the inner
@@ -104,23 +103,12 @@ class _ManifestDispatch:
         key = (treedef, tuple((l.shape, l.dtype.name) for l in leaves))
         ex = self._by_sig.get(key)
         if ex is None:
-            sig = _cc.signature_of(args)
-            try:
-                ex, _src = _cc.aot_compile(self._jit, *args,
-                                           manifest=self._manifest,
-                                           kind=self._kind, signature=sig)
-            except Exception:
-                ex = False  # lowering rejected: serve via the jit path,
-                #             which surfaces any real error
+            ex, _src = _cc.aot_compile(self._jit, *args,
+                                       manifest=self._manifest,
+                                       kind=self._kind,
+                                       signature=_cc.signature_of(args))
             self._by_sig[key] = ex
-        if ex is not False:
-            try:
-                return ex(*args)
-            except TypeError:
-                # AOT arg-passing quirk on this jax version: permanent
-                # jit fallback for this signature (never per-call retry)
-                self._by_sig[key] = False
-        return self._jit(*args)
+        return ex(*args)
 
 
 def make_train_steps(net, k, donate=True, jit=True, with_health=False,

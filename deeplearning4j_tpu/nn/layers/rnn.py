@@ -102,10 +102,7 @@ class LSTM(ParamLayer):
         """Fused Pallas sequence kernel applies? (TPU backend only; the
         dispatch seam mirroring the reference's reflective cuDNN-helper
         loading at ConvolutionLayer.java:74-84 — here explicit.)"""
-        try:
-            from deeplearning4j_tpu.ops import lstm_pallas
-        except ImportError:
-            return False
+        from deeplearning4j_tpu.ops import lstm_pallas
         if not lstm_pallas.enabled():  # env flag + TPU backend, one place
             return False
         return lstm_pallas.supported(

@@ -40,23 +40,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU memory-space hints are only available on TPU builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
+
+from deeplearning4j_tpu.ops import spmd as _spmd
 
 _LANE = 128
 _NEG_INF = -1e30
 
 
 def backend_is_tpu():
-    """Single backend gate shared by the fused-kernel dispatch seams."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """Single backend gate shared by the fused-kernel dispatch seams. A
+    backend that fails to initialize raises here: a chip that cannot start
+    must not read as "no chip, take the scan path"."""
+    return jax.default_backend() == "tpu"
 
 
 def enabled():
@@ -251,9 +247,22 @@ def _run_fwd(q, k, v, mask, h, causal, scale, block_q, block_k, interpret):
     with B = BH // h — the kernel indexes it per batch element (b // h) so
     heads share one mask block. A zero-width [B, 0] mask means "no mask"
     (the custom_vjp needs a real array operand; unmasked calls pay no mask
-    traffic in the kernel). Returns (out [BH, T, D], lse [BH, T])."""
+    traffic in the kernel). Returns (out [BH, T, D], lse [BH, T]). Under a
+    declared device mesh the kernel runs once per batch shard (ops/spmd.py:
+    the bh = b * h + head fold is batch-major, so a contiguous BH shard is
+    a contiguous B shard and the mask shards with it)."""
     if mask is not None and mask.shape[-1] == 0:
         mask = None
+    arrays = (q, k, v) if mask is None else (q, k, v, mask)
+
+    def local(q, k, v, mask=None):
+        return _run_fwd_local(q, k, v, mask, h, causal, scale, block_q,
+                              block_k, interpret)
+    return _spmd.per_batch_shard(local, arrays, (0,) * len(arrays), (0, 0))
+
+
+def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
+                   interpret):
     bh, t, d = q.shape
     # clamp blocks to the 128-rounded sequence: short sequences would
     # otherwise pad up to the full default block (wasted compute), and
@@ -272,10 +281,7 @@ def _run_fwd(q, k, v, mask, h, causal, scale, block_q, block_k, interpret):
                                block_q, block_k, mask is not None)
     scratch = [pltpu.VMEM((block_q,), jnp.float32),
                pltpu.VMEM((block_q,), jnp.float32),
-               pltpu.VMEM((block_q, d_pad), jnp.float32)] if _HAS_PLTPU else [
-        jax.ShapeDtypeStruct((block_q,), jnp.float32),
-        jax.ShapeDtypeStruct((block_q,), jnp.float32),
-        jax.ShapeDtypeStruct((block_q, d_pad), jnp.float32)]
+               pltpu.VMEM((block_q, d_pad), jnp.float32)]
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,

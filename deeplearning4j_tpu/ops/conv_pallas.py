@@ -48,12 +48,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # TPU memory-space hints exist only on TPU builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _pad_to(n, m):
@@ -125,8 +120,6 @@ def _matmul_stats(x2d, w2d, interpret, *, bn=None, bk=None, bj=None):
     TuningDB winner for the shape bucket > hand-picked defaults; every
     choice is clamped to the padded array like the defaults always were.
     """
-    if not _HAS_PLTPU:
-        raise NotImplementedError("Pallas TPU support unavailable")
     n, cin = x2d.shape
     cout = w2d.shape[1]
     dt = x2d.dtype
@@ -211,8 +204,6 @@ def _conv3x3_stats(x, w, interpret, stride=1, *, bt_target=None, bj=None):
     even dims is (lo 0, hi 1); output row h reads padded input rows
     2h..2h+2 (the row index maps do the arithmetic) and every tap
     subsamples its row with a static stride-2 column slice."""
-    if not _HAS_PLTPU:
-        raise NotImplementedError("Pallas TPU support unavailable")
     bsz, h, wd, cin = x.shape
     cout = w.shape[3]
     dt = x.dtype

@@ -48,12 +48,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU memory-space hints are only available on TPU builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
+
+from deeplearning4j_tpu.ops import spmd as _spmd
 
 
 # resident-Wh VMEM ceiling: [H, 4H] bf16 at H=512 is 2 MiB (measured-good,
@@ -84,7 +81,7 @@ def _gate_cell(z, c_prev, wp, hsz):
 
 
 def _apply_mask(m_ref, h, c, h_prev, c_prev):
-    m = m_ref[0].astype(jnp.float32)[:, None]  # [B,1], 1=valid
+    m = m_ref[0]  # [B,1] f32, 1=valid; broadcasts along the H lanes
     return m * h + (1.0 - m) * h_prev, m * c + (1.0 - m) * c_prev
 
 
@@ -181,12 +178,28 @@ def _run_kernel_any(xz, wh, wp, h0, c0, mask, interpret, tile_cols=None):
     mask is time-major [T, B] (1=valid). ``tile_cols`` picks the tiled
     kernel's Wh column width: explicit (the tuner's candidates) >
     TuningDB winner for the shape bucket > the widest 128-multiple
-    divisor of 4H under the hand-picked _TILE_COLS ceiling."""
+    divisor of 4H under the hand-picked _TILE_COLS ceiling. Under a
+    declared device mesh the kernel runs once per batch shard
+    (ops/spmd.py), the weights replicated."""
+    # name -> (array, batch axis), for the operands that are present
+    operands = {name: (a, axis) for name, a, axis in (
+        ("xz", xz, 1), ("wh", wh, None), ("wp", wp, None), ("h0", h0, 0),
+        ("c0", c0, 0), ("mask", mask, 1)) if a is not None}
+
+    def local(*arrays):
+        a = dict(zip(operands, arrays))
+        return tuple(_run_kernel_local(
+            a["xz"], a["wh"], a.get("wp"), a["h0"], a["c0"], a.get("mask"),
+            interpret, tile_cols))
+    return _spmd.per_batch_shard(
+        local, tuple(a for a, _ in operands.values()),
+        tuple(axis for _, axis in operands.values()), (1, 1, 0, 0))
+
+
+def _run_kernel_local(xz, wh, wp, h0, c0, mask, interpret, tile_cols):
     t, b, four_h = xz.shape
     hsz = four_h // 4
     dt = xz.dtype
-    if not _HAS_PLTPU:
-        raise NotImplementedError("Pallas TPU support unavailable")
     has_p, has_m = wp is not None, mask is not None
     tiled = hsz > _RESIDENT_MAX_H
 
@@ -230,8 +243,13 @@ def _run_kernel_any(xz, wh, wp, h0, c0, mask, interpret, tile_cols=None):
     specs += [spec((b, hsz), lambda i: (0, 0), lambda i, k: (0, 0)),
               spec((b, hsz), lambda i: (0, 0), lambda i, k: (0, 0))]
     if has_m:
-        inputs.append(mask.astype(jnp.float32))
-        specs.append(spec((1, b), lambda i: (i, 0), lambda i, k: (i, 0)))
+        # the mask rides in as [T, B, 1]: a (1, B, 1) block's last two dims
+        # equal the array's, which the TPU (8, 128) tile rule accepts — a
+        # (1, B) block of a [T, B] array does not (B sits on the lanes and
+        # the 1 on the sublanes)
+        inputs.append(mask.astype(jnp.float32)[:, :, None])
+        specs.append(spec((1, b, 1), lambda i: (i, 0, 0),
+                          lambda i, k: (i, 0, 0)))
 
     out_specs = [
         spec((1, b, hsz), lambda i: (i, 0, 0), lambda i, k: (i, 0, 0)),
@@ -452,16 +470,8 @@ def supported(x_shape, hsz, *, peephole, mask, gate_activation, activation):
     by exact lane padding (``fused_sequence_padded``). Only non-standard
     activations fall back to the scan path.
     """
-    if mask is not None:
-        if tuple(mask.shape) != (x_shape[0], x_shape[1]):
-            return False  # masking contract is per-(batch, step)
-        # first-contact escape hatch: the [1, B] mask block is the one
-        # input spec of this kernel family never yet compiled on real
-        # TPU; if it trips a tile rule in a live window, flip this env
-        # instead of losing the window (all other paths keep the kernel)
-        import os
-        if os.environ.get("DL4J_TPU_FUSED_LSTM_MASKED", "1") == "0":
-            return False
+    if mask is not None and tuple(mask.shape) != (x_shape[0], x_shape[1]):
+        return False  # masking contract is per-(batch, step)
     if (gate_activation, activation) != ("sigmoid", "tanh"):
         return False
     b = x_shape[0]

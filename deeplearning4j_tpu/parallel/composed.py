@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from deeplearning4j_tpu.utils.compat import shard_map
+from jax import shard_map
 from deeplearning4j_tpu.parallel import mesh as _mesh
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 

@@ -52,7 +52,6 @@ from deeplearning4j_tpu.telemetry import health as _health
 from deeplearning4j_tpu.native import codec as _codec
 from deeplearning4j_tpu.native.queue import FancyBlockingQueue
 from deeplearning4j_tpu.parallel import mesh as _mesh
-from deeplearning4j_tpu.utils import compat as _compat
 
 tree_map = jax.tree_util.tree_map
 
@@ -95,9 +94,10 @@ def _init_counter():
 
 def _probe_coordinator(address, deadline_s):
     """TCP-probe the coordinator before handing the address to
-    jax.distributed: on jax 0.4.37 a client whose RegisterTask RPC never
-    answers dies by a C++ ``LOG(FATAL)`` (SIGABRT) that no Python
-    ``except`` can see — so the common failure (coordinator dead, port
+    jax.distributed: a client whose RegisterTask RPC never answers dies by
+    a C++ ``LOG(FATAL)`` that no Python ``except`` can see (re-checked on
+    jax 0.9.0: DEADLINE_EXCEEDED, process terminated) — so the common
+    failure (coordinator dead, port
     unreachable, generation torn down) is converted HERE into a
     catchable, counted, retryable error. A listener that accepts TCP but
     is not a coordination service still reaches jax's own (bounded)
@@ -430,7 +430,7 @@ class ParameterAveragingTrainingMaster(TrainingMaster):
         out_specs = (P("data"), P("data"), P("data"), P())
         if with_health:
             out_specs = out_specs + (P("data"),)
-        fn = _compat.shard_map(
+        fn = jax.shard_map(
             split_step, mesh=self.mesh,
             in_specs=(P("data"), P("data"), P("data"), P("data"), P("data"),
                       P(), P("data")),
@@ -663,7 +663,7 @@ class SharedTrainingMaster(TrainingMaster):
         out_specs = (P(), P(), opt_spec, P("data"), P(), P())
         if with_health:
             out_specs = out_specs + (P("data"),)
-        fn = _compat.shard_map(
+        fn = jax.shard_map(
             step, mesh=self.mesh,
             in_specs=(P(), P(), opt_spec, P("data"), P(), P("data"),
                       P("data"), P(), P()),

@@ -32,7 +32,7 @@ import numpy as np
 from jax import lax
 from deeplearning4j_tpu.parallel import mesh as _mesh
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from deeplearning4j_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from deeplearning4j_tpu.nn import layers as L
 from deeplearning4j_tpu.nn import updaters as U

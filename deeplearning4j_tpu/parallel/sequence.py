@@ -163,7 +163,7 @@ def make_ring_attention_fn(mesh: Mesh, *, causal=False, seq_axis="seq",
                            use_flash=None, interpret=False):
     """shard_map-wrapped ring attention: takes full [B,T,H,D] arrays,
     returns full attention output, computed sequence-parallel."""
-    from deeplearning4j_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     spec = P(None, seq_axis, None, None)
 

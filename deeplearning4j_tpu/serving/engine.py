@@ -44,6 +44,7 @@ import numpy as np
 from deeplearning4j_tpu import telemetry as _tm
 from deeplearning4j_tpu.telemetry import tracectx as _tracectx
 from deeplearning4j_tpu.datasets.iterator import BucketRegistry, ShapeBuckets
+from deeplearning4j_tpu.ops import spmd as _spmd
 from deeplearning4j_tpu.serving import metering as _metering
 from deeplearning4j_tpu.utils import compile_cache as _cc
 
@@ -262,7 +263,9 @@ class BucketedForward:
                 lambda a: jax.device_put(a, data_sh), x)
 
             def raw(p, s, x):
-                return net.apply_fn(p, s, x, train=False)[0]
+                # Pallas kernels reached while tracing run per batch shard
+                with _spmd.kernel_mesh(mesh):
+                    return net.apply_fn(p, s, x, train=False)[0]
             self._jit = jax.jit(raw, in_shardings=(self._repl, self._repl,
                                                    data_sh),
                                 out_shardings=data_sh)
@@ -333,20 +336,26 @@ class BucketedForward:
 
     def warmup(self, input_spec):
         """Lower + compile the forward for every registered bucket (and the
-        mesh shardings baked into the jit). Returns the wall seconds spent —
-        the startup cost that buys a compile-free request path."""
+        mesh shardings baked into the jit), then CALL each executable once
+        on zeros: on jax 0.9.0 the first call of an AOT executable pays a
+        one-off dispatch set-up (~10x the median on CPU) that would
+        otherwise land on the first request. Returns the wall seconds
+        spent — the startup cost that buys a compile-free request path."""
         t0 = time.perf_counter()
         dtype = self.dtype if self.dtype is not None else np.dtype("float32")
         if self.seq_aware:
             # the full (batch, seq) grid: len(batch) * len(seq) executables
-            for b, s in self.buckets:
-                self._ensure_compiled(
-                    _example_structs(input_spec, b, dtype, seq=s),
-                    warm=True)
+            structs = [_example_structs(input_spec, b, dtype, seq=s)
+                       for b, s in self.buckets]
         else:
-            for b in self.buckets:
-                self._ensure_compiled(_example_structs(input_spec, b, dtype),
-                                      warm=True)
+            structs = [_example_structs(input_spec, b, dtype)
+                       for b in self.buckets]
+        params, state = self._resolve()
+        for x_struct in structs:
+            ex = self._ensure_compiled(x_struct, warm=True)
+            zeros = jax.tree_util.tree_map(
+                lambda s: np.zeros(s.shape, s.dtype), x_struct)
+            jax.block_until_ready(ex(params, state, self._place(zeros)))
         self._warmed = True
         return time.perf_counter() - t0
 
@@ -379,16 +388,20 @@ class BucketedForward:
             # Manifest-first: a warm restart deserializes the executable
             # (src == "manifest", ZERO compiles) and only a key miss pays
             # a live lower+compile. Serialize-back is warmup-only: a LAZY
-            # compile runs under this lock on the request path, and the
-            # put() verify-deserialize would stall every in-flight
-            # request — export_manifest's save-time walk covers lazy
-            # executables instead.
+            # compile runs under this lock on the request path, and
+            # serializing there would stall every in-flight request —
+            # export_manifest's save-time walk covers lazy executables
+            # instead.
             sig_now = _cc.full_signature(json.dumps(key))
             try:
-                ex, src = _cc.aot_compile(
-                    self._jit, self.net.params, self.net.state, x_struct,
-                    manifest=self.manifest, kind=self._manifest_kind,
-                    signature=json.dumps(key), serialize_back=warm)
+                # fresh: any serving executable may be serialized later
+                # (this write-back, or export_manifest's save-time walk)
+                with _cc.fresh_compile():
+                    ex, src = _cc.aot_compile(
+                        self._jit, self.net.params, self.net.state,
+                        x_struct, manifest=self.manifest,
+                        kind=self._manifest_kind,
+                        signature=json.dumps(key), serialize_back=warm)
             except Exception:
                 if warm:
                     # startup/update_model warmup must fail FAST: a spec
@@ -477,8 +490,9 @@ class BucketedForward:
             return placed
 
     def _run(self, x_padded, _phases=None):
-        """One compiled forward at the padded signature; jit fallback when
-        AOT lowering was unavailable or rejects the call convention.
+        """One compiled forward at the padded signature; the jit path
+        serves only a signature whose lazy AOT compile failed (counted as
+        ``jit_serves``).
         ``_phases`` (when given) collects measured ``(name, t0, t1, args)``
         windows — AOT-cache lookup, device exec — that the serving worker
         copies into every request trace of the batch."""
@@ -494,10 +508,7 @@ class BucketedForward:
         t0 = time.perf_counter() if _phases is not None else 0.0
         try:
             if ex is not False:
-                try:
-                    return ex(params, state, x_dev)
-                except TypeError:
-                    pass  # AOT arg-passing quirk on this jax version
+                return ex(params, state, x_dev)
             return self._jit(params, state, x_dev)
         finally:
             if _phases is not None:

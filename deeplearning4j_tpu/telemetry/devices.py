@@ -27,6 +27,42 @@ import jax
 
 from deeplearning4j_tpu.telemetry import registry as _registry
 
+#: Published per-chip peaks, keyed by the EXACT ``device_kind`` jax reports
+#: (a v5e chip reports "TPU v5 lite"). The one table every utilization or
+#: roofline number divides by. Source for "TPU v5 lite": Google Cloud
+#: documentation, "TPU v5e" system architecture page — 197 TFLOP/s bf16,
+#: 393 TOP/s int8, 16 GB HBM2e at 819 GB/s per chip.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_s": 819e9, "hbm_bytes": 16 * 2 ** 30},
+}
+
+
+def device_peaks(device=None):
+    """The :data:`DEVICE_PEAKS` row for ``device`` (default: the first
+    local device), or None on a non-TPU platform — callers then OMIT their
+    utilization fields. A TPU kind that is not in the table raises: a
+    device nobody looked up is an error, not a default."""
+    dev = jax.devices()[0] if device is None else device
+    if dev.platform != "tpu":
+        return None
+    if dev.device_kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no peaks recorded for TPU device_kind {dev.device_kind!r}: "
+            "add its published numbers, with the source, to "
+            "telemetry/devices.DEVICE_PEAKS")
+    return DEVICE_PEAKS[dev.device_kind]
+
+
+def device_stamp():
+    """The device identity every measurement record carries, as jax
+    reports it (bench.py records, cold-start legs, chip_smoke.py lines)."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": len(jax.devices()),
+            "jax_version": jax.__version__}
+
+
 #: recompiles-per-site at which /health flips to "warn": a couple of
 #: recompiles are normal warm-up (ragged final batch, eval shapes); a storm
 #: is one per step

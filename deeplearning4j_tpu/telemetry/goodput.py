@@ -225,25 +225,11 @@ def reset():
 
 
 def device_peak_flops():
-    """Best-effort aggregate peak FLOP/s of the local devices for the
-    MFU denominator: a small known-parts table keyed on the device kind
-    (bf16/f16 peak per chip), falling back to None (MFU then reported
-    as None rather than a number built on a guess)."""
-    try:
-        import jax
-        devs = jax.devices()
-    except Exception:
-        return None
-    if not devs:
-        return None
-    kind = getattr(devs[0], "device_kind", "") or ""
-    low = kind.lower()
-    per = None
-    for key, flops in (("v5e", 197e12), ("v5p", 459e12), ("v4", 275e12),
-                       ("v3", 123e12), ("v2", 45e12), ("v6", 918e12)):
-        if key in low:
-            per = flops
-            break
-    if per is None:
-        return None
-    return per * len(devs)
+    """Aggregate bf16 peak FLOP/s of the local devices for the MFU
+    denominator, from the one peaks table (telemetry/devices.py): None on
+    a non-TPU platform, an error for a TPU kind the table does not know."""
+    import jax
+
+    from deeplearning4j_tpu.telemetry import devices as _devices
+    peaks = _devices.device_peaks()
+    return None if peaks is None else peaks["bf16_flops"] * len(jax.devices())

@@ -11,12 +11,13 @@ whole-program AOT stance of the Julia-to-TPU paper, PAPERS.md arxiv
 1603.04467). Two complementary tiers:
 
 * **Persistent compilation cache** — :func:`enable_persistent_cache`
-  points jax's on-disk compile cache (``jax_compilation_cache_dir``) at a
-  directory, with the min-compile-time/min-entry-size thresholds opened up
-  so even small executables persist. Every ``jit`` in the process then
-  reuses on-disk compilations across restarts. Wired through the
-  ``train``/``serve``/``eval`` CLI verbs (``--compile-cache DIR``, env
-  ``DL4J_TPU_COMPILE_CACHE``).
+  turns on jax's on-disk compile cache with the min-compile-time/
+  min-entry-size thresholds opened up so even small executables persist.
+  One rule for where it lives: ``$JAX_COMPILATION_CACHE_DIR`` when that is
+  set (no directory is set in code then), otherwise ``<checkout>/.jax_cache``
+  — a fixed path, because the path is part of the cache key. Called
+  unconditionally by the CLI verbs, ``fleet/worker.py``, ``bench.py`` and
+  ``chip_smoke.py`` before anything compiles.
 * **Warm manifest** — :class:`WarmManifest` serializes *specific* AOT
   executables (``jax.experimental.serialize_executable``) keyed by
   (model fingerprint, backend+jax version, input shape signature) into an
@@ -44,6 +45,7 @@ can silently bypass the manifest tier.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import io
 import json
@@ -57,15 +59,21 @@ import zipfile
 import jax
 import numpy as np
 
-__all__ = ["ENV_CACHE_DIR", "WarmManifest", "aot_compile", "attach_manifest",
-           "backend_fingerprint", "enable_persistent_cache",
-           "full_signature", "model_fingerprint", "note_first_request",
-           "note_first_step", "signature_of", "status"]
+__all__ = ["DEFAULT_CACHE_DIR", "WarmManifest", "aot_compile",
+           "attach_manifest", "backend_fingerprint",
+           "enable_persistent_cache", "fresh_compile", "full_signature",
+           "model_fingerprint",
+           "note_first_request", "note_first_step", "signature_of", "status"]
 
-#: environment variable naming the persistent compile-cache directory
-ENV_CACHE_DIR = "DL4J_TPU_COMPILE_CACHE"
+#: where the persistent compile cache lives when JAX_COMPILATION_CACHE_DIR
+#: is unset: a fixed path under the checkout (never a temporary name, pid or
+#: time — a directory that moves never hits)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-MANIFEST_VERSION = 1
+#: 2: entries carry the ids of the devices the executable was compiled for
+MANIFEST_VERSION = 2
 
 def _process_start_anchor():
     """The perf_counter value at PROCESS start — /proc-derived on Linux
@@ -194,40 +202,60 @@ def status():
 # persistent compilation cache (tier a)
 # ---------------------------------------------------------------------------
 
-def enable_persistent_cache(cache_dir=None, *, min_compile_time_s=0.0):
-    """Point jax's persistent compilation cache at ``cache_dir``.
+def enable_persistent_cache():
+    """Turn on jax's persistent compilation cache and return its directory.
 
-    ``cache_dir`` defaults to ``$DL4J_TPU_COMPILE_CACHE``; with neither
-    set this is a no-op returning None (callers wire it unconditionally).
-    ``min_compile_time_s=0`` persists even sub-second compiles — the CPU
-    preflight/bench executables jax's 1s default would silently skip —
-    and the min-entry-size threshold is opened to match. jax-0.4.37
-    compatible: flags that don't exist on the running jax are skipped,
-    and the experimental ``set_cache_dir`` entry point is used as the
-    fallback wiring on releases where the config flag alone is inert.
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set — jax reads it itself, so
+    no directory is set in code; otherwise the cache lives at
+    :data:`DEFAULT_CACHE_DIR`. The min-compile-time and min-entry-size
+    thresholds are opened so sub-second compiles persist too (jax's 1 s
+    default would skip most of a cold start's executables).
     """
-    if cache_dir is None:
-        cache_dir = os.environ.get(ENV_CACHE_DIR)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
-        return None
-    cache_dir = os.path.abspath(str(cache_dir))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for flag, val in (
-            ("jax_persistent_cache_min_compile_time_secs",
-             float(min_compile_time_s)),
-            ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(flag, val)
-        except Exception:
-            pass  # older jax: threshold flag not present
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.set_cache_dir(cache_dir)
-    except Exception:
-        pass
+        cache_dir = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return cache_dir
+
+
+_fresh_lock = threading.Lock()
+_fresh_depth = 0      # nested/concurrent fresh_compile() blocks
+_fresh_restore = True  # jax_enable_compilation_cache as the first one found it
+
+
+@contextlib.contextmanager
+def fresh_compile():
+    """Compile inside this block without reading or writing the persistent
+    cache. An executable that may be SERIALIZED into a warm manifest must
+    be a fresh compile: on jax 0.9.0 an XLA:CPU executable served from the
+    persistent cache serializes and deserializes cleanly and then fails at
+    its first call (``NOT_FOUND: Function ... not found``) — and the
+    manifest is the stronger cache for that signature anyway. jax latches
+    "is the cache used" per process, hence the resets around the flag
+    flip; the depth count keeps one thread's exit from re-enabling the
+    cache under another thread still inside its own block."""
+    from jax.experimental.compilation_cache import compilation_cache as _jcc
+    global _fresh_depth, _fresh_restore
+    if not jax.config.jax_compilation_cache_dir:
+        yield       # no persistent cache in this process: nothing to bypass
+        return
+    with _fresh_lock:
+        _fresh_depth += 1
+        if _fresh_depth == 1:
+            _fresh_restore = jax.config.jax_enable_compilation_cache
+            jax.config.update("jax_enable_compilation_cache", False)
+            _jcc.reset_cache()
+    try:
+        yield
+    finally:
+        with _fresh_lock:
+            _fresh_depth -= 1
+            if _fresh_depth == 0:
+                jax.config.update("jax_enable_compilation_cache",
+                                  _fresh_restore)
+                _jcc.reset_cache()
 
 
 # ---------------------------------------------------------------------------
@@ -237,13 +265,8 @@ def enable_persistent_cache(cache_dir=None, *, min_compile_time_s=0.0):
 def backend_fingerprint():
     """Backend identity an executable is bound to: jax version + platform
     + device kind. A manifest from another backend must never load."""
-    try:
-        dev = jax.devices()[0]
-        plat = dev.platform
-        kind = getattr(dev, "device_kind", "?")
-    except Exception:
-        plat, kind = "?", "?"
-    return f"jax-{jax.__version__}/{plat}/{kind}"
+    dev = jax.devices()[0]
+    return f"jax-{jax.__version__}/{dev.platform}/{dev.device_kind}"
 
 
 def model_fingerprint(net):
@@ -303,9 +326,12 @@ class WarmManifest:
     to ONE (model fingerprint, backend fingerprint) pair.
 
     ``put`` serializes a compiled executable
-    (``jax.experimental.serialize_executable``) into the manifest;
-    ``load_executable`` deserializes one back — every interaction counts
-    into ``compile_cache_total``. ``save``/``load`` round-trip the whole
+    (``jax.experimental.serialize_executable``) into the manifest together
+    with the ids of the devices it was compiled for; ``load_executable``
+    deserializes it back ONTO THOSE DEVICES (jax 0.9.0 otherwise loads over
+    every visible device, and a one-device executable then dies at call
+    time expecting one shard per device) — every interaction counts into
+    ``compile_cache_total``. ``save``/``load`` round-trip the whole
     manifest as a zip (one entry per executable + a JSON header), and
     ``to_bytes``/``from_bytes`` embed it inside a checkpoint bundle
     (utils/serialization.save_bundle)."""
@@ -313,7 +339,8 @@ class WarmManifest:
     def __init__(self, model_fp=None, backend_fp=None):
         self.model_fp = model_fp
         self.backend_fp = backend_fp or backend_fingerprint()
-        self._entries = {}  # (kind, signature) -> pickled (payload, trees)
+        # (kind, signature) -> pickled (payload, in_tree, out_tree, device ids)
+        self._entries = {}
         self._mlock = threading.Lock()
 
     @classmethod
@@ -346,20 +373,18 @@ class WarmManifest:
 
     def put(self, kind, signature, compiled):
         """Serialize ``compiled`` under (kind, signature). Returns True on
-        success; a non-serializable executable (backend quirk) is counted
-        and skipped — the manifest never hard-fails a working compile.
-
-        The blob is VERIFIED by deserializing it once before it is kept:
-        on some jax releases an executable served from the persistent
-        compilation cache serializes cleanly but cannot load back
-        ("Symbols not found") — catching that here turns a warm-restart
-        surprise into a save-time fallback."""
+        success; an executable the backend cannot serialize is counted
+        (``serialize_fail``) and skipped — the manifest never hard-fails a
+        working compile. The blob is not test-loaded here: what makes it
+        runnable after a restart is that callers hand in fresh compiles
+        only (see ``fresh_compile``)."""
         from jax.experimental import serialize_executable as _se
         try:
             payload, in_tree, out_tree = _se.serialize(compiled)
-            blob = pickle.dumps((payload, in_tree, out_tree),
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
+            blob = pickle.dumps((payload, in_tree, out_tree, device_ids),
                                 protocol=pickle.HIGHEST_PROTOCOL)
-            _se.deserialize_and_load(*pickle.loads(blob))
         except Exception:
             count_event("serialize_fail")
             return False
@@ -369,8 +394,9 @@ class WarmManifest:
         return True
 
     def load_executable(self, kind, signature):
-        """The deserialized executable for (kind, signature), or None
-        (counted as miss / deserialize_fail — the caller live-compiles)."""
+        """The deserialized executable for (kind, signature), loaded onto
+        the devices it was compiled for, or None (counted as miss /
+        deserialize_fail — the caller live-compiles)."""
         with self._mlock:
             blob = self._entries.get((str(kind), str(signature)))
         if blob is None:
@@ -378,8 +404,11 @@ class WarmManifest:
             return None
         from jax.experimental import serialize_executable as _se
         try:
-            payload, in_tree, out_tree = pickle.loads(blob)
-            loaded = _se.deserialize_and_load(payload, in_tree, out_tree)
+            payload, in_tree, out_tree, device_ids = pickle.loads(blob)
+            by_id = {d.id: d for d in jax.devices()}
+            loaded = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except Exception:
             count_event("deserialize_fail")
             return None
@@ -500,13 +529,15 @@ def aot_compile(jitted, *args, manifest=None, kind="jit", signature=None,
         if ex is not None:
             _note_step_peak(kind, ex)
             return ex, "manifest"
-    with warnings.catch_warnings():
+    write_back = manifest is not None and serialize_back
+    with warnings.catch_warnings(), (
+            fresh_compile() if write_back else contextlib.nullcontext()):
         # donated buffers rarely match an output shape; the warning is
         # per-compile noise, the donation is still wanted (see nn/fused)
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
         ex = jitted.lower(*args).compile()
-    if manifest is not None and serialize_back:
+    if write_back:
         manifest.put(kind, sig, ex)
     _note_step_peak(kind, ex)
     return ex, "compile"

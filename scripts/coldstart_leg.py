@@ -7,8 +7,9 @@ A leg measures the realized cold-start tax — wall time from process start
 train dispatch / first served inference request — with the instant-restart
 tier on:
 
-* both modes point jax's persistent compilation cache at the shared
-  ``<workdir>/xla_cache`` (the cold leg POPULATES it, the fleet story);
+* both modes share one persistent compilation cache: bench.py starts
+  every leg with ``JAX_COMPILATION_CACHE_DIR=<workdir>/xla_cache`` (the
+  cold leg POPULATES it, the fleet story);
 * the cold leg runs with a fresh warm manifest attached and SAVES the
   instant-restart artifact (train: ``utils.serialization.save_bundle``;
   serve: ``ServingEngine.save_warm_manifest``);
@@ -107,10 +108,20 @@ def main(argv):
     from deeplearning4j_tpu import telemetry
     from deeplearning4j_tpu.utils import compile_cache as cc
 
+    from deeplearning4j_tpu.telemetry import devices
+
     telemetry.enable()  # the gate reads compile_cache_total counters
-    cc.enable_persistent_cache(os.path.join(workdir, "xla_cache"))
+    cache_dir = cc.enable_persistent_cache()
+    # each leg holds the device itself (the parent stays off jax), so it
+    # is the leg that knows and refuses the platform
+    device = devices.device_stamp()
+    if device["platform"] != "tpu" \
+            and os.environ.get("BENCH_PREFLIGHT") != "1":
+        sys.exit(f"coldstart leg: platform is {device['platform']!r}, not "
+                 "'tpu' (set BENCH_PREFLIGHT=1 for the CPU counter gate)")
     out = (_train_leg if kind == "train" else _serve_leg)(mode, workdir)
-    out.update(kind=kind, mode=mode, events=cc.event_counts())
+    out.update(kind=kind, mode=mode, events=cc.event_counts(),
+               compile_cache_dir=cache_dir, device=device)
     print(json.dumps(out, default=str), flush=True)
     return 0
 

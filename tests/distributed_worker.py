@@ -11,12 +11,9 @@ via local-mode Spark clusters (BaseSparkTest.java:89).
 Failure protocol (ISSUE 15 satellite): an init that cannot reach the
 coordinator exits ``procutil.INIT_FAILED_RC`` with ONE JSON error line
 (carrying the ``distributed_init_total`` outcome counters) instead of
-hanging into the spawner's 300 s communicate timeout; a backend that
-joined the runtime but cannot EXECUTE multi-process computations (jax
-0.4.37's CPU client) reports ``{"gspmd_unsupported": true}`` and exits 0
-so the spawner can skip instead of fail — the hostfleet tier's host-
-mediated exchange is the CPU-preflight path for real cross-process
-training.
+hanging into the spawner's 300 s communicate timeout. (The installed
+jax's CPU client executes cross-process computations over Gloo, so the
+training leg itself runs here and is not skipped.)
 """
 
 import json
@@ -81,18 +78,7 @@ def main():
     mesh = Mesh(np.array(jax.devices()), ("data",))
     master = SharedTrainingMaster(mesh, batch_size_per_worker=8,
                                   threshold=None)  # exact psum mode
-    try:
-        loss = master.execute_training(net, x, y, epochs=3)
-    except Exception as e:  # noqa: BLE001 — classify, don't wedge/crash raw
-        if "Multiprocess computations aren't implemented" in str(e):
-            # the runtime joined fine; the BACKEND can't execute a
-            # cross-process computation (jax 0.4.37 CPU client) — a
-            # clean, machine-readable skip, not a failure
-            print(json.dumps({"gspmd_unsupported": True, "process": pid,
-                              "n_devices": len(jax.devices()),
-                              "init": init_series()}), flush=True)
-            return
-        raise
+    loss = master.execute_training(net, x, y, epochs=3)
 
     leaves = jax.tree_util.tree_leaves(net.params)
     checksum = float(sum(np.abs(np.asarray(l)).sum() for l in leaves))

@@ -1,0 +1,102 @@
+"""chip_smoke.py at toy size on the CPU: the phase bodies are the same code
+the chip runs at full width (kernels in interpret mode here), and the script
+itself must refuse to report anything without a chip."""
+
+import importlib.util
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+from deeplearning4j_tpu import serving, telemetry
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_spec = importlib.util.spec_from_file_location(
+    "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+smoke = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(smoke)
+
+TOY = dict(vocab=64, n_layers=1, d_model=32, n_heads=2, seq_len=16)
+
+
+@pytest.fixture(autouse=True)
+def _isolate():
+    telemetry.reset()
+    yield
+    serving.registry.reset()
+    telemetry.reset()
+    telemetry.disable()
+
+
+def _last_json(capsys):
+    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def test_train_then_serve_phases_at_toy_size(tmp_path, capsys):
+    net = smoke.build_net(**TOY)
+    # what the chip run relies on: a failed check raises. On the CPU the
+    # compiled step holds no tpu_custom_call, so demanding one must fail.
+    with pytest.raises(AssertionError, match="tpu_custom_call"):
+        smoke.train_phase(net, vocab=TOY["vocab"], seq_len=TOY["seq_len"],
+                          batch=2, steps=1, k=2, workdir=str(tmp_path),
+                          flash_calls=1)
+    doc = smoke.train_phase(net, vocab=TOY["vocab"], seq_len=TOY["seq_len"],
+                            batch=2, steps=2, k=2, workdir=str(tmp_path))
+    assert doc == _last_json(capsys)
+    assert doc["phase"] == "train" and doc["platform"] == "cpu"
+    assert len(doc["losses_k1"]) == 4 and len(doc["losses_k2"]) == 2
+    assert doc["compile_cache_events"]["hit"] >= 1
+    assert doc["tpu_custom_calls"] == {"k1": 0, "k2": 0}  # gate closed
+
+    doc = smoke.serve_phase(net, vocab=TOY["vocab"], batch_buckets=(1, 2),
+                            seq_buckets=(8, 16), lengths=(8, 16, 5, 11),
+                            tol=1e-5)
+    assert doc["phase"] == "serve"
+    assert doc["aot"]["warmed"] == 4
+    assert doc["aot"]["lazy_compiles"] == doc["aot"]["jit_serves"] == 0
+
+
+def test_kernels_phase_in_interpret_mode():
+    doc = smoke.kernels_phase(interpret=True,
+                              tol={"fwd": 1e-4, "bwd": 1e-4})
+    names = {r["kernel"] for r in doc["results"]}
+    assert {"flash_causal", "flash_padding_mask", "flash_block",
+            "lstm_resident", "lstm_resident_peephole_masked",
+            "lstm_tiled_masked"} == names
+
+
+def test_multichip_phase_on_the_virtual_mesh(tmp_path, eight_devices):
+    doc = smoke.multichip_phase(
+        lambda: smoke.build_net(**TOY), vocab=TOY["vocab"],
+        seq_len=TOY["seq_len"], n_chips=4, global_batch=8, parity_batch=4,
+        steps=2, loss_tol=1e-5, batch_buckets=(1, 2), seq_buckets=(8, 16),
+        lengths=(16, 5), serve_tol=1e-5, workdir=str(tmp_path))
+    assert doc["buffers_on_devices"] == [0, 1, 2, 3]
+    assert doc["opt_state_leaves_sharded"] > 0
+    assert doc["warm_manifest_round_trips"] == {
+        "mesh": {"warmed": 2, "manifest_hits": 2},
+        "one_device": {"warmed": 4, "manifest_hits": 4}}
+
+
+def test_script_refuses_without_a_chip():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       capture_output=True, text=True, timeout=120, env=env)
+    assert r.returncode != 0
+    assert r.stdout.strip() == "", "printed a result without a chip"
+    assert "not 'tpu'" in r.stderr
+
+
+def test_multichip_needs_four_devices():
+    with pytest.raises(AssertionError, match="needs 16 devices"):
+        smoke.multichip_phase(
+            lambda: smoke.build_net(**TOY), vocab=64, seq_len=16,
+            n_chips=16, global_batch=16, parity_batch=16, steps=1,
+            loss_tol=1, batch_buckets=(1,), seq_buckets=(16,),
+            lengths=(16,), serve_tol=1, workdir="/nonexistent")
+    assert len(jax.devices()) < 16

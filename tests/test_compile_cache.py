@@ -29,19 +29,10 @@ from deeplearning4j_tpu.utils.serialization import (load_bundle, load_model,
 
 @pytest.fixture(autouse=True)
 def _isolate():
+    # the persistent-cache directory itself is isolated per test by
+    # conftest._isolated_compile_cache
     telemetry.reset()
-    prev = jax.config.jax_compilation_cache_dir
     yield
-    # un-point the persistent cache (tmp_path dirs die with the test) and
-    # drop its in-memory layer: on this jax a CACHE-SERVED executable
-    # serializes but cannot deserialize, which would poison later tests
-    jax.config.update("jax_compilation_cache_dir", prev)
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _jcc)
-        _jcc.reset_cache()
-    except Exception:
-        pass
     telemetry.reset()
     telemetry.disable()
 
@@ -84,32 +75,59 @@ def _counter_total(name, **labels):
 # ---------------------------------------------------------------------------
 
 class TestPersistentCache:
-    def test_enable_creates_dir_and_sets_config(self, tmp_path):
-        d = str(tmp_path / "xla_cache")
-        out = cc.enable_persistent_cache(d)
-        assert out == os.path.abspath(d)
-        assert os.path.isdir(d)
-        assert jax.config.jax_compilation_cache_dir == os.path.abspath(d)
+    """One rule: $JAX_COMPILATION_CACHE_DIR when set (and then no directory
+    is set in code), else <checkout>/.jax_cache."""
 
-    def test_env_var_default(self, tmp_path, monkeypatch):
+    def test_env_dir_wins_and_no_directory_is_set_in_code(self, tmp_path,
+                                                          monkeypatch):
         d = str(tmp_path / "envcache")
-        monkeypatch.setenv(cc.ENV_CACHE_DIR, d)
-        assert cc.enable_persistent_cache() == os.path.abspath(d)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+        before = jax.config.jax_compilation_cache_dir
+        assert cc.enable_persistent_cache() == d
+        assert jax.config.jax_compilation_cache_dir == before
 
-    def test_noop_without_dir_or_env(self, monkeypatch):
-        monkeypatch.delenv(cc.ENV_CACHE_DIR, raising=False)
-        assert cc.enable_persistent_cache() is None
+    def test_default_is_fixed_dir_under_the_checkout(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cc.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        assert cc.enable_persistent_cache() == cc.DEFAULT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == cc.DEFAULT_CACHE_DIR
 
-    def test_compiles_land_on_disk(self, tmp_path):
-        cc.enable_persistent_cache(str(tmp_path / "xc"))
+    def test_thresholds_open_so_small_compiles_land_on_disk(self, tmp_path):
+        d = str(tmp_path / "xc")
+        # what the environment variable does when set before jax starts
+        jax.config.update("jax_compilation_cache_dir", d)
+        cc.enable_persistent_cache()
 
         @jax.jit
         def f(x):
             return x * 3.0
         f(jnp.ones(7)).block_until_ready()
-        cached = [p for p in os.listdir(str(tmp_path / "xc"))
-                  if "cache" in p or p.startswith("jit")]
-        assert cached, "no cache entry written for a fresh compile"
+        assert os.listdir(d), "no cache entry written for a fresh compile"
+
+    def test_manifest_bound_compile_bypasses_a_warm_cache(self, tmp_path):
+        """An executable served from the persistent cache cannot be
+        re-serialized on the CPU backend (loads, then NOT_FOUND at call):
+        aot_compile with a manifest must compile fresh even when the cache
+        already holds the program, and the restored executable must RUN."""
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path / "xc"))
+        cc.enable_persistent_cache()
+
+        def f(x, w):
+            return jnp.tanh(x @ w).sum()
+        x, w = jnp.ones((8, 16)), jnp.ones((16, 4))
+        jax.jit(f).lower(x, w).compile()   # populate the cache  # graftlint: disable=R3
+        n = len(os.listdir(str(tmp_path / "xc")))
+        assert n > 0
+        man = cc.WarmManifest(model_fp="t")
+        cc.aot_compile(jax.jit(f), x, w, manifest=man, kind="t")
+        assert len(os.listdir(str(tmp_path / "xc"))) == n  # not written
+        restored = cc.WarmManifest.from_bytes(man.to_bytes()) \
+            .load_executable("t", cc.full_signature(cc.signature_of((x, w))))
+        assert float(restored(x, w)) == float(f(x, w))
+        # the cache is back on afterwards
+        jax.jit(lambda a: a - 7.0)(jnp.ones(3)).block_until_ready()
+        assert len(os.listdir(str(tmp_path / "xc"))) > n
 
 
 # ---------------------------------------------------------------------------
@@ -481,8 +499,7 @@ class TestWarmRestartZeroCompiles:
         wm = str(tmp_path / "wm.zip")
         args = ["serve", "--model-path", mp, "--max-batch", "4",
                 "--buckets", "1,4", "--port", "0", "--smoke", "2",
-                "--warm-manifest", wm,
-                "--compile-cache", str(tmp_path / "xc")]
+                "--warm-manifest", wm]
         assert main(list(args)) == 0
         assert os.path.exists(wm)
         capsys.readouterr()

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.ops import lstm_pallas
+from deeplearning4j_tpu.ops import attention_pallas, lstm_pallas
 
 
 def _ref_scan(xz, wh, h0, c0):
@@ -662,3 +662,179 @@ class TestMaskedAndTiledPeepholeLstm:
         for p, r, name in zip(gp, gr, ("dxz", "dwh", "dh0", "dc0")):
             np.testing.assert_allclose(np.asarray(p), np.asarray(r),
                                        atol=5e-4, err_msg=name)
+
+
+# ---------------------------------------------------------------------------
+# TPU lowering, checked on the CPU (ISSUE 21): interpret mode checks the
+# arithmetic of a kernel but none of the TPU's block-shape rules, so a spec
+# the chip refuses (the masked LSTM's (1, B) block of a [T, B] array) passed
+# every interpret test and was default-dispatched on the chip. Lowering for
+# the TPU platform runs the Pallas->Mosaic lowering that applies those rules
+# and needs no chip.
+# ---------------------------------------------------------------------------
+
+def _lower_for_tpu(fn, *args, **jit_kw):
+    # the chip runs with 32-bit defaults; conftest's float64 mode would put
+    # f64 constants into the kernel body, which Mosaic refuses to cast
+    with jax.enable_x64(False):
+        return jax.jit(fn, **jit_kw).trace(*args).lower(
+            lowering_platforms=("tpu",))
+
+
+def _lstm_loss(peephole, masked):
+    def loss(xz, wh, h0, c0, wp, mask):
+        hs, (_, cT) = lstm_pallas.fused_sequence_padded(
+            xz, wh, h0, c0, wp=wp if peephole else None,
+            mask=mask if masked else None)
+        return jnp.sum(hs.astype(jnp.float32)) + jnp.sum(
+            cT.astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1))
+
+
+class TestDefaultDispatchKernelsLowerForTpu:
+    """Every Pallas kernel the default dispatch can reach, lowered for the
+    TPU platform at the dispatch gates' real shapes (bf16, fwd + bwd)."""
+
+    @pytest.mark.parametrize("t,b,hsz,peephole,masked", [
+        (128, 64, 512, False, False),     # resident, the bench lstm shape
+        (128, 64, 512, True, False),      # GravesLSTM
+        (128, 64, 512, False, True),      # the spec the chip refused
+        (128, 64, 512, True, True),
+        (128, 12, 512, True, True),       # B off the 8-sublane multiple
+        (128, 64, 200, True, True),       # lane-padded H
+        (32, 64, 1024, False, False),     # tiled
+        (32, 64, 1024, True, True),
+        (16, 8, 2048, False, True),
+    ])
+    def test_lstm(self, t, b, hsz, peephole, masked):
+        bf = jnp.bfloat16
+        args = (jnp.zeros((t, b, 4 * hsz), bf), jnp.zeros((hsz, 4 * hsz), bf),
+                jnp.zeros((b, hsz), bf), jnp.zeros((b, hsz), bf),
+                jnp.zeros((3, hsz), bf), jnp.ones((t, b), jnp.float32))
+        assert lstm_pallas.supported(
+            (b, t, 32), hsz, peephole=peephole,
+            mask=np.ones((b, t)) if masked else None,
+            gate_activation="sigmoid", activation="tanh")
+        text = _lower_for_tpu(_lstm_loss(peephole, masked), *args).as_text()
+        assert "tpu_custom_call" in text
+
+    @pytest.mark.parametrize("b,t,h,d,causal,masked", [
+        (4, 4096, 8, 64, True, False),    # the longcontext shape
+        (1, 2048, 4, 128, True, False),   # full-lane head dim
+        (4, 1024, 8, 64, False, True),    # [B, Tk] key-padding mask
+        (2, 3000, 8, 64, True, True),     # ragged T, padded inside
+    ])
+    def test_flash_attention(self, b, t, h, d, causal, masked):
+        q = jnp.zeros((b, t, h, d), jnp.bfloat16)
+        mask = jnp.ones((b, t), jnp.float32) if masked else None
+        assert attention_pallas.supported(q.shape, q.shape, mask, q.dtype)
+
+        def loss(q, k, v):
+            return jnp.sum(attention_pallas.flash_attention(
+                q, k, v, mask=mask, causal=causal).astype(jnp.float32))
+        text = _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)),
+                              q, q, q).as_text()
+        assert "tpu_custom_call" in text
+
+    def test_ring_attention_block(self):
+        q = jnp.zeros((2, 1024, 8, 64), jnp.bfloat16)
+
+        def loss(q, k, v):
+            out, lse = attention_pallas.flash_attention_block(
+                q, k, v, True, 0.125, False)
+            return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+        text = _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)),
+                              q, q, q).as_text()
+        assert "tpu_custom_call" in text
+
+    def test_batch_sharded_operands_need_the_declared_mesh(self,
+                                                           eight_devices):
+        """GSPMD cannot partition a Mosaic kernel: with batch-sharded
+        operands the lowering is refused unless the caller declares its
+        mesh (ops/spmd.py) — what ParallelTrainer and
+        BucketedForward(mesh=) do around their traces."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from deeplearning4j_tpu.ops import spmd
+        mesh = Mesh(np.array(eight_devices[:4]), ("data",))
+        sh = NamedSharding(mesh, P("data"))
+        q = jax.ShapeDtypeStruct((8, 1024, 8, 64), jnp.bfloat16, sharding=sh)
+
+        def attn(q, k, v):
+            return attention_pallas.flash_attention(q, k, v, causal=True)
+
+        def declared(q, k, v):
+            with spmd.kernel_mesh(mesh):
+                return attn(q, k, v)
+        with pytest.raises(NotImplementedError, match="shard_map"):
+            _lower_for_tpu(attn, q, q, q)
+        assert "tpu_custom_call" in _lower_for_tpu(declared, q, q,
+                                                   q).as_text()
+
+        xz = jax.ShapeDtypeStruct(
+            (16, 32, 2048), jnp.bfloat16,
+            sharding=NamedSharding(mesh, P(None, "data")))
+        hc = jax.ShapeDtypeStruct((32, 512), jnp.bfloat16, sharding=sh)
+        wh = jnp.zeros((512, 2048), jnp.bfloat16)
+        mask = jnp.ones((16, 32), jnp.float32)
+
+        def lstm(xz, wh, h0, c0, mask):
+            with spmd.kernel_mesh(mesh):
+                return lstm_pallas.fused_sequence_padded(xz, wh, h0, c0,
+                                                         mask=mask)
+        assert "tpu_custom_call" in _lower_for_tpu(lstm, xz, wh, hc, hc,
+                                                   mask).as_text()
+
+
+class TestKernelsPerBatchShard:
+    """ops/spmd.py numerics: under a declared mesh the kernels run once per
+    batch shard and agree with the unsharded call (interpret mode)."""
+
+    def test_flash_with_padding_mask(self, eight_devices):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from deeplearning4j_tpu.ops import spmd
+        mesh = Mesh(np.array(eight_devices[:4]), ("data",))
+        rs = np.random.RandomState(5)
+        q, k, v = (jnp.asarray(rs.randn(8, 128, 2, 16).astype(np.float32))
+                   for _ in range(3))
+        mask = jnp.asarray((np.arange(128)[None, :]
+                            < rs.randint(64, 129, 8)[:, None])
+                           .astype(np.float32))
+
+        def attn(q, k, v, mask):
+            return attention_pallas.flash_attention(
+                q, k, v, mask=mask, causal=True, interpret=True)
+
+        def sharded(q, k, v, mask):
+            with spmd.kernel_mesh(mesh):
+                return attn(q, k, v, mask)
+        sh = NamedSharding(mesh, P("data"))
+        got = jax.jit(sharded, in_shardings=(sh,) * 4)(q, k, v, mask)
+        assert got.sharding.spec[0] == "data"
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(attn(q, k, v, mask)),
+                                   atol=1e-6)
+
+    def test_masked_peephole_lstm(self, eight_devices):
+        from jax.sharding import Mesh
+        from deeplearning4j_tpu.ops import spmd
+        mesh = Mesh(np.array(eight_devices[:4]), ("data",))
+        xz, wh, h0, c0 = _inputs(T=4, B=16, H=128, seed=31)
+        rs = np.random.RandomState(31)
+        wp = jnp.asarray(rs.randn(3, 128).astype(np.float32) * 0.1)
+        mask = jnp.asarray((np.arange(4)[:, None]
+                            < rs.randint(1, 5, 16)[None, :])
+                           .astype(np.float32))
+
+        def run(xz, wh, h0, c0, wp, mask):
+            return lstm_pallas.fused_sequence_padded(
+                xz, wh, h0, c0, wp=wp, mask=mask, interpret=True)
+
+        def sharded(*a):
+            with spmd.kernel_mesh(mesh):
+                return run(*a)
+        hs, (hT, cT) = jax.jit(sharded)(xz, wh, h0, c0, wp, mask)
+        hs_r, (hT_r, cT_r) = _ref_scan_any(xz, wh, h0, c0, wp=wp, mask=mask)
+        np.testing.assert_allclose(np.asarray(hs), np.asarray(hs_r),
+                                   atol=1e-5)
+        np.testing.assert_allclose(np.asarray(cT), np.asarray(cT_r),
+                                   atol=1e-5)

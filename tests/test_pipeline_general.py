@@ -184,7 +184,7 @@ class TestOneFOneB:
         """The f/g custom-VJP pair: g backward is identity, f backward is
         psum — the pattern that makes inside-body vjp match whole-
         shard_map AD (pinned independently of the LM)."""
-        from deeplearning4j_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from deeplearning4j_tpu.parallel.composed import (id_psum_bwd,
                                                           psum_id_bwd)

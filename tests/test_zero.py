@@ -41,6 +41,21 @@ def _trainer(mode, mesh, seed=6, **kw):
         shard_params="fsdp" if mode == "fsdp" else None, **kw).init()
 
 
+def _assert_same_math(actual, desired):
+    """The sharded layouts are re-expressions of the same math, pinned to
+    1e-6 relative rather than to the bit. They were bit-exact under jax
+    0.4.37; under jax 0.9.0 the XLA:CPU backend compiles the elementwise
+    Adam update differently for a 1/8 shard than for the full tensor: with
+    bit-identical gradients (``v`` still agrees) the first moment
+    ``b1*m + (1-b1)*g`` comes out one f32 ulp apart at the second step,
+    and the parameters follow (max abs 2.98e-8, max relative 4.2e-7 after
+    five steps). The gradient collectives are not the cause — all-reduce
+    and reduce-scatter + all-gather of the same shards agree to the bit
+    on this backend."""
+    np.testing.assert_allclose(np.asarray(actual), np.asarray(desired),
+                               rtol=1e-6, atol=1e-7)
+
+
 def _stream_net(seed=6, n_in=8, hidden=64, n_out=4, depth=4):
     """A net WITH a homogeneous trunk: entry Dense(n_in->hidden) +
     ``depth`` identical Dense(hidden->hidden) blocks + output head —
@@ -199,8 +214,8 @@ class TestZeroDefaults:
 
 
 class TestZeroParity:
-    """The layouts are re-expressions of the same math: bit-exact, not
-    approximately equal."""
+    """The layouts are re-expressions of the same math (see
+    ``_assert_same_math`` for how tightly that is pinned, and why)."""
 
     def test_zero1_and_fsdp_bit_exact_vs_replicated(self, eight_devices):
         mesh = make_mesh(MeshSpec(data=8, model=1), devices=eight_devices)
@@ -208,12 +223,11 @@ class TestZeroParity:
         ts = {m: _trainer(m, mesh) for m in ("replicated", "zero1", "fsdp")}
         for _ in range(5):
             losses = {m: float(t.step(x, y)) for m, t in ts.items()}
-        assert losses["zero1"] == losses["replicated"]
-        assert losses["fsdp"] == losses["replicated"]
+        _assert_same_math(losses["zero1"], losses["replicated"])
+        _assert_same_math(losses["fsdp"], losses["replicated"])
         w_ref = np.asarray(ts["replicated"].params[0]["W"])
         for m in ("zero1", "fsdp"):
-            np.testing.assert_array_equal(np.asarray(ts[m].params[0]["W"]),
-                                          w_ref)
+            _assert_same_math(ts[m].params[0]["W"], w_ref)
 
     def test_fused_k4_zero_bit_exact_vs_k1_replicated(self, eight_devices):
         """Tentpole (b): the fused lax.scan engine carries the SHARDED
@@ -227,8 +241,7 @@ class TestZeroParity:
         for mode in ("zero1", "fsdp"):
             tr = _trainer(mode, mesh)
             tr.fit(x, y, batch_size=16, epochs=2, steps_per_dispatch=4)
-            np.testing.assert_array_equal(np.asarray(tr.params[0]["W"]),
-                                          w_ref)
+            _assert_same_math(tr.params[0]["W"], w_ref)
             # the carried opt state is still in the sharded layout
             m = tr.opt_state["m"][0]["W"]
             assert m.sharding.spec[0] == "data"
@@ -309,10 +322,9 @@ class TestStreamedFSDP:
               for m in ("replicated", "fsdp", "fsdp_stream")}
         for _ in range(5):
             losses = {m: float(t.step(x, y)) for m, t in ts.items()}
-        assert losses["fsdp_stream"] == losses["replicated"]
+        _assert_same_math(losses["fsdp_stream"], losses["replicated"])
         w_ref = np.asarray(ts["replicated"].params[1]["W"])
-        np.testing.assert_array_equal(
-            np.asarray(ts["fsdp_stream"].params[1]["W"]), w_ref)
+        _assert_same_math(ts["fsdp_stream"].params[1]["W"], w_ref)
         # stored layout: trunk weights sharded P('data') between steps
         w = ts["fsdp_stream"].params[1]["W"]
         assert w.sharding.spec[0] == "data"
@@ -344,9 +356,8 @@ class TestStreamedFSDP:
         for _ in range(4):
             lr = float(tr_r.step(x, y))
             ls = float(tr_s.step(x, y))
-        assert lr == ls
-        np.testing.assert_array_equal(np.asarray(tr_s.params[1]["W"]),
-                                      np.asarray(tr_r.params[1]["W"]))
+        _assert_same_math(ls, lr)
+        _assert_same_math(tr_s.params[1]["W"], tr_r.params[1]["W"])
 
     def test_streamed_fused_k4_bit_exact(self, eight_devices):
         """The K-step scan carries the streamed layout: a K=4 dispatch is
@@ -359,8 +370,7 @@ class TestStreamedFSDP:
         w_ref = np.asarray(ref.params[1]["W"])
         tr = _stream_trainer("fsdp_stream", mesh)
         tr.fit(x, y, batch_size=16, epochs=2, steps_per_dispatch=4)
-        np.testing.assert_array_equal(np.asarray(tr.params[1]["W"]),
-                                      w_ref)
+        _assert_same_math(tr.params[1]["W"], w_ref)
         m = tr.opt_state["m"][1]["W"]
         assert m.sharding.spec[0] == "data"
         assert tr.iteration == ref.iteration
@@ -607,7 +617,7 @@ class TestDistributedZero:
         pmean (psum_scatter + all_gather IS the all-reduce, leaf shapes
         restored incl. a non-divisible tail)."""
         from deeplearning4j_tpu.parallel import distributed as D
-        from deeplearning4j_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         mesh = make_mesh(MeshSpec(data=8, model=1), devices=eight_devices)
         tree = {"a": jnp.arange(24.0).reshape(8, 3),   # 24 % 8 == 0
