@@ -357,9 +357,9 @@ class StepDriver:
     prefetch once and re-enter it on epoch reset.
 
     ``instrumented=False`` is the ParallelTrainer profile: the score
-    pipeline feeds its 3-arg listeners only — no spans, traces, flight
-    records or health monitor — exactly the telemetry surface that loop
-    has always had.
+    pipeline feeds its 3-arg listeners only — the round's boundary spans
+    (``fit.round``, ``fit.next``, ``fit.dispatch``, ``fit.sync``) but no
+    causal traces, flight records or health monitor.
     """
 
     def __init__(self, net, batch_factory, *, k=1, batch_size=None,
@@ -470,24 +470,28 @@ class StepDriver:
         return self._run_round(k_dispatches)
 
     def _run_round(self, k_dispatches=None):
-        if self._it is None:
-            self.start_epoch()
-        rr = RoundResult()
-        while k_dispatches is None or rr.dispatches < k_dispatches:
-            try:
-                item = next(self._it)
-            except StopIteration:
-                rr.epoch_done = True
-                break
-            steps = (self._dispatch_one(item) if self.instrumented
-                     else self._dispatch_lite(item))
-            if steps == 0:
-                continue  # skipped (lite non-divisible batch)
-            rr.dispatches += 1
-            rr.steps += steps
-        if rr.epoch_done:
-            self.end_epoch()
-        return rr
+        with _tm.span("fit.round"):
+            if self._it is None:
+                self.start_epoch()
+            rr = RoundResult()
+            while k_dispatches is None or rr.dispatches < k_dispatches:
+                try:
+                    # the wait on the iterator: batch assembly, or the
+                    # prefetch queue
+                    with _tm.span("fit.next"):
+                        item = next(self._it)
+                except StopIteration:
+                    rr.epoch_done = True
+                    break
+                steps = (self._dispatch_one(item) if self.instrumented
+                         else self._dispatch_lite(item))
+                if steps == 0:
+                    continue  # skipped (lite non-divisible batch)
+                rr.dispatches += 1
+                rr.steps += steps
+            if rr.epoch_done:
+                self.end_epoch()
+            return rr
 
     def run(self, epochs):
         """The classic fit loop: N epochs to exhaustion under the
@@ -561,7 +565,8 @@ class StepDriver:
             with _tm.span("fit.step", **span_kw):
                 if eng.fused:
                     eng._n_real = n_real
-                loss, hb = eng.dispatch(prep)
+                with _tm.span("fit.dispatch"):
+                    loss, hb = eng.dispatch(prep)
                 if want_score:
                     # queue this dispatch, resolve the previous one INSIDE
                     # the span: the blocking fetch overlaps the dispatch
@@ -575,16 +580,17 @@ class StepDriver:
                                          else tctx.trace_id)}
                     if eng.fused:
                         meta["k"] = n_real
-                    t_res = time.perf_counter()
-                    resolved = self._pipe.push(loss, meta)
+                    with _tm.span("fit.score_fetch") as fetch:
+                        resolved = self._pipe.push(loss, meta)
                     if resolved is not None:
                         prev_t = resolved[1].get("trace")
                         if prev_t is not None:
                             # the one-late fetch of dispatch i-1 happens
                             # HERE, overlapped by dispatch i — record it
-                            # in ITS trace, not this one's
-                            prev_t.add_span("train.score_fetch", t_res,
-                                            time.perf_counter())
+                            # in ITS trace, not this one's, from the
+                            # span's own pair of clock reads
+                            prev_t.add_span("train.score_fetch",
+                                            *fetch.interval())
         if meta is None and tctx is not None:
             tctx.finish()  # nobody resolves scores
         if meta is not None:
@@ -611,10 +617,13 @@ class StepDriver:
         return n_real
 
     def _dispatch_lite(self, item):
-        """One ParallelTrainer dispatch: no spans/traces/flight — the
-        score pipeline feeds the trainer's 3-arg listeners one step
-        late, exactly as that loop always has."""
-        out = self.engine.dispatch(item)
+        """One ParallelTrainer dispatch: the ``fit.dispatch`` span (and
+        ``fit.next`` / ``fit.round`` from the round loop) but no causal
+        traces, flight records or step metrics — the score pipeline feeds
+        the trainer's 3-arg listeners one step late, exactly as that loop
+        always has."""
+        with _tm.span("fit.dispatch"):
+            out = self.engine.dispatch(item)
         if out is None:
             return 0  # skipped batch (counted by the engine)
         loss, n, meta = out
@@ -636,11 +645,12 @@ class StepDriver:
         under ``policy='raise'`` a sick round surfaces as
         ``NumericsError`` HERE, one round late (the continuous trainer's
         rollback trigger)."""
-        tail = self._pipe.flush()
-        if tail is not None:
-            self._emit(tail)
-        if self._use_health:
-            self._hm.flush(apply_policy=apply_policy)
+        with _tm.span("fit.sync"):
+            tail = self._pipe.flush()
+            if tail is not None:
+                self._emit(tail)
+            if self._use_health:
+                self._hm.flush(apply_policy=apply_policy)
 
     def checkpoint(self, path, *, buckets=None, save_updater=True):
         """``sync()`` then write one resumable ``save_bundle`` unit —

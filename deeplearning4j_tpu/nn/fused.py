@@ -45,6 +45,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.nn import scopes as _scopes
 from deeplearning4j_tpu.utils import compile_cache as _cc
 
 __all__ = ["make_train_steps", "fit_fused"]
@@ -210,7 +211,7 @@ def make_train_steps(net, k, donate=True, jit=True, with_health=False,
     donate_argnums = (0, 1, 2) if donate else ()
     if donate and donate_batch:
         donate_argnums += (3, 4, 7)  # the consumed super-batch
-    fused = jax.jit(steps_fn, donate_argnums=donate_argnums)
+    fused = jax.jit(_scopes.stamped(steps_fn), donate_argnums=donate_argnums)
     if manifest is not None:
         # warm restart: the K-step scan executable deserializes from the
         # checkpoint's manifest (utils/compile_cache) instead of paying

@@ -33,6 +33,7 @@ from deeplearning4j_tpu.telemetry import flight as _flight
 from deeplearning4j_tpu.telemetry import health as _health
 from deeplearning4j_tpu.nn import gradnorm as _gradnorm
 from deeplearning4j_tpu.nn import listeners as _listeners
+from deeplearning4j_tpu.nn import scopes as _scopes
 from deeplearning4j_tpu.nn import updaters as _updaters
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
 from deeplearning4j_tpu.nn.layers import base as _base_layers
@@ -634,9 +635,10 @@ class ComputationGraph:
             for k, n in enumerate(names):
                 v = self._defs[n]
                 xs = [local[i] for i in v.inputs]
-                local[n], ns[n] = v.vertex.apply(
-                    gp[n], gs[n], xs, train=train and n not in frozen,
-                    rng=subs_[k], mask=m)
+                with _scopes.vertex(n, v.vertex):
+                    local[n], ns[n] = v.vertex.apply(
+                        gp[n], gs[n], xs, train=train and n not in frozen,
+                        rng=subs_[k], mask=m)
             return [local[n] for n in bnd], ns
 
         run = jax.checkpoint(run)
@@ -707,16 +709,20 @@ class ComputationGraph:
                 lm = (label_masks or {}).get(name)
                 if lm is None:
                     lm = _loss_mask_for(mask, labels[name])
-                l_i, preds, st = layer.loss_from_features(
-                    params[name], state[name], x, labels[name], lm,
-                    train=train and name not in frozen)
+                with _scopes.vertex(name, v.vertex), jax.named_scope("loss"):
+                    l_i, preds, st = layer.loss_from_features(
+                        params[name], state[name], x, labels[name], lm,
+                        train=train and name not in frozen)
                 loss = loss + l_i
                 acts[name], new_state[name] = preds, st
             elif (new_carries is not None and isinstance(v.vertex,
                                                          LayerVertex)
                   and v.vertex.has_carry()):
-                acts[name], new_carries[name] = v.vertex.apply_with_carry(
-                    params[name], new_carries.get(name), xs, mask=mask)
+                with _scopes.vertex(name, v.vertex):
+                    acts[name], new_carries[name] = \
+                        v.vertex.apply_with_carry(
+                            params[name], new_carries.get(name), xs,
+                            mask=mask)
             else:
                 # FrozenLayer.java:23: frozen vertices forward in TEST mode
                 # regardless of the network's mode (running-stat BN, no
@@ -729,8 +735,9 @@ class ComputationGraph:
 
                 if self.conf.gradient_checkpointing:
                     run = jax.checkpoint(run)  # remat: HBM for FLOPs
-                acts[name], new_state[name] = run(
-                    params[name], state[name], xs, sub, mask)
+                with _scopes.vertex(name, v.vertex):
+                    acts[name], new_state[name] = run(
+                        params[name], state[name], xs, sub, mask)
                 if labels is not None and name in self.conf.outputs:
                     l_layer = layer if layer is not None else v.vertex
                     if not hasattr(l_layer, "compute_loss"):
@@ -738,8 +745,9 @@ class ComputationGraph:
                     lm = (label_masks or {}).get(name)
                     if lm is None:  # MLN convention, shape-guarded
                         lm = _loss_mask_for(mask, labels[name])
-                    loss = loss + l_layer.compute_loss(acts[name],
-                                                       labels[name], lm)
+                    with jax.named_scope("loss"):
+                        loss = loss + l_layer.compute_loss(
+                            acts[name], labels[name], lm)
         if carries is not None:
             return acts, new_state, loss, new_carries
         return acts, new_state, loss
@@ -769,11 +777,13 @@ class ComputationGraph:
             params, state, inputs, train=train, rng=rng, mask=mask,
             labels=labels, label_masks=label_masks, carries=carries)
         acts, new_state, loss = fwd[:3]
-        for name in self._order:
-            v = self._defs[name]
-            if params[name]:
-                loss = loss + v.vertex.regularization_penalty(params[name])
-        loss, new_state = _base_layers.pop_aux_losses(loss, new_state)
+        with jax.named_scope("loss"):
+            for name in self._order:
+                v = self._defs[name]
+                if params[name]:
+                    loss = loss + v.vertex.regularization_penalty(
+                        params[name])
+            loss, new_state = _base_layers.pop_aux_losses(loss, new_state)
         outs = {o: acts[o] for o in self.conf.outputs}
         if carries is not None:
             return loss, (new_state, outs, fwd[3])
@@ -803,8 +813,6 @@ class ComputationGraph:
                 if isinstance(v.vertex, LayerVertex) and v.vertex.has_carry()}
 
     def make_tbptt_step(self, jit=True):
-        conf = self.conf
-
         def tbptt_step(params, state, opt_state, carries, inputs, labels,
                        step, rng, mask=None):
             carries = jax.tree_util.tree_map(jax.lax.stop_gradient, carries)
@@ -812,16 +820,11 @@ class ComputationGraph:
                 self.loss_fn, has_aux=True)(
                     params, state, inputs, labels, train=True, rng=rng,
                     mask=mask, carries=carries)
-            if conf.gradient_normalization not in (None, "none"):
-                grads = {k: _gradnorm.normalize_layer_grads(
-                    conf.gradient_normalization, g,
-                    conf.gradient_normalization_threshold)
-                    if g else g for k, g in grads.items()}
-            new_params, new_opt = self.apply_update(params, opt_state,
-                                                    grads, step)
+            new_params, new_opt = self.apply_update(
+                params, opt_state, self._normalized(grads), step)
             return new_params, new_state, new_opt, new_carries, loss
 
-        return jax.jit(tbptt_step) if jit else tbptt_step
+        return jax.jit(_scopes.stamped(tbptt_step)) if jit else tbptt_step
 
     @staticmethod
     def _chunk_time(tree, t0, t1):
@@ -915,22 +918,29 @@ class ComputationGraph:
         """Loss + normalized gradients (MultiLayerNetwork.compute_gradients
         contract — the distributed masters insert their gradient exchange
         between this and apply_update)."""
-        conf = self.conf
         (loss, (new_state, _)), grads = jax.value_and_grad(
             self.loss_fn, has_aux=True)(params, state, inputs, labels,
                                         train=True, rng=rng, mask=mask)
-        if conf.gradient_normalization not in (None, "none"):
-            grads = {k: _gradnorm.normalize_layer_grads(
+        return loss, new_state, self._normalized(grads)
+
+    def _normalized(self, grads):
+        """Per-vertex gradient normalisation, the one definition the
+        plain and the TBPTT step share."""
+        conf = self.conf
+        if conf.gradient_normalization in (None, "none"):
+            return grads
+        with jax.named_scope("grad_norm"):
+            return {k: _gradnorm.normalize_layer_grads(
                 conf.gradient_normalization, g,
                 conf.gradient_normalization_threshold)
                 if g else g for k, g in grads.items()}
-        return loss, new_state, grads
 
     def apply_update(self, params, opt_state, grads, step):
-        updates, new_opt = self.conf.updater.update(grads, opt_state, params,
-                                                    step)
-        new_params = jax.tree_util.tree_map(lambda p, u: p + u, params,
-                                            updates)
+        with jax.named_scope("updater"):
+            updates, new_opt = self.conf.updater.update(grads, opt_state,
+                                                        params, step)
+            new_params = jax.tree_util.tree_map(lambda p, u: p + u, params,
+                                                updates)
         return new_params, new_opt
 
     def apply_constraints(self, params, step):
@@ -947,7 +957,8 @@ class ComputationGraph:
             if with_health:
                 # numerics-watchdog bundle, fused into the step (labels the
                 # per-vertex series by vertex name)
-                health = _health.health_stats(grads, params, loss)
+                with jax.named_scope("health"):
+                    health = _health.health_stats(grads, params, loss)
             new_params, new_opt = self.apply_update(params, opt_state, grads,
                                                     step)
             if with_health:
@@ -956,7 +967,8 @@ class ComputationGraph:
 
         if not jit:
             return train_step
-        return jax.jit(train_step, donate_argnums=(0, 1, 2) if donate else ())
+        return jax.jit(_scopes.stamped(train_step),
+                       donate_argnums=(0, 1, 2) if donate else ())
 
     def make_train_steps(self, k, donate=True, jit=True, with_health=False):
         """Fused K-step engine over the graph's train step: one

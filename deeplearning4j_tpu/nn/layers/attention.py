@@ -212,15 +212,18 @@ class TransformerBlock(Layer):
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         from deeplearning4j_tpu.nn import activations as _act
         ln1, mha, ln2 = self._parts()
-        h, _ = ln1.apply(params["ln1"], {}, x)
-        attn, _ = mha.apply(params["mha"], {}, h, mask=mask)
-        x = x + attn
-        h, _ = ln2.apply(params["ln2"], {}, x)
-        b, t, f = h.shape
-        act = _act.get(self.activation)
-        m = act(matmul(h.reshape(b * t, f), params["mlp_W1"]) + params["mlp_b1"])
-        m = matmul(m, params["mlp_W2"]) + params["mlp_b2"]
-        return x + m.reshape(b, t, f), state
+        with jax.named_scope("attn"):
+            h, _ = ln1.apply(params["ln1"], {}, x)
+            attn, _ = mha.apply(params["mha"], {}, h, mask=mask)
+            x = x + attn
+        with jax.named_scope("mlp"):
+            h, _ = ln2.apply(params["ln2"], {}, x)
+            b, t, f = h.shape
+            act = _act.get(self.activation)
+            m = act(matmul(h.reshape(b * t, f), params["mlp_W1"])
+                    + params["mlp_b1"])
+            m = matmul(m, params["mlp_W2"]) + params["mlp_b2"]
+            return x + m.reshape(b, t, f), state
 
     def regularization_penalty(self, params):
         return 0.0

@@ -30,6 +30,7 @@ import numpy as np
 
 from deeplearning4j_tpu.telemetry import health as _health
 from deeplearning4j_tpu.nn import gradnorm as _gradnorm
+from deeplearning4j_tpu.nn import scopes as _scopes
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers import base as _base
@@ -99,46 +100,48 @@ class MultiLayerNetwork:
         bit-exactness contract between the two paths). Returns
         ``(y, new_state_i, rng, next_type)``."""
         layer = self.conf.layers[i]
-        # FrozenLayer.java:23 contract: a frozen layer "behaves as the
-        # layer within it would during TEST regardless of the
-        # training/test mode" — frozen BN normalizes with its running
-        # statistics and does NOT update them; frozen dropout is off
-        l_train = train and i not in set(getattr(self, "frozen_layers", ()))
-        fam = layer.input_family
-        if fam is not None and not isinstance(cur_type, fam):
-            x = _inputs.adapt(x, cur_type, fam)
-            cur_type = _inputs.adapted_type(cur_type, fam)
-        if l_train and layer.dropout > 0.0 and rng is not None:  # graftlint: disable=R2 -- layer is conf metadata picked by a Python int index, never a tracer
-            rng, sub = jax.random.split(rng)
-            from deeplearning4j_tpu.nn.layers.base import dropout_mask
-            x = dropout_mask(sub, x, layer.dropout)
-        kwargs = {}
-        if self._mask_aware[i] and mask is not None \
-                and mask.ndim >= 2:
-            # a 1-d mask is an example-validity mask (shape
-            # bucketing): it has no timestep info to forward into
-            # mask-aware layers, which require [batch, time]
-            kwargs["mask"] = mask
-        if rng is not None:
-            rng, sub = jax.random.split(rng)
-        else:
-            sub = None
-        wn = getattr(layer, "weight_noise", None)
-        if l_train and wn is not None and sub is not None \
-                and layer_params:
-            sub, noise_rng = jax.random.split(sub)
-            layer_params = wn.perturb(noise_rng, layer, layer_params)
+        with _scopes.layer(i, layer):
+            # FrozenLayer.java:23 contract: a frozen layer "behaves as the
+            # layer within it would during TEST regardless of the
+            # training/test mode" — frozen BN normalizes with its running
+            # statistics and does NOT update them; frozen dropout is off
+            l_train = train and i not in set(
+                getattr(self, "frozen_layers", ()))
+            fam = layer.input_family
+            if fam is not None and not isinstance(cur_type, fam):
+                x = _inputs.adapt(x, cur_type, fam)
+                cur_type = _inputs.adapted_type(cur_type, fam)
+            if l_train and layer.dropout > 0.0 and rng is not None:  # graftlint: disable=R2 -- layer is conf metadata picked by a Python int index, never a tracer
+                rng, sub = jax.random.split(rng)
+                from deeplearning4j_tpu.nn.layers.base import dropout_mask
+                x = dropout_mask(sub, x, layer.dropout)
+            kwargs = {}
+            if self._mask_aware[i] and mask is not None \
+                    and mask.ndim >= 2:
+                # a 1-d mask is an example-validity mask (shape
+                # bucketing): it has no timestep info to forward into
+                # mask-aware layers, which require [batch, time]
+                kwargs["mask"] = mask
+            if rng is not None:
+                rng, sub = jax.random.split(rng)
+            else:
+                sub = None
+            wn = getattr(layer, "weight_noise", None)
+            if l_train and wn is not None and sub is not None \
+                    and layer_params:
+                sub, noise_rng = jax.random.split(sub)
+                layer_params = wn.perturb(noise_rng, layer, layer_params)
 
-        def run(p, s, xx, r, _layer=layer, _kwargs=kwargs,
-                _train=l_train):
-            return _layer.apply(p, s, xx, train=_train, rng=r, **_kwargs)
+            def run(p, s, xx, r, _layer=layer, _kwargs=kwargs,
+                    _train=l_train):
+                return _layer.apply(p, s, xx, train=_train, rng=r, **_kwargs)
 
-        if self.conf.gradient_checkpointing:
-            # remat: drop this layer's activations after the forward and
-            # recompute them during backprop — HBM for FLOPs
-            run = jax.checkpoint(run)
-        y, new_state_i = run(layer_params, state_i, x, sub)
-        return y, new_state_i, rng, layer.output_type(cur_type)
+            if self.conf.gradient_checkpointing:
+                # remat: drop this layer's activations after the forward and
+                # recompute them during backprop — HBM for FLOPs
+                run = jax.checkpoint(run)
+            y, new_state_i = run(layer_params, state_i, x, sub)
+            return y, new_state_i, rng, layer.output_type(cur_type)
 
     def loss_fn(self, params, state, x, y, *, train=True, rng=None, mask=None,
                 label_mask=None):
@@ -152,8 +155,9 @@ class MultiLayerNetwork:
             feats, new_state = self.apply_fn(params, state, x, train=train,
                                              rng=rng, mask=mask,
                                              layer_limit=len(self.conf.layers) - 1)
-            loss, preds, out_state = out_layer.loss_from_features(
-                params[-1], state[-1], feats, y, lm, train=train)
+            with jax.named_scope("loss"):
+                loss, preds, out_state = out_layer.loss_from_features(
+                    params[-1], state[-1], feats, y, lm, train=train)
             new_state = list(new_state)
             new_state[-1] = out_state
         else:
@@ -162,11 +166,13 @@ class MultiLayerNetwork:
             if not hasattr(out_layer, "compute_loss"):
                 raise ValueError("Last layer must be an output/loss layer, got "
                                  f"{type(out_layer).__name__}")
-            loss = out_layer.compute_loss(preds, y, lm)
-        for layer, p in zip(self.conf.layers, params):
-            if p:
-                loss = loss + layer.regularization_penalty(p)
-        loss, new_state = _base.pop_aux_losses(loss, new_state)
+            with jax.named_scope("loss"):
+                loss = out_layer.compute_loss(preds, y, lm)
+        with jax.named_scope("loss"):
+            for layer, p in zip(self.conf.layers, params):
+                if p:
+                    loss = loss + layer.regularization_penalty(p)
+            loss, new_state = _base.pop_aux_losses(loss, new_state)
         return loss, (new_state, preds)
 
     # ------------------------------------------------------------------
@@ -183,21 +189,24 @@ class MultiLayerNetwork:
         new_carries = list(carries)
         cur_type = self.conf.input_type
         for i, layer in enumerate(self.conf.layers):
-            fam = layer.input_family
-            if fam is not None and not isinstance(cur_type, fam):
-                x = _inputs.adapt(x, cur_type, fam)
-                cur_type = _inputs.adapted_type(cur_type, fam)
-            if rng is not None:
-                rng, sub = jax.random.split(rng)
-            else:
-                sub = None
-            if hasattr(layer, "apply_with_carry"):
-                x, new_carries[i] = layer.apply_with_carry(
-                    params[i], carries[i], x, mask=mask)
-            else:
-                kwargs = {"mask": mask} if (self._mask_aware[i] and mask is not None) else {}
-                x, new_state[i] = layer.apply(params[i], state[i], x, train=train,
-                                              rng=sub, **kwargs)
+            with _scopes.layer(i, layer):
+                fam = layer.input_family
+                if fam is not None and not isinstance(cur_type, fam):
+                    x = _inputs.adapt(x, cur_type, fam)
+                    cur_type = _inputs.adapted_type(cur_type, fam)
+                if rng is not None:
+                    rng, sub = jax.random.split(rng)
+                else:
+                    sub = None
+                if hasattr(layer, "apply_with_carry"):
+                    x, new_carries[i] = layer.apply_with_carry(
+                        params[i], carries[i], x, mask=mask)
+                else:
+                    kwargs = ({"mask": mask} if self._mask_aware[i]
+                              and mask is not None else {})
+                    x, new_state[i] = layer.apply(
+                        params[i], state[i], x, train=train, rng=sub,
+                        **kwargs)
             cur_type = layer.output_type(cur_type)
         return x, new_state, new_carries
 
@@ -211,22 +220,28 @@ class MultiLayerNetwork:
                 preds, new_state, new_carries = self._apply_rnn(
                     params, state, x, carries, train=True, rng=rng, mask=mask)
                 out_layer = conf.layers[-1]
-                loss = out_layer.compute_loss(preds, y, mask)
-                for layer, p in zip(conf.layers, params):
-                    if p:
-                        loss = loss + layer.regularization_penalty(p)
-                loss, new_state = _base.pop_aux_losses(loss, new_state)
+                with jax.named_scope("loss"):
+                    loss = out_layer.compute_loss(preds, y, mask)
+                    for layer, p in zip(conf.layers, params):
+                        if p:
+                            loss = loss + layer.regularization_penalty(p)
+                    loss, new_state = _base.pop_aux_losses(loss, new_state)
                 return loss, (new_state, new_carries)
 
             (loss, (new_state, new_carries)), grads = jax.value_and_grad(
                 chunk_loss, has_aux=True)(params)
-            grads = _gradnorm.normalize_grads(conf.gradient_normalization, grads,
-                                              conf.gradient_normalization_threshold)
-            updates, new_opt = conf.updater.update(grads, opt_state, params, step)
-            new_params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
+            with jax.named_scope("grad_norm"):
+                grads = _gradnorm.normalize_grads(
+                    conf.gradient_normalization, grads,
+                    conf.gradient_normalization_threshold)
+            with jax.named_scope("updater"):
+                updates, new_opt = conf.updater.update(grads, opt_state,
+                                                       params, step)
+                new_params = jax.tree_util.tree_map(lambda p, u: p + u,
+                                                    params, updates)
             return new_params, new_state, new_opt, new_carries, loss
 
-        return jax.jit(tbptt_step) if jit else tbptt_step
+        return jax.jit(_scopes.stamped(tbptt_step)) if jit else tbptt_step
 
     def _fit_tbptt(self, x, y, mask):
         if not hasattr(self, "_tbptt_step") or self._tbptt_step is None:
@@ -287,19 +302,22 @@ class MultiLayerNetwork:
         (loss, (new_state, _)), grads = jax.value_and_grad(
             self.loss_fn, has_aux=True)(params, state, x, y, train=True,
                                         rng=rng, mask=mask)
-        grads = _gradnorm.normalize_grads(
-            self.conf.gradient_normalization, grads,
-            self.conf.gradient_normalization_threshold)
+        with jax.named_scope("grad_norm"):
+            grads = _gradnorm.normalize_grads(
+                self.conf.gradient_normalization, grads,
+                self.conf.gradient_normalization_threshold)
         return loss, new_state, grads
 
     def apply_update(self, params, opt_state, grads, step):
         """updater -> parameter add -> constraints (reference:
         BaseOptimizer.java:187 -> StochasticGradientDescent step :78 ->
         applyConstraints :97)."""
-        updates, new_opt = self.conf.updater.update(grads, opt_state, params,
-                                                    step)
-        new_params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
-        return self.apply_constraints(new_params, step), new_opt
+        with jax.named_scope("updater"):
+            updates, new_opt = self.conf.updater.update(grads, opt_state,
+                                                        params, step)
+            new_params = jax.tree_util.tree_map(lambda p, u: p + u, params,
+                                                updates)
+            return self.apply_constraints(new_params, step), new_opt
 
     def apply_constraints(self, params, step):
         """The constraint pass of apply_update, exposed separately for
@@ -325,7 +343,8 @@ class MultiLayerNetwork:
             loss, new_state, grads = self.compute_gradients(
                 params, state, x, y, rng=rng, mask=mask)
             if with_health:
-                health = _health.health_stats(grads, params, loss)
+                with jax.named_scope("health"):
+                    health = _health.health_stats(grads, params, loss)
             new_params, new_opt = self.apply_update(params, opt_state, grads,
                                                     step)
             if with_health:
@@ -335,7 +354,8 @@ class MultiLayerNetwork:
         if not jit:
             return train_step
         donate_argnums = (0, 1, 2) if donate else ()
-        return jax.jit(train_step, donate_argnums=donate_argnums)
+        return jax.jit(_scopes.stamped(train_step),
+                       donate_argnums=donate_argnums)
 
     def make_train_steps(self, k, donate=True, jit=True, with_health=False):
         """Fused K-step engine: ONE dispatch runs K train steps under

@@ -1,0 +1,56 @@
+"""The names the train step's device operations carry (`jax.named_scope`).
+
+A scope changes HLO metadata only (`op_name`): nothing on the device, and
+jax leaves metadata out of the persistent cache's key. The profiler's
+trace shows the path per operation (`tf_op`), and JAX wraps the backward
+pass's part of it in `transpose(jvp(...))`, so one scope names a layer's
+forward and its backward. The grammar (PERF.md section 3 lists who reads
+what): `L<ii>.<LayerClass>` per MultiLayerNetwork layer,
+`V.<vertex>.<Class>` per ComputationGraph vertex, `attn` / `mlp` inside a
+transformer block, `loss`, `grad_norm`, `updater`, `health`, and
+`<kernel>.fwd` / `<kernel>.bwd` around the Pallas kernels. Anything
+outside `[A-Za-z0-9_.-]` in a name becomes `_`.
+"""
+
+from __future__ import annotations
+
+import re
+
+import jax
+
+_UNSAFE = re.compile(r"[^A-Za-z0-9_.-]")
+
+#: The grammar's version, stamped on the jitted step functions' names.
+#: jax leaves `op_name` out of the persistent compile cache's key, so a
+#: step whose computation did not change is loaded as an older program
+#: cached it, with that program's names: ResNet-50's first traced run of
+#: PR 25 read `transpose(jvp())` where `transpose(jvp(V.x.Conv))` had
+#: been lowered (PERF.md section 6). A jitted function's name is in the
+#: key. Raise this when a scope is added, renamed or moved: every step
+#: then compiles anew once, and profiles read the names of the code that
+#: runs.
+GRAMMAR = "s1"
+
+
+def safe(name):
+    return _UNSAFE.sub("_", str(name))
+
+
+def stamped(step_fn):
+    """`step_fn` (about to be jitted) named `<name>_<GRAMMAR>`."""
+    step_fn.__name__ = step_fn.__qualname__ = \
+        f"{step_fn.__name__}_{GRAMMAR}"
+    return step_fn
+
+
+def layer(i, layer_conf):
+    """Scope of layer ``i`` of a MultiLayerNetwork."""
+    return jax.named_scope(f"L{i:02d}.{safe(type(layer_conf).__name__)}")
+
+
+def vertex(name, vertex_obj):
+    """Scope of one ComputationGraph vertex: a LayerVertex is named by
+    the class of the layer it holds, any other vertex by its own."""
+    inner = getattr(vertex_obj, "layer", None)
+    cls = type(vertex_obj if inner is None else inner).__name__
+    return jax.named_scope(f"V.{safe(name)}.{safe(cls)}")

@@ -261,6 +261,7 @@ def _run_fwd(q, k, v, mask, h, causal, scale, block_q, block_k, interpret):
     return _spmd.per_batch_shard(local, arrays, (0,) * len(arrays), (0, 0))
 
 
+@jax.named_scope("flash_attn.fwd")
 def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
                    interpret):
     bh, t, d = q.shape
@@ -304,6 +305,7 @@ def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_attn_fwd",
     )(qp, kp, vp, *(() if mask is None else (
         jnp.broadcast_to(_pad_to(mask.astype(jnp.float32), t_pad, 1)
                          [:, None, :], (bh // h, 8, t_pad)),)))
@@ -324,6 +326,7 @@ def _attention_fwd(q, k, v, mask, causal, scale, block_q, block_k,
     return out, (q, k, v, mask, out, lse)
 
 
+@jax.named_scope("flash_attn.bwd")
 def _bwd_core(causal, scale, block_k, res, g, g_lse=None):
     """Blockwise flash backward in jax: scan over KEY blocks recomputing
     P = exp(S - lse) one [BH, T, Bk] tile at a time. dq accumulates in the

@@ -155,6 +155,7 @@ def _matmul_stats(x2d, w2d, interpret, *, bn=None, bk=None, bj=None):
         scratch_shapes=[pltpu.VMEM((bn, bj), jnp.float32),
                         pltpu.VMEM((8, bj), jnp.float32)],
         interpret=interpret,
+        name="conv_bn_fwd_1x1",
     )(xp, wp)
     return z[:n, :cout], stats[:2, :cout]
 
@@ -262,6 +263,7 @@ def _conv3x3_stats(x, w, interpret, stride=1, *, bt_target=None, bj=None):
         ],
         scratch_shapes=[pltpu.VMEM((8, bj), jnp.float32)],
         interpret=interpret,
+        name="conv_bn_fwd_3x3",
     )(xp, xp, xp, wp)
     return z[:, :, :, :cout], stats[:2, :cout]
 
@@ -310,6 +312,7 @@ def fused_conv_bn_act(x, w, gamma, beta, residual,
     return y, mean, var
 
 
+@jax.named_scope("conv_bn.fwd")
 def _fwd_impl(x, w, gamma, beta, residual, stride, eps, act, interpret):
     z, stats = _conv_z(x, w, stride, interpret)
     n_rows = z.shape[0] * z.shape[1] * z.shape[2]
@@ -332,6 +335,7 @@ def _fused_fwd(x, w, gamma, beta, residual, stride, eps, act, interpret):
     return (y, mean, var), (x, w, gamma, beta, z, mean, invstd, y, has_res)
 
 
+@jax.named_scope("conv_bn.bwd")
 def _fused_bwd(stride, eps, act, interpret, res, cots):
     x, w, gamma, beta, z, mean, invstd, y, has_res = res
     dy, _, _ = cots  # mean/var feed only the (stop-grad) running stats

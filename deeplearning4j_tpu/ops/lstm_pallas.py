@@ -196,6 +196,7 @@ def _run_kernel_any(xz, wh, wp, h0, c0, mask, interpret, tile_cols=None):
         tuple(axis for _, axis in operands.values()), (1, 1, 0, 0))
 
 
+@jax.named_scope("lstm.fwd")
 def _run_kernel_local(xz, wh, wp, h0, c0, mask, interpret, tile_cols):
     t, b, four_h = xz.shape
     hsz = four_h // 4
@@ -276,6 +277,7 @@ def _run_kernel_local(xz, wh, wp, h0, c0, mask, interpret, tile_cols):
     return pl.pallas_call(
         kern, grid=grid, in_specs=specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=scratch, interpret=interpret,
+        name="lstm_fwd",
     )(*inputs)
 
 
@@ -301,6 +303,7 @@ def _fwd(xz, wh, wp, h0, c0, mask, interpret, tile_cols):
     return (hs, (hT, cT)), (xz, wh, wp, h0, c0, mask, hs, cs)
 
 
+@jax.named_scope("lstm.bwd")
 def _bwd(interpret, tile_cols, res, grads):
     xz, wh, wp, h0, c0, mask, hs, cs = res
     dhs, (dhT, dcT) = grads

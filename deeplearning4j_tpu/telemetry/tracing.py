@@ -177,12 +177,16 @@ class _NullSpan:
     def set(self, **attrs):
         return self
 
+    def interval(self):
+        t = time.perf_counter()
+        return t, t
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "_t0", "_ann", "_ctx", "_tok")
+    __slots__ = ("name", "args", "_t0", "_t1", "_ann", "_ctx", "_tok")
 
     def __init__(self, name, args):
         self.name = name
@@ -192,6 +196,11 @@ class _Span:
         """Attach attributes discovered mid-span (batch size, hit/miss)."""
         self.args.update(attrs)
         return self
+
+    def interval(self):
+        """(start, end) of a closed span, ``perf_counter`` seconds: for a
+        caller that files the same two clock reads elsewhere too."""
+        return self._t0, self._t1
 
     def __enter__(self):
         self._ann = None
@@ -219,7 +228,7 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
+        t1 = self._t1 = time.perf_counter()
         if self._ann is not None:
             try:
                 self._ann.__exit__(*exc)
