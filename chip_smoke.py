@@ -453,6 +453,9 @@ def kernel_cases(interpret):
         [("flash_causal_t4096_h8_d64",
           dict(b=1, t=4096, h=8, d=64, causal=True, masked=False,
                block=False)),
+         ("flash_causal_t1024_h16_d64",   # the gpt2m-train-t1024 cell's call
+          dict(b=4, t=1024, h=16, d=64, causal=True, masked=False,
+               block=False)),
          ("flash_causal_t2048_h4_d128",
           dict(b=1, t=2048, h=4, d=128, causal=True, masked=False,
                block=False)),

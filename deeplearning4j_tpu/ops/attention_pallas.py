@@ -7,19 +7,31 @@ cuDNN-helper tier though: the naive path materializes the [B, H, T, T]
 logits in HBM, this kernel never does.
 
 Kernel design (FlashAttention-style online softmax, TPU-first):
-* Heads fold into the batch: [B, T, H, D] -> [BH, T, D]; head dim pads to
-  the 128-lane width, sequence pads to a common multiple of the block
-  sizes.
+* Heads fold into the batch: [B, T, H, D] -> [BH, T, D]. Blocks carry the
+  head's own width D (no pad to the 128 lanes: at D 64 half of every DMA,
+  every MXU operand and the accumulator used to be zeros); the sequence
+  pads to a common multiple of the block sizes.
 * Grid = (BH, T/Bq, T/Bk) with the KEY dimension innermost: each (bh, iq)
   pair's query block stays VMEM-resident while key/value blocks [Bk, D]
   stream through, carried by the running (max, sum, acc) online-softmax
   recurrence held in VMEM scratch — VMEM use is O(Bq*D + Bk*D), so
   sequence length is bounded by HBM, not VMEM.
-* The [Bq, Bk] score tile lives only in VMEM/registers — HBM traffic is
-  O(T*D) per query block, never O(T^2).
+* Inside a grid step the [Bq, Bk] tile is walked in [128, 128] pieces: a
+  float32 score piece is 16 vector registers of the file's 64 (a whole
+  512 x 512 tile is 256, and every softmax step went out to VMEM and
+  back). All pieces of a step are one basic block and their score
+  products are issued ahead of the softmax, so the matrix and vector
+  units overlap. HBM traffic is O(T*D) per query block, never O(T^2).
+* Keys run down the sublanes, queries along the lanes (scores are k @ q^T,
+  the accumulator is out^T): the softmax state of a query is one lane of
+  a [1, Bq] row, reductions over keys are elementwise across registers,
+  and nothing is broadcast across lanes inside the walk.
 * Causal masking: key blocks entirely above the diagonal skip their
-  compute via pl.when; the partial block masks by position. Key padding
-  masks against the true length.
+  compute via pl.when (and are not fetched); a tile under the diagonal
+  takes no mask at all; on a diagonal tile of equal blocks the dead, cut
+  and whole pieces are told apart at trace time. The length mask applies
+  only where T is padded and the tile holds the tail; the key padding
+  mask on every tile of a masked call.
 * The kernel also emits the log-sum-exp per row. Backward is a
   jax.custom_vjp that recomputes probabilities from (q, k, v, lse)
   BLOCKWISE with a lax.scan over key blocks — peak gradient memory is
@@ -66,13 +78,26 @@ def enabled():
 # T=8192 (23x — the [B,H,T,T] logits start thrashing HBM). Dispatch follows
 # — unless a TuningDB entry for the shape bucket carries a MEASURED
 # decision (tuning/tune.py times the naive path as an implicit candidate).
+# That window timed the forward kernel as it was before PR 26 (padded to
+# 128 lanes, 3.2x slower at T 1024, D 64): the crossover may now lie below
+# 1024 and has not been measured again.
 _MIN_SEQ = 1024
 
-#: hand-picked default block geometry — the fallback when neither the
-#: tuning DB nor the env override speaks (chosen once on one v5e window;
-#: the whole point of the tuner is retiring this constant per bucket)
+#: default block geometry — the fallback when neither the tuning DB nor
+#: the env override speaks. Kept at 512 x 512 on the v5e's word (PR 26, bf16
+#: causal forward, [BH 64, T 1024, D 64]: 205 us; 256 x 256: 445; 512 x 256:
+#: 309; 256 x 512: 333; one 1024 x 1024 step a head: 160, but block_k is also
+#: the backward scan's tile, whose [BH, T, Bk] float32 temporaries double
+#: with it). The kernel's own pieces come from the blocks (``_sub_tile``).
 _DEFAULT_BLOCK_Q = 512
 _DEFAULT_BLOCK_K = 512
+
+#: score pieces the kernel issues ahead of the softmax at hand (see
+#: ``_attn_kernel``): the matrix units take work in program order, so this
+#: is what lets them run under the vector units' softmax. On the v5e at the
+#: shape above 1: 322 us, 4: 245, 8: 220, 16: 205, 32: 204 (PR 26); 16 is a
+#: whole 512 x 512 tile's pieces, 1 MiB of VMEM in flight
+_SCORES_AHEAD = 16
 
 
 def _tuned(q_shape, dtype):
@@ -168,8 +193,44 @@ def supported(q_shape, k_shape, mask, dtype, *, min_seq=None):
                              min_seq=min_seq) is not None
 
 
-def _attn_kernel(t_true, causal, scale, block_q, block_k, has_mask,
+def _sub_tile(block):
+    """Rows (or keys) of one sub-tile of a block: the kernel walks a
+    [block_q, block_k] grid step in [sub_q, sub_k] pieces so that the live
+    float32 score piece is 16 vector registers of the file's 64, not the
+    256 a whole 512 x 512 tile would ask for. 128 where the block is made
+    of 128s (every block the dispatch resolves); a block that is not (the
+    tests' 8s and 6s) is its own single sub-tile."""
+    return _LANE if block % _LANE == 0 else block
+
+
+def _all(*preds):
+    """``and`` over grid predicates that are Python bools where the call's
+    shape decides them and traced scalars where the grid position does."""
+    if any(p is False for p in preds):
+        return False
+    traced = [p for p in preds if p is not True]
+    return functools.reduce(jnp.logical_and, traced) if traced else True
+
+
+def _not(pred):
+    return (not pred) if isinstance(pred, bool) else jnp.logical_not(pred)
+
+
+def _when(pred, fn):
+    if pred is True:
+        fn()
+    elif pred is not False:
+        pl.when(pred)(fn)
+
+
+def _attn_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask,
                  q_ref, k_ref, v_ref, *rest):
+    """Keys run down the sublanes and queries along the lanes: the score
+    piece is k @ q^T, [sub_k, sub_q], so a query's running max and sum are
+    one lane of a [1, block_q] row (a reduction over keys is elementwise
+    across vector registers, a broadcast back is a sublane broadcast) and
+    the accumulator is out^T, [D, block_q], full in the lanes at any head
+    width. Rows meet lanes once, when the block's output is written."""
     if has_mask:
         mask_ref, o_ref, lse_ref, m_s, l_s, acc_s = rest
     else:
@@ -178,6 +239,10 @@ def _attn_kernel(t_true, causal, scale, block_q, block_k, has_mask,
     iq = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    # a power-of-two scale (1/8 at head width 64) multiplies the query
+    # exactly in any float dtype; any other stays on the float32 scores
+    fold = math.frexp(scale)[0] == 0.5
 
     @pl.when(j == 0)
     def _():
@@ -185,52 +250,116 @@ def _attn_kernel(t_true, causal, scale, block_q, block_k, has_mask,
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    bq = q_ref.shape[1]
-    row_max = (iq + 1) * block_q - 1
-    live = (j * block_k <= row_max) if causal else True
+    def tile(causal_mask, key_masks):
+        """One grid step as ONE basic block, walked in [sub_q, sub_k]
+        pieces. ``causal_mask``: None (the tile lies under the diagonal),
+        "diag" (block_q == block_k and the tile sits on it: which pieces
+        are dead, cut or whole is known here, at trace time) or "iota" (any
+        other geometry: compare positions on every piece). ``key_masks``:
+        the tile takes the length mask (when the call pads T) and the
+        key-padding mask (when the call has one)."""
+        def run():
+            # MXU inputs stay in the native dtype (bf16 under the mixed
+            # policy, 4x the f32 matmul rate on v5e) with f32 accumulation;
+            # only the softmax state is f32
+            q = q_ref[0]                                     # [Bq, D]
+            if fold:
+                q = q * scale
+            pieces = [(r0, c0) for r0 in range(0, block_q, sub_q)
+                      for c0 in range(0, block_k, sub_k)
+                      if not (causal_mask == "diag" and c0 > r0 + sub_q - 1)]
 
-    @pl.when(live)
-    def _():
-        # keep MXU inputs in the native dtype (bf16 under the mixed policy —
-        # 4x the f32 matmul rate on v5e) with f32 accumulation; only the
-        # softmax state is f32
-        q = q_ref[0]                                         # [Bq, D]
-        k = k_ref[0]                                         # [Bk, D]
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        col = j * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                     (1, block_k), 1)
-        valid = col < t_true
-        if has_mask:
-            valid = valid & (mask_ref[0][0:1] > 0)       # key padding mask
-        if causal:
-            row = iq * block_q + jax.lax.broadcasted_iota(jnp.int32,
-                                                          (bq, 1), 0)
-            valid = valid & (col <= row)
-        s = jnp.where(valid, s, _NEG_INF)
-        m_old = m_s[:]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1))
-        # explicit zeroing: on a fully-masked row m_new == s == _NEG_INF and
-        # exp(s - m_new) would be 1, silently averaging v — zero it so l
-        # stays 0 and the row emits 0 (the naive path emits NaN there; 0 is
-        # the contract the masked-output multiply downstream expects)
-        p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-        alpha = jnp.exp(m_old - m_new)
-        m_s[:] = m_new
-        l_s[:] = l_s[:] * alpha + jnp.sum(p, axis=-1)
-        acc_s[:] = acc_s[:] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            def scores(r0, c0):
+                return jax.lax.dot_general(
+                    k_ref[0, c0:c0 + sub_k, :], q[r0:r0 + sub_q],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)      # [Sk, Sq]
+
+            # the matrix units take their work in program order: score
+            # products issued _SCORES_AHEAD pieces early keep them busy
+            # while the vector units do the softmax of the piece at hand
+            ahead = [scores(*pc) for pc in pieces[:_SCORES_AHEAD]]
+            state = {}
+            for i, (r0, c0) in enumerate(pieces):
+                if i + _SCORES_AHEAD < len(pieces):
+                    ahead.append(scores(*pieces[i + _SCORES_AHEAD]))
+                s = ahead.pop(0)
+                if not fold:
+                    s = s * scale
+                rows = slice(r0, r0 + sub_q)
+                if r0 not in state:
+                    state[r0] = (m_s[:, rows], l_s[:, rows], acc_s[:, rows])
+                m, l, acc = state[r0]
+                v = v_ref[0, c0:c0 + sub_k, :]
+                masks = []
+                cut = causal_mask == "iota" or (
+                    causal_mask == "diag" and c0 + sub_k - 1 > r0)
+                if cut or (key_masks and ragged):
+                    key = j * block_k + c0 + jax.lax.broadcasted_iota(
+                        jnp.int32, (sub_k, 1), 0)
+                if key_masks and ragged:
+                    masks.append(key < t_true)
+                if key_masks and has_mask:       # key padding mask
+                    masks.append(jnp.broadcast_to(
+                        mask_ref[0, 0:1, c0:c0 + sub_k],
+                        (sub_q, sub_k)).T > 0)
+                if cut:
+                    query = iq * block_q + r0 + jax.lax.broadcasted_iota(
+                        jnp.int32, (1, sub_q), 1)
+                    masks.append(key <= query)
+                valid = functools.reduce(jnp.logical_and, masks) \
+                    if masks else None
+                if masks:
+                    s = jnp.where(valid, s, _NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+                p = jnp.exp(s - m_new)
+                if key_masks and has_mask:
+                    # explicit zeroing: on a fully-masked row m_new == s ==
+                    # _NEG_INF and exp(s - m_new) would be 1, silently
+                    # averaging v. Zero it so l stays 0 and the row emits 0
+                    # (the naive path emits NaN there; 0 is the contract the
+                    # masked-output multiply downstream expects). Only a key
+                    # mask can empty a row: under the causal and length
+                    # masks every row has met key 0 before any masked entry,
+                    # so m is finite and the exp underflows to exactly 0
+                    p = jnp.where(valid, p, 0.0)
+                alpha = jnp.exp(m - m_new)
+                l = alpha * l + jnp.sum(p, axis=0, keepdims=True)
+                acc = alpha * acc + jax.lax.dot_general(
+                    v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)      # [D, Sq]
+                state[r0] = (m_new, l, acc)
+            for r0, (m, l, acc) in state.items():
+                rows = slice(r0, r0 + sub_q)
+                m_s[:, rows], l_s[:, rows], acc_s[:, rows] = m, l, acc
+        return run
+
+    # masks only where a tile needs them: the length mask where the call
+    # pads T and this key block holds the tail, the key-padding mask on
+    # every tile of a masked call, the causal mask on the diagonal
+    tail = _all(ragged, (j + 1) * block_k > t_true)
+    keyed = True if has_mask else tail
+    if not causal:
+        _when(_not(keyed), tile(None, False))
+        _when(keyed, tile(None, True))
+    elif block_q == block_k:
+        _when(_all(j < iq, _not(keyed)), tile(None, False))
+        _when(_all(j < iq, keyed), tile(None, True))
+        _when(j == iq, tile("diag", has_mask or ragged))
+    else:
+        live = j * block_k <= (iq + 1) * block_q - 1
+        under = _all((j + 1) * block_k - 1 <= iq * block_q, _not(keyed))
+        _when(under, tile(None, False))
+        _when(_all(live, _not(under)), tile("iota", has_mask or ragged))
 
     @pl.when(j == nk - 1)
     def _():
         l_safe = jnp.maximum(l_s[:], 1e-30)  # fully-masked padding rows
-        o_ref[0] = (acc_s[:] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_s[:] / l_safe).T.astype(o_ref.dtype)
         # lse block is [8, Bq] (8-sublane broadcast): a [1, Bq] block would
         # violate the TPU (8, 128) tile rule — real-TPU compile rejects it
         lse = (m_s[:] + jnp.log(l_safe)).astype(lse_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
+        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
 def _pad_to(x, size, axis):
@@ -262,8 +391,13 @@ def _run_fwd(q, k, v, mask, h, causal, scale, block_q, block_k, interpret):
 
 
 @jax.named_scope("flash_attn.fwd")
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9), inline=True)
 def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
                    interpret):
+    # jitted and inlined: the kernel body is some hundreds of operations
+    # unrolled, and a model calls it once a layer with the same shapes, so
+    # it is traced once and its equations are copied into each caller under
+    # the caller's own scopes (24 layers of gpt2-medium: 3 s of set-up)
     bh, t, d = q.shape
     # clamp blocks to the 128-rounded sequence: short sequences would
     # otherwise pad up to the full default block (wasted compute), and
@@ -273,34 +407,44 @@ def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
     block_k = min(block_k, t128)
     step = math.lcm(block_q, block_k)
     t_pad = -(-t // step) * step
-    d_pad = -(-d // _LANE) * _LANE
-    qp = _pad_to(_pad_to(q, t_pad, 1), d_pad, 2)
-    kp = _pad_to(_pad_to(k, t_pad, 1), d_pad, 2)
-    vp = _pad_to(_pad_to(v, t_pad, 1), d_pad, 2)
+    # blocks carry the head's own width (a block's last dimension may equal
+    # the array's): no pad to the 128 lanes, no slice of the result
+    qp, kp, vp = (_pad_to(x, t_pad, 1) for x in (q, k, v))
     grid = (bh, t_pad // block_q, t_pad // block_k)
-    kernel = functools.partial(_attn_kernel, t, causal, scale,
-                               block_q, block_k, mask is not None)
-    scratch = [pltpu.VMEM((block_q,), jnp.float32),
-               pltpu.VMEM((block_q,), jnp.float32),
-               pltpu.VMEM((block_q, d_pad), jnp.float32)]
+    sub_q, sub_k = _sub_tile(block_q), _sub_tile(block_k)
+    kernel = functools.partial(_attn_kernel, t, t_pad != t, causal, scale,
+                               sub_q, sub_k, mask is not None)
+
+    def kv_block(i, j):
+        # a key block above the diagonal is skipped by the kernel; naming
+        # the last live block again keeps the pipeline from fetching it
+        return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k) \
+            if causal else j
+
+    scratch = [pltpu.VMEM((1, block_q), jnp.float32),
+               pltpu.VMEM((1, block_q), jnp.float32),
+               pltpu.VMEM((d, block_q), jnp.float32)]
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda b, i, j: (b, kv_block(i, j), 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda b, i, j: (b, kv_block(i, j), 0)),
         ] + ([
             # mask rides in as [B, 8, t_pad] f32 — the 8-sublane broadcast
             # satisfies the TPU (8, 128) tile rule like the lse output block
-            pl.BlockSpec((1, 8, block_k), lambda b, i, j: (b // h, 0, j)),
+            pl.BlockSpec((1, 8, block_k),
+                         lambda b, i, j: (b // h, 0, kv_block(i, j))),
         ] if mask is not None else []),
         out_specs=[
-            pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t_pad, d_pad), q.dtype),
+            jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype),
             jax.ShapeDtypeStruct((bh, 8, t_pad), jnp.float32),
         ],
         scratch_shapes=scratch,
@@ -309,7 +453,7 @@ def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
     )(qp, kp, vp, *(() if mask is None else (
         jnp.broadcast_to(_pad_to(mask.astype(jnp.float32), t_pad, 1)
                          [:, None, :], (bh // h, 8, t_pad)),)))
-    return out[:, :t, :d], lse[:, 0, :t]
+    return out[:, :t], lse[:, 0, :t]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))

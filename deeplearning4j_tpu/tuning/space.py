@@ -12,7 +12,7 @@ candidates, then reject statically-invalid ones BEFORE any compile —
   8-multiple — real-TPU compiles reject violations with an opaque mosaic
   error, so the space prunes them for free;
 * the **VMEM budget**: per-grid-step block residency (double-buffered
-  in/out blocks + scratch + the score/accumulator tile) must fit the
+  in/out blocks + scratch + the score pieces / accumulator) must fit the
   ~16 MiB scoped VMEM; the estimate uses the same arithmetic the kernel
   docstrings derive (14 MiB budget — the margin ops/lstm_pallas.py
   already uses);
@@ -84,26 +84,35 @@ def enumerate_space(kernel, *, include_remat=False):
 # ---------------------------------------------------------------------------
 
 def _attention_valid(cfg, shape, dtype):
-    """shape: layer-level [B, T, H, D]."""
+    """shape: layer-level [B, T, H, D]. The residency of one grid step of
+    ops/attention_pallas.py's forward kernel as it is built: blocks at the
+    head's own width (a VMEM block still pads its minor dimension to the
+    128 lanes), the out^T accumulator and the max / sum rows with block_q
+    along the lanes, and the score pieces in flight — [sub_k, sub_q]
+    float32 each, never the whole block_q x block_k tile."""
+    from deeplearning4j_tpu.ops import attention_pallas as _ap
     bq, bk = int(cfg["block_q"]), int(cfg["block_k"])
     _, t, _, d = shape
     if bq % LANE or bk % LANE:
-        # block_q rides the LANE axis of the [1, 8, Bq] lse output block
-        # and block_k the lane axis of the [Bq, Bk] score tile / mask
-        # block — both must be 128-multiples (the round-2 lse lesson)
+        # block_q rides the LANE axis of the [1, 8, Bq] lse output block,
+        # the [1, Bq] softmax state and the [D, Bq] accumulator, block_k
+        # the lane axis of the [1, 8, Bk] key-mask block — both must be
+        # 128-multiples (the round-2 lse lesson)
         return "tile rule: block_q/block_k must be 128-multiples"
     t128 = _round_up(t, LANE)
     if bq > t128 or bk > t128:
         return "redundant: block exceeds the 128-rounded sequence (clamps)"
-    dp = _round_up(d, LANE)
+    dl = _round_up(d, LANE)
     itm = _itemsize(dtype)
+    piece = _ap._sub_tile(bq) * _ap._sub_tile(bk) * 4
     vmem = (
-        2 * bq * dp * itm          # q block, double-buffered
-        + 2 * 2 * bk * dp * itm    # k + v blocks, double-buffered
-        + 2 * bq * dp * itm        # out block
+        2 * bq * dl * itm          # q block, double-buffered
+        + 2 * 2 * bk * dl * itm    # k + v blocks, double-buffered
+        + 2 * bq * dl * itm        # out block
         + 2 * 8 * bq * 4           # lse block (8-sublane broadcast)
-        + bq * dp * 4 + 2 * bq * 4  # acc/m/l scratch (f32)
-        + bq * bk * 4              # the score tile
+        + 2 * 8 * bk * 4           # key-mask block (8-sublane broadcast)
+        + _round_up(d, SUBLANE) * bq * 4 + 2 * 8 * bq * 4  # acc/m/l (f32)
+        + _ap._SCORES_AHEAD * piece  # the score pieces in flight
     )
     if vmem > VMEM_BUDGET:
         return f"vmem: ~{vmem // 1024} KiB exceeds the {VMEM_BUDGET // 1024} KiB budget"

@@ -456,6 +456,115 @@ class TestFlashAttention:
                                    rtol=2e-5, atol=2e-6)
 
 
+def _naive_folded(q, k, v, mask, causal, scale):
+    """[BH, T, D] reference with the kernel's contract: (out, lse), the
+    log-sum-exp over the keys a row may see; rows that see none are NaN
+    here and 0 in the kernel. ``mask``: [BH, T] key validity or None."""
+    s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
+    t = s.shape[-1]
+    valid = jnp.ones((1, t, t), bool)
+    if causal:
+        valid = valid & jnp.tril(jnp.ones((t, t), bool))[None]
+    if mask is not None:
+        valid = valid & (mask[:, None, :] > 0)
+    s = jnp.where(valid, s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, axis=-1), v), lse
+
+
+class TestFlashKernelGeometry:
+    """The forward kernel at the head's own width, walked in pieces
+    (ISSUE 26): 256-wide blocks hold 2 x 2 pieces of 128, so the inner walk
+    runs more than once, over tiles under, on and (skipped) above the
+    diagonal. Interpret mode; Mosaic's side of it is
+    TestDefaultDispatchKernelsLowerForTpu and chip_smoke.py."""
+
+    H = 2
+
+    def _inputs(self, t, d, masked, causal, seed):
+        rs = np.random.RandomState(seed)
+        q, k, v = (jnp.asarray(rs.randn(self.H, t, d).astype(np.float32)
+                               * 0.5) for _ in range(3))
+        mask = None
+        if masked:
+            m = np.ones((1, t), np.float32)
+            m[0, t - 37:] = 0.0      # a padded tail, not piece-aligned
+            m[0, 5::11] = 0.0        # holes
+            if causal:
+                m[0, :3] = 0.0       # left padding: rows 0-2 see no key
+            mask = jnp.asarray(m)
+        return q, k, v, mask
+
+    @pytest.mark.parametrize("d,causal,masked,t,block_q,block_k", [
+        (d, causal, masked, t, bq, 256)
+        # every width on equal blocks; the unequal geometry (positions
+        # compared on every piece) at width 64
+        for d, bq in ((64, 256), (128, 256), (80, 256), (64, 128))
+        for causal in (False, True) for masked in (False, True)
+        for t in (512, 300)])      # whole blocks; a ragged tail
+    def test_forward_and_lse_match_naive(self, d, causal, masked, t,
+                                         block_q, block_k):
+        q, k, v, mask = self._inputs(t, d, masked, causal, seed=d + t)
+        scale = 1.0 / float(d) ** 0.5
+        out, lse = attention_pallas._run_fwd(
+            q, k, v, mask, self.H, causal, scale, block_q, block_k, True)
+        assert out.shape == (self.H, t, d) and lse.shape == (self.H, t)
+        ref_out, ref_lse = _naive_folded(
+            q, k, v, None if mask is None else jnp.repeat(mask, self.H, 0),
+            causal, scale)
+        empty = 3 if (masked and causal) else 0
+        assert np.isnan(np.asarray(ref_out)[:, :empty]).all()
+        assert np.all(np.asarray(out)[:, :empty] == 0.0)
+        np.testing.assert_allclose(np.asarray(out)[:, empty:],
+                                   np.asarray(ref_out)[:, empty:],
+                                   rtol=2e-5, atol=2e-6)
+        np.testing.assert_allclose(np.asarray(lse)[:, empty:],
+                                   np.asarray(ref_lse)[:, empty:],
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("entry", ["flash_attention",
+                                       "flash_attention_block"])
+    def test_gradients_at_width_64(self, entry):
+        """Through the custom_vjp of both entries, the block primitive
+        with a cotangent on its lse output too (ring attention's
+        combination weights depend on it)."""
+        rs = np.random.RandomState(26)
+        q, k, v = (jnp.asarray(rs.randn(1, 256, 2, 64).astype(np.float32)
+                               * 0.5) for _ in range(3))
+        w = jnp.asarray(rs.randn(1, 2, 256).astype(np.float32))
+
+        def fold(x):
+            return x.transpose(0, 2, 1, 3).reshape(2, 256, 64)
+
+        def naive(q, k, v):
+            out, lse = _naive_folded(fold(q), fold(k), fold(v), None, True,
+                                     0.125)
+            return out.reshape(1, 2, 256, 64).transpose(0, 2, 1, 3), \
+                lse.reshape(1, 2, 256)
+
+        if entry == "flash_attention":
+            def kernel(q, k, v):
+                return attention_pallas.flash_attention(
+                    q, k, v, causal=True, block_q=128, block_k=256,
+                    interpret=True), 0.0
+        else:
+            def kernel(q, k, v):
+                return attention_pallas.flash_attention_block(
+                    q, k, v, True, 0.125, True)
+
+        def loss(fn):
+            def f(q, k, v):
+                out, lse = fn(q, k, v)
+                return jnp.sum(out * out) + jnp.sum(
+                    w * lse if entry == "flash_attention_block" else 0.0)
+            return f
+        got = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(naive), argnums=(0, 1, 2))(q, k, v)
+        for g, r, name in zip(got, want, ("dq", "dk", "dv")):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       rtol=5e-4, atol=2e-5, err_msg=name)
+
+
 def _ref_scan_any(xz, wh, h0, c0, wp=None, mask=None):
     """Scan reference covering peephole x mask (mask time-major [T, B],
     1=valid: state freezes at padded steps — nn/layers/rnn.py _step)."""
@@ -719,6 +828,7 @@ class TestDefaultDispatchKernelsLowerForTpu:
         assert "tpu_custom_call" in text
 
     @pytest.mark.parametrize("b,t,h,d,causal,masked", [
+        (4, 1024, 16, 64, True, False),   # the gpt2m-train-t1024 cell's
         (4, 4096, 8, 64, True, False),    # the longcontext shape
         (1, 2048, 4, 128, True, False),   # full-lane head dim
         (4, 1024, 8, 64, False, True),    # [B, Tk] key-padding mask
@@ -735,6 +845,14 @@ class TestDefaultDispatchKernelsLowerForTpu:
         text = _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)),
                               q, q, q).as_text()
         assert "tpu_custom_call" in text
+        # the forward kernel's q, k, v operands keep the head's own width:
+        # no pad of d to the 128 lanes in front of the custom call
+        call = next(ln for ln in text.splitlines()
+                    if 'kernel_name = "flash_attn_fwd"' in ln)
+        t_pad = -(-t // 512) * 512
+        operands = call.split(" : (")[1].split(") -> ")[0]
+        assert operands.startswith(
+            ", ".join([f"tensor<{b * h}x{t_pad}x{d}xbf16>"] * 3)), operands
 
     def test_ring_attention_block(self):
         q = jnp.zeros((2, 1024, 8, 64), jnp.bfloat16)

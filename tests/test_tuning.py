@@ -71,7 +71,8 @@ class TestSpace:
         assert r and "128-multiple" in r
 
     def test_vmem_budget_rejects(self):
-        # a 4096x4096 f32 score tile alone is 64 MiB — over any budget
+        # 4096-row f32 q, k, v and out blocks at 128 lanes, double-buffered,
+        # are 16 MiB before any scratch — over the budget
         r = tuning.validate("attention", {"block_q": 4096, "block_k": 4096,
                                           "remat": False},
                             (2, 8192, 4, 128), F32)
@@ -501,18 +502,30 @@ class TestTuneDrivers:
         cfg = db.lookup("attention", (1, 128, 2, 16), F32)
         assert cfg["backend"] == "flash" and cfg["block_q"] == 128
 
-    def test_tune_attention_crossover_records_xla_winner(self):
-        """On CPU the interpreted kernel can never beat XLA — the
-        crossover candidate wins and the DB verdict routes the dispatch
-        back to the naive path."""
+    @pytest.mark.parametrize("xla_ms,flash_ms,backend", [
+        (1.0, 5.0, "xla"),      # the naive path is the faster one here
+        (5.0, 1.0, "flash"),    # the kernel is
+    ])
+    def test_tune_attention_crossover_records_the_faster_backend(
+            self, monkeypatch, xla_ms, flash_ms, backend):
+        """The crossover candidate ("do not run the kernel") against one
+        block geometry, with the two timings handed in: a CPU stopwatch
+        says nothing about the chip, and the verdict must follow the
+        timings whichever way they fall. The DB's verdict then routes the
+        dispatch at a T below the _MIN_SEQ constant."""
+        def timed(fn, args, **_):
+            return 1e-3 * (xla_ms if fn is ttune.naive_attention
+                           else flash_ms)
+        monkeypatch.setattr(tmeasure, "time_callable", timed)
         db = tuning.TuningDB()
         s = ttune.tune_attention(
             db, b=1, t=128, h=2, d=16, interpret=True, iters=2, reps=1,
             candidates=[{"block_q": 128, "block_k": 128, "remat": False}])
-        assert s["winner"] == {"backend": "xla"}
+        assert s["winner"].get("backend", "flash") == backend
+        assert s["rejected_parity"] == 0
         tuning.set_db(db)
         shape = (1, 128, 2, 16)
-        assert not ap.supported(shape, shape, None, F32)
+        assert ap.supported(shape, shape, None, F32) == (backend == "flash")
 
     def test_tune_conv_matmul_smoke(self):
         db = tuning.TuningDB()
