@@ -431,6 +431,35 @@ def _lstm_case(name, *, t, b, hsz, peephole, masked, interpret, tol):
     return _compare(name, kernel, ref, (params, x), tol)
 
 
+def _looped_block_case(name, *, b, t, width, h, d, ffn, interpret, tol):
+    """Forward and gradient of one sandwich block with rotary positions
+    (RMSNorm before and after each of a causal attention and a gated SiLU
+    FFN, no biases: the ouro-train-t2048 cell's block) through the
+    dispatch, which takes the flash kernel on the chip, against the same
+    block on the naive branch."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu import models
+    from deeplearning4j_tpu.nn.conf import inputs as I
+    from deeplearning4j_tpu.ops import attention_pallas as _ap
+
+    block = models.looped_lm(8, n_layers=1, d_model=width, n_heads=h,
+                             head_dim=d, ffn_width=ffn,
+                             seq_len=t).layers[1].blocks[0]
+    params = block.init(jax.random.PRNGKey(width), I.RecurrentType(width, t))
+    x = jax.random.normal(jax.random.PRNGKey(t), (b, t, width), jnp.float32)
+    if not interpret:
+        _expect(_ap.enabled() and _ap.supported(
+            (b, t, h, d), (b, t, h, d), None, jnp.float32),
+            f"{name}: the dispatch gate does not admit this shape")
+
+    def apply(params, x):
+        return block.apply(params, {}, x)[0]
+
+    return _compare(name, apply, apply, (params, x), tol)
+
+
 def kernel_cases(interpret):
     """The widths the dispatch gates admit on the chip; toy widths for the
     interpret-mode CPU test (same kernels, same variants)."""
@@ -484,6 +513,10 @@ def kernels_phase(*, interpret, tol):
     flash, lstm = kernel_cases(interpret)
     results = [_flash_case(n, interpret=interpret, tol=tol, **kw)
                for n, kw in flash]
+    if not interpret:  # through the dispatch: nothing to choose off the chip
+        results.append(_looped_block_case(
+            "looped_lm_t2048_h16_d128", b=2, t=2048, width=2048, h=16, d=128,
+            ffn=5632, interpret=False, tol=tol))
     results += [_lstm_case(n, interpret=interpret, tol=tol, **kw)
                 for n, kw in lstm]
     return _say({"phase": "kernels", **_device_doc(), "interpret": interpret,

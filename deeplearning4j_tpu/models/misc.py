@@ -133,3 +133,32 @@ def transformer_lm(vocab_size, n_layers=4, d_model=256, n_heads=4,
         L.RnnOutputLayer(n_out=vocab_size, loss="mcxent"),
         input_type=I.RecurrentType(1, seq_len),
     )
+
+
+def looped_lm(vocab_size, n_layers=4, d_model=2048, n_heads=16, head_dim=None,
+              ffn_width=5632, passes=4, seq_len=2048, rope_theta=1e6,
+              norm_eps=1e-6, beta=0.1, updater=None, seed=12345):
+    """Looped decoder-only language model (Ouro, arXiv:2510.25741; net-new):
+    ``n_layers`` sandwich-norm blocks (RMSNorm before and after each of a
+    rotary causal attention and a gated SiLU FFN, no biases) whose ONE set
+    of weights runs ``passes`` times a step, a final RMSNorm closing every
+    pass, and an exit-weighted head over the passes' states
+    (``LoopedLMOutputLayer``). Input: [B, T] integer token ids; labels:
+    [B, T] integer next-token ids. The defaults are Ouro-2.6B's published
+    widths at four of its 48 layers."""
+    from deeplearning4j_tpu.nn.initializers import Distribution
+    init = Distribution(kind="normal", std=0.02)
+    block = L.TransformerBlock(
+        n_out=d_model, n_heads=n_heads, causal=True, activation="silu",
+        norm="rms", norm_eps=norm_eps, sandwich=True, bias=False,
+        rope_theta=rope_theta, head_dim=head_dim, ffn="gated",
+        ffn_width=ffn_width, weight_init=init)
+    return NeuralNetConfig(seed=seed,
+                           updater=updater or U.Adam(learning_rate=3e-4)).list(
+        L.EmbeddingSequenceLayer(n_in=vocab_size, n_out=d_model,
+                                 weight_init=init),
+        L.LoopedStack(blocks=(block,) * n_layers, passes=passes,
+                      final_norm=L.RMSNorm(eps=norm_eps)),
+        L.LoopedLMOutputLayer(n_out=vocab_size, beta=beta, weight_init=init),
+        input_type=I.RecurrentType(1, seq_len),
+    )

@@ -26,6 +26,13 @@ from deeplearning4j_tpu.utils import dtypes as _dtypes
 from deeplearning4j_tpu.utils.serde import register_config
 
 
+def _nfeat(input_type):
+    """Width of the last axis a norm scales."""
+    if isinstance(input_type, _inputs.ConvolutionalType):
+        return input_type.channels
+    return input_type.size
+
+
 @register_config
 @dataclasses.dataclass(frozen=True)
 class LayerNormalization(ParamLayer):
@@ -39,16 +46,11 @@ class LayerNormalization(ParamLayer):
     WEIGHT_KEYS = ("gamma",)
     BIAS_KEYS = ("beta",)
 
-    def _nfeat(self, input_type):
-        if isinstance(input_type, _inputs.ConvolutionalType):
-            return input_type.channels
-        return input_type.size
-
     def output_type(self, input_type):
         return input_type
 
     def init(self, key, input_type, dtype=jnp.float32):
-        n = self._nfeat(input_type)
+        n = _nfeat(input_type)
         return {"gamma": jnp.ones((n,), dtype), "beta": jnp.zeros((n,), dtype)}
 
     def apply(self, params, state, x, *, train=False, rng=None):
@@ -57,6 +59,49 @@ class LayerNormalization(ParamLayer):
         y = (x - mean) * jax.lax.rsqrt(var + self.eps)
         y = y * params["gamma"] + params["beta"]
         return self.activation_fn()(y), state
+
+
+@register_config
+@dataclasses.dataclass(frozen=True)
+class RMSNorm(ParamLayer):
+    """Root-mean-square norm over the last axis (Zhang & Sennrich 2019):
+    ``x / sqrt(mean(x^2) + eps) * gamma``; no mean, no bias."""
+
+    eps: float = 1e-6
+    activation: object = dataclasses.field(default="identity", kw_only=True)
+
+    input_family = None
+
+    WEIGHT_KEYS = ("gamma",)
+    BIAS_KEYS = ()
+
+    def output_type(self, input_type):
+        return input_type
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        return {"gamma": jnp.ones((_nfeat(input_type),), dtype)}
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        with jax.named_scope("rmsnorm"):
+            ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+            y = x * jax.lax.rsqrt(ms + self.eps) * params["gamma"]
+            return self.activation_fn()(y), state
+
+
+def rope(x, theta):
+    """Rotary position embedding (Su et al. 2021) of ``x`` [B, T, H, D] at
+    positions 0..T-1, in the rotate-half convention over the whole head
+    width: pair ``i`` is (x[i], x[i + D/2]) and turns by
+    ``t * theta**(-2i/D)``."""
+    with jax.named_scope("rope"):
+        t, d = x.shape[1], x.shape[-1]
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+        cos = jnp.cos(ang)[None, :, None, :].astype(x.dtype)
+        sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
+        x1, x2 = x[..., :d // 2], x[..., d // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1)
 
 
 def dot_product_attention(q, k, v, *, mask=None, causal=False, scale=None):
@@ -118,11 +163,20 @@ def dot_product_attention(q, k, v, *, mask=None, causal=False, scale=None):
 @register_config
 @dataclasses.dataclass(frozen=True)
 class MultiHeadAttention(ParamLayer):
-    """Self-attention over [B,T,F] with fused QKV projection."""
+    """Self-attention over [B,T,F] with fused QKV projection.
+
+    Model-definition fields beyond the original four (their defaults keep
+    the original parameter tree and arithmetic): ``bias=False`` drops
+    ``bqkv`` / ``bo``; ``rope_theta`` turns q and k by their positions
+    before the attention; ``head_dim`` sets the head width apart from
+    ``n_out / n_heads``."""
 
     n_out: int = 0     # model dim (also output dim)
     n_heads: int = 4
     causal: bool = False
+    bias: bool = True
+    rope_theta: float | None = None
+    head_dim: int | None = None
     weight_init: object = dataclasses.field(default="xavier", kw_only=True)
 
     input_family = _inputs.RecurrentType
@@ -130,34 +184,49 @@ class MultiHeadAttention(ParamLayer):
     WEIGHT_KEYS = ("Wqkv", "Wo")
     BIAS_KEYS = ("bqkv", "bo")
 
+    def _head_dim(self):
+        if self.head_dim is not None:
+            return self.head_dim
+        assert self.n_out % self.n_heads == 0
+        return self.n_out // self.n_heads
+
     def output_type(self, input_type):
         return _inputs.RecurrentType(self.n_out, input_type.timesteps)
 
     def init(self, key, input_type, dtype=jnp.float32):
         n_in = input_type.size
-        assert self.n_out % self.n_heads == 0
+        inner = self.n_heads * self._head_dim()
         k1, k2 = jax.random.split(key)
-        return {
-            "Wqkv": _init.init_weight(self.weight_init, k1, (n_in, 3 * self.n_out),
-                                      n_in, 3 * self.n_out, dtype),
-            "bqkv": jnp.zeros((3 * self.n_out,), dtype),
-            "Wo": _init.init_weight(self.weight_init, k2, (self.n_out, self.n_out),
-                                    self.n_out, self.n_out, dtype),
-            "bo": jnp.zeros((self.n_out,), dtype),
+        p = {
+            "Wqkv": _init.init_weight(self.weight_init, k1, (n_in, 3 * inner),
+                                      n_in, 3 * inner, dtype),
+            "Wo": _init.init_weight(self.weight_init, k2, (inner, self.n_out),
+                                    inner, self.n_out, dtype),
         }
+        if self.bias:
+            p["bqkv"] = jnp.zeros((3 * inner,), dtype)
+            p["bo"] = jnp.zeros((self.n_out,), dtype)
+        return p
 
     def heads(self, params, x):
         """Project to q,k,v [B,T,H,D]."""
         b, t, _ = x.shape
-        h, d = self.n_heads, self.n_out // self.n_heads
-        qkv = matmul(x.reshape(b * t, -1), params["Wqkv"]) + params["bqkv"]
+        h, d = self.n_heads, self._head_dim()
+        qkv = matmul(x.reshape(b * t, -1), params["Wqkv"])
+        if self.bias:
+            qkv = qkv + params["bqkv"]
         qkv = qkv.reshape(b, t, 3, h, d)
-        return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        if self.rope_theta is not None:
+            q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
+        return q, k, v
 
     def out_proj(self, params, attn):
         b, t, h, d = attn.shape
-        y = matmul(attn.reshape(b * t, h * d), params["Wo"]) + params["bo"]
-        return y.reshape(b, t, h * d)
+        y = matmul(attn.reshape(b * t, h * d), params["Wo"])
+        if self.bias:
+            y = y + params["bo"]
+        return y.reshape(b, t, self.n_out)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         q, k, v = self.heads(params, x)
@@ -171,21 +240,49 @@ class MultiHeadAttention(ParamLayer):
 @register_config
 @dataclasses.dataclass(frozen=True)
 class TransformerBlock(Layer):
-    """Pre-norm transformer block: LN -> MHA -> residual, LN -> MLP -> residual."""
+    """Transformer block: norm -> MHA -> residual, norm -> FFN -> residual.
+
+    The defaults are the original pre-norm block (LayerNorm, biased fused
+    QKV, a ``mlp_ratio`` x GELU MLP). The other fields define other
+    published blocks on the same code: ``norm`` "layer" | "rms" (with
+    ``norm_eps``, None = the norm's own default); ``sandwich`` adds a norm
+    after the mixer and after the FFN, before each residual add
+    (``ln1_post`` / ``ln2_post``); ``bias=False`` drops every bias;
+    ``rope_theta`` and ``head_dim`` go to the attention; ``ffn`` "mlp" |
+    "gated" (``act(x Wg) * (x Wu)`` then ``Wd``) of width ``ffn_width``
+    (None = ``n_out * mlp_ratio``)."""
 
     n_out: int = 0
     n_heads: int = 4
     mlp_ratio: int = 4
     causal: bool = False
     activation: object = "gelu"
+    norm: str = "layer"
+    norm_eps: float | None = None
+    sandwich: bool = False
+    bias: bool = True
+    rope_theta: float | None = None
+    head_dim: int | None = None
+    ffn: str = "mlp"
+    ffn_width: int | None = None
+    weight_init: object = "xavier"
 
     input_family = _inputs.RecurrentType
 
+    def _norm(self):
+        if self.norm not in ("layer", "rms"):
+            raise ValueError(f"norm is 'layer' or 'rms', got {self.norm!r}")
+        cls = LayerNormalization if self.norm == "layer" else RMSNorm
+        return cls() if self.norm_eps is None else cls(eps=self.norm_eps)
+
     def _parts(self):
-        return (LayerNormalization(),
+        return (self._norm(),
                 MultiHeadAttention(n_out=self.n_out, n_heads=self.n_heads,
-                                   causal=self.causal),
-                LayerNormalization())
+                                   causal=self.causal, bias=self.bias,
+                                   rope_theta=self.rope_theta,
+                                   head_dim=self.head_dim,
+                                   weight_init=self.weight_init),
+                self._norm())
 
     def output_type(self, input_type):
         return _inputs.RecurrentType(self.n_out, input_type.timesteps)
@@ -193,36 +290,63 @@ class TransformerBlock(Layer):
     def init(self, key, input_type, dtype=jnp.float32):
         assert input_type.size == self.n_out, \
             "TransformerBlock requires input size == n_out (residual)"
+        if self.ffn not in ("mlp", "gated"):
+            raise ValueError(f"ffn is 'mlp' or 'gated', got {self.ffn!r}")
+        if self.bias and self.ffn == "gated":
+            raise ValueError("the gated FFN has no biases: set bias=False")
         ln1, mha, ln2 = self._parts()
         k1, k2, k3, k4 = jax.random.split(key, 4)
-        hidden = self.n_out * self.mlp_ratio
+        hidden = self.ffn_width or self.n_out * self.mlp_ratio
         it = _inputs.RecurrentType(self.n_out, input_type.timesteps)
-        return {
-            "ln1": ln1.init(k1, it, dtype),
-            "mha": mha.init(k1, it, dtype),
-            "ln2": ln2.init(k2, it, dtype),
-            "mlp_W1": _init.init_weight("xavier", k3, (self.n_out, hidden),
-                                        self.n_out, hidden, dtype),
-            "mlp_b1": jnp.zeros((hidden,), dtype),
-            "mlp_W2": _init.init_weight("xavier", k4, (hidden, self.n_out),
-                                        hidden, self.n_out, dtype),
-            "mlp_b2": jnp.zeros((self.n_out,), dtype),
-        }
+
+        def weight(k, n_in, n_out):
+            return _init.init_weight(self.weight_init, k, (n_in, n_out),
+                                     n_in, n_out, dtype)
+
+        p = {"ln1": ln1.init(k1, it, dtype),
+             "mha": mha.init(k1, it, dtype),
+             "ln2": ln2.init(k2, it, dtype)}
+        if self.sandwich:
+            p["ln1_post"] = ln1.init(k1, it, dtype)
+            p["ln2_post"] = ln2.init(k2, it, dtype)
+        if self.ffn == "gated":
+            k3g, k3u = jax.random.split(k3)
+            p["mlp_Wg"] = weight(k3g, self.n_out, hidden)
+            p["mlp_Wu"] = weight(k3u, self.n_out, hidden)
+            p["mlp_Wd"] = weight(k4, hidden, self.n_out)
+        else:
+            p["mlp_W1"] = weight(k3, self.n_out, hidden)
+            p["mlp_W2"] = weight(k4, hidden, self.n_out)
+        if self.bias:
+            p["mlp_b1"] = jnp.zeros((hidden,), dtype)
+            p["mlp_b2"] = jnp.zeros((self.n_out,), dtype)
+        return p
+
+    def _ffn(self, params, h):
+        from deeplearning4j_tpu.nn import activations as _act
+        act = _act.get(self.activation)
+        if self.ffn == "gated":
+            m = act(matmul(h, params["mlp_Wg"])) * matmul(h, params["mlp_Wu"])
+            return matmul(m, params["mlp_Wd"])
+        m = matmul(h, params["mlp_W1"])
+        m = act(m + params["mlp_b1"] if self.bias else m)
+        m = matmul(m, params["mlp_W2"])
+        return m + params["mlp_b2"] if self.bias else m
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        from deeplearning4j_tpu.nn import activations as _act
         ln1, mha, ln2 = self._parts()
         with jax.named_scope("attn"):
             h, _ = ln1.apply(params["ln1"], {}, x)
             attn, _ = mha.apply(params["mha"], {}, h, mask=mask)
+            if self.sandwich:
+                attn, _ = ln1.apply(params["ln1_post"], {}, attn)
             x = x + attn
         with jax.named_scope("mlp"):
             h, _ = ln2.apply(params["ln2"], {}, x)
             b, t, f = h.shape
-            act = _act.get(self.activation)
-            m = act(matmul(h.reshape(b * t, f), params["mlp_W1"])
-                    + params["mlp_b1"])
-            m = matmul(m, params["mlp_W2"]) + params["mlp_b2"]
+            m = self._ffn(params, h.reshape(b * t, f))
+            if self.sandwich:
+                m, _ = ln2.apply(params["ln2_post"], {}, m)
             return x + m.reshape(b, t, f), state
 
     def regularization_penalty(self, params):

@@ -69,6 +69,15 @@ def test_kernels_phase_in_interpret_mode():
             "lstm_tiled_masked"} == names
 
 
+def test_looped_block_case_at_toy_size():
+    """Off the chip both sides take the naive branch: the case's own
+    plumbing (the block's fields, shapes, the comparison) is what runs."""
+    r = smoke._looped_block_case("looped_toy", b=2, t=16, width=32, h=2,
+                                 d=16, ffn=48, interpret=True,
+                                 tol={"fwd": 1e-6, "bwd": 1e-6})
+    assert r["kernel"] == "looped_toy" and r["fwd_rel_err"] == 0.0
+
+
 def test_multichip_phase_on_the_virtual_mesh(tmp_path, eight_devices):
     doc = smoke.multichip_phase(
         lambda: smoke.build_net(**TOY), vocab=TOY["vocab"],

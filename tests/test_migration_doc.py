@@ -31,7 +31,8 @@ SYMBOLS = {
         "Upsampling2DLayer", "ZeroPaddingLayer", "ZeroPadding1DLayer",
         "VariationalAutoencoder", "Yolo2OutputLayer",
         "CenterLossOutputLayer", "TransformerBlock", "MultiHeadAttention",
-        "LayerNormalization", "MoETransformerBlock"],
+        "LayerNormalization", "MoETransformerBlock", "RMSNorm",
+        "LoopedStack", "LoopedLMOutputLayer"],
     "deeplearning4j_tpu.nn.multilayer": ["MultiLayerNetwork"],
     "deeplearning4j_tpu.nn.listeners": [
         "ScoreIterationListener", "PerformanceListener",
@@ -70,7 +71,8 @@ SYMBOLS = {
     "deeplearning4j_tpu.models": [
         "alexnet", "darknet19", "facenet_nn4_small2", "googlenet",
         "inception_resnet_v1", "lenet", "resnet50", "simple_cnn",
-        "text_generation_lstm", "tiny_yolo", "vgg16", "vgg19"],
+        "text_generation_lstm", "tiny_yolo", "vgg16", "vgg19",
+        "transformer_lm", "looped_lm"],
     "deeplearning4j_tpu.parallel": [
         "ParallelTrainer", "MeshSpec", "make_mesh"],
     "deeplearning4j_tpu.parallel.inference": ["ParallelInference"],
