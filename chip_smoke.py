@@ -136,8 +136,9 @@ def train_phase(net, *, vocab, seq_len, batch, steps, k, workdir,
                 flash_calls=None):
     """K=1 steps, one K-step fused dispatch with a warm manifest attached,
     then save -> load_bundle -> resume. ``flash_calls``: how many
-    ``tpu_custom_call``s the compiled step must contain (None off-chip,
-    where the dispatch gate is closed and the count is 0)."""
+    ``tpu_custom_call``s the compiled step must contain, the flash forward
+    and backward kernel of every layer (None off-chip, where the dispatch
+    gate is closed and the count is 0)."""
     import jax
 
     from deeplearning4j_tpu import telemetry
@@ -177,7 +178,8 @@ def train_phase(net, *, vocab, seq_len, batch, steps, k, workdir,
     if flash_calls is not None:
         _expect(n_calls == flash_calls,
                 f"compiled train step holds {n_calls} tpu_custom_call(s), "
-                f"expected {flash_calls} (one flash kernel per layer)")
+                f"expected {flash_calls} (the flash forward and backward "
+                f"kernel of each layer)")
 
     # -- steps_per_dispatch=k: nn/fused.py, manifest attached ---------------
     events0 = dict(cc.event_counts())
@@ -388,6 +390,35 @@ def _flash_case(name, *, b, t, h, d, causal, masked, block, interpret, tol):
     return _compare(name, kernel, ref, (q, k, v), tol)
 
 
+def _flash_backward_time(name, *, b, t, h, d, interpret, iters=20):
+    """Milliseconds of one flash backward alone: the pullback of a causal
+    call on float32 operands, as the train cells hand them (the kernel and
+    what ``flash_attn.bwd`` holds beside it, the head folds around them),
+    on the host's clock around ``iters`` calls."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops import attention_pallas as _ap
+
+    t0 = time.perf_counter()
+    q, k, v, g = (jax.random.normal(key, (b, t, h, d), jnp.float32)
+                  for key in jax.random.split(jax.random.PRNGKey(t + d), 4))
+    _, pullback = jax.vjp(
+        lambda q, k, v: _ap.flash_attention(q, k, v, causal=True,
+                                            interpret=interpret), q, k, v)
+    run = jax.jit(pullback)
+    jax.block_until_ready(run(g))
+    t1 = time.perf_counter()
+    for _ in range(iters):
+        grads = run(g)
+    jax.block_until_ready(grads)
+    ms = (time.perf_counter() - t1) / iters * 1e3
+    for leaf in grads:
+        _expect(bool(jnp.isfinite(leaf).all()), f"{name}: non-finite value")
+    return {"kernel": name, "bwd_ms": float(f"{ms:.4g}"),
+            "wall_s": round(time.perf_counter() - t0, 1)}
+
+
 def _lstm_case(name, *, t, b, hsz, peephole, masked, interpret, tol):
     import jax
     import jax.numpy as jnp
@@ -517,6 +548,11 @@ def kernels_phase(*, interpret, tol):
         results.append(_looped_block_case(
             "looped_lm_t2048_h16_d128", b=2, t=2048, width=2048, h=16, d=128,
             ffn=5632, interpret=False, tol=tol))
+        results += [   # the two train cells' calls
+            _flash_backward_time("flash_bwd_t1024_h16_d64_f32", b=4, t=1024,
+                                 h=16, d=64, interpret=False),
+            _flash_backward_time("flash_bwd_t2048_h16_d128_f32", b=2, t=2048,
+                                 h=16, d=128, interpret=False)]
     results += [_lstm_case(n, interpret=interpret, tol=tol, **kw)
                 for n, kw in lstm]
     return _say({"phase": "kernels", **_device_doc(), "interpret": interpret,
@@ -714,7 +750,8 @@ def main(argv=None):
             net = build_net(**MODEL)
             phases = [
                 train_phase(net, **shape, batch=4, steps=4, k=4,
-                            workdir=workdir, flash_calls=MODEL["n_layers"]),
+                            workdir=workdir,
+                            flash_calls=2 * MODEL["n_layers"]),
                 serve_phase(net, vocab=MODEL["vocab"],
                             batch_buckets=BATCH_BUCKETS,
                             seq_buckets=SEQ_BUCKETS, lengths=LENGTHS,

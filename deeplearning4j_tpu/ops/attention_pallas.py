@@ -33,9 +33,12 @@ Kernel design (FlashAttention-style online softmax, TPU-first):
   only where T is padded and the tile holds the tail; the key padding
   mask on every tile of a masked call.
 * The kernel also emits the log-sum-exp per row. Backward is a
-  jax.custom_vjp that recomputes probabilities from (q, k, v, lse)
-  BLOCKWISE with a lax.scan over key blocks — peak gradient memory is
-  O(BH * T * Bk), not O(BH * T^2).
+  jax.custom_vjp over a second kernel in the same layout and with the same
+  causal skipping (``_bwd_kernel``): a piece's probabilities are recomputed
+  from (q, k, v, lse) in VMEM, so the gradient costs no [BH, T, Bk]
+  temporary in HBM. One kernel a head with dq^T resident in VMEM where a
+  head's dq fits there (five products a piece), else a dK/dV kernel and a
+  dQ kernel (seven): ``_run_bwd`` chooses from the shape.
 
 ``interpret=True`` runs the same kernel on CPU for tests (slow);
 ``enabled()`` gates the fast path to real TPU backends plus an env flag,
@@ -86,9 +89,10 @@ _MIN_SEQ = 1024
 #: default block geometry — the fallback when neither the tuning DB nor
 #: the env override speaks. Kept at 512 x 512 on the v5e's word (PR 26, bf16
 #: causal forward, [BH 64, T 1024, D 64]: 205 us; 256 x 256: 445; 512 x 256:
-#: 309; 256 x 512: 333; one 1024 x 1024 step a head: 160, but block_k is also
-#: the backward scan's tile, whose [BH, T, Bk] float32 temporaries double
-#: with it). The kernel's own pieces come from the blocks (``_sub_tile``).
+#: 309; 256 x 512: 333; one 1024 x 1024 step a head: 160, not taken then
+#: because the backward was a scan over key blocks of block_k; the backward
+#: kernel runs on the same two blocks and has not been timed at 1024). The
+#: kernels' own pieces come from the blocks (``_sub_tile``).
 _DEFAULT_BLOCK_Q = 512
 _DEFAULT_BLOCK_K = 512
 
@@ -96,7 +100,8 @@ _DEFAULT_BLOCK_K = 512
 #: ``_attn_kernel``): the matrix units take work in program order, so this
 #: is what lets them run under the vector units' softmax. On the v5e at the
 #: shape above 1: 322 us, 4: 245, 8: 220, 16: 205, 32: 204 (PR 26); 16 is a
-#: whole 512 x 512 tile's pieces, 1 MiB of VMEM in flight
+#: whole 512 x 512 tile's pieces, 1 MiB of VMEM in flight. The backward
+#: issues its two operand-only products (k q^T, v g^T) as far ahead: 2 MiB
 _SCORES_AHEAD = 16
 
 
@@ -223,6 +228,76 @@ def _when(pred, fn):
         pl.when(pred)(fn)
 
 
+def _pieces(block_q, block_k, sub_q, sub_k, causal_mask):
+    """(r0, c0) of a tile's [sub_q, sub_k] pieces, rows outermost; on a
+    "diag" tile the pieces wholly above the diagonal are left out."""
+    return [(r0, c0) for r0 in range(0, block_q, sub_q)
+            for c0 in range(0, block_k, sub_k)
+            if not (causal_mask == "diag" and c0 > r0 + sub_q - 1)]
+
+
+def _issued_ahead(pieces, product):
+    """(piece, product(*piece)) in order, each product issued (traced)
+    ``_SCORES_AHEAD`` pieces before the vector work that consumes it: the
+    matrix units take their work in program order, so this is what keeps
+    them busy under the vector units."""
+    ahead = [product(*pc) for pc in pieces[:_SCORES_AHEAD]]
+    for i, pc in enumerate(pieces):
+        if i + _SCORES_AHEAD < len(pieces):
+            ahead.append(product(*pieces[i + _SCORES_AHEAD]))
+        yield pc, ahead.pop(0)
+
+
+def _piece_valid(causal_mask, t_true, mask_ref, iq, j, block_q, block_k, r0,
+                 c0, sub_q, sub_k):
+    """The entries of a [sub_k, sub_q] piece (keys down the sublanes) that
+    a query may see, or None where the piece takes no mask: the piece at
+    rows ``r0``, keys ``c0`` of the tile (query block ``iq``, key block
+    ``j``). ``causal_mask`` is the tile's (see ``tile``); ``t_true`` the
+    true length where the tile takes the length mask, else None;
+    ``mask_ref`` the key-padding block where it takes that one, else None."""
+    masks = []
+    cut = causal_mask == "iota" or (
+        causal_mask == "diag" and c0 + sub_k - 1 > r0)
+    if cut or t_true is not None:
+        key = j * block_k + c0 + jax.lax.broadcasted_iota(
+            jnp.int32, (sub_k, 1), 0)
+    if t_true is not None:
+        masks.append(key < t_true)
+    if mask_ref is not None:
+        masks.append(jnp.broadcast_to(mask_ref[0, 0:1, c0:c0 + sub_k],
+                                      (sub_q, sub_k)).T > 0)
+    if cut:
+        query = iq * block_q + r0 + jax.lax.broadcasted_iota(
+            jnp.int32, (1, sub_q), 1)
+        masks.append(key <= query)
+    return functools.reduce(jnp.logical_and, masks) if masks else None
+
+
+def _walk_tiles(tile, causal, ragged, has_mask, iq, j, block_q, block_k,
+                t_true):
+    """Run the grid step (query block ``iq``, key block ``j``) as the one
+    ``tile(causal_mask, key_masks)`` body it needs, or none above the
+    diagonal: masks only where a tile needs them, the length mask where
+    the call pads T and this key block holds the tail, the key-padding
+    mask on every tile of a masked call, the causal mask on the diagonal.
+    Shared by the forward and the backward kernel."""
+    tail = _all(ragged, (j + 1) * block_k > t_true)
+    keyed = True if has_mask else tail
+    if not causal:
+        _when(_not(keyed), tile(None, False))
+        _when(keyed, tile(None, True))
+    elif block_q == block_k:
+        _when(_all(j < iq, _not(keyed)), tile(None, False))
+        _when(_all(j < iq, keyed), tile(None, True))
+        _when(j == iq, tile("diag", has_mask or ragged))
+    else:
+        live = j * block_k <= (iq + 1) * block_q - 1
+        under = _all((j + 1) * block_k - 1 <= iq * block_q, _not(keyed))
+        _when(under, tile(None, False))
+        _when(_all(live, _not(under)), tile("iota", has_mask or ragged))
+
+
 def _attn_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask,
                  q_ref, k_ref, v_ref, *rest):
     """Keys run down the sublanes and queries along the lanes: the score
@@ -265,25 +340,16 @@ def _attn_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask,
             q = q_ref[0]                                     # [Bq, D]
             if fold:
                 q = q * scale
-            pieces = [(r0, c0) for r0 in range(0, block_q, sub_q)
-                      for c0 in range(0, block_k, sub_k)
-                      if not (causal_mask == "diag" and c0 > r0 + sub_q - 1)]
-
             def scores(r0, c0):
                 return jax.lax.dot_general(
                     k_ref[0, c0:c0 + sub_k, :], q[r0:r0 + sub_q],
                     (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)      # [Sk, Sq]
 
-            # the matrix units take their work in program order: score
-            # products issued _SCORES_AHEAD pieces early keep them busy
-            # while the vector units do the softmax of the piece at hand
-            ahead = [scores(*pc) for pc in pieces[:_SCORES_AHEAD]]
             state = {}
-            for i, (r0, c0) in enumerate(pieces):
-                if i + _SCORES_AHEAD < len(pieces):
-                    ahead.append(scores(*pieces[i + _SCORES_AHEAD]))
-                s = ahead.pop(0)
+            for (r0, c0), s in _issued_ahead(
+                    _pieces(block_q, block_k, sub_q, sub_k, causal_mask),
+                    scores):
                 if not fold:
                     s = s * scale
                 rows = slice(r0, r0 + sub_q)
@@ -291,25 +357,11 @@ def _attn_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask,
                     state[r0] = (m_s[:, rows], l_s[:, rows], acc_s[:, rows])
                 m, l, acc = state[r0]
                 v = v_ref[0, c0:c0 + sub_k, :]
-                masks = []
-                cut = causal_mask == "iota" or (
-                    causal_mask == "diag" and c0 + sub_k - 1 > r0)
-                if cut or (key_masks and ragged):
-                    key = j * block_k + c0 + jax.lax.broadcasted_iota(
-                        jnp.int32, (sub_k, 1), 0)
-                if key_masks and ragged:
-                    masks.append(key < t_true)
-                if key_masks and has_mask:       # key padding mask
-                    masks.append(jnp.broadcast_to(
-                        mask_ref[0, 0:1, c0:c0 + sub_k],
-                        (sub_q, sub_k)).T > 0)
-                if cut:
-                    query = iq * block_q + r0 + jax.lax.broadcasted_iota(
-                        jnp.int32, (1, sub_q), 1)
-                    masks.append(key <= query)
-                valid = functools.reduce(jnp.logical_and, masks) \
-                    if masks else None
-                if masks:
+                valid = _piece_valid(
+                    causal_mask, t_true if key_masks and ragged else None,
+                    mask_ref if key_masks else None, iq, j, block_q,
+                    block_k, r0, c0, sub_q, sub_k)
+                if valid is not None:
                     s = jnp.where(valid, s, _NEG_INF)
                 m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
                 p = jnp.exp(s - m_new)
@@ -334,23 +386,8 @@ def _attn_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask,
                 m_s[:, rows], l_s[:, rows], acc_s[:, rows] = m, l, acc
         return run
 
-    # masks only where a tile needs them: the length mask where the call
-    # pads T and this key block holds the tail, the key-padding mask on
-    # every tile of a masked call, the causal mask on the diagonal
-    tail = _all(ragged, (j + 1) * block_k > t_true)
-    keyed = True if has_mask else tail
-    if not causal:
-        _when(_not(keyed), tile(None, False))
-        _when(keyed, tile(None, True))
-    elif block_q == block_k:
-        _when(_all(j < iq, _not(keyed)), tile(None, False))
-        _when(_all(j < iq, keyed), tile(None, True))
-        _when(j == iq, tile("diag", has_mask or ragged))
-    else:
-        live = j * block_k <= (iq + 1) * block_q - 1
-        under = _all((j + 1) * block_k - 1 <= iq * block_q, _not(keyed))
-        _when(under, tile(None, False))
-        _when(_all(live, _not(under)), tile("iota", has_mask or ragged))
+    _walk_tiles(tile, causal, ragged, has_mask, iq, j, block_q, block_k,
+                t_true)
 
     @pl.when(j == nk - 1)
     def _():
@@ -369,6 +406,18 @@ def _pad_to(x, size, axis):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
+
+
+def _geometry(t, block_q, block_k):
+    """(block_q, block_k, t_pad) a call of length ``t`` runs with, forward
+    and backward alike. Blocks clamp to the 128-rounded sequence: short
+    sequences would otherwise pad up to the full default block (wasted
+    compute), and blocks larger than the array are invalid. The sequence
+    pads to a common multiple of the two."""
+    t128 = -(-t // _LANE) * _LANE
+    block_q, block_k = min(block_q, t128), min(block_k, t128)
+    step = math.lcm(block_q, block_k)
+    return block_q, block_k, -(-t // step) * step
 
 
 def _run_fwd(q, k, v, mask, h, causal, scale, block_q, block_k, interpret):
@@ -399,14 +448,7 @@ def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
     # it is traced once and its equations are copied into each caller under
     # the caller's own scopes (24 layers of gpt2-medium: 3 s of set-up)
     bh, t, d = q.shape
-    # clamp blocks to the 128-rounded sequence: short sequences would
-    # otherwise pad up to the full default block (wasted compute), and
-    # blocks larger than the array are invalid
-    t128 = -(-t // _LANE) * _LANE
-    block_q = min(block_q, t128)
-    block_k = min(block_k, t128)
-    step = math.lcm(block_q, block_k)
-    t_pad = -(-t // step) * step
+    block_q, block_k, t_pad = _geometry(t, block_q, block_k)
     # blocks carry the head's own width (a block's last dimension may equal
     # the array's): no pad to the 128 lanes, no slice of the result
     qp, kp, vp = (_pad_to(x, t_pad, 1) for x in (q, k, v))
@@ -470,85 +512,278 @@ def _attention_fwd(q, k, v, mask, causal, scale, block_q, block_k,
     return out, (q, k, v, mask, out, lse)
 
 
-@jax.named_scope("flash_attn.bwd")
-def _bwd_core(causal, scale, block_k, res, g, g_lse=None):
-    """Blockwise flash backward in jax: scan over KEY blocks recomputing
-    P = exp(S - lse) one [BH, T, Bk] tile at a time. dq accumulates in the
-    carry; dk/dv stack per block. Peak memory O(BH*T*Bk), never O(T^2).
+def _bwd_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask, form,
+                q_ref, k_ref, v_ref, g_ref, st_ref, *rest):
+    """The backward in the forward's orientation: a piece's probabilities
+    are recomputed as p^T = exp(k q^T * scale - lse), [sub_k, sub_q], from
+    the residuals, so ``lse`` and ``delta`` (``st_ref`` rows 0 and 1) are
+    [1, sub_q] rows and nothing is broadcast across lanes, and the three
+    gradients accumulate transposed, [D, .], full in the lanes at any head
+    width. Five products a live piece (k q^T, v g^T, g^T p, q^T ds, k^T ds).
 
-    ``g_lse`` (optional, [BH, T]): cotangent on the log-sum-exp output —
-    d(lse)/d(s) is the softmax row, so it adds ``p * g_lse`` to ds. Used by
-    the ring-attention block primitive whose combination weights depend on
-    lse."""
+    ``form``: "fused" walks a head key block by key block with the query
+    blocks innermost: dk^T / dv^T close with their key block, dq^T stays in
+    VMEM for the whole head. "dkv" is the same walk without dq; "dq" walks
+    query block by query block with the key blocks innermost."""
+    with_dq, with_dkv = form != "dkv", form != "dq"
+    rest = list(rest)
+    mask_ref = rest.pop(0) if has_mask else None
+    outs, scratch = rest[:len(rest) // 2], rest[len(rest) // 2:]
+    if with_dq:
+        dq_ref, dq_s = outs.pop(0), scratch.pop(0)
+    if with_dkv:
+        (dk_ref, dv_ref), (dk_s, dv_s) = outs, scratch
+    if form == "dq":
+        iq, j = pl.program_id(1), pl.program_id(2)
+    else:
+        j, iq = pl.program_id(1), pl.program_id(2)
+    # the innermost axis' last step closes the block the outer axis names
+    last = pl.program_id(2) == pl.num_programs(2) - 1
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    fold = math.frexp(scale)[0] == 0.5       # as the forward's
+
+    if with_dkv:
+        @pl.when(iq == 0)
+        def _():
+            dk_s[:] = jnp.zeros_like(dk_s)
+            dv_s[:] = jnp.zeros_like(dv_s)
+    if with_dq:
+        # "fused": [nq, D, block_q], one slab a query block, zeroed as the
+        # head opens; "dq": [1, D, block_q], zeroed as the query block opens
+        dq_i = iq if form == "fused" else 0
+
+        @pl.when(_all(j == 0, True if form == "dq" else iq == 0))
+        def _():
+            dq_s[:] = jnp.zeros_like(dq_s)
+
+    def tile(causal_mask, key_masks):
+        """One grid step as one basic block of [sub_q, sub_k] pieces; the
+        arguments are ``_attn_kernel``'s."""
+        def run():
+            q = q_ref[0]                                     # [Bq, D]
+            if fold:
+                q = q * scale
+            g = g_ref[0]
+
+            def products(r0, c0):
+                nt = (((1,), (1,)), ((), ()))
+                rows, cols = slice(r0, r0 + sub_q), slice(c0, c0 + sub_k)
+                return (jax.lax.dot_general(
+                            k_ref[0, cols, :], q[rows], nt,
+                            preferred_element_type=jnp.float32),
+                        jax.lax.dot_general(
+                            v_ref[0, cols, :], g[rows], nt,
+                            preferred_element_type=jnp.float32))  # [Sk, Sq]
+
+            # the two products that wait for no vector work go ahead of it
+            dq_acc, dkv_acc = {}, {}
+            for (r0, c0), (s, dp) in _issued_ahead(
+                    _pieces(block_q, block_k, sub_q, sub_k, causal_mask),
+                    products):
+                if not fold:
+                    s = s * scale
+                rows, cols = slice(r0, r0 + sub_q), slice(c0, c0 + sub_k)
+                p = jnp.exp(s - st_ref[0, 0:1, rows])
+                valid = _piece_valid(
+                    causal_mask, t_true if key_masks and ragged else None,
+                    mask_ref if key_masks else None, iq, j, block_q,
+                    block_k, r0, c0, sub_q, sub_k)
+                if valid is not None:
+                    # zeroed, not exponentiated: a row that saw no key has
+                    # the sentinel for its lse, and exp(s - lse) overflows
+                    p = jnp.where(valid, p, 0.0)
+                ds = (p * (dp - st_ref[0, 1:2, rows])).astype(q.dtype)
+                tt = (((0,), (1,)), ((), ()))
+                if with_dkv:
+                    if c0 not in dkv_acc:
+                        dkv_acc[c0] = (dk_s[:, cols], dv_s[:, cols])
+                    dk, dv = dkv_acc[c0]
+                    dkv_acc[c0] = (
+                        dk + jax.lax.dot_general(
+                            q[rows], ds, tt,
+                            preferred_element_type=jnp.float32),
+                        dv + jax.lax.dot_general(
+                            g[rows], p.astype(g.dtype), tt,
+                            preferred_element_type=jnp.float32))  # [D, Sk]
+                if with_dq:
+                    if r0 not in dq_acc:
+                        dq_acc[r0] = dq_s[dq_i, :, rows]
+                    dq_acc[r0] = dq_acc[r0] + jax.lax.dot_general(
+                        k_ref[0, cols, :], ds, (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)      # [D, Sq]
+            for c0, (dk, dv) in dkv_acc.items():
+                cols = slice(c0, c0 + sub_k)
+                dk_s[:, cols], dv_s[:, cols] = dk, dv
+            for r0, dq in dq_acc.items():
+                dq_s[dq_i, :, r0:r0 + sub_q] = dq
+        return run
+
+    _walk_tiles(tile, causal, ragged, has_mask, iq, j, block_q, block_k,
+                t_true)
+
+    # rows meet lanes once, as a block closes. dk took the scale with q
+    # where it folds; dq always owes it
+    if with_dkv:
+        @pl.when(last)
+        def _():
+            dk = dk_s[:] if fold else dk_s[:] * scale
+            dk_ref[0] = dk.T.astype(dk_ref.dtype)
+            dv_ref[0] = dv_s[:].T.astype(dv_ref.dtype)
+    if with_dq:
+        @pl.when(_all(last, True if form == "dq"
+                      else j == pl.num_programs(1) - 1))
+        def _():
+            for i in range(dq_s.shape[0]):
+                dq_ref[0, i * block_q:(i + 1) * block_q, :] = (
+                    dq_s[i] * scale).T.astype(dq_ref.dtype)
+
+
+#: what one kernel may hold in VMEM: Mosaic's scoped limit is 16 MiB, and
+#: tuning/space.py keeps the same margin under it
+_VMEM_BUDGET = 14 * 1024 * 1024
+
+
+def bwd_vmem_bytes(form, t_pad, d, block_q, block_k, itemsize):
+    """VMEM the backward holds in one grid step, in the form it would take
+    ("fused", or "split": the larger of its two kernels): the operand and
+    gradient blocks twice (the pipeline's two buffers; a block keeps the
+    head's own width), the float32 accumulators, the product pieces in
+    flight and, fused, a whole head's dq: its output block twice and its
+    float32 accumulator, ``t_pad * d`` each. Checked against the compiler's
+    own count at [*, 8192, 128] float32, where it refuses the fused form by
+    0.46 MiB of 16 (PERF.md, PR 28)."""
+    d8 = -(-d // 8) * 8
+    pieces = 2 * _SCORES_AHEAD * _sub_tile(block_q) * _sub_tile(block_k) * 4
+    blocks = 2 * itemsize * d * (2 * block_q + 2 * block_k)   # q g k v
+    small = 2 * 8 * 4 * (block_q + block_k)                   # stats, mask
+    dkv = 2 * itemsize * d * 2 * block_k + 2 * d8 * block_k * 4
+    if form == "fused":
+        return blocks + small + pieces + dkv + (
+            2 * itemsize * d * t_pad + d8 * t_pad * 4)
+    dq = 2 * itemsize * d * block_q + d8 * block_q * 4
+    return blocks + small + pieces + max(dkv, dq)
+
+
+def _run_bwd(res, g, g_lse, h, causal, scale, block_q, block_k, interpret):
+    """(dq, dk, dv) [BH, T, D] from the forward's residuals and the
+    cotangent ``g`` [BH, T, D]; ``g_lse`` [BH, T] or None is the cotangent
+    on the log-sum-exp output (``flash_attention_block``). One kernel a
+    head with dq resident ("fused", five products a piece) where a head's
+    dq fits VMEM beside the blocks, else a dK/dV and a dQ kernel that each
+    recompute the probabilities ("split", seven products): chosen here,
+    from the shape. Once a batch shard under a declared mesh, as
+    ``_run_fwd``."""
     q, k, v, mask, out, lse = res
     if mask is not None and mask.shape[-1] == 0:   # zero-width = unmasked
         mask = None
+    _, t, d = q.shape
+    form = "fused" if bwd_vmem_bytes(
+        "fused", _geometry(t, block_q, block_k)[2], d, block_q, block_k,
+        q.dtype.itemsize) <= _VMEM_BUDGET else "split"
+    arrays = [q, k, v, out, lse, g]
+    arrays += [] if g_lse is None else [g_lse]
+    arrays += [] if mask is None else [mask]
+
+    def local(q, k, v, out, lse, g, *rest):
+        return _run_bwd_local(
+            q, k, v, out, lse, g, None if g_lse is None else rest[0],
+            None if mask is None else rest[-1], h, causal, scale, block_q,
+            block_k, interpret, form)
+    return _spmd.per_batch_shard(local, arrays, (0,) * len(arrays),
+                                 (0, 0, 0))
+
+
+@jax.named_scope("flash_attn.bwd")
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13, 14),
+                   inline=True)
+def _run_bwd_local(q, k, v, out, lse, g, g_lse, mask, h, causal, scale,
+                   block_q, block_k, interpret, form):
+    # jitted and inlined for the reason _run_fwd_local is
+    bh, t, d = q.shape
     f32 = jnp.float32
-    # big einsums stay in the input dtype (bf16 under the mixed policy) with
-    # f32 accumulation via preferred_element_type; softmax math is f32
-    qf, kf, vf, gf, of = q, k, v, g.astype(q.dtype), out
-    bh, t, d = qf.shape
-    # same clamp as _run_fwd: an unclamped 512 block would pad short
-    # sequences' key blocks with masked-out columns the einsums still chew
-    bk = min(block_k, -(-t // _LANE) * _LANE)
-    t_pad = -(-t // bk) * bk
-    kp = _pad_to(kf, t_pad, 1).reshape(bh, t_pad // bk, bk, d)
-    vp = _pad_to(vf, t_pad, 1).reshape(bh, t_pad // bk, bk, d)
-    # move the block axis to front for scan
-    kp = jnp.moveaxis(kp, 1, 0)                      # [nk, BH, Bk, D]
-    vp = jnp.moveaxis(vp, 1, 0)
+    block_q, block_k, t_pad = _geometry(t, block_q, block_k)
+    nq, nk = t_pad // block_q, t_pad // block_k
+    # d(lse)/d(s) is the softmax row, so a cotangent on lse adds p * g_lse
+    # to ds = p * (dp - delta): it folds into delta
+    delta = jnp.sum(g.astype(f32) * out.astype(f32), axis=-1)
+    if g_lse is not None:
+        delta = delta - g_lse.astype(f32)
+    # padded query rows have q = g = 0 and lse = delta = 0: p = 1, ds = 0,
+    # nothing reaches dk or dv
+    stats = _pad_to(jnp.stack([lse.astype(f32), delta], axis=1), t_pad, 2)
+    # the matrix units round a float32 operand to bfloat16 as they take it
+    # (default precision, as the forward's products and the einsums this
+    # kernel replaced): rounded here the gradients are the same to the bit
+    # (PERF.md, PR 28) and XLA keeps q, k, v for the backward at half the
+    # bytes, as it did for the scan. The interpreter multiplies in float32
+    cd = jnp.bfloat16 if q.dtype == f32 and not interpret else q.dtype
+    operands = [_pad_to(x.astype(cd), t_pad, 1) for x in (q, k, v, g)]
+    operands.append(stats)
     if mask is not None:
-        # key padding mask, repeated per head ([B, T] -> [BH, T],
-        # batch-major to match _fold_heads' bh = b * h + head layout),
-        # blocked like k/v
-        maskh = jnp.repeat(mask.astype(f32), bh // mask.shape[0], axis=0)
-        mp = jnp.moveaxis(_pad_to(maskh, t_pad, 1)
-                          .reshape(bh, t_pad // bk, bk), 1, 0)  # [nk,BH,Bk]
-    delta = jnp.sum(gf.astype(f32) * of.astype(f32), axis=-1,
-                    keepdims=True)                    # [BH, T, 1]
-    row = jnp.arange(t)[None, :, None]                # [1, T, 1]
+        operands.append(jnp.broadcast_to(
+            _pad_to(mask.astype(f32), t_pad, 1)[:, None, :],
+            (bh // h, 8, t_pad)))
 
-    def body(carry, blk):
-        dq_acc, j = carry
-        if mask is not None:
-            k_j, v_j, m_j = blk                       # [BH, Bk, D], [BH, Bk]
+    def call(form):
+        kernel = functools.partial(
+            _bwd_kernel, t, t_pad != t, causal, scale, _sub_tile(block_q),
+            _sub_tile(block_k), mask is not None, form)
+        # a step above the diagonal names the nearest live block again, so
+        # nothing is fetched for it
+        if form == "dq":
+            grid = (bh, nq, nk)
+
+            def q_at(b, i, j):
+                return i
+
+            def k_at(b, i, j):
+                return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k) \
+                    if causal else j
         else:
-            k_j, v_j = blk
-        col = j * bk + jnp.arange(bk)[None, None, :]  # [1, 1, Bk]
-        s = jnp.einsum("bqd,bkd->bqk", qf, k_j,
-                       preferred_element_type=f32) * scale
-        valid = col < t
-        if mask is not None:
-            valid = valid & (m_j[:, None, :] > 0)
-        if causal:
-            valid = valid & (col <= row)
-        s = jnp.where(valid, s, _NEG_INF)
-        # zero (not exp) masked entries: on fully-masked rows lse is the
-        # _NEG_INF sentinel and exp(s - lse) would be ~1, corrupting grads
-        p = jnp.where(valid, jnp.exp(s - lse[..., None]), 0.0)  # [BH,T,Bk]
-        pc = p.astype(qf.dtype)
-        dv_j = jnp.einsum("bqk,bqd->bkd", pc, gf, preferred_element_type=f32)
-        dp = jnp.einsum("bqd,bkd->bqk", gf, v_j, preferred_element_type=f32)
-        ds = p * (dp - delta)
-        if g_lse is not None:
-            ds = ds + p * g_lse[..., None].astype(f32)
-        ds = ds.astype(qf.dtype)
-        dq_acc = dq_acc + jnp.einsum("bqk,bkd->bqd", ds, k_j,
-                                     preferred_element_type=f32) * scale
-        dk_j = jnp.einsum("bqk,bqd->bkd", ds, qf,
-                          preferred_element_type=f32) * scale
-        return (dq_acc, j + 1), (dk_j, dv_j)
+            grid = (bh, nk, nq)
 
-    (dq, _), (dk_blocks, dv_blocks) = jax.lax.scan(
-        body, (jnp.zeros(qf.shape, f32), 0),
-        (kp, vp) if mask is None else (kp, vp, mp))
-    dk = jnp.moveaxis(dk_blocks, 0, 1).reshape(bh, t_pad, d)[:, :t]
-    dv = jnp.moveaxis(dv_blocks, 0, 1).reshape(bh, t_pad, d)[:, :t]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+            def q_at(b, j, i):
+                return jnp.maximum(i, (j * block_k) // block_q) \
+                    if causal else i
+
+            def k_at(b, j, i):
+                return j
+        q_spec = pl.BlockSpec((1, block_q, d),
+                              lambda *ix: (ix[0], q_at(*ix), 0))
+        k_spec = pl.BlockSpec((1, block_k, d),
+                              lambda *ix: (ix[0], k_at(*ix), 0))
+        in_specs = [q_spec, k_spec, k_spec, q_spec,
+                    pl.BlockSpec((1, 2, block_q),
+                                 lambda *ix: (ix[0], 0, q_at(*ix)))]
+        if mask is not None:
+            in_specs.append(pl.BlockSpec(
+                (1, 8, block_k), lambda *ix: (ix[0] // h, 0, k_at(*ix))))
+        dkv_scratch = [pltpu.VMEM((d, block_k), f32)] * 2
+        if form == "fused":
+            out_specs = [pl.BlockSpec((1, t_pad, d),
+                                      lambda *ix: (ix[0], 0, 0)),
+                         k_spec, k_spec]
+            scratch = [pltpu.VMEM((nq, d, block_q), f32)] + dkv_scratch
+        elif form == "dkv":
+            out_specs, scratch = [k_spec, k_spec], dkv_scratch
+        else:
+            out_specs, scratch = [q_spec], [pltpu.VMEM((1, d, block_q), f32)]
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=[jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype)]
+            * len(out_specs), scratch_shapes=scratch, interpret=interpret,
+            name="flash_attn_bwd_" + form)(*operands)
+
+    if form == "fused":
+        dq, dk, dv = call("fused")
+    else:
+        (dk, dv), (dq,) = call("dkv"), call("dq")
+    return dq[:, :t], dk[:, :t], dv[:, :t]
 
 
 def _attention_bwd(causal, scale, block_q, block_k, interpret, h, res, g):
-    dq, dk, dv = _bwd_core(causal, scale, block_k, res, g)
+    dq, dk, dv = _run_bwd(res, g, None, h, causal, scale, block_q, block_k,
+                          interpret)
     return dq, dk, dv, jnp.zeros_like(res[3])
 
 
@@ -567,7 +802,7 @@ def flash_attention_block(q, k, v, causal, scale, interpret):
     """(out [B,T,H,D], lse [B,H,T]) for ONE ring-attention block pair —
     the fused-kernel replacement for a naive [B,H,Tq,Tk]-logits block in
     parallel/sequence.py. The lse output lets the caller combine blocks by
-    log-sum-exp; its cotangent is handled exactly (see _bwd_core). Block
+    log-sum-exp; its cotangent is handled exactly (_run_bwd_local). Block
     sizes resolve through the same TuningDB/env/default table as the main
     ``flash_attention`` entry (this entry used to hardcode 512x512 and
     bypass even the env override)."""
@@ -585,17 +820,17 @@ def _flash_block_fwd(q, k, v, causal, scale, interpret):
     out, lse = _run_fwd(qf, kf, vf, None, h, causal, scale, bq, bk,
                         interpret)
     return (_unfold_heads(out, b, h), lse.reshape(b, h, t)), \
-        (qf, kf, vf, out, lse, b, h, bk)
+        (qf, kf, vf, out, lse, b, h, bq, bk)
 
 
 def _flash_block_bwd(causal, scale, interpret, res, grads):
-    # bk rides the residuals so fwd and bwd tile identically even if the
-    # DB/env resolution were to change between the two traces
-    qf, kf, vf, out, lse, b, h, bk = res
+    # the blocks ride the residuals so fwd and bwd tile identically even if
+    # the DB/env resolution were to change between the two traces
+    qf, kf, vf, out, lse, b, h, bq, bk = res
     g_out, g_lse = grads
-    dq, dk, dv = _bwd_core(causal, scale, bk, (qf, kf, vf, None, out, lse),
-                           _fold_heads(g_out),
-                           g_lse=g_lse.reshape(b * h, -1))
+    dq, dk, dv = _run_bwd((qf, kf, vf, None, out, lse), _fold_heads(g_out),
+                          g_lse.reshape(b * h, -1), h, causal, scale, bq, bk,
+                          interpret)
     return (_unfold_heads(dq, b, h), _unfold_heads(dk, b, h),
             _unfold_heads(dv, b, h))
 

@@ -15,7 +15,7 @@ candidates, then reject statically-invalid ones BEFORE any compile —
   in/out blocks + scratch + the score pieces / accumulator) must fit the
   ~16 MiB scoped VMEM; the estimate uses the same arithmetic the kernel
   docstrings derive (14 MiB budget — the margin ops/lstm_pallas.py
-  already uses);
+  already uses), for attention of the forward and of the backward kernel;
 * **redundant clamps**: blocks larger than the (128-rounded) array are
   clamped by the kernels at trace time, so such candidates duplicate a
   smaller one — measuring them would just burn live-window time;
@@ -89,7 +89,9 @@ def _attention_valid(cfg, shape, dtype):
     head's own width (a VMEM block still pads its minor dimension to the
     128 lanes), the out^T accumulator and the max / sum rows with block_q
     along the lanes, and the score pieces in flight — [sub_k, sub_q]
-    float32 each, never the whole block_q x block_k tile."""
+    float32 each, never the whole block_q x block_k tile. Then the
+    backward kernel's, in the form the call would take at this length (the
+    tuner times gradients): the kernel module's own count."""
     from deeplearning4j_tpu.ops import attention_pallas as _ap
     bq, bk = int(cfg["block_q"]), int(cfg["block_k"])
     _, t, _, d = shape
@@ -116,6 +118,12 @@ def _attention_valid(cfg, shape, dtype):
     )
     if vmem > VMEM_BUDGET:
         return f"vmem: ~{vmem // 1024} KiB exceeds the {VMEM_BUDGET // 1024} KiB budget"
+    t_pad = _ap._geometry(t, bq, bk)[2]
+    bwd = min(_ap.bwd_vmem_bytes(form, t_pad, d, bq, bk, itm)
+              for form in ("fused", "split"))
+    if bwd > VMEM_BUDGET:
+        return (f"vmem: the backward's ~{bwd // 1024} KiB exceeds the "
+                f"{VMEM_BUDGET // 1024} KiB budget")
     return None
 
 

@@ -39,11 +39,12 @@ def _last_json(capsys):
 def test_train_then_serve_phases_at_toy_size(tmp_path, capsys):
     net = smoke.build_net(**TOY)
     # what the chip run relies on: a failed check raises. On the CPU the
-    # compiled step holds no tpu_custom_call, so demanding one must fail.
+    # compiled step holds no tpu_custom_call, so demanding the two of a
+    # layer (the flash forward and backward kernel) must fail.
     with pytest.raises(AssertionError, match="tpu_custom_call"):
         smoke.train_phase(net, vocab=TOY["vocab"], seq_len=TOY["seq_len"],
                           batch=2, steps=1, k=2, workdir=str(tmp_path),
-                          flash_calls=1)
+                          flash_calls=2 * TOY["n_layers"])
     doc = smoke.train_phase(net, vocab=TOY["vocab"], seq_len=TOY["seq_len"],
                             batch=2, steps=2, k=2, workdir=str(tmp_path))
     assert doc == _last_json(capsys)
@@ -76,6 +77,14 @@ def test_looped_block_case_at_toy_size():
                                  d=16, ffn=48, interpret=True,
                                  tol={"fwd": 1e-6, "bwd": 1e-6})
     assert r["kernel"] == "looped_toy" and r["fwd_rel_err"] == 0.0
+
+
+def test_flash_backward_time_at_toy_size():
+    """The interpreted kernel's pullback runs and is timed; the number is
+    the host's and means nothing here."""
+    r = smoke._flash_backward_time("bwd_toy", b=1, t=128, h=1, d=16,
+                                   interpret=True, iters=1)
+    assert r["kernel"] == "bwd_toy" and r["bwd_ms"] > 0
 
 
 def test_multichip_phase_on_the_virtual_mesh(tmp_path, eight_devices):

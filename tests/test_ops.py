@@ -2,6 +2,8 @@
 ValidateCudnnLSTM — fast path vs reference path on identical inputs,
 SURVEY.md §4.6). Pallas kernels run in interpret mode on the CPU fixture."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -565,6 +567,166 @@ class TestFlashKernelGeometry:
                                        rtol=5e-4, atol=2e-5, err_msg=name)
 
 
+def _naive_folded_safe(q, k, v, mask, causal, scale):
+    """``_naive_folded`` with the kernel's contract on rows that see no
+    key: out 0 there, a zero gradient and no NaN (the plain softmax is NaN
+    on them, and so is its gradient)."""
+    s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
+    t = s.shape[-1]
+    valid = jnp.ones((1, t, t), bool)
+    if causal:
+        valid = valid & jnp.tril(jnp.ones((t, t), bool))[None]
+    if mask is not None:
+        valid = valid & (mask[:, None, :] > 0)
+    s = jnp.where(valid, s, -1e30)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    e = jnp.where(valid, jnp.exp(s - m), 0.0)
+    l = jnp.sum(e, axis=-1, keepdims=True)
+    out = jnp.einsum("bqk,bkd->bqd", e / jnp.maximum(l, 1e-30), v)
+    return out, (m + jnp.log(jnp.maximum(l, 1e-30)))[..., 0]
+
+
+class TestFlashBackwardKernel:
+    """The backward kernel (ISSUE 28) in interpret mode against jax.grad of
+    the naive path in float32, called where the custom_vjps call it
+    (``_run_bwd_local``) so that both of its forms are reached at a small
+    T: "fused" (one kernel a head, dq resident) and "split" (a dK/dV and a
+    dQ kernel), which ``_run_bwd`` chooses between from the shape. Every
+    case carries a cotangent on lse too, as ``flash_attention_block``'s
+    does. Mosaic's side is TestDefaultDispatchKernelsLowerForTpu."""
+
+    H = 2
+
+    def _case(self, d, causal, masked, t, seed):
+        rs = np.random.RandomState(seed)
+        q, k, v, g = (jnp.asarray(rs.randn(self.H, t, d).astype(np.float32)
+                                  * 0.5) for _ in range(4))
+        g_lse = rs.randn(self.H, t).astype(np.float32)
+        mask, empty = None, 0
+        if masked:
+            m = np.ones((1, t), np.float32)
+            m[0, t - t // 8:] = 0.0      # a padded tail, not piece-aligned
+            m[0, 5::11] = 0.0            # holes
+            if causal:
+                m[0, :3] = 0.0           # left padding: rows 0-2 see no key
+                empty = 3
+            mask = jnp.asarray(m)
+        g_lse[:, :empty] = 0.0           # their lse is the sentinel
+        scale = 1.0 / float(d) ** 0.5
+        maskh = None if mask is None else jnp.repeat(mask, self.H, 0)
+        (out, lse), vjp = jax.vjp(
+            lambda q, k, v: _naive_folded_safe(q, k, v, maskh, causal, scale),
+            q, k, v)
+        g_lse = jnp.asarray(g_lse)
+        return (q, k, v, out, lse, g, g_lse, mask), vjp((g, g_lse)), empty
+
+    def _check(self, got, want, empty):
+        for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+            assert np.isfinite(np.asarray(a)).all(), name
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=5e-4, atol=2e-5, err_msg=name)
+        # rows that see no key: out is 0 there whatever q is
+        assert np.all(np.asarray(got[0])[:, :empty] == 0.0)
+
+    @pytest.mark.parametrize("d,causal,masked,t,block_q,block_k,form", [
+        (d, causal, masked, t, bq, bk, form)
+        # every width on equal blocks, fused; both unequal geometries
+        # (positions compared on every piece) and the split form at width 64
+        for d, bq, bk, form in ((64, 256, 256, "fused"),
+                                (128, 256, 256, "fused"),
+                                (80, 256, 256, "fused"),
+                                (64, 128, 256, "fused"),
+                                (64, 256, 128, "split"),
+                                (64, 256, 256, "split"))
+        for causal in (False, True) for masked in (False, True)
+        for t in (512, 300)])      # whole blocks; a ragged tail
+    def test_gradients_match_naive(self, d, causal, masked, t, block_q,
+                                   block_k, form):
+        args, want, empty = self._case(d, causal, masked, t, seed=d + t)
+        got = attention_pallas._run_bwd_local(
+            *args, self.H, causal, 1.0 / float(d) ** 0.5, block_q, block_k,
+            True, form)
+        self._check(got, want, empty)
+
+    @pytest.mark.parametrize("t,block_q,block_k,causal,masked,form", [
+        (24, 8, 8, True, False, "fused"), (24, 8, 8, True, True, "split"),
+        (20, 8, 6, True, True, "fused"), (20, 8, 6, False, False, "split"),
+        (13, 8, 8, False, True, "fused"), (20, 6, 8, True, False, "fused")])
+    def test_blocks_that_are_not_128s(self, t, block_q, block_k, causal,
+                                      masked, form):
+        """A block of 8 or 6 is its own single piece; t 20 pads to
+        lcm(8, 6) = 24."""
+        args, want, empty = self._case(8, causal, masked, t, seed=t)
+        got = attention_pallas._run_bwd_local(
+            *args, self.H, causal, 1.0 / 8.0 ** 0.5, block_q, block_k, True,
+            form)
+        self._check(got, want, empty)
+
+    @pytest.mark.parametrize("wrong", ["no_delta", "no_causal_mask"])
+    def test_a_wrong_kernel_fails_the_check(self, wrong):
+        """The control: the comparison above refuses a backward that drops
+        the delta term of ds = p * (dp - delta) (out = 0 makes delta 0) and
+        one that walks the tiles as if the call were not causal."""
+        (q, k, v, out, lse, g, g_lse, mask), want, empty = self._case(
+            64, True, False, 512, seed=28)
+        if wrong == "no_delta":
+            out, g_lse = jnp.zeros_like(out), None
+        got = attention_pallas._run_bwd_local(
+            q, k, v, out, lse, g, g_lse, mask, self.H,
+            wrong != "no_causal_mask", 0.125, 256, 256, True, "fused")
+        with pytest.raises(AssertionError):
+            self._check(got, want, empty)
+
+    @pytest.mark.parametrize("t,d,itemsize,form", [
+        (1024, 64, 4, "fused"),      # the gpt2m-train-t1024 cell's call
+        (2048, 128, 4, "fused"),     # the ouro-train-t2048 cell's
+        (4096, 64, 2, "fused"),      # the longcontext configuration's
+        (8192, 128, 4, "split"),     # refused fused by the TPU compiler
+        (65536, 128, 2, "split")])
+    def test_form_follows_the_shape(self, monkeypatch, t, d, itemsize, form):
+        """``_run_bwd`` takes the fused form while a head's dq fits VMEM
+        beside the blocks and the split one past that; the count it
+        decides by is the tuner's validity model too."""
+        seen = []
+        monkeypatch.setattr(
+            attention_pallas, "_run_bwd_local",
+            lambda *a: seen.append(a[-1]) or (a[0], a[1], a[2]))
+        x = jax.ShapeDtypeStruct(
+            (2, t, d), jnp.float32 if itemsize == 4 else jnp.bfloat16)
+        lse = jax.ShapeDtypeStruct((2, t), jnp.float32)
+        jax.eval_shape(
+            lambda q, lse: attention_pallas._run_bwd(
+                (q, q, q, None, q, lse), q, None, 1, True, 0.125, 512, 512,
+                False), x, lse)
+        assert seen == [form]
+        fused = attention_pallas.bwd_vmem_bytes("fused", t, d, 512, 512,
+                                                itemsize)
+        assert (fused <= attention_pallas._VMEM_BUDGET) == (form == "fused")
+        assert attention_pallas.bwd_vmem_bytes(
+            "split", t, d, 512, 512, itemsize) < 6 * 2 ** 20
+
+    def test_custom_vjp_reaches_the_split_form(self, monkeypatch):
+        """Through ``flash_attention``'s custom_vjp with the budget at 0:
+        the two kernels' gradients are the fused kernel's."""
+        rs = np.random.RandomState(7)
+        q, k, v = (jnp.asarray(rs.randn(1, 160, 2, 16).astype(np.float32))
+                   for _ in range(3))
+
+        def grads():
+            return jax.grad(lambda q, k, v: jnp.sum(
+                attention_pallas.flash_attention(
+                    q, k, v, causal=True, block_q=128, block_k=128,
+                    interpret=True) ** 2), argnums=(0, 1, 2))(q, k, v)
+        fused = grads()
+        monkeypatch.setattr(attention_pallas, "_VMEM_BUDGET", 0)
+        names = str(jax.make_jaxpr(grads)())
+        assert "flash_attn_bwd_dkv" in names and "flash_attn_bwd_dq" in names
+        assert "flash_attn_bwd_fused" not in names
+        for a, b in zip(grads(), fused):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-6)
+
+
 def _ref_scan_any(xz, wh, h0, c0, wp=None, mask=None):
     """Scan reference covering peephole x mask (mask time-major [T, B],
     1=valid: state freezes at padded steps — nn/layers/rnn.py _step)."""
@@ -854,6 +1016,70 @@ class TestDefaultDispatchKernelsLowerForTpu:
         assert operands.startswith(
             ", ".join([f"tensor<{b * h}x{t_pad}x{d}xbf16>"] * 3)), operands
 
+    @pytest.mark.parametrize("b,t,h,d,kernels", [
+        (4, 1024, 16, 64, ("fused",)),    # the gpt2m-train-t1024 cell's
+        (2, 2048, 16, 128, ("fused",)),   # the ouro-train-t2048 cell's
+        (1, 8192, 2, 128, ("dkv", "dq")),  # a head's dq past VMEM
+    ])
+    def test_flash_backward_is_a_kernel_under_its_scope(self, b, t, h, d,
+                                                        kernels):
+        """float32 operands, as both cells hand them: the gradient holds
+        the backward kernel(s) under ``flash_attn.bwd`` and no loop."""
+        q = jnp.zeros((b, t, h, d), jnp.float32)
+
+        def loss(q, k, v):
+            return jnp.sum(attention_pallas.flash_attention(
+                q, k, v, causal=True))
+        text = _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)),
+                              q, q, q).as_text(debug_info=True)
+        assert "stablehlo.while" not in text
+        calls = re.findall(r'kernel_name = "(flash_attn_[a-z_]+)"', text)
+        assert sorted(calls) == sorted(
+            ["flash_attn_fwd"] + ["flash_attn_bwd_" + k for k in kernels])
+        paths = set(re.findall(r'loc\("([^"]+)"', text))
+        for k in kernels:
+            assert any("transpose(" in p and "flash_attn.bwd" in p
+                       and "/flash_attn_bwd_" + k + "/" in p
+                       for p in paths), k
+            # the matrix units round a float32 operand to bfloat16 as they
+            # take it, so the backward is handed q, k, v, g already rounded
+            # (half the residuals kept from the forward; the chip's results
+            # are the same to the bit, PERF.md PR 28) and returns float32
+            call = next(ln for ln in text.splitlines()
+                        if f'kernel_name = "flash_attn_bwd_{k}"' in ln)
+            operands, results = call.split(" : (")[1].split(") -> ")
+            assert operands.startswith(
+                ", ".join([f"tensor<{b * h}x{t}x{d}xbf16>"] * 4)), operands
+            assert "bf16" not in results and f"x{d}xf32>" in results, results
+
+    def test_flash_backward_shards_by_batch_under_the_declared_mesh(
+            self, eight_devices):
+        """A pallas_call does not partition itself as the scan did: under
+        the declared mesh the backward kernel runs once a batch shard, the
+        key mask sharded with it."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from deeplearning4j_tpu.ops import spmd
+        mesh = Mesh(np.array(eight_devices), ("data",))
+        sh = NamedSharding(mesh, P("data"))
+        q = jax.ShapeDtypeStruct((8, 1024, 4, 64), jnp.bfloat16, sharding=sh)
+        mask = jax.ShapeDtypeStruct((8, 1024), jnp.float32, sharding=sh)
+
+        def grads(q, k, v, mask):
+            with spmd.kernel_mesh(mesh):
+                return jax.grad(lambda q, k, v: jnp.sum(
+                    attention_pallas.flash_attention(
+                        q, k, v, mask=mask, causal=True)
+                    .astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+        text = _lower_for_tpu(grads, q, q, q, mask).as_text()
+        call = next(ln for ln in text.splitlines()
+                    if 'kernel_name = "flash_attn_bwd_fused"' in ln)
+        operands, results = call.split(" : (")[1].split(") -> ")
+        # one batch element of four heads a device; its [1, 8, T] mask
+        assert operands.startswith(
+            ", ".join(["tensor<4x1024x64xbf16>"] * 4)), operands
+        assert operands.endswith("tensor<1x8x1024xf32>"), operands
+        assert results.count("tensor<4x1024x64xbf16>") == 3, results
+
     def test_ring_attention_block(self):
         q = jnp.zeros((2, 1024, 8, 64), jnp.bfloat16)
 
@@ -931,6 +1157,35 @@ class TestKernelsPerBatchShard:
         np.testing.assert_allclose(np.asarray(got),
                                    np.asarray(attn(q, k, v, mask)),
                                    atol=1e-6)
+
+    def test_flash_gradients_with_padding_mask(self, eight_devices):
+        """The backward kernel once a batch shard, its mask sharded with
+        the batch, against the same gradients with no mesh declared."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from deeplearning4j_tpu.ops import spmd
+        mesh = Mesh(np.array(eight_devices[:4]), ("data",))
+        rs = np.random.RandomState(28)
+        q, k, v = (jnp.asarray(rs.randn(8, 128, 2, 16).astype(np.float32))
+                   for _ in range(3))
+        mask = jnp.asarray((np.arange(128)[None, :]
+                            < rs.randint(64, 129, 8)[:, None])
+                           .astype(np.float32))
+
+        def grads(q, k, v, mask):
+            return jax.grad(lambda q, k, v: jnp.sum(
+                attention_pallas.flash_attention(
+                    q, k, v, mask=mask, causal=True, interpret=True) ** 2),
+                argnums=(0, 1, 2))(q, k, v)
+
+        def sharded(q, k, v, mask):
+            with spmd.kernel_mesh(mesh):
+                return grads(q, k, v, mask)
+        sh = NamedSharding(mesh, P("data"))
+        got = jax.jit(sharded, in_shardings=(sh,) * 4)(q, k, v, mask)
+        for g, r in zip(got, grads(q, k, v, mask)):
+            assert g.sharding.spec[0] == "data"
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       rtol=1e-5, atol=1e-6)
 
     def test_masked_peephole_lstm(self, eight_devices):
         from jax.sharding import Mesh

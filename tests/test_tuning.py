@@ -341,7 +341,7 @@ class TestRuntimeDispatch:
 
     def test_flash_attention_block_grad_uses_resolved_blocks(self):
         """fwd/bwd parity under a tuned block size (bk rides the
-        residuals into _bwd_core)."""
+        residuals into _run_bwd)."""
         self._db_with_attention((1, 128, 2, 16), backend="flash",
                                 block_q=128, block_k=128)
         rs = np.random.RandomState(1)
