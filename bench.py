@@ -175,11 +175,8 @@ def bench_resnet50(batch=64, hw=224, warmup=2, iters=30):
     # BENCH_REMAT=1: block-level activation rematerialization (A/B knob for
     # the HBM-traffic-vs-FLOPs trade; see models/resnet.py docstring)
     remat = os.environ.get("BENCH_REMAT", "0") == "1"
-    # BENCH_FUSED_CONV=1: FusedConvBNVertex graph — the Pallas conv kernel
-    # folds the BN stats reduction into the conv epilogue (ops/conv_pallas)
-    fused = os.environ.get("BENCH_FUSED_CONV", "0") == "1"
     net = ComputationGraph(resnet50(
-        height=hw, width=hw, n_classes=1000, fused=fused,
+        height=hw, width=hw, n_classes=1000,
         checkpoint_scope="prefix" if remat else None))
     net.init()
     raw = net.make_train_step(donate=True, jit=False)
@@ -197,15 +194,14 @@ def bench_resnet50(batch=64, hw=224, warmup=2, iters=30):
     # analytic estimate: train step ~ 3x fwd FLOPs
     analytic = 3.0 * resnet50_flops_per_example(hw, hw) * batch
     # MFU counts USEFUL model FLOPs: under remat XLA's cost analysis also
-    # counts the recompute (inflating MFU), and under fused-conv the Pallas
-    # custom-calls are invisible to it (deflating MFU) — both use analytic
-    flops = analytic if (remat or fused) else (info.get("xla_flops_per_step")
-                                               or analytic)
+    # counts the recompute (inflating MFU), so that leg uses analytic
+    flops = analytic if remat else (info.get("xla_flops_per_step")
+                                    or analytic)
     rec = {"metric": "resnet50_train_samples_per_sec",
            "value": round(sps, 2), "unit": "samples/sec/chip",
            "vs_baseline": round(sps / BASELINES["resnet50"], 2),
            "step_time_ms": round(1e3 * dt, 2), "batch": batch, "hw": hw,
-           "remat": remat, "fused_conv": fused,
+           "remat": remat,
            "analytic_flops_per_step": analytic,
            "flops_source": ("analytic_3x_fwd"
                             if flops is analytic
@@ -243,8 +239,8 @@ def bench_lstm(batch=64, seq=128, hidden=512, vocab=96, warmup=2, iters=30):
     y = jnp.asarray(np.eye(vocab, dtype=np.float32)[np.roll(ids, -1, axis=1)])
     rng = jax.random.PRNGKey(0)
     # BENCH_LSTM_MASKED=1: a variable-length batch (25-100% of T) — the
-    # masked fused-kernel path (state freezing), A/B against the scan path
-    # via DL4J_TPU_FUSED_LSTM=0 (VERDICT r3 #4 coverage on hardware)
+    # masked fused-kernel path (state freezing; VERDICT r3 #4 coverage on
+    # hardware)
     masked = os.environ.get("BENCH_LSTM_MASKED", "0") == "1"
     mask = None
     if masked:
@@ -427,20 +423,10 @@ def bench_transformer(batch=32, seq=512, d_model=512, n_layers=6,
     dt, info = _train_bench(raw, net.params, net.state, net.opt_state,
                             (x, y, 0, rng, None), warmup, iters)
     tps = batch * seq / dt
-    # report whether the fused kernel actually DISPATCHES for these shapes,
-    # not just that the seam is enabled (A/B integrity)
+    # report whether the fused kernel actually DISPATCHES for these shapes
     q_shape = (batch, seq, n_heads, d_model // n_heads)
-    fused = attention_pallas.enabled() and attention_pallas.supported(
-        q_shape, q_shape, None, jnp.bfloat16)
-    # flash block-size tuning legs record their knob, and only when the
-    # kernel actually dispatched: with fused=False the knob is never read
-    # and the numbers are plain naive-path numbers.
-    flash_block = None
-    if fused and (os.environ.get("DL4J_TPU_FLASH_BLOCK_Q")
-                  or os.environ.get("DL4J_TPU_FLASH_BLOCK_K")):
-        from deeplearning4j_tpu.ops.attention_pallas import env_block
-        flash_block = (f'{env_block("DL4J_TPU_FLASH_BLOCK_Q")}'
-                       f'x{env_block("DL4J_TPU_FLASH_BLOCK_K")}')
+    fused = attention_pallas.resolve_attention(
+        q_shape, q_shape, None, jnp.bfloat16) is not None
     # MFU by the standard LM accounting: train FLOPs/token ~ 6*P where P
     # counts MATMUL-path params only (the input embedding + positional
     # tables are a gather — counting them would inflate MFU ~14% at the
@@ -460,8 +446,6 @@ def bench_transformer(batch=32, seq=512, d_model=512, n_layers=6,
     peak = _peak_flops()
     if peak is not None:
         rec["mfu"] = round(flops_per_token * tps / peak, 4)
-    if flash_block:
-        rec["flash_block"] = flash_block
     return rec
 
 
@@ -2099,151 +2083,13 @@ def bench_longcontext():
                              metric="transformer_lm_4k_train_tokens_per_sec")
 
 
-def bench_kernels():
-    """Kernel-autotuner A/B (deeplearning4j_tpu/tuning, ISSUE 11): tune a
-    fresh DB, run each kernel tuned-vs-default, then prove the
-    warm-restart composition — a process with the populated TuningDB +
-    a warm manifest runs TUNED kernels with zero compiles. The gate
-    (scripts/check_tuning.py) is parity and counters, never wall time:
-    CPU legs run the kernels in interpret mode, where only the mechanics
-    (enumerate→prune→measure→persist→lookup→manifest) are under test."""
-    import shutil
-    import tempfile
-
-    import jax
-    import jax.numpy as jnp
-
-    from deeplearning4j_tpu import telemetry, tuning
-    from deeplearning4j_tpu.ops import attention_pallas as _ap
-    from deeplearning4j_tpu.ops import conv_pallas as _cp
-    from deeplearning4j_tpu.tuning import measure as _measure
-    from deeplearning4j_tpu.utils import compile_cache as _cc
-
-    telemetry.enable()
-    interpret = jax.default_backend() != "tpu"
-    smoke = _preflight() or interpret
-    kernels = (["attention", "conv_matmul"] if smoke
-               else ["attention", "conv_matmul", "conv3x3", "lstm"])
-    workdir = tempfile.mkdtemp(prefix="dl4j_kernels_bench_")
-    try:
-        db_path = os.path.join(workdir, "tuning_db.json")
-        db = tuning.TuningDB(db_path)
-        summaries = tuning.tune_kernels(db, kernels, smoke=smoke,
-                                        interpret=interpret)
-        db.save(db_path)
-
-        # ---- tuned-vs-default A/B: same entry point, DB bound or not --
-        rs = np.random.RandomState(7)
-        ab_args = {}
-        if "attention" in summaries:
-            b, t, h, d = summaries["attention"]["shape"]
-            q, k, v = (jnp.asarray(rs.normal(size=(b, t, h, d)) * 0.1,
-                                   jnp.float32) for _ in range(3))
-
-            def attn_fn(q, k, v):
-                return _ap.flash_attention(q, k, v, interpret=interpret)
-
-            ab_args["attention"] = (attn_fn, (q, k, v))
-        if "conv_matmul" in summaries:
-            n, cin, cout = summaries["conv_matmul"]["shape"]
-            x2 = jnp.asarray(rs.normal(size=(n, cin)) * 0.1, jnp.float32)
-            w2 = jnp.asarray(rs.normal(size=(cin, cout)) * 0.1, jnp.float32)
-
-            def conv_fn(x2, w2):
-                return _cp._matmul_stats(x2, w2, interpret)
-
-            ab_args["conv_matmul"] = (conv_fn, (x2, w2))
-
-        iters = 2 if smoke else 8
-        # the default legs must see NO tuned configs: an explicit EMPTY
-        # binding (set_db(None) would fall back to an operator's
-        # $DL4J_TPU_TUNING_DB and contaminate the A/B reference)
-        no_db = tuning.TuningDB()
-        legs = {}
-        for name, (fn, args) in ab_args.items():
-            s = summaries[name]
-            tuning.set_db(no_db)          # default (hand-picked) leg
-            out_def = fn(*args)
-            def_ms = 1e3 * _measure.time_callable(fn, args, iters=iters,
-                                                  reps=1)
-            tuning.set_db(db)             # tuned leg: DB consulted at trace
-            out_tuned = fn(*args)
-            tuned_ms = 1e3 * _measure.time_callable(fn, args, iters=iters,
-                                                    reps=1)
-            tuning.set_db(no_db)
-            legs[name] = {
-                "winner": s["winner"], "winner_ms": s["winner_ms"],
-                "candidates": s["candidates"],
-                "pruned_static": s["pruned_static"],
-                "rejected_parity": s["rejected_parity"],
-                "default_ms": round(def_ms, 4),
-                "tuned_ms": round(tuned_ms, 4),
-                "parity_tuned_vs_default":
-                    _measure.parity_diff(out_tuned, out_def),
-            }
-
-        # ---- warm-restart composition: DB + manifest → tuned kernels,
-        # zero compiles, only hit events ----------------------------------
-        warm = {}
-        if "attention" in ab_args:
-            fn, args = ab_args["attention"]
-            tuning.set_db(no_db)          # default-path parity reference
-            out_default = fn(*args)
-            tuning.set_db(db)
-            jitted = jax.jit(fn)
-            man = _cc.WarmManifest(model_fp="bench:kernels")
-            ex, src_cold = _cc.aot_compile(jitted, *args, manifest=man,
-                                           kind="bench:kernels")
-            blob = man.to_bytes()
-            # --- simulated restart: fresh jit object, manifest reloaded,
-            # counters snapshotted so only the warm path moves them ---
-            man2 = _cc.WarmManifest.from_bytes(blob)
-            cc0 = dict(_cc.event_counts())
-            tu0 = dict(tuning.event_counts())
-            from deeplearning4j_tpu.telemetry import devices as _devices
-            rec0 = sum(_devices.recompile_counts().values())
-            cfg = tuning.tuned_config(
-                "attention", summaries["attention"]["shape"], jnp.float32)
-            jitted2 = jax.jit(fn)
-            ex2, src_warm = _cc.aot_compile(jitted2, *args, manifest=man2,
-                                            kind="bench:kernels")
-            out_warm = ex2(*args)
-            cc1, tu1 = _cc.event_counts(), tuning.event_counts()
-            tuning.set_db(no_db)
-            warm = {
-                "cold_source": src_cold, "warm_source": src_warm,
-                "tuned_config": cfg,
-                "compile_cache_delta": {
-                    k: cc1.get(k, 0) - cc0.get(k, 0)
-                    for k in set(cc0) | set(cc1)},
-                "tuning_db_delta": {
-                    k: tu1.get(k, 0) - tu0.get(k, 0)
-                    for k in set(tu0) | set(tu1)},
-                "recompiles_delta":
-                    sum(_devices.recompile_counts().values()) - rec0,
-                "parity_warm_vs_default":
-                    _measure.parity_diff(out_warm, out_default),
-            }
-
-        attn = legs.get("attention", {})
-        return {"metric": "kernel_autotuner_ab",
-                "value": attn.get("tuned_ms", 0), "unit": "ms/iter",
-                "vs_baseline": None, "interpret": interpret,
-                "smoke": smoke, "db_entries": len(db),
-                "db_events": tuning.event_counts(),
-                "kernels": legs, "warm": warm}
-    finally:
-        tuning.set_db(None)
-        shutil.rmtree(workdir, ignore_errors=True)
-
-
 CONFIGS = {"lenet": bench_lenet, "resnet50": bench_resnet50,
            "lstm": bench_lstm, "word2vec": bench_word2vec,
            "parallel": bench_parallel, "transformer": bench_transformer,
            "longcontext": bench_longcontext, "fused": bench_fused,
            "serving": bench_serving, "trace_overhead": bench_trace_overhead,
            "coldstart": bench_coldstart, "zero": bench_zero,
-           "kernels": bench_kernels, "fleet": bench_fleet,
+           "fleet": bench_fleet,
            "continuous": bench_continuous, "hostfleet": bench_hostfleet,
            "cluster_obs": bench_cluster_obs,
            "slo_goodput": bench_slo_goodput,

@@ -362,8 +362,8 @@ def _flash_case(name, *, b, t, h, d, causal, masked, block, interpret, tol):
         mask = jnp.asarray(np.arange(t)[None, :] < lens[:, None],
                            jnp.float32)
     if not interpret:
-        _expect(_ap.enabled() and _ap.supported(q.shape, k.shape, mask,
-                                                q.dtype),
+        _expect(_ap.resolve_attention(q.shape, k.shape, mask,
+                                      q.dtype) is not None,
                 f"{name}: the dispatch gate does not admit this shape")
     if block:
         scale = 1.0 / float(d) ** 0.5
@@ -481,8 +481,8 @@ def _looped_block_case(name, *, b, t, width, h, d, ffn, interpret, tol):
     params = block.init(jax.random.PRNGKey(width), I.RecurrentType(width, t))
     x = jax.random.normal(jax.random.PRNGKey(t), (b, t, width), jnp.float32)
     if not interpret:
-        _expect(_ap.enabled() and _ap.supported(
-            (b, t, h, d), (b, t, h, d), None, jnp.float32),
+        _expect(_ap.resolve_attention(
+            (b, t, h, d), (b, t, h, d), None, jnp.float32) is not None,
             f"{name}: the dispatch gate does not admit this shape")
 
     def apply(params, x):

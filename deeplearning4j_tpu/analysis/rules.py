@@ -514,7 +514,7 @@ class RecompileRule(Rule):
             if fn is not None and mod.in_loop_within(node, fn) \
                     and not self._feeds_aot_compile(node, mod):
                 # a jit whose result flows into aot_compile() in the same
-                # loop body is the AUTOTUNE idiom (tuning/measure.py):
+                # loop body is the AUTOTUNE idiom:
                 # one deliberate, manifest-aware compile per candidate is
                 # the search working, not a recompile hazard — the
                 # blessed site counts and caches it

@@ -174,40 +174,6 @@ def _build_parser():
                     help="print the runner's own argument reference")
     cn.add_argument("runner_args", nargs=argparse.REMAINDER)
 
-    tn = sub.add_parser(
-        "tune",
-        help="kernel autotuner (tuning/): search Pallas configs "
-             "(attention blocks + crossover, conv tiles, lstm column "
-             "tiles), parity-gate every candidate against the reference "
-             "path, and persist winners into the tuning DB the ops "
-             "dispatch seams consult at trace time")
-    tn.add_argument("--db", metavar="PATH",
-                    help="tuning DB JSON to update (default: "
-                         "$DL4J_TPU_TUNING_DB); existing entries merge — "
-                         "a re-tune IS the refresh")
-    tn.add_argument("--kernels",
-                    help="comma-separated kernel subset "
-                         "(attention,conv_matmul,conv3x3,lstm; default "
-                         "all)")
-    tn.add_argument("--interpret", action="store_true",
-                    help="run candidates in Pallas interpret mode "
-                         "(forced automatically off-TPU: the mechanics "
-                         "run anywhere, the timings only transfer from "
-                         "real hardware)")
-    tn.add_argument("--smoke", action="store_true",
-                    help="tiny shapes + trimmed candidate sets (CI "
-                         "mechanics check)")
-    tn.add_argument("--grad", action="store_true",
-                    help="time fwd+bwd instead of forward only (opens "
-                         "the attention remat dimension)")
-    tn.add_argument("--iters", type=int,
-                    help="chained in-jit iterations per timing window")
-    tn.add_argument("--reps", type=int,
-                    help="timing windows per candidate (best-of)")
-    tn.add_argument("--tol", type=float, default=1e-6,
-                    help="parity gate vs the reference path (default "
-                         "1e-6; raise explicitly for bf16 tuning)")
-
     tl = sub.add_parser(
         "telemetry",
         help="dump a metrics snapshot (local registry, or scrape a "
@@ -731,59 +697,6 @@ def _cmd_eval(args):
     ev = Evaluation()
     ev.eval(y, preds)
     print(ev.stats())
-    return 0
-
-
-def _cmd_tune(args):
-    """Populate the kernel-tuning DB (ROADMAP's TVM-mold autotuner): the
-    live-TPU workflow is one `tune --db tuned.json` per window — every
-    later process with DL4J_TPU_TUNING_DB pointed at it traces tuned
-    kernels, and warm manifests built under it serve TUNED executables
-    with zero compiles."""
-    import json
-    import os
-
-    from deeplearning4j_tpu import telemetry, tuning
-    from deeplearning4j_tpu.ops.attention_pallas import backend_is_tpu
-
-    telemetry.enable()  # the event counters are part of the output
-    path = args.db or os.environ.get(tuning.ENV_DB)
-    if not path:
-        raise SystemExit("tune: no DB path (--db PATH or "
-                         f"${tuning.ENV_DB})")
-    interpret = args.interpret
-    if not backend_is_tpu() and not interpret:
-        print("tune: no TPU backend — running candidates in interpret "
-              "mode (mechanics only; timings do not transfer)")
-        interpret = True
-    db = tuning.TuningDB.load_lenient(path) or tuning.TuningDB(path)
-    tuning.set_db(db)  # this process's later traces see the fresh winners
-    kernels = ([k.strip() for k in args.kernels.split(",") if k.strip()]
-               if args.kernels else None)
-    overrides = {"tol": args.tol}
-    if args.iters:
-        overrides["iters"] = args.iters
-    if args.reps:
-        overrides["reps"] = args.reps
-    try:
-        summaries = tuning.tune_kernels(
-            db, kernels, smoke=args.smoke, interpret=interpret,
-            grad=args.grad, log=print, **overrides)
-    except ValueError as e:
-        raise SystemExit(f"tune: {e}")
-    finally:
-        tuning.set_db(None)
-    db.save(path)
-    for name, s in summaries.items():
-        print(f"{name}: winner {s['winner']} "
-              f"({s['winner_ms']} ms/iter; {s['candidates']} measured, "
-              f"{s['pruned_static']} pruned, {s['rejected_parity']} "
-              f"parity-rejected)")
-    print(f"tuning DB: {path} ({len(db)} entr"
-          f"{'y' if len(db) == 1 else 'ies'}); events "
-          f"{json.dumps(tuning.event_counts())}")
-    print("note: warm manifests key on the DB content — executables "
-          "compiled under the old DB refresh themselves on next start")
     return 0
 
 
@@ -1351,8 +1264,6 @@ def main(argv=None):
         return _cmd_bench(args)
     if args.command == "eval":
         return _cmd_eval(args)
-    if args.command == "tune":
-        return _cmd_tune(args)
     if args.command == "telemetry":
         return _cmd_telemetry(args)
     if args.command == "flightrec":

@@ -18,21 +18,8 @@ from deeplearning4j_tpu.nn.conf import inputs as I
 from deeplearning4j_tpu.nn.graph import ElementWiseVertex, GraphBuilder
 
 
-def _fused_vertex():
-    # deferred so the unfused path never imports ops/conv_pallas
-    from deeplearning4j_tpu.nn.fusion import FusedConvBNVertex
-    return FusedConvBNVertex
-
-
 def _conv_bn(g, name, inp, n_out, kernel, stride=(1, 1), padding="same",
-             activation="relu", fused=False):
-    if fused:
-        FusedConvBNVertex = _fused_vertex()
-        g.add_vertex(f"{name}_bn",
-                     FusedConvBNVertex(n_out=n_out, kernel=kernel,
-                                       stride=stride, padding=padding,
-                                       activation=activation), inp)
-        return f"{name}_bn"
+             activation="relu"):
     g.add_layer(f"{name}_conv",
                 L.ConvolutionLayer(n_out=n_out, kernel=kernel, stride=stride,
                                    padding=padding, has_bias=False,
@@ -42,27 +29,11 @@ def _conv_bn(g, name, inp, n_out, kernel, stride=(1, 1), padding="same",
     return f"{name}_bn"
 
 
-def _bottleneck(g, name, inp, filters, stride=(1, 1), project=False,
-                fused=False):
+def _bottleneck(g, name, inp, filters, stride=(1, 1), project=False):
     """1x1 reduce -> 3x3 -> 1x1 expand (4x) with shortcut add."""
     f1, f2, f3 = filters, filters, filters * 4
-    x = _conv_bn(g, f"{name}_a", inp, f1, (1, 1), stride=stride, fused=fused)
-    x = _conv_bn(g, f"{name}_b", x, f2, (3, 3), fused=fused)
-    if fused:
-        # the bottleneck tail (conv_c -> BN -> add -> relu) collapses into
-        # ONE fused vertex with the shortcut as the residual input
-        FusedConvBNVertex = _fused_vertex()
-        if project:
-            shortcut = _conv_bn(g, f"{name}_proj", inp, f3, (1, 1),
-                                stride=stride, activation="identity",
-                                fused=True)
-        else:
-            shortcut = inp
-        g.add_vertex(f"{name}_relu",
-                     FusedConvBNVertex(n_out=f3, kernel=(1, 1),
-                                       activation="relu", residual=True),
-                     x, shortcut)
-        return f"{name}_relu"
+    x = _conv_bn(g, f"{name}_a", inp, f1, (1, 1), stride=stride)
+    x = _conv_bn(g, f"{name}_b", x, f2, (3, 3))
     x = _conv_bn(g, f"{name}_c", x, f3, (1, 1), activation="identity")
     if project:
         shortcut = _conv_bn(g, f"{name}_proj", inp, f3, (1, 1), stride=stride,
@@ -75,17 +46,10 @@ def _bottleneck(g, name, inp, filters, stride=(1, 1), project=False,
 
 
 def resnet50(height=224, width=224, channels=3, n_classes=1000, updater=None,
-             seed=12345, checkpoint_scope=None, fused=False):
+             seed=12345, checkpoint_scope=None):
     """``checkpoint_scope="prefix"`` remats each bottleneck block during
     backward (nn/graph.py scope-level checkpointing): only block-boundary
-    activations are stashed, the block interior recomputes. On v5e the
-    model is HBM-bandwidth-bound at 27% MXU (PROFILE.md) — trading idle
-    FLOPs for the activation-stash traffic is the MFU lever.
-
-    ``fused=True`` builds conv->BN(->add->relu) chains as FusedConvBNVertex
-    (nn/fusion.py): the Pallas conv kernel folds the BN statistics
-    reduction into the conv epilogue (ops/conv_pallas.py), the stacked
-    second lever on the same HBM bound (BENCH_FUSED_CONV A/B)."""
+    activations are stashed, the block interior recomputes."""
     g = GraphBuilder(updater=updater or U.Adam(learning_rate=1e-3), seed=seed,
                      checkpoint_scope=checkpoint_scope)
     g.add_inputs("input")
@@ -101,7 +65,7 @@ def resnet50(height=224, width=224, channels=3, n_classes=1000, updater=None,
         for bi in range(blocks):
             x = _bottleneck(g, f"s{si}b{bi}", x, filters,
                             stride=stride if bi == 0 else (1, 1),
-                            project=bi == 0, fused=fused)
+                            project=bi == 0)
 
     g.add_layer("avgpool", L.GlobalPoolingLayer(mode="avg"), x)
     g.add_layer("fc", L.OutputLayer(n_out=n_classes, loss="mcxent",

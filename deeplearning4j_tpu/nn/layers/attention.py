@@ -112,25 +112,14 @@ def dot_product_attention(q, k, v, *, mask=None, causal=False, scale=None):
     traffic instead of the [B,H,T,T] logits tensor; the dispatch seam
     mirrors the LSTM fused path."""
     from deeplearning4j_tpu.ops import attention_pallas as _ap
-    resolved = (_ap.resolve_attention(q.shape, k.shape, mask, q.dtype)
-                if (_ap.enabled() and (scale is None
-                                       or isinstance(scale, (int, float))))
-                else None)
-    if resolved is not None:
-        # one DB lookup decides dispatch AND geometry: TuningDB winner >
-        # the DL4J_TPU_FLASH_BLOCK_Q/K env knobs (live-window A/B
-        # sweeps) > the hand-picked 512x512. Read once per trace — jit
-        # caches the chosen blocks into the compiled step. A tuned
-        # remat=True wraps the kernel in jax.checkpoint: the backward
-        # recomputes the forward instead of saving out/lse residuals
-        # (the searched memory-for-time dimension).
-        bq, bk, remat = resolved
-
-        def flash(q, k, v):
-            return _ap.flash_attention(q, k, v, mask=mask, causal=causal,
-                                       scale=scale, block_q=bq, block_k=bk)
-
-        return (jax.checkpoint(flash) if remat else flash)(q, k, v)
+    # the kernel needs a static scale; read once per trace, and jit keeps
+    # the chosen blocks in the compiled step
+    blocks = (_ap.resolve_attention(q.shape, k.shape, mask, q.dtype)
+              if scale is None or isinstance(scale, (int, float)) else None)
+    if blocks is not None:
+        return _ap.flash_attention(q, k, v, mask=mask, causal=causal,
+                                   scale=scale, block_q=blocks[0],
+                                   block_k=blocks[1])
     cd, ad = _dtypes.compute_dtypes_for(q.dtype)
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / jnp.sqrt(jnp.asarray(d, ad))

@@ -103,7 +103,7 @@ class LSTM(ParamLayer):
         dispatch seam mirroring the reference's reflective cuDNN-helper
         loading at ConvolutionLayer.java:74-84 — here explicit.)"""
         from deeplearning4j_tpu.ops import lstm_pallas
-        if not lstm_pallas.enabled():  # env flag + TPU backend, one place
+        if not lstm_pallas.enabled():  # the TPU backend gate, one place
             return False
         return lstm_pallas.supported(
             x.shape, self.n_out, peephole=self.peephole, mask=mask,

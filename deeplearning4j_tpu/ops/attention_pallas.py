@@ -41,15 +41,15 @@ Kernel design (FlashAttention-style online softmax, TPU-first):
   dQ kernel (seven): ``_run_bwd`` chooses from the shape.
 
 ``interpret=True`` runs the same kernel on CPU for tests (slow);
-``enabled()`` gates the fast path to real TPU backends plus an env flag,
-sharing the backend check with ops/lstm_pallas.py.
+``resolve_attention`` is the one place that chooses between this kernel
+and the XLA path and that names the blocks, from shape, dtype, mask and
+backend (the backend check is shared with ops/lstm_pallas.py).
 """
 
 from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -70,31 +70,24 @@ def backend_is_tpu():
     return jax.default_backend() == "tpu"
 
 
-def enabled():
-    if os.environ.get("DL4J_TPU_FUSED_ATTENTION", "1") == "0":
-        return False
-    return backend_is_tpu()
-
-
 # Measured v5e crossover (fwd+bwd, bf16, h=8 d=64, chained in-jit timing):
 # naive XLA wins at T<=512 (0.4-0.9x), flash wins from T=1024 (1.4x) through
-# T=8192 (23x — the [B,H,T,T] logits start thrashing HBM). Dispatch follows
-# — unless a TuningDB entry for the shape bucket carries a MEASURED
-# decision (tuning/tune.py times the naive path as an implicit candidate).
-# That window timed the forward kernel as it was before PR 26 (padded to
-# 128 lanes, 3.2x slower at T 1024, D 64): the crossover may now lie below
-# 1024 and has not been measured again.
+# T=8192 (23x — the [B,H,T,T] logits start thrashing HBM). That window
+# timed the forward kernel as it was before PR 26 (padded to 128 lanes,
+# 3.2x slower at T 1024, D 64): the crossover may now lie below 1024 and
+# has not been measured again. A constant until a sweep at the benchmark's
+# shapes moves it.
 _MIN_SEQ = 1024
 
-#: default block geometry — the fallback when neither the tuning DB nor
-#: the env override speaks. Kept at 512 x 512 on the v5e's word (PR 26, bf16
-#: causal forward, [BH 64, T 1024, D 64]: 205 us; 256 x 256: 445; 512 x 256:
-#: 309; 256 x 512: 333; one 1024 x 1024 step a head: 160, not taken then
-#: because the backward was a scan over key blocks of block_k; the backward
-#: kernel runs on the same two blocks and has not been timed at 1024). The
-#: kernels' own pieces come from the blocks (``_sub_tile``).
-_DEFAULT_BLOCK_Q = 512
-_DEFAULT_BLOCK_K = 512
+#: the block geometry ``resolve_attention`` hands out. 512 x 512 on the
+#: v5e's word (PR 26, bf16 causal forward, [BH 64, T 1024, D 64]: 205 us;
+#: 256 x 256: 445; 512 x 256: 309; 256 x 512: 333; one 1024 x 1024 step a
+#: head: 160, not taken then because the backward was a scan over key
+#: blocks of block_k; the backward kernel runs on the same two blocks and
+#: has not been timed at 1024). The kernels' own pieces come from the
+#: blocks (``_sub_tile``).
+_BLOCK_Q = 512
+_BLOCK_K = 512
 
 #: score pieces the kernel issues ahead of the softmax at hand (see
 #: ``_attn_kernel``): the matrix units take work in program order, so this
@@ -105,58 +98,18 @@ _DEFAULT_BLOCK_K = 512
 _SCORES_AHEAD = 16
 
 
-def _tuned(q_shape, dtype):
-    """The TuningDB entry for a [B, T, H, D] call (tuning/db.py), or
-    None. Trace-time host lookup — the resolved config compiles into the
-    step, so the counters move once per compile."""
-    from deeplearning4j_tpu.tuning.db import tuned_config
-    return tuned_config("attention", tuple(int(d) for d in q_shape), dtype)
-
-
-def env_block(name, default=512):
-    """Env block-size override, validated: a positive 128-multiple (the
-    TPU lane tile rule the kernel's BlockSpecs must satisfy) or the
-    default. Malformed values fall back rather than killing a scarce
-    live-window leg mid-trace."""
-    raw = os.environ.get(name)
-    if not raw:
-        return default
-    try:
-        val = int(raw)
-    except ValueError:
-        return default
-    return val if val >= 128 and val % 128 == 0 else default
-
-
-def resolve_block_sizes(q_shape, dtype):
-    """(block_q, block_k, remat) for a [B, T, H, D] call — the ONE
-    default table both ``flash_attention`` and ``flash_attention_block``
-    resolve through: TuningDB entry (searched winner for this shape
-    bucket) > ``DL4J_TPU_FLASH_BLOCK_Q/K`` env override (live-window
-    A/B sweeps) > the hand-picked 512x512 default."""
-    cfg = _tuned(q_shape, dtype)
-    if cfg and cfg.get("backend", "flash") == "flash":
-        return (int(cfg.get("block_q", _DEFAULT_BLOCK_Q)),
-                int(cfg.get("block_k", _DEFAULT_BLOCK_K)),
-                bool(cfg.get("remat", False)))
-    return (env_block("DL4J_TPU_FLASH_BLOCK_Q", _DEFAULT_BLOCK_Q),
-            env_block("DL4J_TPU_FLASH_BLOCK_K", _DEFAULT_BLOCK_K),
-            False)
-
-
-def resolve_attention(q_shape, k_shape, mask, dtype, *, min_seq=None):
-    """The whole dispatch decision in ONE TuningDB lookup: None when the
-    naive path should run, else the ``(block_q, block_k, remat)`` to run
-    the kernel with. Structural gates first (self-attention shapes only
-    — KV-cache decode goes naive; head_dim <= 128; float dtype; masks
-    only as key-side [B, Tk] padding, the reference's masking contract
-    (MaskedReductionUtil.java) — arbitrary-rank score masks go naive).
-    Then the flash-vs-naive crossover: a TuningDB entry for this shape
-    bucket carries a MEASURED verdict (``{"backend": "xla"}`` = the
-    naive path won there, else the winning block geometry); without one
-    the hand-measured _MIN_SEQ heuristic applies (override via
-    DL4J_TPU_FUSED_ATTENTION_MIN_SEQ or min_seq=) with the env/default
-    block table."""
+def resolve_attention(q_shape, k_shape, mask, dtype):
+    """The whole dispatch decision, from what the call shows: None where
+    the XLA path should run, else the ``(block_q, block_k)`` to run the
+    kernel with. A TPU backend; self-attention shapes only (KV-cache
+    decode goes naive); head_dim <= 128; a float dtype; masks only as
+    key-side [B, Tk] padding, the reference's masking contract
+    (MaskedReductionUtil.java) — arbitrary-rank score masks go naive; and
+    the measured crossover ``_MIN_SEQ``. Another block for another shape
+    is a branch on the shape here, with the chip run that justifies it in
+    PERF.md."""
+    if not backend_is_tpu():
+        return None
     if mask is not None:
         mshape = tuple(getattr(mask, "shape", ()))
         if mshape != (q_shape[0], k_shape[1]):
@@ -167,35 +120,22 @@ def resolve_attention(q_shape, k_shape, mask, dtype, *, min_seq=None):
         return None
     if not jnp.issubdtype(dtype, jnp.floating):
         return None
-    if min_seq is None:
-        cfg = _tuned(q_shape, dtype)
-        if cfg is not None:
-            # measured crossover: the tuner timed the naive XLA path as
-            # an implicit candidate at this bucket — its verdict replaces
-            # the one-window _MIN_SEQ constant
-            if cfg.get("backend", "flash") != "flash":
-                return None
-            return (int(cfg.get("block_q", _DEFAULT_BLOCK_Q)),
-                    int(cfg.get("block_k", _DEFAULT_BLOCK_K)),
-                    bool(cfg.get("remat", False)))
-        try:
-            min_seq = int(os.environ.get("DL4J_TPU_FUSED_ATTENTION_MIN_SEQ",
-                                         _MIN_SEQ))
-        except ValueError:  # malformed override: keep the measured default
-            min_seq = _MIN_SEQ
-    if q_shape[1] < min_seq:
+    if q_shape[1] < _MIN_SEQ:
         return None
-    return (env_block("DL4J_TPU_FLASH_BLOCK_Q", _DEFAULT_BLOCK_Q),
-            env_block("DL4J_TPU_FLASH_BLOCK_K", _DEFAULT_BLOCK_K),
-            False)
+    return _BLOCK_Q, _BLOCK_K
 
 
-def supported(q_shape, k_shape, mask, dtype, *, min_seq=None):
-    """Whether the fast path applies (see ``resolve_attention``, which
-    callers on the dispatch path should prefer — it returns the resolved
-    block geometry from the SAME single DB lookup)."""
-    return resolve_attention(q_shape, k_shape, mask, dtype,
-                             min_seq=min_seq) is not None
+def _resolved(q, k, mask):
+    """``resolve_attention`` for a caller that has already chosen the
+    kernel: a call the dispatch would hand to XLA is refused, not given a
+    geometry of its own."""
+    blocks = resolve_attention(q.shape, k.shape, mask, q.dtype)
+    if blocks is None:
+        raise ValueError(
+            f"flash attention does not take q {q.shape}, k {k.shape} "
+            f"{q.dtype} here (resolve_attention): pass block_q/block_k or "
+            "take the XLA path")
+    return blocks
 
 
 def _sub_tile(block):
@@ -639,7 +579,7 @@ def _bwd_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask, form,
 
 
 #: what one kernel may hold in VMEM: Mosaic's scoped limit is 16 MiB, and
-#: tuning/space.py keeps the same margin under it
+#: ops/lstm_pallas.py's ``supported`` keeps the same margin under it
 _VMEM_BUDGET = 14 * 1024 * 1024
 
 
@@ -802,12 +742,10 @@ def flash_attention_block(q, k, v, causal, scale, interpret):
     """(out [B,T,H,D], lse [B,H,T]) for ONE ring-attention block pair —
     the fused-kernel replacement for a naive [B,H,Tq,Tk]-logits block in
     parallel/sequence.py. The lse output lets the caller combine blocks by
-    log-sum-exp; its cotangent is handled exactly (_run_bwd_local). Block
-    sizes resolve through the same TuningDB/env/default table as the main
-    ``flash_attention`` entry (this entry used to hardcode 512x512 and
-    bypass even the env override)."""
+    log-sum-exp; its cotangent is handled exactly (_run_bwd_local). The
+    blocks are ``resolve_attention``'s, as ``flash_attention``'s are."""
     b, t, h, d = q.shape
-    bq, bk, _ = resolve_block_sizes(q.shape, q.dtype)
+    bq, bk = _resolved(q, k, None)
     out, lse = _run_fwd(_fold_heads(q), _fold_heads(k), _fold_heads(v),
                         None, h, causal, scale, bq, bk, interpret)
     return _unfold_heads(out, b, h), lse.reshape(b, h, t)
@@ -815,7 +753,7 @@ def flash_attention_block(q, k, v, causal, scale, interpret):
 
 def _flash_block_fwd(q, k, v, causal, scale, interpret):
     b, t, h, d = q.shape
-    bq, bk, _ = resolve_block_sizes(q.shape, q.dtype)
+    bq, bk = _resolved(q, k, None)
     qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
     out, lse = _run_fwd(qf, kf, vf, None, h, causal, scale, bq, bk,
                         interpret)
@@ -824,8 +762,7 @@ def _flash_block_fwd(q, k, v, causal, scale, interpret):
 
 
 def _flash_block_bwd(causal, scale, interpret, res, grads):
-    # the blocks ride the residuals so fwd and bwd tile identically even if
-    # the DB/env resolution were to change between the two traces
+    # the blocks ride the residuals: forward and backward tile alike
     qf, kf, vf, out, lse, b, h, bq, bk = res
     g_out, g_lse = grads
     dq, dk, dv = _run_bwd((qf, kf, vf, None, out, lse), _fold_heads(g_out),
@@ -848,12 +785,12 @@ def flash_attention(q, k, v, *, mask=None, causal=False, scale=None,
     cross-length decode). ``mask``: optional [B, Tk] key-side padding mask
     (1 = valid). Fully-masked query rows emit 0 (the naive path emits NaN
     there — 0 is what the downstream masked-output multiply expects).
-    ``block_q``/``block_k`` default to ``resolve_block_sizes`` (TuningDB
-    winner for the shape bucket > env override > 512x512); explicit
-    values win unconditionally (tests, the tuner's own candidates)."""
+    ``block_q``/``block_k`` default to ``resolve_attention``'s, and a
+    call it would hand to XLA is then refused; explicit values are taken
+    as given (the dispatch passes what it resolved; tests name their own)."""
     b, t, h, d = q.shape
     if block_q is None or block_k is None:
-        rq, rk, _ = resolve_block_sizes(q.shape, q.dtype)
+        rq, rk = _resolved(q, k, mask)
         block_q = rq if block_q is None else block_q
         block_k = rk if block_k is None else block_k
     if scale is None:

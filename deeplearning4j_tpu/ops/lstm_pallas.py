@@ -173,14 +173,12 @@ def _lstm_seq_kernel_tiled(n_tiles, has_peephole, has_mask, *refs):
             cT_ref[:] = c.astype(cT_ref.dtype)
 
 
-def _run_kernel_any(xz, wh, wp, h0, c0, mask, interpret, tile_cols=None):
+def _run_kernel_any(xz, wh, wp, h0, c0, mask, interpret):
     """Dispatch to the resident or tiled kernel; wp/mask may be None.
-    mask is time-major [T, B] (1=valid). ``tile_cols`` picks the tiled
-    kernel's Wh column width: explicit (the tuner's candidates) >
-    TuningDB winner for the shape bucket > the widest 128-multiple
-    divisor of 4H under the hand-picked _TILE_COLS ceiling. Under a
-    declared device mesh the kernel runs once per batch shard
-    (ops/spmd.py), the weights replicated."""
+    mask is time-major [T, B] (1=valid). The tiled kernel's Wh column
+    width is the widest 128-multiple divisor of 4H under the hand-picked
+    _TILE_COLS ceiling. Under a declared device mesh the kernel runs once
+    per batch shard (ops/spmd.py), the weights replicated."""
     # name -> (array, batch axis), for the operands that are present
     operands = {name: (a, axis) for name, a, axis in (
         ("xz", xz, 1), ("wh", wh, None), ("wp", wp, None), ("h0", h0, 0),
@@ -190,14 +188,14 @@ def _run_kernel_any(xz, wh, wp, h0, c0, mask, interpret, tile_cols=None):
         a = dict(zip(operands, arrays))
         return tuple(_run_kernel_local(
             a["xz"], a["wh"], a.get("wp"), a["h0"], a["c0"], a.get("mask"),
-            interpret, tile_cols))
+            interpret))
     return _spmd.per_batch_shard(
         local, tuple(a for a, _ in operands.values()),
         tuple(axis for _, axis in operands.values()), (1, 1, 0, 0))
 
 
 @jax.named_scope("lstm.fwd")
-def _run_kernel_local(xz, wh, wp, h0, c0, mask, interpret, tile_cols):
+def _run_kernel_local(xz, wh, wp, h0, c0, mask, interpret):
     t, b, four_h = xz.shape
     hsz = four_h // 4
     dt = xz.dtype
@@ -210,23 +208,8 @@ def _run_kernel_local(xz, wh, wp, h0, c0, mask, interpret, tile_cols):
         pl.BlockSpec((hsz, four_h), lambda i: (0, 0)),
     ]
     if tiled:
-        if tile_cols is None:
-            from deeplearning4j_tpu.tuning.db import tuned_config
-            cfg = tuned_config("lstm", (t, b, hsz), dt)
-            if cfg:
-                tile_cols = cfg.get("tile_cols")
-        tile = None
-        if tile_cols:
-            tile_cols = int(tile_cols)
-            # honor only a geometry the kernel grid can express; an
-            # invalid value (stale DB vs a new shape) falls back to the
-            # default divisor rather than failing the compile
-            if (tile_cols % 128 == 0 and 0 < tile_cols <= four_h
-                    and four_h % tile_cols == 0):
-                tile = tile_cols
-        if tile is None:
-            tile = next(c for c in range(min(_TILE_COLS, four_h), 0, -128)
-                        if four_h % c == 0)
+        tile = next(c for c in range(min(_TILE_COLS, four_h), 0, -128)
+                    if four_h % c == 0)
         n_tiles = four_h // tile
         in_specs_t = [  # tiled: grid (T, K)
             pl.BlockSpec((1, b, tile), lambda i, k: (i, 0, k)),
@@ -286,25 +269,22 @@ def _run_kernel_local(xz, wh, wp, h0, c0, mask, interpret, tile_cols):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _fused_seq(xz, wh, wp, h0, c0, mask, interpret=False, tile_cols=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _fused_seq(xz, wh, wp, h0, c0, mask, interpret=False):
     """xz [T,B,4H] (= x@Wx + b, time-major), wh [H,4H], wp [3,H] (i|f|o
     rows) or None, h0/c0 [B,H], mask [T,B] (1=valid) or None. Returns
-    (hs [T,B,H], (hT, cT)). ``tile_cols``: explicit tiled-kernel column
-    width (see _run_kernel_any)."""
-    hs, cs, hT, cT = _run_kernel_any(xz, wh, wp, h0, c0, mask, interpret,
-                                     tile_cols)
+    (hs [T,B,H], (hT, cT))."""
+    hs, cs, hT, cT = _run_kernel_any(xz, wh, wp, h0, c0, mask, interpret)
     return hs, (hT, cT)
 
 
-def _fwd(xz, wh, wp, h0, c0, mask, interpret, tile_cols):
-    hs, cs, hT, cT = _run_kernel_any(xz, wh, wp, h0, c0, mask, interpret,
-                                     tile_cols)
+def _fwd(xz, wh, wp, h0, c0, mask, interpret):
+    hs, cs, hT, cT = _run_kernel_any(xz, wh, wp, h0, c0, mask, interpret)
     return (hs, (hT, cT)), (xz, wh, wp, h0, c0, mask, hs, cs)
 
 
 @jax.named_scope("lstm.bwd")
-def _bwd(interpret, tile_cols, res, grads):
+def _bwd(interpret, res, grads):
     xz, wh, wp, h0, c0, mask, hs, cs = res
     dhs, (dhT, dcT) = grads
     t, b, hsz = hs.shape
@@ -420,7 +400,7 @@ def pad_hidden(hsz):
 
 
 def fused_sequence_padded(xz, wh, h0, c0, wp=None, mask=None,
-                          interpret=False, tile_cols=None):
+                          interpret=False):
     """Dispatch wrapper that lane-pads H to a 128-multiple when needed.
 
     Padding is exact, not approximate: padded xz/Wh/Wp/h0/c0 lanes are zero,
@@ -438,7 +418,7 @@ def fused_sequence_padded(xz, wh, h0, c0, wp=None, mask=None,
     if mask is not None:
         mask = mask.astype(jnp.float32)  # float cotangent (always zero)
     if hp == hsz:
-        return _fused_seq(xz, wh, wp, h0, c0, mask, interpret, tile_cols)
+        return _fused_seq(xz, wh, wp, h0, c0, mask, interpret)
 
     dpad = hp - hsz
     # re-lay the packed 4H axis as [4, H] blocks, pad each gate block
@@ -449,19 +429,15 @@ def fused_sequence_padded(xz, wh, h0, c0, wp=None, mask=None,
     h0p = jnp.pad(h0, ((0, 0), (0, dpad)))
     c0p = jnp.pad(c0, ((0, 0), (0, dpad)))
     wpp = None if wp is None else jnp.pad(wp, ((0, 0), (0, dpad)))
-    hsp, (hTp, cTp) = _fused_seq(xzp, whp, wpp, h0p, c0p, mask, interpret,
-                                 tile_cols)
+    hsp, (hTp, cTp) = _fused_seq(xzp, whp, wpp, h0p, c0p, mask, interpret)
     return hsp[:, :, :hsz], (hTp[:, :hsz], cTp[:, :hsz])
 
 
 def enabled():
-    """Whether the fused dispatch seam is live for this process: env flag on
-    AND a TPU backend (CPU always takes the reference scan path outside
+    """Whether the fused dispatch seam is live for this process: a TPU
+    backend (CPU always takes the reference scan path outside
     interpret-mode tests)."""
-    import os
     from deeplearning4j_tpu.ops.attention_pallas import backend_is_tpu
-    if os.environ.get("DL4J_TPU_FUSED_LSTM", "1") == "0":
-        return False
     return backend_is_tpu()
 
 

@@ -60,12 +60,6 @@ def _naive_block(q, k, v, scale, block_mask):
     return out, lse
 
 
-def _use_flash_blocks(q):
-    from deeplearning4j_tpu.ops import attention_pallas as _ap
-    return (_ap.enabled()
-            and _ap.supported(q.shape, q.shape, None, q.dtype))
-
-
 def ring_self_attention(q, k, v, *, axis_name="seq", causal=False,
                         scale=None, use_flash=None, interpret=False):
     """Exact self-attention with q/k/v sharded over ``axis_name`` on the time
@@ -89,7 +83,9 @@ def ring_self_attention(q, k, v, *, axis_name="seq", causal=False,
     t_local = q.shape[1]
     f32 = jnp.float32
     if use_flash is None:
-        use_flash = static_scale and _use_flash_blocks(q)
+        from deeplearning4j_tpu.ops import attention_pallas as _ap
+        use_flash = static_scale and _ap.resolve_attention(
+            q.shape, q.shape, None, q.dtype) is not None
     elif use_flash and not static_scale:
         raise ValueError("flash ring blocks need a static (python float) "
                          "scale; got a traced value")

@@ -300,11 +300,6 @@ class BucketedForward:
             kind += f":grid={buckets.signature()}"
         self._manifest_kind = kind
         self._compiled = {}  # input signature -> AOT executable (False=jit)
-        # manifest signature (incl. the tuning-DB fingerprint) captured
-        # WHEN each executable compiled — export must ship that stamp,
-        # not the fingerprint active at save time (a mid-process DB
-        # refresh would otherwise relabel stale executables as tuned)
-        self._compiled_sigs = {}
         self._warmed = False  # has an AOT warmup declared coverage?
         self._lock = threading.Lock()
         self._aot = {"warmed": 0, "lazy_compiles": 0, "hits": 0,
@@ -392,7 +387,6 @@ class BucketedForward:
             # serializing there would stall every in-flight request —
             # export_manifest's save-time walk covers lazy executables
             # instead.
-            sig_now = _cc.full_signature(json.dumps(key))
             try:
                 # fresh: any serving executable may be serialized later
                 # (this write-back, or export_manifest's save-time walk)
@@ -412,8 +406,6 @@ class BucketedForward:
                 # odd request signature: serve via the jit path, which
                 # surfaces any real shape error
             self._compiled[key] = ex
-            if ex is not False:
-                self._compiled_sigs[key] = sig_now
             if src == "manifest":
                 self._aot["manifest_hits"] += 1
             elif self.manifest is not None:
@@ -456,14 +448,8 @@ class BucketedForward:
             m = _cc.WarmManifest.for_net(self.net)
         with self._lock:
             compiled = dict(self._compiled)
-            sigs = dict(self._compiled_sigs)
         for key, ex in compiled.items():
-            # same key discipline as aot_compile's lookups: the tuning-DB
-            # fingerprint ACTIVE WHEN THIS EXECUTABLE COMPILED folds into
-            # the signature, so a restart under a re-tuned DB misses
-            # these entries instead of serving executables baked with
-            # stale kernel configs
-            sig = sigs.get(key, _cc.full_signature(json.dumps(key)))
+            sig = json.dumps(key)  # the signature aot_compile looks up
             if ex is False or m.has(self._manifest_kind, sig):
                 continue  # jit fallback entries have no executable to ship
             m.put(self._manifest_kind, sig, ex)

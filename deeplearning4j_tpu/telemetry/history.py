@@ -11,9 +11,9 @@ window it didn't live through.
   e.g. a federated merge) on an interval into a fixed-size in-memory
   ring of ``{"t": unix_seconds, "metrics": <registry snapshot>}`` docs;
 * every ``segment_samples`` samples are persisted as ONE atomic JSONL
-  segment under ``history_dir`` (tmp + ``os.replace``, the TuningDB
-  discipline), oldest segments evicted past ``max_segments`` — a crash
-  leaves whole segments, never a torn line;
+  segment under ``history_dir`` (tmp + ``os.replace``), oldest segments
+  evicted past ``max_segments`` — a crash leaves whole segments, never a
+  torn line;
 * ``query(series, t0, t1)`` answers range queries over the ring, and
   ``rate_over(series, window_s)`` applies the SLO engine's per-series
   counter-delta discipline (:class:`~.slo._DeltaTrack`): a series that

@@ -61,7 +61,7 @@ import numpy as np
 
 __all__ = ["DEFAULT_CACHE_DIR", "WarmManifest", "aot_compile",
            "attach_manifest", "backend_fingerprint",
-           "enable_persistent_cache", "fresh_compile", "full_signature",
+           "enable_persistent_cache", "fresh_compile",
            "model_fingerprint",
            "note_first_request", "note_first_step", "signature_of", "status"]
 
@@ -299,24 +299,6 @@ def signature_of(args):
     return json.dumps([str(treedef), sig], separators=(",", ":"))
 
 
-def full_signature(signature):
-    """``signature`` with the active TuningDB's content fingerprint
-    folded in (no-op without a bound/populated DB — old manifests keep
-    hitting). Tuned kernel configs resolve at TRACE time, so an
-    executable bakes them in: keying the manifest on the DB content
-    means a re-tuned DB cleanly invalidates stale entries (miss → live
-    compile with the NEW configs → serialize-back) instead of silently
-    serving kernels tuned under the old ones. The one helper every
-    manifest key goes through — ``aot_compile`` applies it to lookups
-    and write-backs, the serving export walk to its save-time puts."""
-    try:
-        from deeplearning4j_tpu.tuning.db import active_fingerprint
-        fp = active_fingerprint()
-    except Exception:
-        fp = None
-    return str(signature) if not fp else f"{signature}|tuning:{fp}"
-
-
 # ---------------------------------------------------------------------------
 # warm manifest (tier b)
 # ---------------------------------------------------------------------------
@@ -522,8 +504,7 @@ def aot_compile(jitted, *args, manifest=None, kind="jit", signature=None,
     the executable up instead. graftlint R3 flags raw
     ``.lower().compile()`` chains outside this module, so serving/fused
     compiles cannot silently bypass the cache tier."""
-    sig = full_signature(signature if signature is not None
-                         else signature_of(args))
+    sig = str(signature if signature is not None else signature_of(args))
     if manifest is not None:
         ex = manifest.load_executable(kind, sig)
         if ex is not None:

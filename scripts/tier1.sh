@@ -137,23 +137,7 @@ env JAX_PLATFORMS=cpu BENCH_PREFLIGHT=1 \
   || { echo "tier1: zero smoke FAILED (sharded layout not 1/N, a leg"
        echo "tier1: recompiled, or sharded params diverged)"; exit 1; }
 
-# Stage 7: kernel-autotuner smoke (deeplearning4j_tpu/tuning, ISSUE 11) —
-# tune a fresh DB (CPU interpret mode: mechanics, not timings), A/B each
-# kernel tuned-vs-default, and prove the warm-restart composition: the
-# populated TuningDB + warm manifest serve TUNED executables with zero
-# compiles. scripts/check_tuning.py gates on PARITY AND COUNTERS (tuned
-# == default <=1e-6, warm leg = manifest-served, compile_cache/tuning_db
-# deltas hits-only, recompiles 0) — never wall time on CPU.
-echo "== kernel-autotuner smoke =="
-env JAX_PLATFORMS=cpu BENCH_PREFLIGHT=1 \
-  timeout -k 10 300 python bench.py kernels \
-  > /tmp/_kernels.jsonl \
-  && tee -a BENCH_smoke.json < /tmp/_kernels.jsonl > /dev/null \
-  && env JAX_PLATFORMS=cpu \
-    python scripts/check_tuning.py /tmp/_kernels.jsonl \
-  || { echo "tier1: kernel-autotuner smoke FAILED (parity broke, a"
-       echo "tier1: rejected candidate persisted, or the warm restart"
-       echo "tier1: recompiled instead of loading tuned executables)"; exit 1; }
+# (There is no stage 7: README and PROFILE.md cite the numbers below.)
 
 # Stage 8: fleet serving smoke (deeplearning4j_tpu/fleet, ISSUE 12) —
 # the multi-process pool end to end: 3 worker processes warm-started

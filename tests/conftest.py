@@ -6,6 +6,7 @@ xla_force_host_platform_device_count=8 so every parallelism test exercises a
 real (virtual) mesh, the same suite running unchanged on real TPU.
 """
 
+import contextlib
 import itertools
 import os
 
@@ -57,6 +58,25 @@ def _isolated_compile_cache(tmp_path_factory, monkeypatch):
 
 
 _cache_dir_ids = itertools.count()
+
+
+@pytest.fixture
+def kernel_dispatch(monkeypatch):
+    """A context in which ``resolve_attention`` answers as it does on the
+    chip, at any length: the backend gate open and the crossover at 0, so
+    a toy shape goes through the dispatch's own blocks (the kernels then
+    need ``interpret=True`` from their caller). A context and not the whole
+    test: a reference that ``dot_product_attention`` computes stays outside
+    it, on the XLA path."""
+    from deeplearning4j_tpu.ops import attention_pallas
+
+    @contextlib.contextmanager
+    def on_the_chip():
+        with monkeypatch.context() as m:
+            m.setattr(attention_pallas, "backend_is_tpu", lambda: True)
+            m.setattr(attention_pallas, "_MIN_SEQ", 0)
+            yield
+    return on_the_chip
 
 
 @pytest.fixture(scope="session")

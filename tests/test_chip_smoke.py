@@ -61,9 +61,10 @@ def test_train_then_serve_phases_at_toy_size(tmp_path, capsys):
     assert doc["aot"]["lazy_compiles"] == doc["aot"]["jit_serves"] == 0
 
 
-def test_kernels_phase_in_interpret_mode():
-    doc = smoke.kernels_phase(interpret=True,
-                              tol={"fwd": 1e-4, "bwd": 1e-4})
+def test_kernels_phase_in_interpret_mode(kernel_dispatch):
+    with kernel_dispatch():  # the flash cases run on the dispatch's blocks
+        doc = smoke.kernels_phase(interpret=True,
+                                  tol={"fwd": 1e-4, "bwd": 1e-4})
     names = {r["kernel"] for r in doc["results"]}
     assert {"flash_causal", "flash_padding_mask", "flash_block",
             "lstm_resident", "lstm_resident_peephole_masked",
@@ -79,11 +80,12 @@ def test_looped_block_case_at_toy_size():
     assert r["kernel"] == "looped_toy" and r["fwd_rel_err"] == 0.0
 
 
-def test_flash_backward_time_at_toy_size():
+def test_flash_backward_time_at_toy_size(kernel_dispatch):
     """The interpreted kernel's pullback runs and is timed; the number is
     the host's and means nothing here."""
-    r = smoke._flash_backward_time("bwd_toy", b=1, t=128, h=1, d=16,
-                                   interpret=True, iters=1)
+    with kernel_dispatch():
+        r = smoke._flash_backward_time("bwd_toy", b=1, t=128, h=1, d=16,
+                                       interpret=True, iters=1)
     assert r["kernel"] == "bwd_toy" and r["bwd_ms"] > 0
 
 

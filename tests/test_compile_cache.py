@@ -123,7 +123,7 @@ class TestPersistentCache:
         cc.aot_compile(jax.jit(f), x, w, manifest=man, kind="t")
         assert len(os.listdir(str(tmp_path / "xc"))) == n  # not written
         restored = cc.WarmManifest.from_bytes(man.to_bytes()) \
-            .load_executable("t", cc.full_signature(cc.signature_of((x, w))))
+            .load_executable("t", cc.signature_of((x, w)))
         assert float(restored(x, w)) == float(f(x, w))
         # the cache is back on afterwards
         jax.jit(lambda a: a - 7.0)(jnp.ones(3)).block_until_ready()

@@ -303,10 +303,10 @@ class TestR3Recompile:
         assert "R3" not in rule_set(src)
 
     def test_jit_in_loop_into_aot_compile_silent(self):
-        # ISSUE 11: the autotuner's measurement harness deliberately
-        # compiles one candidate per loop iteration — routed through the
-        # blessed manifest-aware site, that is the search working, not a
-        # recompile hazard (tuning/measure.py's idiom)
+        # ISSUE 11: a search that deliberately compiles one candidate per
+        # loop iteration — routed through the blessed manifest-aware site,
+        # that is the search working, not a recompile hazard (the harness
+        # that did so went with the autotuner in PR 29; ROADMAP D17)
         src = """
             import jax
             from deeplearning4j_tpu.utils.compile_cache import aot_compile

@@ -2,6 +2,8 @@
 ValidateCudnnLSTM — fast path vs reference path on identical inputs,
 SURVEY.md §4.6). Pallas kernels run in interpret mode on the CPU fixture."""
 
+import ast
+import pathlib
 import re
 
 import jax
@@ -10,6 +12,30 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.ops import attention_pallas, lstm_pallas
+
+PKG = pathlib.Path(attention_pallas.__file__).resolve().parents[1]
+
+#: ROADMAP's third aim as a test: a choice between kernels, or between a
+#: layer's paths, is made from what the code can observe and never from
+#: the environment. These are the modules that make such choices.
+_NO_ENVIRONMENT = sorted(p for pattern in ("ops/*.py", "nn/**/*.py",
+                                           "models/*.py")
+                         for p in PKG.glob(pattern))
+
+
+@pytest.mark.parametrize("path", _NO_ENVIRONMENT,
+                         ids=lambda p: str(p.relative_to(PKG)))
+def test_no_kernel_or_layer_module_reads_the_environment(path):
+    names = {"environ", "environb", "getenv", "getenvb"}
+    reads = sorted({
+        n.lineno for n in ast.walk(ast.parse(path.read_text()))
+        if (isinstance(n, ast.Attribute) and n.attr in names)
+        or (isinstance(n, ast.Name) and n.id in names)
+        or (isinstance(n, ast.ImportFrom) and n.module == "os"
+            and names & {a.name for a in n.names})})
+    assert not reads, (f"{path.relative_to(PKG)} reads the environment at "
+                       f"line(s) {reads}: choose from shape, dtype, mask or "
+                       "jax.default_backend() instead")
 
 
 def _ref_scan(xz, wh, h0, c0):
@@ -211,6 +237,75 @@ class TestFusedLstmKernel:
         assert not layer._fused_eligible(x, None)
 
 
+def _pallas_calls(jaxpr):
+    """Every pallas_call equation under ``jaxpr``, through custom_vjp and
+    pjit bodies."""
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            yield e
+        for v in e.params.values():
+            for j in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    yield from _pallas_calls(inner)
+
+
+class TestLstmDispatchTables:
+    """The LSTM seam's answers as tables: ``supported()`` by hidden size,
+    batch and peepholes, and the kernel the call then builds (resident
+    under 512, else tiled) with its Wh column tile (ISSUE 29)."""
+
+    OK = dict(mask=None, gate_activation="sigmoid", activation="tanh")
+
+    @pytest.mark.parametrize("peephole", [False, True],
+                             ids=["plain", "peephole"])
+    @pytest.mark.parametrize("hsz,by_batch", [
+        (96, {4: False, 8: True, 256: True}),    # lane-padded to 128
+        (512, {4: False, 8: True, 256: True}),   # the resident bound
+        (640, {4: False, 8: True, 256: True}),
+        (1024, {4: False, 8: True, 256: True}),
+        (2048, {4: False, 8: True, 256: False}),  # past the VMEM estimate
+    ])
+    def test_supported(self, hsz, by_batch, peephole):
+        for b, want in by_batch.items():
+            assert lstm_pallas.supported(
+                (b, 16, 32), hsz, peephole=peephole, **self.OK) == want, b
+
+    @pytest.mark.parametrize("peephole", [False, True],
+                             ids=["plain", "peephole"])
+    @pytest.mark.parametrize("hsz,grid,wh_block", [
+        (96, (3,), (128, 512)),        # resident: Wh whole, padded lanes
+        (512, (3,), (512, 2048)),
+        (640, (3, 4), (640, 640)),     # tiled: 2560 has no 1024 divisor
+        (1024, (3, 4), (1024, 1024)),
+        (2048, (3, 8), (2048, 1024)),
+    ])
+    def test_kernel_and_tile_by_hidden_size(self, hsz, grid, wh_block,
+                                            peephole):
+        t, b = 3, 8
+        S = jax.ShapeDtypeStruct
+        f32 = jnp.float32
+        args = (S((t, b, 4 * hsz), f32), S((hsz, 4 * hsz), f32),
+                S((b, hsz), f32), S((b, hsz), f32))
+        wp = S((3, hsz), f32) if peephole else None
+
+        def call(xz, wh, h0, c0, wp=None):
+            return lstm_pallas.fused_sequence_padded(
+                xz, wh, h0, c0, wp=wp, interpret=True)
+        jaxpr = (jax.make_jaxpr(call)(*args, wp) if peephole
+                 else jax.make_jaxpr(call)(*args))
+        (eqn,) = _pallas_calls(jaxpr.jaxpr)
+        gm = eqn.params["grid_mapping"]
+        assert gm.grid == grid
+        assert tuple(getattr(d, "block_size", d) for d in
+                     gm.block_mappings[1].block_shape) == wh_block
+
+    def test_enabled_is_the_backend_gate(self, monkeypatch):
+        assert not lstm_pallas.enabled()
+        monkeypatch.setattr(attention_pallas, "backend_is_tpu", lambda: True)
+        assert lstm_pallas.enabled()
+
+
 class TestTiledLstmKernel:
     """Large-H variant (H > _RESIDENT_MAX_H streams Wh column tiles —
     VERDICT r2 #5, reference: CudnnLSTMHelper had no hidden-size cap).
@@ -345,27 +440,6 @@ class TestFlashAttention:
             np.asarray(out, np.float32), np.asarray(self._ref(q, k, v)),
             rtol=0.05, atol=0.02)
 
-    def test_supported_gate(self):
-        from deeplearning4j_tpu.ops.attention_pallas import supported
-        assert supported((2, 16, 2, 64), (2, 16, 2, 64), None, np.float32,
-                         min_seq=0)
-        # [B, Tk] key-padding masks take the fast path; other shapes don't
-        assert supported((2, 16, 2, 64), (2, 16, 2, 64),
-                         np.ones((2, 16)), np.float32, min_seq=0)
-        assert not supported((2, 16, 2, 64), (2, 16, 2, 64),
-                             np.ones((2, 16, 16)), np.float32, min_seq=0)
-        assert not supported((2, 16, 2, 256), (2, 16, 2, 256), None,
-                             np.float32, min_seq=0)
-        # KV-cache decode (tq != tk) must fall back to the naive path
-        assert not supported((2, 1, 2, 64), (2, 16, 2, 64), None, np.float32,
-                             min_seq=0)
-        # short sequences go to XLA's naive path (measured crossover: the
-        # kernel only wins from ~1024 tokens)
-        assert not supported((2, 512, 2, 64), (2, 512, 2, 64), None,
-                             np.float32)
-        assert supported((2, 2048, 2, 64), (2, 2048, 2, 64), None,
-                         np.float32)
-
     def test_non_divisor_blocks(self):
         # t=20 with block_q=8, block_k=6 pads to lcm(8,6)=24
         from deeplearning4j_tpu.ops.attention_pallas import flash_attention
@@ -458,6 +532,91 @@ class TestFlashAttention:
                                    rtol=2e-5, atol=2e-6)
 
 
+@pytest.fixture
+def backend_gate_open(monkeypatch):
+    """The one backend gate both dispatch seams read answers "tpu"."""
+    monkeypatch.setattr(attention_pallas, "backend_is_tpu", lambda: True)
+
+
+@pytest.mark.usefixtures("backend_gate_open")
+class TestResolveAttention:
+    """``resolve_attention`` is the one place that chooses between the flash
+    kernel and the XLA path and that names the blocks: its answers as
+    tables, a row a case (ISSUE 29)."""
+
+    @pytest.mark.parametrize("shape,want", [
+        ((4, 1024, 16, 64), (512, 512)),    # gpt2m-train-t1024's call
+        ((2, 2048, 16, 128), (512, 512)),   # ouro-train-t2048's call
+        ((4, 4096, 8, 64), (512, 512)),     # chip_smoke's longcontext call
+        ((8, 128, 16, 64), None),           # the serving grid's short lengths:
+        ((8, 512, 16, 64), None),           # XLA below the crossover
+        ((1, 1023, 16, 64), None),
+    ], ids=["gpt2m", "ouro", "longcontext", "t128", "t512", "t1023"])
+    def test_blocks_by_shape(self, shape, want):
+        for dtype in (jnp.bfloat16, jnp.float32):
+            assert attention_pallas.resolve_attention(
+                shape, shape, None, dtype) == want
+
+    T = 2048
+    Q = (2, T, 2, 64)
+
+    @pytest.mark.parametrize("q,k,mask,dtype,want", [
+        (Q, Q, None, np.float32, (512, 512)),
+        (Q, Q, np.ones((2, T)), np.float32, (512, 512)),   # [B, Tk] padding
+        (Q, Q, np.ones((2, T, T)), np.float32, None),      # a score mask
+        (Q, Q, np.ones((T,)), np.float32, None),
+        (Q, Q, np.ones((3, T)), np.float32, None),         # another batch
+        ((2, T, 2, 256), (2, T, 2, 256), None, np.float32, None),  # d > 128
+        ((2, T, 2, 128), (2, T, 2, 128), None, np.float32, (512, 512)),
+        ((2, 1, 2, 64), Q, None, np.float32, None),        # KV-cache decode
+        (Q, (2, 2 * T, 2, 64), None, np.float32, None),    # Tq != Tk
+        (Q, Q, None, np.int32, None),
+        (Q, Q, None, jnp.bfloat16, (512, 512)),
+    ], ids=["plain", "key_mask", "rank3_mask", "rank1_mask",
+            "mask_of_another_batch", "head_256", "head_128", "decode",
+            "tq_ne_tk", "int32", "bf16"])
+    def test_each_structural_gate(self, q, k, mask, dtype, want):
+        assert attention_pallas.resolve_attention(q, k, mask, dtype) == want
+
+    def test_a_cpu_backend_takes_the_xla_path(self, monkeypatch):
+        monkeypatch.setattr(attention_pallas, "backend_is_tpu",
+                            lambda: False)
+        assert attention_pallas.resolve_attention(
+            self.Q, self.Q, None, np.float32) is None
+        # and so does the layer's seam: no kernel in what it lowers to
+        from deeplearning4j_tpu.nn.layers.attention import \
+            dot_product_attention
+        q = jax.ShapeDtypeStruct(self.Q, jnp.float32)
+        text = jax.jit(lambda q: dot_product_attention(
+            q, q, q, causal=True)).lower(q).as_text()
+        assert "custom_call" not in text
+
+    def test_a_traced_scale_takes_the_xla_path(self):
+        """The kernel folds a static scale; the seam checks it before it
+        asks for blocks."""
+        from deeplearning4j_tpu.nn.layers.attention import \
+            dot_product_attention
+        q = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.float32)
+        text = _lower_for_tpu(
+            lambda q, s: dot_product_attention(q, q, q, scale=s), q,
+            jax.ShapeDtypeStruct((), jnp.float32)).as_text()
+        assert "tpu_custom_call" not in text
+        text = _lower_for_tpu(
+            lambda q: dot_product_attention(q, q, q, scale=0.125),
+            q).as_text()
+        assert 'kernel_name = "flash_attn_fwd"' in text
+
+    def test_the_kernel_refuses_a_call_the_dispatch_leaves_to_xla(self):
+        """``flash_attention`` without blocks and ``flash_attention_block``
+        take ``resolve_attention``'s, and have none of their own."""
+        q = jnp.zeros((1, 128, 2, 16), jnp.float32)
+        with pytest.raises(ValueError, match="resolve_attention"):
+            attention_pallas.flash_attention(q, q, q, interpret=True)
+        with pytest.raises(ValueError, match="resolve_attention"):
+            attention_pallas.flash_attention_block(q, q, q, False, 0.25,
+                                                   True)
+
+
 def _naive_folded(q, k, v, mask, causal, scale):
     """[BH, T, D] reference with the kernel's contract: (out, lse), the
     log-sum-exp over the keys a row may see; rows that see none are NaN
@@ -526,10 +685,11 @@ class TestFlashKernelGeometry:
 
     @pytest.mark.parametrize("entry", ["flash_attention",
                                        "flash_attention_block"])
-    def test_gradients_at_width_64(self, entry):
+    def test_gradients_at_width_64(self, entry, kernel_dispatch):
         """Through the custom_vjp of both entries, the block primitive
-        with a cotangent on its lse output too (ring attention's
-        combination weights depend on it)."""
+        (on the blocks ``resolve_attention`` hands it) with a cotangent on
+        its lse output too (ring attention's combination weights depend
+        on it)."""
         rs = np.random.RandomState(26)
         q, k, v = (jnp.asarray(rs.randn(1, 256, 2, 64).astype(np.float32)
                                * 0.5) for _ in range(3))
@@ -560,7 +720,8 @@ class TestFlashKernelGeometry:
                 return jnp.sum(out * out) + jnp.sum(
                     w * lse if entry == "flash_attention_block" else 0.0)
             return f
-        got = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+        with kernel_dispatch():
+            got = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
         want = jax.grad(loss(naive), argnums=(0, 1, 2))(q, k, v)
         for g, r, name in zip(got, want, ("dq", "dk", "dv")):
             np.testing.assert_allclose(np.asarray(g), np.asarray(r),
@@ -962,9 +1123,11 @@ def _lstm_loss(peephole, masked):
     return jax.grad(loss, argnums=(0, 1))
 
 
+@pytest.mark.usefixtures("backend_gate_open")
 class TestDefaultDispatchKernelsLowerForTpu:
     """Every Pallas kernel the default dispatch can reach, lowered for the
-    TPU platform at the dispatch gates' real shapes (bf16, fwd + bwd)."""
+    TPU platform at the dispatch gates' real shapes (bf16, fwd + bwd), on
+    the blocks ``resolve_attention`` gives with its backend gate open."""
 
     @pytest.mark.parametrize("t,b,hsz,peephole,masked", [
         (128, 64, 512, False, False),     # resident, the bench lstm shape
@@ -999,7 +1162,8 @@ class TestDefaultDispatchKernelsLowerForTpu:
     def test_flash_attention(self, b, t, h, d, causal, masked):
         q = jnp.zeros((b, t, h, d), jnp.bfloat16)
         mask = jnp.ones((b, t), jnp.float32) if masked else None
-        assert attention_pallas.supported(q.shape, q.shape, mask, q.dtype)
+        assert attention_pallas.resolve_attention(
+            q.shape, q.shape, mask, q.dtype) == (512, 512)
 
         def loss(q, k, v):
             return jnp.sum(attention_pallas.flash_attention(
@@ -1146,7 +1310,8 @@ class TestKernelsPerBatchShard:
 
         def attn(q, k, v, mask):
             return attention_pallas.flash_attention(
-                q, k, v, mask=mask, causal=True, interpret=True)
+                q, k, v, mask=mask, causal=True, block_q=128, block_k=128,
+                interpret=True)
 
         def sharded(q, k, v, mask):
             with spmd.kernel_mesh(mesh):
@@ -1174,7 +1339,8 @@ class TestKernelsPerBatchShard:
         def grads(q, k, v, mask):
             return jax.grad(lambda q, k, v: jnp.sum(
                 attention_pallas.flash_attention(
-                    q, k, v, mask=mask, causal=True, interpret=True) ** 2),
+                    q, k, v, mask=mask, causal=True, block_q=128,
+                    block_k=128, interpret=True) ** 2),
                 argnums=(0, 1, 2))(q, k, v)
 
         def sharded(q, k, v, mask):

@@ -403,13 +403,14 @@ class TestRegistryGrid:
 # ---------------------------------------------------------------------------
 
 class TestCrossoverPerSeqBucket:
-    def test_resolve_verdict_differs_across_buckets(self):
+    def test_resolve_verdict_differs_across_buckets(self, monkeypatch):
         from deeplearning4j_tpu.ops import attention_pallas as _ap
+        monkeypatch.setattr(_ap, "backend_is_tpu", lambda: True)
         shape = lambda t: (2, t, 8, 64)  # noqa: E731
         short = _ap.resolve_attention(shape(128), shape(128), None,
-                                      jnp.float32, min_seq=1024)
+                                      jnp.float32)
         long_ = _ap.resolve_attention(shape(2048), shape(2048), None,
-                                      jnp.float32, min_seq=1024)
+                                      jnp.float32)
         assert short is None          # naive XLA below the crossover
         assert long_ is not None      # flash geometry above it
 
@@ -421,11 +422,10 @@ class TestCrossoverPerSeqBucket:
         from deeplearning4j_tpu.ops import attention_pallas as _ap
         seen = []
 
-        def spy(q_shape, k_shape, mask, dtype, *, min_seq=None):
+        def spy(q_shape, k_shape, mask, dtype):
             seen.append(int(q_shape[1]))
             return None               # always take the naive (CPU) path
 
-        monkeypatch.setattr(_ap, "enabled", lambda: True)
         monkeypatch.setattr(_ap, "resolve_attention", spy)
         seq_grid = (128, 512, 2048)
         for t in seq_grid:

@@ -159,7 +159,8 @@ def test_flash_kernel_names_forward_and_backward():
     q = jnp.ones((1, 128, 2, 64), jnp.float32)
 
     def loss(q):
-        return ap.flash_attention(q, q, q, causal=True, interpret=True).sum()
+        return ap.flash_attention(q, q, q, causal=True, block_q=128,
+                                  block_k=128, interpret=True).sum()
 
     paths = _paths(jax.jit(jax.grad(loss)).lower(q))
     assert _has(paths, r"flash_attn\.fwd", backward=False)
