@@ -101,7 +101,9 @@ def build_net(*, vocab, n_layers, d_model, n_heads, seq_len):
 
 def lm_batch(batch, seq_len, vocab, seed=0):
     """One seeded next-token batch in the LM input contract: ids as float32
-    ``[B, T, 1]``, labels one-hot float32 ``[B, T, V]``."""
+    ``[B, T, 1]``, labels one-hot float32 ``[B, T, V]``. The step takes the
+    head's loss from its logits (``losses.softmax_xent``), not from the
+    probabilities ``output()`` returns."""
     ids = np.random.RandomState(seed).randint(0, vocab, (batch, seq_len))
     x = ids[..., None].astype(np.float32)
     y = np.zeros((batch, seq_len, vocab), np.float32)

@@ -33,6 +33,7 @@ from deeplearning4j_tpu.telemetry import flight as _flight
 from deeplearning4j_tpu.telemetry import health as _health
 from deeplearning4j_tpu.nn import gradnorm as _gradnorm
 from deeplearning4j_tpu.nn import listeners as _listeners
+from deeplearning4j_tpu.nn import losses as _losses
 from deeplearning4j_tpu.nn import scopes as _scopes
 from deeplearning4j_tpu.nn import updaters as _updaters
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
@@ -106,12 +107,17 @@ class LayerVertex(GraphVertex):
     def init_state(self, input_types, dtype=jnp.float32):
         return self.layer.init_state(self._adapted(input_types), dtype)
 
-    def apply(self, params, state, xs, *, train=False, rng=None, mask=None):
-        x = xs[0]
-        fam = self.layer.input_family
+    def _adapt(self, x):
         # family adaptation by rank (jit-safe: static shapes)
-        if fam is _inputs.FeedForwardType and x.ndim > 2:
+        if self.layer.input_family is _inputs.FeedForwardType and x.ndim > 2:
             x = x.reshape((x.shape[0], -1))
+        return x
+
+    def pre_output(self, params, xs):
+        return self.layer.pre_output(params, self._adapt(xs[0]))
+
+    def apply(self, params, state, xs, *, train=False, rng=None, mask=None):
+        x = self._adapt(xs[0])
         kwargs = {}
         # 1-d masks are example-validity (shape bucketing), not [B, T]
         # timestep masks — mask-aware layers only get the latter
@@ -728,8 +734,17 @@ class ComputationGraph:
                 # regardless of the network's mode (running-stat BN, no
                 # stat updates, no dropout)
                 l_train = train and name not in frozen
+                is_output = labels is not None and name in self.conf.outputs
+                # a softmax head under a cross-entropy: the loss from the
+                # logits; its activations are for the caller, and dead
+                # code in a train step
+                logits_loss = _losses.from_logits(layer) if is_output \
+                    else None
 
-                def run(p, s, x_list, r, m, _v=v.vertex, _train=l_train):
+                def run(p, s, x_list, r, m, _v=v.vertex, _train=l_train,
+                        _logits=logits_loss is not None):
+                    if _logits:
+                        return _v.pre_output(p, x_list), s
                     return _v.apply(p, s, x_list, train=_train, rng=r,
                                     mask=m)
 
@@ -738,7 +753,7 @@ class ComputationGraph:
                 with _scopes.vertex(name, v.vertex):
                     acts[name], new_state[name] = run(
                         params[name], state[name], xs, sub, mask)
-                if labels is not None and name in self.conf.outputs:
+                if is_output:
                     l_layer = layer if layer is not None else v.vertex
                     if not hasattr(l_layer, "compute_loss"):
                         raise ValueError(f"Output vertex {name!r} has no loss")
@@ -746,8 +761,10 @@ class ComputationGraph:
                     if lm is None:  # MLN convention, shape-guarded
                         lm = _loss_mask_for(mask, labels[name])
                     with jax.named_scope("loss"):
-                        loss = loss + l_layer.compute_loss(
+                        loss = loss + (logits_loss or l_layer.compute_loss)(
                             acts[name], labels[name], lm)
+                    if logits_loss is not None:
+                        acts[name] = layer.activation_fn()(acts[name])
         if carries is not None:
             return acts, new_state, loss, new_carries
         return acts, new_state, loss

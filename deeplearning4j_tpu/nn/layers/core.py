@@ -53,18 +53,24 @@ class DenseLayer(ParamLayer):
             p["b"] = jnp.full((self.n_out,), self.bias_init, dtype)
         return p
 
-    def apply(self, params, state, x, *, train=False, rng=None):
+    def pre_output(self, params, x):
+        """z = xW + b (reference: BaseLayer.preOutput)."""
         z = matmul(x, params["W"])
         if self.has_bias:
             z = z + params["b"]
-        return self.activation_fn()(z), state
+        return z
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        return self.activation_fn()(self.pre_output(params, x)), state
 
 
 @register_config
 @dataclasses.dataclass(frozen=True)
 class OutputLayer(DenseLayer):
     """Dense + loss head (reference: conf/layers/OutputLayer.java; score at
-    MultiLayerNetwork.java:2307)."""
+    MultiLayerNetwork.java:2307). ``compute_loss`` takes the activations;
+    under softmax with a cross-entropy the networks' ``loss_fn`` takes the
+    loss from ``pre_output`` instead (``losses.from_logits``)."""
 
     loss: object = "mcxent"
     activation: object = dataclasses.field(default="softmax", kw_only=True)
@@ -210,13 +216,13 @@ class TimeDistributedDenseLayer(DenseLayer):
             p["b"] = jnp.full((self.n_out,), self.bias_init, dtype)
         return p
 
-    def apply(self, params, state, x, *, train=False, rng=None):
+    def pre_output(self, params, x):
         b, t, f = x.shape
         z = matmul(x.reshape(b * t, f), params["W"]).reshape(
             b, t, self.n_out)
         if self.has_bias:
             z = z + params["b"]
-        return self.activation_fn()(z), state
+        return z
 
 
 @register_config

@@ -367,10 +367,13 @@ class RnnOutputLayer(ParamLayer):
                                        n_in, self.n_out, dtype),
                 "b": jnp.full((self.n_out,), self.bias_init, dtype)}
 
-    def apply(self, params, state, x, *, train=False, rng=None):
+    def pre_output(self, params, x):
         b, t, f = x.shape
         z = matmul(x.reshape(b * t, f), params["W"]) + params["b"]
-        return self.activation_fn()(z.reshape(b, t, self.n_out)), state
+        return z.reshape(b, t, self.n_out)
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        return self.activation_fn()(self.pre_output(params, x)), state
 
     def compute_loss(self, predictions, labels, mask=None):
         return _losses.get(self.loss)(predictions, labels, mask)

@@ -30,6 +30,7 @@ import numpy as np
 
 from deeplearning4j_tpu.telemetry import health as _health
 from deeplearning4j_tpu.nn import gradnorm as _gradnorm
+from deeplearning4j_tpu.nn import losses as _losses
 from deeplearning4j_tpu.nn import scopes as _scopes
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
@@ -79,19 +80,20 @@ class MultiLayerNetwork:
         return params, state
 
     def apply_fn(self, params, state, x, *, train=False, rng=None, mask=None,
-                 layer_limit=None):
-        """Forward pass. Returns (output, new_state)."""
+                 layer_limit=None, logits=False):
+        """Forward pass. Returns (output, new_state); with ``logits`` the
+        last layer hands out its pre-activation output instead."""
         new_state = list(state)
         cur_type = self.conf.input_type
         n = len(self.conf.layers) if layer_limit is None else layer_limit
         for i in range(n):
             x, new_state[i], rng, cur_type = self._apply_layer(
                 i, params[i], state[i], x, cur_type, train=train, rng=rng,
-                mask=mask)
+                mask=mask, logits=logits and i == n - 1)
         return x, new_state
 
     def _apply_layer(self, i, layer_params, state_i, x, cur_type, *, train,
-                     rng, mask):
+                     rng, mask, logits=False):
         """ONE layer of the forward loop — the definition ``apply_fn``
         iterates and the ZeRO-3 streamed-gather scan body reuses
         (parallel/data_parallel._streamed_loss runs it inside a
@@ -134,6 +136,8 @@ class MultiLayerNetwork:
 
             def run(p, s, xx, r, _layer=layer, _kwargs=kwargs,
                     _train=l_train):
+                if logits:
+                    return _layer.pre_output(p, xx), s
                 return _layer.apply(p, s, xx, train=_train, rng=r, **_kwargs)
 
             if self.conf.gradient_checkpointing:
@@ -161,13 +165,20 @@ class MultiLayerNetwork:
             new_state = list(new_state)
             new_state[-1] = out_state
         else:
-            preds, new_state = self.apply_fn(params, state, x, train=train,
-                                             rng=rng, mask=mask)
             if not hasattr(out_layer, "compute_loss"):
                 raise ValueError("Last layer must be an output/loss layer, got "
                                  f"{type(out_layer).__name__}")
+            # a softmax head under a cross-entropy takes its loss from its
+            # logits; the probabilities are then for the caller, and dead
+            # code in a train step
+            logits_loss = _losses.from_logits(out_layer)
+            preds, new_state = self.apply_fn(
+                params, state, x, train=train, rng=rng, mask=mask,
+                logits=logits_loss is not None)
             with jax.named_scope("loss"):
-                loss = out_layer.compute_loss(preds, y, lm)
+                loss = (logits_loss or out_layer.compute_loss)(preds, y, lm)
+            if logits_loss is not None:
+                preds = out_layer.activation_fn()(preds)
         with jax.named_scope("loss"):
             for layer, p in zip(self.conf.layers, params):
                 if p:
@@ -183,11 +194,14 @@ class MultiLayerNetwork:
     # ------------------------------------------------------------------
 
     def _apply_rnn(self, params, state, x, carries, *, train=False, rng=None,
-                   mask=None):
-        """Forward pass threading RNN carries. Returns (y, new_state, new_carries)."""
+                   mask=None, logits=False):
+        """Forward pass threading RNN carries. Returns (y, new_state,
+        new_carries); with ``logits`` the last layer hands out its
+        pre-activation output, as in ``apply_fn``."""
         new_state = list(state)
         new_carries = list(carries)
         cur_type = self.conf.input_type
+        last = len(self.conf.layers) - 1
         for i, layer in enumerate(self.conf.layers):
             with _scopes.layer(i, layer):
                 fam = layer.input_family
@@ -201,6 +215,8 @@ class MultiLayerNetwork:
                 if hasattr(layer, "apply_with_carry"):
                     x, new_carries[i] = layer.apply_with_carry(
                         params[i], carries[i], x, mask=mask)
+                elif logits and i == last:
+                    x = layer.pre_output(params[i], x)
                 else:
                     kwargs = ({"mask": mask} if self._mask_aware[i]
                               and mask is not None else {})
@@ -217,11 +233,14 @@ class MultiLayerNetwork:
             carries = jax.tree_util.tree_map(jax.lax.stop_gradient, carries)
 
             def chunk_loss(params):
-                preds, new_state, new_carries = self._apply_rnn(
-                    params, state, x, carries, train=True, rng=rng, mask=mask)
                 out_layer = conf.layers[-1]
+                logits_loss = _losses.from_logits(out_layer)
+                out, new_state, new_carries = self._apply_rnn(
+                    params, state, x, carries, train=True, rng=rng, mask=mask,
+                    logits=logits_loss is not None)
                 with jax.named_scope("loss"):
-                    loss = out_layer.compute_loss(preds, y, mask)
+                    loss = (logits_loss or out_layer.compute_loss)(out, y,
+                                                                   mask)
                     for layer, p in zip(conf.layers, params):
                         if p:
                             loss = loss + layer.regularization_penalty(p)

@@ -403,6 +403,7 @@ class ParallelTrainer:
             self.param_store_shardings[i0])
         wsc = jax.lax.with_sharding_constraint
 
+        from deeplearning4j_tpu.nn import losses as _losses
         from deeplearning4j_tpu.nn.conf import inputs as _inputs
         from deeplearning4j_tpu.nn.layers import base as _lbase
         from deeplearning4j_tpu.parallel.pipeline import stack_blocks
@@ -413,6 +414,7 @@ class ParallelTrainer:
                 raise ValueError(
                     "Last layer must be an output/loss layer, got "
                     f"{type(out_layer).__name__}")
+            logits_loss = _losses.from_logits(out_layer)
             new_state = list(state)
 
             def edge(i, h, rng, cur_type):
@@ -423,7 +425,7 @@ class ParallelTrainer:
                         if params[i] else params[i])
                 h, new_state[i], rng, cur_type = net._apply_layer(
                     i, full, state[i], h, cur_type, train=True, rng=rng,
-                    mask=mask)
+                    mask=mask, logits=logits_loss is not None and i == n - 1)
                 return h, rng, cur_type
 
             h, cur_type = x, net.conf.input_type
@@ -467,8 +469,10 @@ class ParallelTrainer:
             cur_type = trunk_layer.output_type(ct)
             for i in range(i1, n):
                 h, rng, cur_type = edge(i, h, rng, cur_type)
-            preds = h
-            loss = out_layer.compute_loss(preds, y, mask)
+            # as MultiLayerNetwork.loss_fn: a softmax head's from its logits
+            loss = (logits_loss or out_layer.compute_loss)(h, y, mask)
+            preds = h if logits_loss is None \
+                else out_layer.activation_fn()(h)
             for i in range(n):
                 if i0 <= i < i1:
                     loss = loss + pens[i - i0]

@@ -2,8 +2,8 @@
 text of a toy MultiLayerNetwork and ComputationGraph step, built the plain
 way, for truncated BPTT and as the fused K-step scan, holds the layer /
 vertex / loss / grad_norm / updater scopes; the backward pass repeats a
-layer's scope under JAX's ``transpose(``; the flash kernel's forward and
-backward carry their own."""
+layer's scope under JAX's ``transpose(``; the softmax head's loss from its
+logits, the flash kernel's forward and backward carry their own."""
 
 import re
 
@@ -137,6 +137,22 @@ def test_graph_step_carries_its_scopes(builder):
     for scope in ("grad_norm", "updater"):
         assert _has(paths, scope, backward=False), scope
     assert _has(paths, "health") == (builder == "health")
+
+
+@pytest.mark.parametrize("builder", ["plain", "tbptt", "fused", "health"])
+@pytest.mark.parametrize("make", [_mln, _cg])
+def test_softmax_heads_loss_is_named_inside_loss(make, builder):
+    """`softmax_xent`: the forward and the hand-written backward of the
+    loss a softmax head takes from its logits, both inside `loss` (what
+    `step_loss_ms.*` reads) and neither inside the layer's own scope,
+    which keeps the head's matmul."""
+    net, x, y = make(recurrent=builder == "tbptt")
+    paths = _paths(_lower(net, x, y, builder))
+    named = [p for p in paths if re.search(r"(?:^|[/(])softmax_xent/", p)]
+    assert {("transpose(" in p) for p in named} == {False, True}
+    for p in named:
+        assert re.search(r"jvp\(loss\)\)?/softmax_xent/", p), p
+        assert "OutputLayer" not in p, p
 
 
 def test_transformer_block_names_its_halves():
