@@ -493,6 +493,70 @@ def _looped_block_case(name, *, b, t, width, h, d, ffn, interpret, tol):
     return _compare(name, apply, apply, (params, x), tol)
 
 
+def _hybrid_step_case(name, *, t, vocab, tol):
+    """The hybrid conv/attention mixture-of-experts step at a small depth
+    and the lfm2-train-t8192 cell's widths (a short convolution with the
+    dense FFN, grouped-query attention with QK-norm and 8 of 64 routed
+    experts, a short convolution with 8 more; routing fixed by the expert
+    bias, so that no near-tie decides differently on the two branches): the
+    compiled train step's
+    kernel count (the flash forward and backward, and nine grouped
+    products an expert layer: gate, up and down, each forward, for the
+    input gradient and for the weight gradient), then logits and every
+    gradient through the dispatch against the naive branch (attention in
+    ``jax.numpy``, the grouped products as ``jax.lax.ragged_dot``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu import models
+    from deeplearning4j_tpu.nn.layers import moe as _moe
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+    from deeplearning4j_tpu.utils import dtypes as _dtypes
+
+    net = MultiLayerNetwork(models.hybrid_moe_lm(
+        vocab, layer_types=("conv", "full_attention", "conv"),
+        num_dense_layers=1, experts_held=(0, 8), seq_len=t))
+    net.init()
+    # routing by the bias alone (router weights zero, so every score is a
+    # half): a token near a tie would pick another expert on the naive
+    # branch than through the dispatch, and one such token moves the
+    # largest difference by its whole FFN. Experts 1, 2 and 5 of the four
+    # chosen are held in one layer, 0 and 3 in the other; the router's
+    # gradient still flows through the weights
+    for i, picks in ((2, (1, 2, 5, 40)), (3, (0, 3, 10, 20))):
+        net.params[i]["moe_router"] = jnp.zeros_like(
+            net.params[i]["moe_router"])
+        net.state[i]["expert_bias"] = jnp.zeros(
+            (64,), jnp.float32).at[jnp.array(picks)].set(1.0)
+    x = jnp.asarray(np.random.RandomState(0).randint(0, vocab, (1, t)),
+                    jnp.int32)
+    labels = jnp.roll(x, -1, axis=1)
+    step = net.make_train_step(donate=False)
+    text = step.lower(net.params, net.state, net.opt_state, x, labels, 0,
+                      jax.random.PRNGKey(0), None).compile().as_text()
+    n_calls, want = text.count("tpu_custom_call"), 2 + 2 * 9
+    _expect(n_calls == want,
+            f"{name}: compiled train step holds {n_calls} "
+            f"tpu_custom_call(s), expected {want}")
+    state = net.state
+
+    def logits(params):
+        return net.apply_fn(params, state, x, train=True, logits=True)[0]
+
+    def naive(params):
+        cd, _ = _dtypes.compute_dtypes_for(jnp.float32)
+        saved = _moe.grouped_matmul
+        _moe.grouped_matmul = lambda a, w, sizes, out: jax.lax.ragged_dot(
+            a.astype(cd), w.astype(cd), sizes, preferred_element_type=out)
+        try:
+            return logits(params)
+        finally:
+            _moe.grouped_matmul = saved
+
+    out = _compare(name, logits, naive, (net.params,), tol)
+    return {**out, "tpu_custom_calls": n_calls}
+
+
 def kernel_cases(interpret):
     """The widths the dispatch gates admit on the chip; toy widths for the
     interpret-mode CPU test (same kernels, same variants)."""
@@ -550,6 +614,8 @@ def kernels_phase(*, interpret, tol):
         results.append(_looped_block_case(
             "looped_lm_t2048_h16_d128", b=2, t=2048, width=2048, h=16, d=128,
             ffn=5632, interpret=False, tol=tol))
+        results.append(_hybrid_step_case(
+            "hybrid_moe_lm_t2048_3layers", t=2048, vocab=1024, tol=tol))
         results += [   # the two train cells' calls
             _flash_backward_time("flash_bwd_t1024_h16_d64_f32", b=4, t=1024,
                                  h=16, d=64, interpret=False),

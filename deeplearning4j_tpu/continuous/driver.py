@@ -651,6 +651,9 @@ class StepDriver:
                 self._emit(tail)
             if self._use_health:
                 self._hm.flush(apply_policy=apply_policy)
+            if self.instrumented and self._reg.enabled:
+                # the routed-experts layers' counts of the last step
+                _tm.note_routing(self.net.state)
 
     def checkpoint(self, path, *, buckets=None, save_updater=True):
         """``sync()`` then write one resumable ``save_bundle`` unit —

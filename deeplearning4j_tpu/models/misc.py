@@ -162,3 +162,55 @@ def looped_lm(vocab_size, n_layers=4, d_model=2048, n_heads=16, head_dim=None,
         L.LoopedLMOutputLayer(n_out=vocab_size, beta=beta, weight_init=init),
         input_type=I.RecurrentType(1, seq_len),
     )
+
+
+def hybrid_moe_lm(vocab_size, layer_types=("conv", "full_attention"),
+                  num_dense_layers=1, d_model=2048, n_heads=32, n_kv_heads=8,
+                  head_dim=None, ffn_width=11776, expert_width=1536,
+                  n_experts=64, top_k=4, experts_held=(), conv_kernel=3,
+                  routed_scale=1.0, seq_len=8192, rope_theta=1e6,
+                  norm_eps=1e-5, updater=None, seed=12345):
+    """Hybrid conv/attention mixture-of-experts decoder (the LFM2 family's
+    ``lfm2_moe``; net-new): a token embedding, one pre-norm block a layer
+    whose mixer is a gated short convolution (``"conv"``) or grouped-query
+    attention with QK-norm and rotary positions (``"full_attention"``) as
+    ``layer_types`` says, and whose FFN is a dense gated SiLU FFN for the
+    first ``num_dense_layers`` layers and ``n_experts`` routed experts
+    (top-``top_k``, sigmoid scores, an expert bias that moves the selection
+    only, weights renormalised over the selected) for the rest; a final
+    RMSNorm and an untied softmax head under ``sparse_mcxent``. No bias
+    anywhere. ``experts_held`` = (first, end) is the share of every expert
+    layer that this network holds (() = all). Input: [B, T] integer token
+    ids; labels: [B, T] integer next-token ids. The defaults are
+    LFM2-24B-A2B's published widths."""
+    from deeplearning4j_tpu.nn.initializers import Distribution
+    init = Distribution(kind="normal", std=0.02)
+    mixers = {"conv": "short_conv", "full_attention": "attention"}
+    blocks = []
+    for i, kind in enumerate(layer_types):
+        if kind not in mixers:
+            raise ValueError(f"layer_types[{i}] is 'conv' or "
+                             f"'full_attention', got {kind!r}")
+        dense = i < num_dense_layers
+        moe = {} if dense else {"n_experts": n_experts, "top_k": top_k,
+                                "experts_held": tuple(experts_held),
+                                "routed_scale": routed_scale}
+        blocks.append(L.TransformerBlock(
+            n_out=d_model, n_heads=n_heads, causal=True, activation="silu",
+            norm="rms", norm_eps=norm_eps, bias=False, rope_theta=rope_theta,
+            head_dim=head_dim, n_kv_heads=n_kv_heads, qk_norm=True,
+            mixer=mixers[kind], conv_kernel=conv_kernel,
+            ffn="gated" if dense else "moe",
+            ffn_width=ffn_width if dense else expert_width,
+            weight_init=init, **moe))
+    return NeuralNetConfig(
+        seed=seed,
+        updater=updater or U.Adam(learning_rate=3e-4)).list(
+        L.EmbeddingSequenceLayer(n_in=vocab_size, n_out=d_model,
+                                 weight_init=init),
+        *blocks,
+        L.RMSNorm(eps=norm_eps),
+        L.RnnOutputLayer(n_out=vocab_size, loss="sparse_mcxent",
+                         has_bias=False, weight_init=init),
+        input_type=I.RecurrentType(1, seq_len),
+    )

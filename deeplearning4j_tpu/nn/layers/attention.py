@@ -158,7 +158,13 @@ class MultiHeadAttention(ParamLayer):
     the original parameter tree and arithmetic): ``bias=False`` drops
     ``bqkv`` / ``bo``; ``rope_theta`` turns q and k by their positions
     before the attention; ``head_dim`` sets the head width apart from
-    ``n_out / n_heads``."""
+    ``n_out / n_heads``; ``n_kv_heads`` (grouped-query attention) gives
+    ``n_heads / n_kv_heads`` query heads one key/value head, query head
+    ``j`` reading key/value head ``j // group``, with the projections
+    apart (``Wq`` [n_in, H D], ``Wkv`` [n_in, 2 Hkv D]) in place of
+    ``Wqkv``; ``qk_norm`` puts an RMSNorm over each head's width on q and
+    on k (gains ``q_gamma`` / ``k_gamma`` [D], shared by the heads, eps
+    ``qk_norm_eps``) before the rotation."""
 
     n_out: int = 0     # model dim (also output dim)
     n_heads: int = 4
@@ -166,11 +172,14 @@ class MultiHeadAttention(ParamLayer):
     bias: bool = True
     rope_theta: float | None = None
     head_dim: int | None = None
+    n_kv_heads: int | None = None
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-6
     weight_init: object = dataclasses.field(default="xavier", kw_only=True)
 
     input_family = _inputs.RecurrentType
 
-    WEIGHT_KEYS = ("Wqkv", "Wo")
+    WEIGHT_KEYS = ("Wqkv", "Wq", "Wkv", "Wo")
     BIAS_KEYS = ("bqkv", "bo")
 
     def _head_dim(self):
@@ -179,6 +188,20 @@ class MultiHeadAttention(ParamLayer):
         assert self.n_out % self.n_heads == 0
         return self.n_out // self.n_heads
 
+    def _grouped(self):
+        """Key/value heads where they are fewer than the query heads,
+        else None (plain multi-head: one fused projection)."""
+        kv = self.n_kv_heads
+        if kv is None or kv == self.n_heads:
+            return None
+        if self.n_heads % kv:
+            raise ValueError(f"n_heads {self.n_heads} is no multiple of "
+                             f"n_kv_heads {kv}")
+        if self.bias:
+            raise ValueError("grouped-query projections have no biases: "
+                             "set bias=False")
+        return kv
+
     def output_type(self, input_type):
         return _inputs.RecurrentType(self.n_out, input_type.timesteps)
 
@@ -186,12 +209,24 @@ class MultiHeadAttention(ParamLayer):
         n_in = input_type.size
         inner = self.n_heads * self._head_dim()
         k1, k2 = jax.random.split(key)
-        p = {
-            "Wqkv": _init.init_weight(self.weight_init, k1, (n_in, 3 * inner),
-                                      n_in, 3 * inner, dtype),
-            "Wo": _init.init_weight(self.weight_init, k2, (inner, self.n_out),
-                                    inner, self.n_out, dtype),
-        }
+        kv = self._grouped()
+        p = {"Wo": _init.init_weight(self.weight_init, k2, (inner, self.n_out),
+                                     inner, self.n_out, dtype)}
+        if kv is None:
+            p["Wqkv"] = _init.init_weight(self.weight_init, k1,
+                                          (n_in, 3 * inner), n_in, 3 * inner,
+                                          dtype)
+        else:
+            kq, kkv = jax.random.split(k1)
+            kv_inner = 2 * kv * self._head_dim()
+            p["Wq"] = _init.init_weight(self.weight_init, kq, (n_in, inner),
+                                        n_in, inner, dtype)
+            p["Wkv"] = _init.init_weight(self.weight_init, kkv,
+                                         (n_in, kv_inner), n_in, kv_inner,
+                                         dtype)
+        if self.qk_norm:
+            p["q_gamma"] = jnp.ones((self._head_dim(),), dtype)
+            p["k_gamma"] = jnp.ones((self._head_dim(),), dtype)
         if self.bias:
             p["bqkv"] = jnp.zeros((3 * inner,), dtype)
             p["bo"] = jnp.zeros((self.n_out,), dtype)
@@ -201,13 +236,29 @@ class MultiHeadAttention(ParamLayer):
         """Project to q,k,v [B,T,H,D]."""
         b, t, _ = x.shape
         h, d = self.n_heads, self._head_dim()
-        qkv = matmul(x.reshape(b * t, -1), params["Wqkv"])
-        if self.bias:
-            qkv = qkv + params["bqkv"]
-        qkv = qkv.reshape(b, t, 3, h, d)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        kv = self._grouped()
+        if kv is None:
+            qkv = matmul(x.reshape(b * t, -1), params["Wqkv"])
+            if self.bias:
+                qkv = qkv + params["bqkv"]
+            qkv = qkv.reshape(b, t, 3, h, d)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        else:
+            x2 = x.reshape(b * t, -1)
+            q = matmul(x2, params["Wq"]).reshape(b, t, h, d)
+            k_v = matmul(x2, params["Wkv"]).reshape(b, t, 2, kv, d)
+            k, v = k_v[:, :, 0], k_v[:, :, 1]
+        if self.qk_norm:
+            norm = RMSNorm(eps=self.qk_norm_eps)
+            q, _ = norm.apply({"gamma": params["q_gamma"]}, {}, q)
+            k, _ = norm.apply({"gamma": params["k_gamma"]}, {}, k)
         if self.rope_theta is not None:
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
+        if kv is not None:
+            # each key/value head serves its group of query heads; autodiff
+            # sums the group's gradients back onto the one head
+            k = jnp.repeat(k, h // kv, axis=2)
+            v = jnp.repeat(v, h // kv, axis=2)
         return q, k, v
 
     def out_proj(self, params, attn):
@@ -228,6 +279,62 @@ class MultiHeadAttention(ParamLayer):
 
 @register_config
 @dataclasses.dataclass(frozen=True)
+class ShortConv(ParamLayer):
+    """Gated short convolution over [B,T,F] (the LFM2 family's second
+    mixer): ``[B_, C_, x_] = split3(u W_in)`` in that order, ``z = B_ *
+    x_``, a depthwise causal convolution of length ``kernel`` over time
+    (zeros before the sequence's start; tap ``kernel - 1`` meets the
+    present position), ``out = (C_ * c) W_out``. No bias and no
+    activation inside. Two shifted multiply-adds that XLA fuses; no
+    kernel."""
+
+    n_out: int = 0
+    kernel: int = 3
+    weight_init: object = dataclasses.field(default="xavier", kw_only=True)
+
+    input_family = _inputs.RecurrentType
+
+    WEIGHT_KEYS = ("W_in", "conv_w", "W_out")
+    BIAS_KEYS = ()
+
+    def output_type(self, input_type):
+        return _inputs.RecurrentType(self.n_out, input_type.timesteps)
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        n_in, d = input_type.size, self.n_out
+        k1, k2, k3 = jax.random.split(key, 3)
+        return {
+            "W_in": _init.init_weight(self.weight_init, k1, (n_in, 3 * d),
+                                      n_in, 3 * d, dtype),
+            "conv_w": _init.init_weight(self.weight_init, k2,
+                                        (d, self.kernel), self.kernel, 1,
+                                        dtype),
+            "W_out": _init.init_weight(self.weight_init, k3, (d, d), d, d,
+                                       dtype),
+        }
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        with jax.named_scope("short_conv"):
+            b, t, _ = x.shape
+            d = self.n_out
+            bcx = matmul(x.reshape(b * t, -1), params["W_in"])
+            bcx = bcx.reshape(b, t, 3, d)
+            gate_b, gate_c, xx = bcx[:, :, 0], bcx[:, :, 1], bcx[:, :, 2]
+            z = gate_b * xx
+            w = params["conv_w"].astype(z.dtype)
+            c = z * w[:, self.kernel - 1]
+            for back in range(1, self.kernel):
+                past = jnp.pad(z, ((0, 0), (back, 0), (0, 0)))[:, :t]
+                c = c + past * w[:, self.kernel - 1 - back]
+            y = matmul((gate_c * c).reshape(b * t, d), params["W_out"])
+            y = y.reshape(b, t, d)
+            if mask is not None:
+                y = y * mask[..., None].astype(y.dtype)
+            return y, state
+
+
+@register_config
+@dataclasses.dataclass(frozen=True)
 class TransformerBlock(Layer):
     """Transformer block: norm -> MHA -> residual, norm -> FFN -> residual.
 
@@ -237,9 +344,17 @@ class TransformerBlock(Layer):
     ``norm_eps``, None = the norm's own default); ``sandwich`` adds a norm
     after the mixer and after the FFN, before each residual add
     (``ln1_post`` / ``ln2_post``); ``bias=False`` drops every bias;
-    ``rope_theta`` and ``head_dim`` go to the attention; ``ffn`` "mlp" |
-    "gated" (``act(x Wg) * (x Wu)`` then ``Wd``) of width ``ffn_width``
-    (None = ``n_out * mlp_ratio``)."""
+    ``rope_theta``, ``head_dim``, ``n_kv_heads`` and ``qk_norm`` (with
+    ``norm_eps``) go to the attention; ``mixer`` "attention" |
+    "short_conv" puts a ``ShortConv`` of length ``conv_kernel`` in the
+    attention's place (parameters under ``conv``, not ``mha``); ``ffn``
+    "mlp" | "gated" (``act(x Wg) * (x Wu)`` then ``Wd``) | "moe" of width
+    ``ffn_width`` (None = ``n_out * mlp_ratio``). ``"moe"`` is a dropless
+    top-``top_k`` sigmoid router over ``n_experts`` gated experts of that
+    width, of which this layer holds ``experts_held`` = (first, end) (()
+    = all of them) and computes their part of the result
+    (``moe.routed_experts``); ``expert_bias`` and the last step's
+    ``moe_load`` / ``moe_elsewhere`` live in the layer's state."""
 
     n_out: int = 0
     n_heads: int = 4
@@ -255,8 +370,24 @@ class TransformerBlock(Layer):
     ffn: str = "mlp"
     ffn_width: int | None = None
     weight_init: object = "xavier"
+    mixer: str = "attention"
+    conv_kernel: int = 3
+    n_kv_heads: int | None = None
+    qk_norm: bool = False
+    n_experts: int = 0
+    top_k: int = 1
+    experts_held: tuple = ()
+    routed_scale: float = 1.0
 
     input_family = _inputs.RecurrentType
+
+    def _held(self):
+        """(first, end) of the experts this layer holds."""
+        first, end = self.experts_held or (0, self.n_experts)
+        if not 0 <= first < end <= self.n_experts:
+            raise ValueError(f"experts_held {self.experts_held} does not "
+                             f"lie in 0..{self.n_experts}")
+        return int(first), int(end)
 
     def _norm(self):
         if self.norm not in ("layer", "rms"):
@@ -265,13 +396,26 @@ class TransformerBlock(Layer):
         return cls() if self.norm_eps is None else cls(eps=self.norm_eps)
 
     def _parts(self):
-        return (self._norm(),
-                MultiHeadAttention(n_out=self.n_out, n_heads=self.n_heads,
-                                   causal=self.causal, bias=self.bias,
-                                   rope_theta=self.rope_theta,
-                                   head_dim=self.head_dim,
-                                   weight_init=self.weight_init),
-                self._norm())
+        """(norm, mixer, norm); the mixer's parameters sit under
+        ``_mixer_key()``."""
+        if self.mixer == "short_conv":
+            mixer = ShortConv(n_out=self.n_out, kernel=self.conv_kernel,
+                              weight_init=self.weight_init)
+        elif self.mixer == "attention":
+            qk = ({} if self.norm_eps is None
+                  else {"qk_norm_eps": self.norm_eps})
+            mixer = MultiHeadAttention(
+                n_out=self.n_out, n_heads=self.n_heads, causal=self.causal,
+                bias=self.bias, rope_theta=self.rope_theta,
+                head_dim=self.head_dim, n_kv_heads=self.n_kv_heads,
+                qk_norm=self.qk_norm, weight_init=self.weight_init, **qk)
+        else:
+            raise ValueError("mixer is 'attention' or 'short_conv', got "
+                             f"{self.mixer!r}")
+        return self._norm(), mixer, self._norm()
+
+    def _mixer_key(self):
+        return "conv" if self.mixer == "short_conv" else "mha"
 
     def output_type(self, input_type):
         return _inputs.RecurrentType(self.n_out, input_type.timesteps)
@@ -279,10 +423,12 @@ class TransformerBlock(Layer):
     def init(self, key, input_type, dtype=jnp.float32):
         assert input_type.size == self.n_out, \
             "TransformerBlock requires input size == n_out (residual)"
-        if self.ffn not in ("mlp", "gated"):
-            raise ValueError(f"ffn is 'mlp' or 'gated', got {self.ffn!r}")
-        if self.bias and self.ffn == "gated":
-            raise ValueError("the gated FFN has no biases: set bias=False")
+        if self.ffn not in ("mlp", "gated", "moe"):
+            raise ValueError("ffn is 'mlp', 'gated' or 'moe', got "
+                             f"{self.ffn!r}")
+        if self.bias and self.ffn != "mlp":
+            raise ValueError(f"the {self.ffn} FFN has no biases: set "
+                             "bias=False")
         ln1, mha, ln2 = self._parts()
         k1, k2, k3, k4 = jax.random.split(key, 4)
         hidden = self.ffn_width or self.n_out * self.mlp_ratio
@@ -293,7 +439,7 @@ class TransformerBlock(Layer):
                                      n_in, n_out, dtype)
 
         p = {"ln1": ln1.init(k1, it, dtype),
-             "mha": mha.init(k1, it, dtype),
+             self._mixer_key(): mha.init(k1, it, dtype),
              "ln2": ln2.init(k2, it, dtype)}
         if self.sandwich:
             p["ln1_post"] = ln1.init(k1, it, dtype)
@@ -303,6 +449,18 @@ class TransformerBlock(Layer):
             p["mlp_Wg"] = weight(k3g, self.n_out, hidden)
             p["mlp_Wu"] = weight(k3u, self.n_out, hidden)
             p["mlp_Wd"] = weight(k4, hidden, self.n_out)
+        elif self.ffn == "moe":
+            first, end = self._held()
+            kr, kg, ku = jax.random.split(k3, 3)
+
+            def experts(k, n_in, n_out):
+                return jnp.stack([weight(kk, n_in, n_out) for kk in
+                                  jax.random.split(k, end - first)])
+
+            p["moe_router"] = weight(kr, self.n_out, self.n_experts)
+            p["moe_Wg"] = experts(kg, self.n_out, hidden)
+            p["moe_Wu"] = experts(ku, self.n_out, hidden)
+            p["moe_Wd"] = experts(k4, hidden, self.n_out)
         else:
             p["mlp_W1"] = weight(k3, self.n_out, hidden)
             p["mlp_W2"] = weight(k4, hidden, self.n_out)
@@ -310,6 +468,33 @@ class TransformerBlock(Layer):
             p["mlp_b1"] = jnp.zeros((hidden,), dtype)
             p["mlp_b2"] = jnp.zeros((self.n_out,), dtype)
         return p
+
+    def init_state(self, input_type, dtype=jnp.float32):
+        if self.ffn != "moe":
+            return {}
+        first, end = self._held()
+        return {"expert_bias": jnp.zeros((self.n_experts,), dtype),
+                "moe_load": jnp.zeros((end - first,), dtype),
+                "moe_elsewhere": jnp.zeros((1,), dtype)}
+
+    def _moe(self, params, state, h):
+        """The routed experts' part of the result and the state with this
+        step's row counts."""
+        from deeplearning4j_tpu.nn import activations as _act
+        from deeplearning4j_tpu.nn.layers import moe as _moe
+        if "expert_bias" not in state:
+            raise ValueError(
+                "ffn='moe' keeps its expert bias and its load in the "
+                "layer's state; this caller hands the block none")
+        with jax.named_scope("moe"):
+            y, load, elsewhere = _moe.routed_experts(
+                h, params["moe_router"], params["moe_Wg"], params["moe_Wu"],
+                params["moe_Wd"], state["expert_bias"], top_k=self.top_k,
+                held=self._held(), scale=self.routed_scale,
+                act=_act.get(self.activation))
+        dt = state["moe_load"].dtype
+        return y, {**state, "moe_load": load.astype(dt),
+                   "moe_elsewhere": elsewhere.astype(dt)}
 
     def _ffn(self, params, h):
         from deeplearning4j_tpu.nn import activations as _act
@@ -326,14 +511,17 @@ class TransformerBlock(Layer):
         ln1, mha, ln2 = self._parts()
         with jax.named_scope("attn"):
             h, _ = ln1.apply(params["ln1"], {}, x)
-            attn, _ = mha.apply(params["mha"], {}, h, mask=mask)
+            attn, _ = mha.apply(params[self._mixer_key()], {}, h, mask=mask)
             if self.sandwich:
                 attn, _ = ln1.apply(params["ln1_post"], {}, attn)
             x = x + attn
         with jax.named_scope("mlp"):
             h, _ = ln2.apply(params["ln2"], {}, x)
             b, t, f = h.shape
-            m = self._ffn(params, h.reshape(b * t, f))
+            if self.ffn == "moe":
+                m, state = self._moe(params, state, h.reshape(b * t, f))
+            else:
+                m = self._ffn(params, h.reshape(b * t, f))
             if self.sandwich:
                 m, _ = ln2.apply(params["ln2_post"], {}, m)
             return x + m.reshape(b, t, f), state

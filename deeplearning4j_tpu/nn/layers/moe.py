@@ -1,4 +1,9 @@
-"""Mixture-of-Experts transformer block with expert parallelism.
+"""Routed experts: the dropless top-k layer that is ``TransformerBlock``'s
+``ffn="moe"`` (``routed_experts``, below), and the older Switch-style
+block monolith (``MoETransformerBlock``, top-1 with a capacity drop and
+an auxiliary loss, partitioned by GSPMD over a stacked expert axis).
+
+Mixture-of-Experts transformer block with expert parallelism.
 
 Reference analog: none — DL4J has no MoE (nor attention); net-new for the
 TPU scale goals, completing the dp/tp/sp/pp/ep parallelism set (driver
@@ -25,6 +30,7 @@ Design (Switch-Transformer style, TPU-first):
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +41,122 @@ from deeplearning4j_tpu.nn.conf import inputs as _inputs
 from deeplearning4j_tpu.nn.layers.attention import (LayerNormalization,
                                                     MultiHeadAttention)
 from deeplearning4j_tpu.nn.layers.base import Layer
+from deeplearning4j_tpu.ops.grouped_matmul import grouped_matmul
+from deeplearning4j_tpu.utils import dtypes as _dtypes
 from deeplearning4j_tpu.utils.serde import register_config
+
+ROUTER_EPS = 1e-6  # added to the selected scores' sum before the division
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(k, x, order, inv, valid):
+    """Token rows ``x`` [N, d] laid out by sorted assignment, [N k, d]:
+    row ``p`` is the token of assignment ``order[p]`` (assignment ``a``
+    belongs to token ``a // k``). ``inv`` is ``order``'s inverse, so the
+    backward pass is a gather too: a token's gradient is the sum of its
+    ``k`` rows'. ``valid`` marks the rows inside a group here."""
+    return _dispatch_fwd(k, x, order, inv, valid)[0]
+
+
+def _dispatch_fwd(k, x, order, inv, valid):
+    return x[order // k], (inv, valid)
+
+
+def _dispatch_bwd(k, res, dxs):
+    inv, valid = res
+    n = inv.shape[0] // k
+    dxs = jnp.where(valid[:, None], dxs, 0)
+    _, ad = _dtypes.compute_dtypes_for(dxs.dtype)
+    dx = jnp.sum(dxs[inv].astype(ad).reshape(n, k, -1), axis=1)
+    return dx.astype(dxs.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, w, order, inv, valid):
+    """``y[t] = sum_j w[t, j] ys[inv[t k + j]]``: the sorted rows' results
+    [N k, d] back at their tokens, weighted. ``w`` [N, k] is zero for an
+    assignment computed elsewhere; rows of ``ys`` past the groups are
+    masked before they are read."""
+    return _combine_fwd(ys, w, order, inv, valid)[0]
+
+
+def _combine_fwd(ys, w, order, inv, valid):
+    cd, _ = _dtypes.compute_dtypes_for(ys.dtype)
+    ys = jnp.where(valid[:, None], ys, 0)
+    n, k = w.shape
+    y = jnp.sum(ys[inv].reshape(n, k, -1) * w[..., None].astype(ys.dtype),
+                axis=1)
+    return y, (ys.astype(cd), w, order, inv)
+
+
+def _combine_bwd(res, dy):
+    ys, w, order, inv = res
+    n, k = w.shape
+    # a row past the groups carries weight zero: its gradient is zero
+    dys = dy[order // k] * w.reshape(-1)[order][:, None].astype(dy.dtype)
+    dw = jnp.sum(ys[inv].reshape(n, k, -1).astype(dy.dtype)
+                 * dy[:, None, :], axis=-1)
+    return dys, dw.astype(w.dtype), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
+                   top_k, held, scale, act):
+    """One chip's share of a dropless top-``top_k`` routed-experts layer.
+
+    ``x`` [N, d]; ``router_w`` [d, E] scores ALL ``E`` experts in float32:
+    ``s = sigmoid(x W_r)``, ``sel = top_k(s + expert_bias)`` (the bias
+    moves the selection only), ``w = s[sel] / (sum(s[sel]) + 1e-6) *
+    scale``, renormalised over the selected experts wherever they live.
+    ``held = (first, end)`` names the experts whose weights
+    ``w_gate`` / ``w_up`` [n_held, d, f] and ``w_down`` [n_held, f, d]
+    are: the result is ``sum_{j in sel, first <= j < end} w_j E_j(x)``,
+    ``E_j(x) = (act(x Wg_j) * (x Wu_j)) Wd_j``; what the other experts
+    would add is left out (their chips add it).
+
+    No token is dropped and every shape is static: the ``N k``
+    assignments are sorted by held expert, those routed elsewhere behind
+    a sentinel; the counts per held expert are the group sizes of the
+    grouped products, whose work follows the rows really routed here
+    (between 0 and ``N k``) while the buffers are sized for ``N k``.
+
+    Returns ``(y [N, d], load [n_held], elsewhere [1])``: the counts of
+    assignments per held expert and of those routed to experts not held.
+    """
+    n, _ = x.shape
+    first, end = held
+    n_held = end - first
+    cd, ad = _dtypes.compute_dtypes_for(x.dtype)
+    with jax.named_scope("moe_route"):
+        logits = jnp.matmul(x.astype(ad), router_w.astype(ad),
+                            precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits)
+        _, sel = jax.lax.top_k(s + expert_bias.astype(ad), top_k)
+        w = jnp.take_along_axis(s, sel, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + ROUTER_EPS) * scale
+        local = sel.reshape(-1).astype(jnp.int32) - first
+        here = (local >= 0) & (local < n_held)
+        local = jnp.where(here, local, n_held)
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        counts = jnp.bincount(local, length=n_held + 1).astype(jnp.int32)
+        sizes = counts[:n_held]
+        valid = jnp.arange(n * top_k, dtype=jnp.int32) < jnp.sum(sizes)
+        xs = _dispatch(top_k, x.astype(cd), order, inv, valid)
+        w_here = jnp.where(here.reshape(n, top_k), w, 0.0)
+    with jax.named_scope("moe_experts"):
+        g = grouped_matmul(xs, w_gate, sizes, cd)
+        u = grouped_matmul(xs, w_up, sizes, cd)
+        h = (act(g.astype(ad)) * u.astype(ad)).astype(cd)
+        ys = grouped_matmul(h, w_down, sizes, ad)
+    with jax.named_scope("moe_route"):
+        y = _combine(ys, w_here, order, inv, valid)
+    return y.astype(x.dtype), sizes, counts[n_held:]
 
 
 @register_config

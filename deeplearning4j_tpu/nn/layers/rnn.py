@@ -350,10 +350,12 @@ class GravesBidirectionalLSTM(Layer):
 @dataclasses.dataclass(frozen=True)
 class RnnOutputLayer(ParamLayer):
     """Per-timestep dense + loss (reference: conf/layers/RnnOutputLayer.java).
-    Applies [B,T,F]x[F,O] as one flattened MXU matmul."""
+    Applies [B,T,F]x[F,O] as one flattened MXU matmul. ``has_bias=False``
+    leaves ``b`` out (a language-model head without one)."""
 
     n_out: int = 0
     loss: object = "mcxent"
+    has_bias: bool = True
     activation: object = dataclasses.field(default="softmax", kw_only=True)
 
     input_family = _inputs.RecurrentType
@@ -363,13 +365,17 @@ class RnnOutputLayer(ParamLayer):
 
     def init(self, key, input_type, dtype=jnp.float32):
         n_in = input_type.size
-        return {"W": _init.init_weight(self.weight_init, key, (n_in, self.n_out),
-                                       n_in, self.n_out, dtype),
-                "b": jnp.full((self.n_out,), self.bias_init, dtype)}
+        p = {"W": _init.init_weight(self.weight_init, key, (n_in, self.n_out),
+                                    n_in, self.n_out, dtype)}
+        if self.has_bias:
+            p["b"] = jnp.full((self.n_out,), self.bias_init, dtype)
+        return p
 
     def pre_output(self, params, x):
         b, t, f = x.shape
-        z = matmul(x.reshape(b * t, f), params["W"]) + params["b"]
+        z = matmul(x.reshape(b * t, f), params["W"])
+        if self.has_bias:
+            z = z + params["b"]
         return z.reshape(b, t, self.n_out)
 
     def apply(self, params, state, x, *, train=False, rng=None):

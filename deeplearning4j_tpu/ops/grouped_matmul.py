@@ -1,0 +1,111 @@
+"""Grouped matrix product: rows of one matrix against a stack of matrices,
+each run of consecutive rows (a group) against its own (Pallas, TPU).
+
+What a routed-experts layer needs once its token rows are sorted by
+expert (nn/layers/moe.py): ``out[r] = x[r] @ w[g]`` for the rows ``r`` of
+group ``g``, the group sizes known only on the device. The kernels are the
+``gmm`` / ``tgmm`` that ship with jax
+(``jax.experimental.pallas.ops.tpu.megablox``): the row tiles a group
+touches are listed in scalar-prefetched metadata and the grid's length is
+the number of tiles really touched, so the matrix work follows the rows
+inside the groups and not the buffer they lie in. This module adds the
+tiling, the backward pass in float32 (the library's own VJP hands the
+weight gradient back in the operand's dtype, bfloat16 here) and the
+interpreter off the chip.
+
+Chosen by one measured call on the chip (PERF.md section 6, PR 32) over
+``jax.lax.ragged_dot``, which XLA:TPU lowers to a grouped kernel of its
+own with row tiles of 512: the three products of an expert FFN, forward
+and backward, over 8 experts of [2048, 1536] in a buffer of 32,768 rows
+took 8.84 / 9.84 / 19.16 ms with these kernels against 10.11 / 11.34 /
+23.03 ms at 4,096 rows spread evenly, 4,096 unevenly and all 32,768; 0.86
+ms of the difference is ``ragged_dot`` writing zeros into the rows past
+the last group, which these kernels leave unwritten.
+
+Rows past the last group are NOT computed and the result holds whatever
+the buffer held there: callers mask them (``jnp.where``, not a product).
+"""
+
+from __future__ import annotations
+
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.ops import attention_pallas as _ap
+
+# the package's __init__ rebinds the name `gmm` to the function
+_gmm = importlib.import_module(
+    "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+# row tile, contraction tile, column tile: the best of seven measured at
+# [32768, 2048] x [8, 2048, 1536] (the call above); the transposed product
+# of the weight gradient keeps its output tile in VMEM and takes 512 cubed
+_TM, _TK, _TN = 512, 2048, 512
+_T_WGRAD = 512
+
+
+def _row_tile(m):
+    """The largest power of two up to ``_TM`` that divides ``m``: the
+    kernels take whole row tiles only."""
+    t = _TM
+    while m % t:
+        t //= 2
+    return t
+
+
+def _tiling(m, k, n):
+    return _row_tile(m), min(_TK, k), min(_TN, n)
+
+
+def _wgrad_tiling(m, k, n):
+    return min(_row_tile(m), _T_WGRAD), min(_T_WGRAD, k), min(_T_WGRAD, n)
+
+
+def _kernel(fn, *args, **kw):
+    """The library's kernels index with 32-bit integers throughout; under
+    `jax_enable_x64` (the tests' gradient-check mode) their Python
+    constants would trace as 64-bit beside them."""
+    with jax.enable_x64(False):
+        return fn(*args, **kw)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped(x, w, group_sizes, out_dtype, interpret):
+    m, k = x.shape
+    return _kernel(_gmm.gmm, x, w.astype(x.dtype), group_sizes, out_dtype,
+                   _tiling(m, k, w.shape[2]), interpret=interpret)
+
+
+def _grouped_fwd(x, w, group_sizes, out_dtype, interpret):
+    return (_grouped(x, w, group_sizes, out_dtype, interpret),
+            (x, w, group_sizes))
+
+
+def _grouped_bwd(out_dtype, interpret, res, g):
+    x, w, group_sizes = res
+    m, k = x.shape
+    n = w.shape[2]
+    g = g.astype(x.dtype)  # the matrix units round an operand anyway
+    dx = _kernel(_gmm.gmm, g, w.astype(x.dtype), group_sizes, x.dtype,
+                 _tiling(m, n, k), transpose_rhs=True, interpret=interpret)
+    dw = _kernel(_gmm.tgmm, x.swapaxes(0, 1), g, group_sizes, jnp.float32,
+                 _wgrad_tiling(m, k, n), interpret=interpret)
+    return dx, dw.astype(w.dtype), None
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def grouped_matmul(x, w, group_sizes, out_dtype):
+    """``x`` [M, K] times ``w`` [G, K, N] by groups of rows -> [M, N] of
+    ``out_dtype``, accumulated in float32. ``group_sizes`` int32 [G] sums
+    to at most M; group ``g`` is the rows from ``sum(sizes[:g])``. ``x`` is
+    float32 or bfloat16 and ``w`` is read at ``x``'s dtype. Differentiable
+    in ``x`` and ``w`` (the weight gradient is accumulated in float32 over
+    a group's rows, returned in ``w``'s own dtype, and exactly zero for an
+    empty group)."""
+    return _grouped(x, w, group_sizes.astype(jnp.int32), out_dtype,
+                    not _ap.backend_is_tpu())

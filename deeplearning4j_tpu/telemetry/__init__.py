@@ -79,7 +79,7 @@ from deeplearning4j_tpu.telemetry.tracectx import TraceContext
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry", "Tracer",
            "DEFAULT_BUCKETS", "get_registry", "get_tracer", "span",
            "write_jsonl", "enable", "disable", "enabled", "reset",
-           "series_map",
+           "series_map", "note_routing",
            "health", "devices", "flight", "scorepipe", "ScorePipeline",
            "NumericsError", "tracectx", "TraceContext",
            "federate", "timeline", "profiling", "slo", "goodput",
@@ -157,3 +157,57 @@ def train_metrics():
             reg.counter("train_iterations_total",
                         "optimizer iterations completed"),
             reg.gauge("train_score", "last training score (loss)"))
+
+
+def _routing_states(state):
+    """The routed-experts layers' states in a net's state tree (a list a
+    layer, a dict a vertex): the dicts that hold ``moe_load``."""
+    if isinstance(state, dict):
+        if "moe_load" in state:
+            yield state
+        else:
+            for v in state.values():
+                yield from _routing_states(v)
+    elif isinstance(state, (list, tuple)):
+        for v in state:
+            yield from _routing_states(v)
+
+
+def note_routing(state):
+    """The last step's routing counts, from the state the step left on
+    the net (``moe_load``: rows per held expert; ``moe_elsewhere``:
+    assignments routed to experts not held), into the registry: the
+    counters ``moe_rows_here_sampled_total`` and
+    ``moe_assignments_sampled_total`` grow by that ONE step's counts summed
+    over the expert layers (a sample of a round's steps, one a call: their
+    ratio is a share, neither is a total of the run), the gauges
+    ``moe_load_hottest_rows`` and ``moe_load_mean_rows`` hold each layer's
+    hottest and mean held expert, summed over the layers. One small fetch;
+    the fit loop calls it where it already waits for the device
+    (``StepDriver.sync``), and only while the registry records."""
+    reg = get_registry()
+    if not reg.enabled:
+        return
+    found = [(s["moe_load"], s["moe_elsewhere"])
+             for s in _routing_states(state)]
+    if not found:
+        return
+    import jax
+    here = hottest = mean = elsewhere = 0.0
+    for load, away in jax.device_get(found):
+        here += float(load.sum())
+        hottest += float(load.max())
+        mean += float(load.mean())
+        elsewhere += float(away.sum())
+    reg.counter("moe_rows_here_sampled_total",
+                "assignments computed by the experts held here, over the "
+                "sampled steps alone (one a fit.sync)").inc(here)
+    reg.counter("moe_assignments_sampled_total",
+                "assignments routed, here and elsewhere, over the sampled "
+                "steps alone (one a fit.sync)").inc(here + elsewhere)
+    reg.gauge("moe_load_hottest_rows",
+              "rows of each layer's hottest held expert, summed over the "
+              "expert layers, last sampled step").set(hottest)
+    reg.gauge("moe_load_mean_rows",
+              "mean rows a held expert, summed over the expert layers, "
+              "last sampled step").set(mean)

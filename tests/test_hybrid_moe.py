@@ -1,0 +1,338 @@
+"""The hybrid conv/attention mixture-of-experts vocabulary (ISSUE 32) at toy
+size on the CPU: the routed-experts layer against the benchmark's plain
+reference (the shares adding up to the uncut layer, all-here and none-here
+routing, a bias that moves the selection and not the weights), the grouped
+product against `jax.lax.ragged_dot`, the gated short convolution's
+causality, grouped-query attention with QK-norm against repeat-and-attend,
+the defaults leaving the older blocks as they were, serde, and a fit."""
+
+import collections
+import itertools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import lfm2_moe as ref
+from deeplearning4j_tpu import models, telemetry
+from deeplearning4j_tpu.continuous.driver import StepDriver
+from deeplearning4j_tpu.nn import layers as L
+from deeplearning4j_tpu.nn.conf import inputs as I
+from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
+from deeplearning4j_tpu.nn.layers import moe
+from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+from deeplearning4j_tpu.ops.grouped_matmul import grouped_matmul
+
+D, F, E, K, N = 16, 24, 8, 2, 64
+MODEL = {"num_experts_per_tok": K, "routed_scaling_factor": 1.0,
+         "experts_held": (0, E)}
+
+
+@pytest.fixture(scope="module")
+def layer():
+    """An uncut expert layer's float32 weights, a bias and token rows."""
+    k = jax.random.split(jax.random.PRNGKey(3), 6)
+
+    def nrm(key, scale, *shape):
+        return scale * jax.random.normal(key, shape, jnp.float32)
+
+    return {"u": nrm(k[0], 1.0, N, D), "bias": nrm(k[5], 0.1, E),
+            "p": {"w_r": nrm(k[1], 0.3, D, E), "e_w1": nrm(k[2], 0.2, E, D, F),
+                  "e_w3": nrm(k[3], 0.2, E, D, F),
+                  "e_w2": nrm(k[4], 0.2, E, F, D)}}
+
+
+def _share(p, first, end):
+    return {"w_r": p["w_r"], **{n: p[n][first:end]
+                                for n in ("e_w1", "e_w3", "e_w2")}}
+
+
+def _system(u, p, bias, held, top_k=K):
+    return moe.routed_experts(
+        u, p["w_r"], p["e_w1"], p["e_w3"], p["e_w2"], bias, top_k=top_k,
+        held=held, scale=1.0, act=jax.nn.silu)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(layer):
+    u, p, bias = layer["u"], layer["p"], layer["bias"]
+    whole, load, away = ref.experts(u, p, bias, MODEL, "f32")
+    assert float(away[0]) == 0 and float(load.sum()) == N * K
+    parts, rows = [], 0.0
+    for first in range(0, E, 2):
+        held = (first, first + 2)
+        y, here, elsewhere = _system(u, _share(p, *held), bias, held)
+        want, want_here, _ = ref.experts(u, _share(p, *held), bias, MODEL,
+                                         "f32", held=held)
+        np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-6)
+        np.testing.assert_array_equal(here, want_here)
+        assert float(here.sum() + elsewhere[0]) == N * K  # none dropped
+        parts.append(y)
+        rows += float(here.sum())
+    assert rows == N * K
+    np.testing.assert_allclose(sum(parts), whole, rtol=2e-5, atol=2e-6)
+    y, here, _ = _system(u, p, bias, (0, E))
+    np.testing.assert_allclose(y, whole, rtol=2e-5, atol=2e-6)
+    np.testing.assert_array_equal(here, load)
+
+
+@pytest.mark.parametrize("push,rows_here", [(10.0, N * K), (-10.0, 0)],
+                         ids=["all-here", "none-here"])
+def test_every_token_or_none_routed_here_gives_the_references_result(
+        layer, push, rows_here):
+    """A bias that sends every assignment to the held experts, and one
+    that sends none: the buffers are the same, the rows differ."""
+    u, p = layer["u"], layer["p"]
+    held = (2, 4)
+    bias = jnp.zeros((E,)).at[2:4].set(push)
+    share = _share(p, *held)
+
+    def loss(share, f):
+        y, here, away = f(share)
+        return jnp.sum(y * y), (y, here, away)
+
+    (_, (y, here, away)), g = jax.value_and_grad(loss, has_aux=True)(
+        share, lambda s: _system(u, s, bias, held))
+    (_, (want, want_here, want_away)), want_g = jax.value_and_grad(
+        loss, has_aux=True)(
+        share, lambda s: ref.experts(u, s, bias, MODEL, "f32", held=held))
+    assert float(here.sum()) == rows_here == float(want_here.sum())
+    assert float(away[0]) == N * K - rows_here == float(want_away[0])
+    np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-6)
+    for name in share:
+        assert np.all(np.isfinite(g[name]))
+        np.testing.assert_allclose(g[name], want_g[name], rtol=2e-4,
+                                   atol=2e-6)
+    if not rows_here:
+        assert float(jnp.abs(y).max()) == 0.0
+
+
+def test_the_bias_moves_the_selection_and_not_the_weights(layer):
+    u, p = layer["u"], layer["p"]
+    bias = jnp.zeros((E,)).at[5].set(0.5)
+    s = jax.nn.sigmoid(jnp.matmul(u, p["w_r"], precision="highest"))
+    sel0, _ = ref.route(u, p["w_r"], jnp.zeros((E,)), MODEL)
+    sel, w = ref.route(u, p["w_r"], bias, MODEL)
+    assert int((sel == 5).sum()) > int((sel0 == 5).sum())
+    picked = jnp.take_along_axis(s, sel, axis=-1)
+    np.testing.assert_allclose(
+        w, picked / (picked.sum(-1, keepdims=True) + 1e-6), rtol=1e-6)
+    # the system agrees, through the whole layer, and in the router's
+    # gradient (which flows through the weights alone)
+    f = lambda w_r: jnp.sum(_system(u, {**p, "w_r": w_r}, bias, (0, E))[0])
+    f_ref = lambda w_r: jnp.sum(
+        ref.experts(u, {**p, "w_r": w_r}, bias, MODEL, "f32")[0])
+    np.testing.assert_allclose(jax.grad(f)(p["w_r"]),
+                               jax.grad(f_ref)(p["w_r"]), rtol=2e-4,
+                               atol=1e-6)
+
+
+def test_grouped_matmul_follows_the_groups(layer):
+    k = jax.random.split(jax.random.PRNGKey(5), 3)
+    x = jax.random.normal(k[0], (64, D), jnp.float32)
+    w = jax.random.normal(k[1], (4, D, F), jnp.float32)
+    r = jax.random.normal(k[2], (64, F), jnp.float32)
+    sizes = jnp.array([17, 0, 30, 5], jnp.int32)   # 52 of 64 rows
+    valid = (jnp.arange(64) < 52)[:, None]
+
+    def through(f):
+        return lambda x, w: jnp.sum(jnp.where(valid, f(x, w), 0.0) * r)
+
+    mine = through(lambda x, w: grouped_matmul(x, w, sizes, jnp.float32))
+    want = through(lambda x, w: jax.lax.ragged_dot(
+        x, w, sizes, precision="highest"))
+    np.testing.assert_allclose(
+        grouped_matmul(x, w, sizes, jnp.float32)[:52],
+        jax.lax.ragged_dot(x, w, sizes, precision="highest")[:52],
+        rtol=1e-5, atol=1e-5)
+    (dx, dw), (dx_w, dw_w) = (jax.grad(f, argnums=(0, 1))(x, w)
+                              for f in (mine, want))
+    np.testing.assert_allclose(dx[:52], dx_w[:52], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(dw, dw_w, rtol=1e-5, atol=1e-5)
+    assert float(jnp.abs(dw[1]).max()) == 0.0      # the empty group
+
+
+def test_short_conv_is_causal_and_starts_from_zeros():
+    conv = L.ShortConv(n_out=8, kernel=3)
+    p = conv.init(jax.random.PRNGKey(0), I.RecurrentType(8, 12))
+    p["conv_w"] = jax.random.normal(jax.random.PRNGKey(1), (8, 3))
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 12, 8))
+    y, _ = conv.apply(p, {}, x)
+    later = x.at[:, 7:].set(jax.random.normal(jax.random.PRNGKey(3),
+                                              (2, 5, 8)))
+    y2, _ = conv.apply(p, {}, later)
+    np.testing.assert_array_equal(np.asarray(y[:, :7]), np.asarray(y2[:, :7]))
+    assert not np.allclose(y[:, 7:], y2[:, 7:])
+    # the first two positions: zeros before the sequence's start
+    bcx = (x @ p["W_in"]).reshape(2, 12, 3, 8)
+    gate_b, gate_c, xx = bcx[:, :, 0], bcx[:, :, 1], bcx[:, :, 2]
+    z, w = gate_b * xx, p["conv_w"]
+    np.testing.assert_allclose(
+        y[:, 0], (gate_c[:, 0] * (w[:, 2] * z[:, 0])) @ p["W_out"],
+        rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        y[:, 1], (gate_c[:, 1] * (w[:, 1] * z[:, 0] + w[:, 2] * z[:, 1]))
+        @ p["W_out"], rtol=1e-5, atol=1e-6)
+    # and the plain reference's, a sequence at a time
+    want = ref.short_conv(x[0], {"w_in": p["W_in"], "conv_w": w,
+                                 "w_out": p["W_out"]}, "f32")
+    np.testing.assert_allclose(y[0], want, rtol=1e-5, atol=1e-6)
+
+
+def test_grouped_query_attention_with_qk_norm_is_repeat_and_attend():
+    d, h, kv, dh, t = 16, 4, 2, 8, 12
+    mha = L.MultiHeadAttention(n_out=d, n_heads=h, n_kv_heads=kv,
+                               head_dim=dh, causal=True, bias=False,
+                               rope_theta=1e4, qk_norm=True,
+                               qk_norm_eps=1e-5)
+    p = mha.init(jax.random.PRNGKey(0), I.RecurrentType(d, t))
+    assert set(p) == {"Wq", "Wkv", "Wo", "q_gamma", "k_gamma"}
+    assert p["Wq"].shape == (d, h * dh) and p["Wkv"].shape == (d, 2 * kv * dh)
+    p["q_gamma"] = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(1), (dh,))
+    p["k_gamma"] = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(2), (dh,))
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, t, d))
+    y, _ = mha.apply(p, {}, x)
+    model = {"n_head": h, "n_kv_head": kv, "head_dim": dh, "norm_eps": 1e-5,
+             "rope_theta": 1e4}
+    want = ref.attention(x[1], {
+        "w_q": p["Wq"], "w_k": p["Wkv"][:, :kv * dh],
+        "w_v": p["Wkv"][:, kv * dh:], "w_o": p["Wo"],
+        "g_q": p["q_gamma"], "g_k": p["k_gamma"]}, model, "f32")
+    np.testing.assert_allclose(y[1], want, rtol=2e-5, atol=2e-6)
+    with pytest.raises(ValueError, match="no multiple"):
+        L.MultiHeadAttention(n_out=d, n_heads=4, n_kv_heads=3,
+                             bias=False).init(jax.random.PRNGKey(0),
+                                              I.RecurrentType(d, t))
+
+
+def _primitives(jaxpr, into=None):
+    into = collections.Counter() if into is None else into
+    for eqn in jaxpr.eqns:
+        into[eqn.primitive.name] += 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                if hasattr(sub, "jaxpr"):
+                    _primitives(getattr(sub.jaxpr, "jaxpr", sub.jaxpr), into)
+                elif hasattr(sub, "eqns"):
+                    _primitives(sub, into)
+    return into
+
+
+@pytest.mark.parametrize("block,keys,dots", [
+    (L.TransformerBlock(n_out=16, n_heads=2, causal=True),
+     {"ln1", "mha", "ln2", "mlp_W1", "mlp_W2", "mlp_b1", "mlp_b2"}, 6),
+    (L.TransformerBlock(n_out=16, n_heads=2, causal=True, activation="silu",
+                        norm="rms", norm_eps=1e-6, sandwich=True, bias=False,
+                        rope_theta=1e6, head_dim=8, ffn="gated",
+                        ffn_width=24),
+     {"ln1", "ln1_post", "mha", "ln2", "ln2_post", "mlp_Wg", "mlp_Wu",
+      "mlp_Wd"}, 7),
+], ids=["gpt2-block", "ouro-block"])
+def test_the_defaults_leave_the_older_blocks_as_they_were(block, keys, dots):
+    """The parameter trees and the matrix products of the two blocks the
+    benchmark already ran: the new fields at their defaults add no leaf,
+    no state and no product."""
+    it = I.RecurrentType(16, 8)
+    p = block.init(jax.random.PRNGKey(0), it)
+    assert set(p) == keys
+    assert set(p["mha"]) == ({"Wqkv", "Wo", "bqkv", "bo"} if block.bias
+                             else {"Wqkv", "Wo"})
+    assert block.init_state(it) == {}
+    x = jnp.ones((2, 8, 16))
+    jaxpr = jax.make_jaxpr(lambda p, x: block.apply(p, {}, x)[0])(p, x)
+    counts = _primitives(jaxpr.jaxpr)
+    assert counts["dot_general"] == dots
+    assert not {"sort", "top_k", "ragged_dot", "pallas_call",
+                "cumsum"} & set(counts)
+
+
+def _toy_conf(**kw):
+    args = dict(layer_types=("conv", "full_attention", "conv"),
+                num_dense_layers=1, d_model=16, n_heads=4, n_kv_heads=2,
+                head_dim=4, ffn_width=24, expert_width=12, n_experts=8,
+                top_k=2, experts_held=(2, 6), seq_len=16)
+    args.update(kw)
+    return models.hybrid_moe_lm(32, **args)
+
+
+def test_serde_round_trip_of_the_new_fields():
+    conf = _toy_conf()
+    again = MultiLayerConfiguration.from_json(conf.to_json())
+    assert again == conf
+    block = again.layers[2]
+    assert (block.mixer, block.ffn, block.experts_held, block.top_k,
+            block.n_kv_heads, block.qk_norm) == (
+        "attention", "moe", (2, 6), 2, 2, True)
+    assert again.layers[1].mixer == "short_conv"
+    assert again.layers[-1].has_bias is False
+    with pytest.raises(ValueError, match="mixer is"):
+        L.TransformerBlock(n_out=16, mixer="ssm").init(
+            jax.random.PRNGKey(0), I.RecurrentType(16, 8))
+    with pytest.raises(ValueError, match="experts_held"):
+        L.TransformerBlock(n_out=16, bias=False, ffn="moe", n_experts=4,
+                           experts_held=(2, 6)).init(
+            jax.random.PRNGKey(0), I.RecurrentType(16, 8))
+
+
+def test_hybrid_moe_lm_trains_through_fit_and_counts_its_routing():
+    net = MultiLayerNetwork(_toy_conf())
+    net.init()
+    assert set(net.params[3]) == {"ln1", "conv", "ln2", "moe_router",
+                                  "moe_Wg", "moe_Wu", "moe_Wd"}
+    assert net.params[3]["moe_Wg"].shape == (4, 16, 12)
+    assert set(net.params[-1]) == {"W"}
+    assert set(net.state[2]) == {"expert_bias", "moe_load", "moe_elsewhere"}
+    x = jax.random.randint(jax.random.PRNGKey(0), (2, 16), 0, 32)
+    y = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 32)
+    bias = np.asarray(0.3 * jax.random.normal(jax.random.PRNGKey(2), (8,)))
+    net.state[2]["expert_bias"] = jnp.asarray(bias)  # the step donates it
+    first = net.score(x, y)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        net.fit(x, y, epochs=6)
+        # the counters fill where the loop's round boundary already waits
+        driver = StepDriver(net, lambda: itertools.cycle([(x, y, None)]))
+        driver.run_round(2)
+        driver.sync()
+        driver.close_source()
+        snap = telemetry.get_registry().snapshot()
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    assert float(net.score_value) < first
+    for s in net.state[2:4]:
+        assert float(s["moe_load"].sum() + s["moe_elsewhere"][0]) == 2 * 16 * 2
+    np.testing.assert_array_equal(net.state[2]["expert_bias"], bias)
+    total = lambda name: sum(s["value"] for s in snap[name]["series"])
+    assert total("moe_assignments_sampled_total") > 0
+    assert 0 < total("moe_rows_here_sampled_total") < total("moe_assignments_sampled_total")
+    assert total("moe_load_hottest_rows") >= total("moe_load_mean_rows") > 0
+    out = net.output(x)
+    assert out.shape == (2, 16, 32)
+    np.testing.assert_allclose(np.asarray(out).sum(-1), 1.0, rtol=1e-5)
+
+
+def test_the_hybrid_step_carries_its_scopes_forward_and_backward():
+    """`short_conv`, `moe`, `moe_route` and `moe_experts` as whole
+    components of the lowered step's paths, outside and inside JAX's
+    `transpose(`, the routed ones inside `moe`."""
+    import re
+    net = MultiLayerNetwork(_toy_conf())
+    net.init()
+    x = jnp.zeros((2, 16), jnp.int32)
+    lowered = net.make_train_step(donate=False).lower(
+        net.params, net.state, net.opt_state, x, x, 0,
+        jax.random.PRNGKey(0), None)
+    paths = set(re.findall(r'loc\("([^"]+)"',
+                           lowered.as_text(debug_info=True)))
+
+    def has(scope, backward):
+        rx = re.compile(r"(?:^|[/(])" + scope + r"(?:$|[/)])")
+        return any(rx.search(p) and ("transpose(" in p) == backward
+                   for p in paths)
+
+    for scope in ("short_conv", "moe", "moe_route", "moe_experts",
+                  "moe/moe_route", "moe/moe_experts", "attn/short_conv",
+                  "mlp/moe", r"L02\.TransformerBlock"):
+        assert has(scope, False) and has(scope, True), scope
