@@ -500,11 +500,14 @@ def _hybrid_step_case(name, *, t, vocab, tol):
     experts, a short convolution with 8 more; routing fixed by the expert
     bias, so that no near-tie decides differently on the two branches): the
     compiled train step's
-    kernel count (the flash forward and backward, and nine grouped
-    products an expert layer: gate, up and down, each forward, for the
-    input gradient and for the weight gradient), then logits and every
+    kernel count (the flash forward and backward, and thirteen kernels an
+    expert layer: nine grouped products, gate, up and down, each forward,
+    for the input gradient and for the weight gradient, and the row
+    movement's two kernels twice each), then logits and every
     gradient through the dispatch against the naive branch (attention in
-    ``jax.numpy``, the grouped products as ``jax.lax.ragged_dot``)."""
+    ``jax.numpy``, the grouped products as ``jax.lax.ragged_dot``, the
+    routed layer's row movement as plain gathers of every slot under
+    autodiff)."""
     import jax
     import jax.numpy as jnp
 
@@ -534,7 +537,7 @@ def _hybrid_step_case(name, *, t, vocab, tol):
     step = net.make_train_step(donate=False)
     text = step.lower(net.params, net.state, net.opt_state, x, labels, 0,
                       jax.random.PRNGKey(0), None).compile().as_text()
-    n_calls, want = text.count("tpu_custom_call"), 2 + 2 * 9
+    n_calls, want = text.count("tpu_custom_call"), 2 + 2 * 13
     _expect(n_calls == want,
             f"{name}: compiled train step holds {n_calls} "
             f"tpu_custom_call(s), expected {want}")
@@ -543,15 +546,26 @@ def _hybrid_step_case(name, *, t, vocab, tol):
     def logits(params):
         return net.apply_fn(params, state, x, train=True, logits=True)[0]
 
+    def inside(rows, r):
+        return jnp.where((jnp.arange(rows.shape[0]) < r)[:, None], rows, 0)
+
+    def gather_back(ys, w, order, inv, r):
+        w = jnp.where((inv < r).reshape(w.shape), w, 0)
+        return jnp.sum(inside(ys, r)[inv].reshape(*w.shape, -1)
+                       * w[..., None].astype(ys.dtype), axis=1)
+
     def naive(params):
         cd, _ = _dtypes.compute_dtypes_for(jnp.float32)
-        saved = _moe.grouped_matmul
+        saved = _moe.grouped_matmul, _moe._dispatch, _moe._combine
         _moe.grouped_matmul = lambda a, w, sizes, out: jax.lax.ragged_dot(
             a.astype(cd), w.astype(cd), sizes, preferred_element_type=out)
+        _moe._dispatch = lambda k, dtype, x, tok, r: inside(
+            x[tok], r).astype(dtype)
+        _moe._combine = gather_back
         try:
             return logits(params)
         finally:
-            _moe.grouped_matmul = saved
+            _moe.grouped_matmul, _moe._dispatch, _moe._combine = saved
 
     out = _compare(name, logits, naive, (net.params,), tol)
     return {**out, "tpu_custom_calls": n_calls}

@@ -1,30 +1,28 @@
-"""Routed experts: the dropless top-k layer that is ``TransformerBlock``'s
-``ffn="moe"`` (``routed_experts``, below), and the older Switch-style
-block monolith (``MoETransformerBlock``, top-1 with a capacity drop and
-an auxiliary loss, partitioned by GSPMD over a stacked expert axis).
+"""Routed experts, two layers that share no logic.
 
-Mixture-of-Experts transformer block with expert parallelism.
+``routed_experts`` is ``TransformerBlock``'s ``ffn="moe"``: one chip's
+share of a dropless top-k layer. A float32 sigmoid router scores all the
+experts, an expert bias moves the selection only, the ``N k`` assignments
+are sorted by held expert (those routed elsewhere behind a sentinel) and
+the held experts run as grouped products over the counts
+(ops/grouped_matmul.py). Every shape is static and no token is dropped;
+the matrix work and, from PR 33, the row movement around it (tokens into
+sorted order, results back, and both backward passes, all written by
+hand) follow the rows really routed here: two kernels (ops/moe_rows.py)
+whose grids skip the row tiles past the count read from the group sizes.
 
-Reference analog: none — DL4J has no MoE (nor attention); net-new for the
-TPU scale goals, completing the dp/tp/sp/pp/ep parallelism set (driver
-contract: __graft_entry__.dryrun_multichip exercises every axis).
-
-Design (Switch-Transformer style, TPU-first):
-* Top-1 router with a capacity limit: tokens route to their argmax expert,
-  each expert processes at most C = ceil(tokens/E * capacity_factor);
-  overflow tokens pass through the residual unchanged (standard Switch
-  semantics — keeps every shape static for XLA).
-* Dispatch/combine are dense einsums against a [N, E, C] one-hot dispatch
-  tensor — gather-free, MXU-friendly, and differentiable through the
-  router probabilities (combine carries the router prob).
-* Expert weights are STACKED with a leading expert axis. Under a mesh,
-  sharding that axis over ``model`` (see parallel/data_parallel.py's
-  param-spec rule) makes GSPMD partition the per-expert einsums and insert
-  the all-to-alls — expert parallelism without manual collectives.
-* Load-balancing auxiliary loss (Switch eq. 4): E * sum_e f_e * p_e, where
-  f_e is the fraction of tokens dispatched to expert e and p_e the mean
-  router probability — exposed via ``aux_loss`` in the layer state so the
-  container can add it to the objective.
+``MoETransformerBlock`` is the older Switch-style block monolith, kept
+for its users (ROADMAP D6(a)). Reference analog: none, DL4J has no MoE;
+it completes the dp/tp/sp/pp/ep parallelism set
+(``__graft_entry__.dryrun_multichip`` exercises every axis). Top-1
+router with a capacity limit ``C = ceil(tokens / E * capacity_factor)``,
+overflow tokens passing through the residual unchanged; dispatch and
+combine are dense einsums against a ``[N, E, C]`` one-hot tensor
+(gather-free, differentiable through the router probability); expert
+weights are stacked on a leading axis that GSPMD partitions over
+``model`` (parallel/data_parallel.py's param-spec rule), inserting the
+all-to-alls; Switch's load-balancing loss ``E * sum_e f_e * p_e`` goes
+through ``aux_loss`` in the layer state to the container's objective.
 """
 
 from __future__ import annotations
@@ -42,64 +40,67 @@ from deeplearning4j_tpu.nn.layers.attention import (LayerNormalization,
                                                     MultiHeadAttention)
 from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.ops.grouped_matmul import grouped_matmul
+from deeplearning4j_tpu.ops.moe_rows import rows_back, rows_into_order
 from deeplearning4j_tpu.utils import dtypes as _dtypes
 from deeplearning4j_tpu.utils.serde import register_config
 
 ROUTER_EPS = 1e-6  # added to the selected scores' sum before the division
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _dispatch(k, x, order, inv, valid):
-    """Token rows ``x`` [N, d] laid out by sorted assignment, [N k, d]:
-    row ``p`` is the token of assignment ``order[p]`` (assignment ``a``
-    belongs to token ``a // k``). ``inv`` is ``order``'s inverse, so the
-    backward pass is a gather too: a token's gradient is the sum of its
-    ``k`` rows'. ``valid`` marks the rows inside a group here."""
-    return _dispatch_fwd(k, x, order, inv, valid)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _dispatch(k, dtype, x, tok, r):
+    """Token rows ``x`` [N, d] laid out by sorted assignment, [N k, d] in
+    ``dtype``: slot ``p < r`` holds the row of token ``tok[p]``, rounded
+    as it is stored; the slots from the tile after ``r`` on are not
+    written. Backward: a token's gradient is the float32 sum of its slots'
+    below ``r``, rounded to ``dtype`` as the forward's rows were."""
+    return _dispatch_fwd(k, dtype, x, tok, r)[0]
 
 
-def _dispatch_fwd(k, x, order, inv, valid):
-    return x[order // k], (inv, valid)
+def _dispatch_fwd(k, dtype, x, tok, r):
+    return rows_into_order(x, tok, r, dtype)[0], (tok, r)
 
 
-def _dispatch_bwd(k, res, dxs):
-    inv, valid = res
-    n = inv.shape[0] // k
-    dxs = jnp.where(valid[:, None], dxs, 0)
+def _dispatch_bwd(k, dtype, res, dxs):
+    tok, r = res
     _, ad = _dtypes.compute_dtypes_for(dxs.dtype)
-    dx = jnp.sum(dxs[inv].astype(ad).reshape(n, k, -1), axis=1)
-    return dx.astype(dxs.dtype), None, None, None
+    dx, _ = rows_back(dxs, tok, r, tok.shape[0] // k,
+                      jnp.ones(tok.shape, ad), ad)
+    return dx.astype(dtype).astype(ad), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(ys, w, order, inv, valid):
-    """``y[t] = sum_j w[t, j] ys[inv[t k + j]]``: the sorted rows' results
-    [N k, d] back at their tokens, weighted. ``w`` [N, k] is zero for an
-    assignment computed elsewhere; rows of ``ys`` past the groups are
-    masked before they are read."""
-    return _combine_fwd(ys, w, order, inv, valid)[0]
+def _combine(ys, w, order, inv, r):
+    """``y[t] = sum_j w[t, j] ys[inv[t k + j]]`` over the assignments held
+    here (``inv[a] < r``): the sorted rows' results [N k, d] back at their
+    tokens, weighted, summed in float32. ``w`` [N, k] is read in sorted
+    order and only below ``r``, so an assignment computed elsewhere adds
+    nothing and gets a zero gradient."""
+    return _combine_fwd(ys, w, order, inv, r)[0]
 
 
-def _combine_fwd(ys, w, order, inv, valid):
-    cd, _ = _dtypes.compute_dtypes_for(ys.dtype)
-    ys = jnp.where(valid[:, None], ys, 0)
+def _combine_fwd(ys, w, order, inv, r):
+    cd, ad = _dtypes.compute_dtypes_for(ys.dtype)
     n, k = w.shape
-    y = jnp.sum(ys[inv].reshape(n, k, -1) * w[..., None].astype(ys.dtype),
-                axis=1)
-    return y, (ys.astype(cd), w, order, inv)
+    tok = order // k
+    w_sorted = w.reshape(-1)[order]
+    y, ys_kept = rows_back(ys, tok, r, n, w_sorted, ad, keep=cd)
+    return y.astype(ys.dtype), (ys_kept, w_sorted, tok, inv, r)
 
 
 def _combine_bwd(res, dy):
-    ys, w, order, inv = res
-    n, k = w.shape
-    # a row past the groups carries weight zero: its gradient is zero
-    dys = dy[order // k] * w.reshape(-1)[order][:, None].astype(dy.dtype)
-    dw = jnp.sum(ys[inv].reshape(n, k, -1).astype(dy.dtype)
-                 * dy[:, None, :], axis=-1)
-    return dys, dw.astype(w.dtype), None, None, None
+    ys, w_sorted, tok, inv, r = res
+    # the rows this brings into sorted order serve both gradients: the
+    # weights' is taken there and goes back as N k scalars. The rows'
+    # own leaves in the dtype the grouped product's backward rounds its
+    # operand to anyway: the same bits, and no float32 buffer between
+    dys, dw_sorted = rows_into_order(dy, tok, r, ys.dtype, scale=w_sorted,
+                                     other=ys)
+    dw = jnp.where(inv < r, dw_sorted[inv], 0).reshape(dy.shape[0], -1)
+    return dys, dw.astype(w_sorted.dtype), None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -121,14 +122,25 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
 
     No token is dropped and every shape is static: the ``N k``
     assignments are sorted by held expert, those routed elsewhere behind
-    a sentinel; the counts per held expert are the group sizes of the
-    grouped products, whose work follows the rows really routed here
-    (between 0 and ``N k``) while the buffers are sized for ``N k``.
+    a sentinel, and the counts per held expert are the group sizes of the
+    grouped products. The sorted buffers are sized for ``N k`` rows;
+    ``r = sum(sizes)`` of them (between 0 and ``N k``) lie inside a group,
+    and that is how many the layer moves and multiplies: the grouped
+    products touch the row tiles of the groups, and so do the two kernels
+    of the row movement under the scope ``moe_permute`` (ops/moe_rows.py:
+    ``moe_rows_fwd`` brings token rows into sorted order and, in the
+    backward pass, the result's gradient, with the weights' gradient
+    taken from the same rows; ``moe_rows_back`` adds the weighted results
+    back at their tokens and, in the backward pass, the sorted rows'
+    gradient). Slots from the tile after ``r`` on are left unwritten in
+    every sorted buffer and nothing reads them. What still walks all
+    ``N k`` slots is small: the two sorts, the weights in sorted order and
+    their gradient back (scalars) and, under ``moe_experts``, the
+    activation's passes.
 
     Returns ``(y [N, d], load [n_held], elsewhere [1])``: the counts of
     assignments per held expert and of those routed to experts not held.
     """
-    n, _ = x.shape
     first, end = held
     n_held = end - first
     cd, ad = _dtypes.compute_dtypes_for(x.dtype)
@@ -146,16 +158,16 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
         inv = jnp.argsort(order).astype(jnp.int32)
         counts = jnp.bincount(local, length=n_held + 1).astype(jnp.int32)
         sizes = counts[:n_held]
-        valid = jnp.arange(n * top_k, dtype=jnp.int32) < jnp.sum(sizes)
-        xs = _dispatch(top_k, x.astype(cd), order, inv, valid)
-        w_here = jnp.where(here.reshape(n, top_k), w, 0.0)
+        r = jnp.sum(sizes)
+        with jax.named_scope("moe_permute"):
+            xs = _dispatch(top_k, cd, x.astype(ad), order // top_k, r)
     with jax.named_scope("moe_experts"):
         g = grouped_matmul(xs, w_gate, sizes, cd)
         u = grouped_matmul(xs, w_up, sizes, cd)
         h = (act(g.astype(ad)) * u.astype(ad)).astype(cd)
         ys = grouped_matmul(h, w_down, sizes, ad)
-    with jax.named_scope("moe_route"):
-        y = _combine(ys, w_here, order, inv, valid)
+    with jax.named_scope("moe_route"), jax.named_scope("moe_permute"):
+        y = _combine(ys, w, order, inv, r)
     return y.astype(x.dtype), sizes, counts[n_held:]
 
 

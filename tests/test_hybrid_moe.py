@@ -22,6 +22,7 @@ from deeplearning4j_tpu.nn.conf import inputs as I
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers import moe
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+from deeplearning4j_tpu.ops import moe_rows
 from deeplearning4j_tpu.ops.grouped_matmul import grouped_matmul
 
 D, F, E, K, N = 16, 24, 8, 2, 64
@@ -150,6 +151,136 @@ def test_grouped_matmul_follows_the_groups(layer):
     np.testing.assert_allclose(dx[:52], dx_w[:52], rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(dw, dw_w, rtol=1e-5, atol=1e-5)
     assert float(jnp.abs(dw[1]).max()) == 0.0      # the empty group
+
+
+def _sorted_case(sizes, n, k):
+    """``n k`` assignments of which ``sum(sizes)`` fall to the held experts
+    in those counts (the rest behind the sentinel), as the layer sorts
+    them: order, its inverse, the rows here and who is here."""
+    m, n_held = n * k, len(sizes)
+    local = np.full((m,), n_held, np.int32)
+    picks = np.random.RandomState(7).permutation(m)[:sum(sizes)]
+    local[picks] = np.repeat(np.arange(n_held), sizes)
+    local = jnp.asarray(local)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    return order, inv, jnp.int32(sum(sizes)), (local < n_held).reshape(n, k)
+
+
+# 96 slots in tiles of 32: a boundary between groups inside a tile, an
+# empty group, a lone row, every row
+@pytest.mark.parametrize("sizes,dtype", [
+    ((0, 0, 0), jnp.float32), ((0, 1, 0), jnp.float32),
+    ((17, 0, 30), jnp.float32), ((25, 30, 10), jnp.float32),
+    ((40, 16, 40), jnp.float32), ((17, 0, 30), jnp.float64)],
+    ids=["none", "one-row", "uneven-with-an-empty-group",
+         "boundary-inside-a-tile", "all", "float64"])
+def test_the_row_movement_reads_and_moves_only_the_rows_inside_the_groups(
+        monkeypatch, sizes, dtype):
+    """Dispatch, combine and both backward passes against a plain
+    take-and-sum, with NaN in every row of the sorted buffers from the
+    count on: nothing there is read or reaches ``y``, ``dx`` or ``dw``."""
+    monkeypatch.setattr(moe_rows, "_TILE", 32)
+    n, k, d = 48, 2, 24
+    order, inv, r, here = _sorted_case(sizes, n, k)
+    rows = int(r)
+    tok = order // k
+    inside = (jnp.arange(n * k) < r)[:, None]
+    key = jax.random.split(jax.random.PRNGKey(11), 5)
+    x, dy = (jax.random.normal(kk, (n, d), dtype) for kk in key[:2])
+    ys, dxs = (jax.random.normal(kk, (n * k, d), dtype) for kk in key[2:4])
+    w = jax.random.uniform(key[4], (n, k), dtype, 0.1, 1.0)
+    planted = lambda a: jnp.where(inside, a, jnp.nan)
+
+    def take_and_sum(rows_, w_):
+        picked = jnp.where(inside, rows_, 0)[inv].reshape(n, k, d)
+        return jnp.sum(picked * jnp.where(here, w_, 0)[..., None], axis=1)
+
+    xs, back = jax.vjp(lambda x: moe._dispatch(k, dtype, x, tok, r), x)
+    np.testing.assert_array_equal(xs[:rows], x[tok][:rows])
+    dx, = back(planted(dxs))
+    want_dx, = jax.vjp(lambda x: x[tok], x)[1](jnp.where(inside, dxs, 0))
+    np.testing.assert_allclose(dx, want_dx, rtol=1e-6, atol=1e-6)
+
+    y, back = jax.vjp(lambda ys, w: moe._combine(ys, w, order, inv, r),
+                      planted(ys), w)
+    want_y, want_back = jax.vjp(take_and_sum, ys, w)
+    np.testing.assert_allclose(y, want_y, rtol=1e-6, atol=1e-6)
+    dys, dw = back(dy)
+    want_dys, want_dw = want_back(dy)
+    np.testing.assert_allclose(dys[:rows], want_dys[:rows], rtol=1e-6,
+                               atol=1e-6)
+    np.testing.assert_allclose(dw, want_dw, rtol=1e-5, atol=1e-6)
+    assert float(jnp.abs(jnp.where(here, 0, dw)).max()) == 0.0
+    for a in (y, dx, dw):
+        assert a.dtype == dtype and np.all(np.isfinite(a))
+
+
+def test_the_row_kernels_walk_column_blocks_when_the_rows_do_not_fit(
+        monkeypatch):
+    """An [N, d] array too large to keep resident is taken 128 columns at
+    a time (the benchmark's [8192, 2048] float32 is one block): the same
+    results, the weights' products summed over the blocks."""
+    n, k, d = 32, 2, 384
+    assert moe_rows._columns(8192, 2048, 4) == 2048
+    assert moe_rows._columns(16384, 2048, 4) == 1024
+    monkeypatch.setattr(moe_rows, "_RESIDENT", n * 128 * 4)
+    assert moe_rows._columns(n, d, 4) == 128
+    order, inv, r, _ = _sorted_case((9, 0, 20), n, k)
+    tok, rows = order // k, int(r)
+    key = jax.random.split(jax.random.PRNGKey(2), 4)
+    x = jax.random.normal(key[0], (n, d), jnp.float32)
+    ys, other = (jax.random.normal(kk, (n * k, d), jnp.float32)
+                 for kk in key[1:3])
+    w = jax.random.uniform(key[3], (n * k,), jnp.float32, 0.1, 1.0)
+    out, dots = moe_rows.rows_into_order(x, tok, r, jnp.float32, scale=w,
+                                         other=other)
+    np.testing.assert_allclose(out[:rows], (x[tok] * w[:, None])[:rows],
+                               rtol=1e-6)
+    np.testing.assert_allclose(
+        dots[:rows], jnp.sum(other * x[tok], axis=-1)[:rows], rtol=1e-5,
+        atol=1e-5)
+    acc, kept = moe_rows.rows_back(ys, tok, r, n, w, jnp.float32,
+                                   keep=jnp.float32)
+    inside = (jnp.arange(n * k) < r)[:, None]
+    np.testing.assert_allclose(
+        acc, jnp.zeros((n, d)).at[tok].add(
+            jnp.where(inside, ys * w[:, None], 0)), rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(kept[:rows], ys[:rows])
+
+
+def _count(jaxpr, found):
+    """Equations of a jaxpr, and of the jaxprs inside them, for which
+    ``found`` holds (a kernel's own body is not entered)."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += bool(found(eqn))
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                total += _count(sub, found)
+    return total
+
+
+def test_no_gather_of_every_slot_is_left_beside_the_kernels(layer):
+    """The guard of ISSUE 33's mechanism: forward and backward of the
+    routed layer hold no gather or scatter whose rows are the sorted
+    buffer's (``N k`` rows of the model's width; the parent held five
+    such gathers), and the four movements are the two kernels, twice
+    each, whose grids skip the tiles past the rows here."""
+    u, p, bias = layer["u"], layer["p"], layer["bias"]
+    held = (2, 6)
+
+    def loss(u, share):
+        return jnp.sum(_system(u, share, bias, held)[0] ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(
+        u, _share(p, *held)).jaxpr
+    whole = lambda e: e.primitive.name in ("gather", "scatter-add") and any(
+        v.aval.shape == (N * K, D) for v in (*e.invars, *e.outvars))
+    assert _count(jaxpr, whole) == 0
+    for name in ("moe_rows_fwd", "moe_rows_back"):
+        assert _count(jaxpr, lambda e: e.primitive.name == "pallas_call"
+                      and e.params["name"] == name) == 2
 
 
 def test_short_conv_is_causal_and_starts_from_zeros():
