@@ -493,6 +493,45 @@ def _looped_block_case(name, *, b, t, width, h, d, ffn, interpret, tol):
     return _compare(name, apply, apply, (params, x), tol)
 
 
+def _rows_inside(rows, r):
+    """A sorted buffer's rows below ``r``, zeros past them."""
+    import jax.numpy as jnp
+    return jnp.where((jnp.arange(rows.shape[0]) < r)[:, None], rows, 0)
+
+
+def _gather_back(ys, w, order, inv, r):
+    """``moe._combine`` as plain gathers of every slot under autodiff."""
+    import jax.numpy as jnp
+    w = jnp.where((inv < r).reshape(w.shape), w, 0)
+    return jnp.sum(_rows_inside(ys, r)[inv].reshape(*w.shape, -1)
+                   * w[..., None].astype(ys.dtype), axis=1)
+
+
+@contextlib.contextmanager
+def _naive_routed_layers():
+    """The routed layer's naive branch for the length of a trace: the
+    grouped products as ``jax.lax.ragged_dot``, the row movement as plain
+    gathers of every slot under autodiff."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.layers import moe as _moe
+    from deeplearning4j_tpu.utils import dtypes as _dtypes
+
+    cd, _ = _dtypes.compute_dtypes_for(jnp.float32)
+    saved = _moe.grouped_matmul, _moe._dispatch, _moe._combine
+    _moe.grouped_matmul = (
+        lambda a, w, sizes, out, rows=None: jax.lax.ragged_dot(
+            a.astype(cd), w.astype(cd), sizes, preferred_element_type=out))
+    _moe._dispatch = lambda k, dtype, x, tok, r: _rows_inside(
+        x[tok], r).astype(dtype)
+    _moe._combine = _gather_back
+    try:
+        yield
+    finally:
+        _moe.grouped_matmul, _moe._dispatch, _moe._combine = saved
+
+
 def _hybrid_step_case(name, *, t, vocab, tol):
     """The hybrid conv/attention mixture-of-experts step at a small depth
     and the lfm2-train-t8192 cell's widths (a short convolution with the
@@ -512,9 +551,7 @@ def _hybrid_step_case(name, *, t, vocab, tol):
     import jax.numpy as jnp
 
     from deeplearning4j_tpu import models
-    from deeplearning4j_tpu.nn.layers import moe as _moe
     from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
-    from deeplearning4j_tpu.utils import dtypes as _dtypes
 
     net = MultiLayerNetwork(models.hybrid_moe_lm(
         vocab, layer_types=("conv", "full_attention", "conv"),
@@ -546,26 +583,74 @@ def _hybrid_step_case(name, *, t, vocab, tol):
     def logits(params):
         return net.apply_fn(params, state, x, train=True, logits=True)[0]
 
-    def inside(rows, r):
-        return jnp.where((jnp.arange(rows.shape[0]) < r)[:, None], rows, 0)
+    def naive(params):
+        with _naive_routed_layers():
+            return logits(params)
 
-    def gather_back(ys, w, order, inv, r):
-        w = jnp.where((inv < r).reshape(w.shape), w, 0)
-        return jnp.sum(inside(ys, r)[inv].reshape(*w.shape, -1)
-                       * w[..., None].astype(ys.dtype), axis=1)
+    out = _compare(name, logits, naive, (net.params,), tol)
+    return {**out, "tpu_custom_calls": n_calls}
+
+
+def _gated_delta_step_case(name, *, t, vocab, tol):
+    """The hybrid linear/softmax-attention mixture-of-experts step at three
+    layers and the qwen3next-train-t4096 cell's widths (gated delta rule,
+    gated attention at head width 256, gated delta rule; every layer the
+    softmax top-10 mixture over 512 experts with 16 held and its gated
+    shared expert; router weights zero, so that every probability is
+    1/512, the ten lowest-numbered experts are chosen on both branches and
+    no near-tie decides differently): the compiled train step's kernel
+    count (the flash forward and the split backward's two kernels, and
+    thirteen kernels an expert layer as ``_hybrid_step_case`` counts
+    them), then logits and every gradient through the dispatch against
+    the naive branch (the recurrence token by token as the benchmark's
+    plain reference runs it, attention in ``jax.numpy``, the grouped
+    products as ``jax.lax.ragged_dot``, the row movement as plain
+    gathers)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference import qwen3_next as _plain
+    from deeplearning4j_tpu import models
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+    from deeplearning4j_tpu.ops import gated_delta as _gd
+
+    net = MultiLayerNetwork(models.gated_delta_moe_lm(
+        vocab, n_layers=3, full_attention_interval=2, experts_held=(0, 16),
+        seq_len=t))
+    net.init()
+    for i in (1, 2, 3):
+        net.params[i]["moe_router"] = jnp.zeros_like(
+            net.params[i]["moe_router"])
+    x = jnp.asarray(np.random.RandomState(0).randint(0, vocab, (1, t)),
+                    jnp.int32)
+    labels = jnp.roll(x, -1, axis=1)
+    step = net.make_train_step(donate=False)
+    text = step.lower(net.params, net.state, net.opt_state, x, labels, 0,
+                      jax.random.PRNGKey(0), None).compile().as_text()
+    n_calls, want = text.count("tpu_custom_call"), 3 + 3 * 13
+    _expect(n_calls == want,
+            f"{name}: compiled train step holds {n_calls} "
+            f"tpu_custom_call(s), expected {want}")
+    for kernel in ("flash_attn_fwd", "flash_attn_bwd_dkv",
+                   "flash_attn_bwd_dq"):
+        _expect(kernel in text, f"{name}: no {kernel} in the compiled step")
+    state = net.state
+
+    def logits(params):
+        return net.apply_fn(params, state, x, train=True, logits=True)[0]
+
+    def token_by_token(q, k, v, g, beta):
+        r = v.shape[2] // q.shape[2]
+        return jax.vmap(_plain.delta_rule)(
+            jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2), v, g, beta)
 
     def naive(params):
-        cd, _ = _dtypes.compute_dtypes_for(jnp.float32)
-        saved = _moe.grouped_matmul, _moe._dispatch, _moe._combine
-        _moe.grouped_matmul = lambda a, w, sizes, out: jax.lax.ragged_dot(
-            a.astype(cd), w.astype(cd), sizes, preferred_element_type=out)
-        _moe._dispatch = lambda k, dtype, x, tok, r: inside(
-            x[tok], r).astype(dtype)
-        _moe._combine = gather_back
+        saved, _gd.gated_delta_rule = _gd.gated_delta_rule, token_by_token
         try:
-            return logits(params)
+            with _naive_routed_layers():
+                return logits(params)
         finally:
-            _moe.grouped_matmul, _moe._dispatch, _moe._combine = saved
+            _gd.gated_delta_rule = saved
 
     out = _compare(name, logits, naive, (net.params,), tol)
     return {**out, "tpu_custom_calls": n_calls}
@@ -630,6 +715,8 @@ def kernels_phase(*, interpret, tol):
             ffn=5632, interpret=False, tol=tol))
         results.append(_hybrid_step_case(
             "hybrid_moe_lm_t2048_3layers", t=2048, vocab=1024, tol=tol))
+        results.append(_gated_delta_step_case(
+            "gated_delta_moe_lm_t2048_3layers", t=2048, vocab=1024, tol=tol))
         results += [   # the two train cells' calls
             _flash_backward_time("flash_bwd_t1024_h16_d64_f32", b=4, t=1024,
                                  h=16, d=64, interpret=False),

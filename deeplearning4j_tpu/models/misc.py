@@ -164,6 +164,47 @@ def looped_lm(vocab_size, n_layers=4, d_model=2048, n_heads=16, head_dim=None,
     )
 
 
+#: `layer_types` entry -> `TransformerBlock.mixer`
+_MIXERS = {"conv": "short_conv", "full_attention": "attention",
+           "linear_attention": "gated_delta"}
+
+
+def _hybrid_decoder(vocab_size, layer_types, num_dense_layers, d_model,
+                    seq_len, block, dense, moe, final_norm, updater, seed):
+    """The one loop behind the hybrid decoders: a token embedding, a
+    pre-norm ``TransformerBlock`` a layer whose mixer is what
+    ``layer_types[i]`` names (``"conv"``: the gated short convolution,
+    ``"full_attention"``: softmax attention, ``"linear_attention"``: the
+    gated delta rule) and whose FFN takes the fields ``dense`` for the
+    first ``num_dense_layers`` layers and ``moe`` for the rest, on top of
+    ``block``'s; ``final_norm`` and an untied softmax head under
+    ``sparse_mcxent``."""
+    from deeplearning4j_tpu.nn.initializers import Distribution
+    init = Distribution(kind="normal", std=0.02)
+    blocks = []
+    for i, kind in enumerate(layer_types):
+        if kind not in _MIXERS:
+            raise ValueError(
+                f"layer_types[{i}] is one of {sorted(_MIXERS)} (the gated "
+                "short convolution, softmax attention, the gated delta "
+                f"rule), got {kind!r}")
+        blocks.append(L.TransformerBlock(
+            n_out=d_model, causal=True, activation="silu", norm="rms",
+            bias=False, mixer=_MIXERS[kind], weight_init=init,
+            **{**block, **(dense if i < num_dense_layers else moe)}))
+    return NeuralNetConfig(
+        seed=seed,
+        updater=updater or U.Adam(learning_rate=3e-4)).list(
+        L.EmbeddingSequenceLayer(n_in=vocab_size, n_out=d_model,
+                                 weight_init=init),
+        *blocks,
+        final_norm,
+        L.RnnOutputLayer(n_out=vocab_size, loss="sparse_mcxent",
+                         has_bias=False, weight_init=init),
+        input_type=I.RecurrentType(1, seq_len),
+    )
+
+
 def hybrid_moe_lm(vocab_size, layer_types=("conv", "full_attention"),
                   num_dense_layers=1, d_model=2048, n_heads=32, n_kv_heads=8,
                   head_dim=None, ffn_width=11776, expert_width=1536,
@@ -174,43 +215,69 @@ def hybrid_moe_lm(vocab_size, layer_types=("conv", "full_attention"),
     ``lfm2_moe``; net-new): a token embedding, one pre-norm block a layer
     whose mixer is a gated short convolution (``"conv"``) or grouped-query
     attention with QK-norm and rotary positions (``"full_attention"``) as
-    ``layer_types`` says, and whose FFN is a dense gated SiLU FFN for the
-    first ``num_dense_layers`` layers and ``n_experts`` routed experts
-    (top-``top_k``, sigmoid scores, an expert bias that moves the selection
-    only, weights renormalised over the selected) for the rest; a final
-    RMSNorm and an untied softmax head under ``sparse_mcxent``. No bias
-    anywhere. ``experts_held`` = (first, end) is the share of every expert
-    layer that this network holds (() = all). Input: [B, T] integer token
-    ids; labels: [B, T] integer next-token ids. The defaults are
-    LFM2-24B-A2B's published widths."""
-    from deeplearning4j_tpu.nn.initializers import Distribution
-    init = Distribution(kind="normal", std=0.02)
-    mixers = {"conv": "short_conv", "full_attention": "attention"}
-    blocks = []
-    for i, kind in enumerate(layer_types):
-        if kind not in mixers:
-            raise ValueError(f"layer_types[{i}] is 'conv' or "
-                             f"'full_attention', got {kind!r}")
-        dense = i < num_dense_layers
-        moe = {} if dense else {"n_experts": n_experts, "top_k": top_k,
-                                "experts_held": tuple(experts_held),
-                                "routed_scale": routed_scale}
-        blocks.append(L.TransformerBlock(
-            n_out=d_model, n_heads=n_heads, causal=True, activation="silu",
-            norm="rms", norm_eps=norm_eps, bias=False, rope_theta=rope_theta,
-            head_dim=head_dim, n_kv_heads=n_kv_heads, qk_norm=True,
-            mixer=mixers[kind], conv_kernel=conv_kernel,
-            ffn="gated" if dense else "moe",
-            ffn_width=ffn_width if dense else expert_width,
-            weight_init=init, **moe))
-    return NeuralNetConfig(
-        seed=seed,
-        updater=updater or U.Adam(learning_rate=3e-4)).list(
-        L.EmbeddingSequenceLayer(n_in=vocab_size, n_out=d_model,
-                                 weight_init=init),
-        *blocks,
-        L.RMSNorm(eps=norm_eps),
-        L.RnnOutputLayer(n_out=vocab_size, loss="sparse_mcxent",
-                         has_bias=False, weight_init=init),
-        input_type=I.RecurrentType(1, seq_len),
-    )
+    ``layer_types`` says (``"linear_attention"``, the third kind the loop
+    takes, needs the widths ``gated_delta_moe_lm`` passes), and whose FFN
+    is a dense gated SiLU FFN for the first ``num_dense_layers`` layers
+    and ``n_experts`` routed experts (top-``top_k``, sigmoid scores, an
+    expert bias that moves the selection only, weights renormalised over
+    the selected) for the rest; a final RMSNorm and an untied softmax head
+    under ``sparse_mcxent``. No bias anywhere. ``experts_held`` = (first,
+    end) is the share of every expert layer that this network holds (() =
+    all). Input: [B, T] integer token ids; labels: [B, T] integer
+    next-token ids. The defaults are LFM2-24B-A2B's published widths."""
+    return _hybrid_decoder(
+        vocab_size, layer_types, num_dense_layers, d_model, seq_len,
+        block={"n_heads": n_heads, "norm_eps": norm_eps,
+               "rope_theta": rope_theta, "head_dim": head_dim,
+               "n_kv_heads": n_kv_heads, "qk_norm": True,
+               "conv_kernel": conv_kernel},
+        dense={"ffn": "gated", "ffn_width": ffn_width},
+        moe={"ffn": "moe", "ffn_width": expert_width,
+             "n_experts": n_experts, "top_k": top_k,
+             "experts_held": tuple(experts_held),
+             "routed_scale": routed_scale},
+        final_norm=L.RMSNorm(eps=norm_eps), updater=updater, seed=seed)
+
+
+def gated_delta_moe_lm(vocab_size, n_layers=48, full_attention_interval=4,
+                       d_model=2048, n_heads=16, n_kv_heads=2, head_dim=256,
+                       partial_rotary_factor=0.25, linear_k_heads=16,
+                       linear_v_heads=32, linear_k_head_dim=128,
+                       linear_v_head_dim=128, conv_kernel=4,
+                       expert_width=512, shared_expert_width=512,
+                       n_experts=512, top_k=10, experts_held=(),
+                       seq_len=4096, rope_theta=1e7, norm_eps=1e-6,
+                       updater=None, seed=12345):
+    """Hybrid linear/softmax-attention mixture-of-experts decoder (the
+    Qwen3-Next family's ``qwen3_next``; net-new), through the loop
+    ``hybrid_moe_lm`` runs: layer ``i`` mixes by gated softmax attention
+    where ``(i + 1) % full_attention_interval == 0`` (grouped-query, an
+    output gate from a doubled query projection, QK-norm, rotary positions
+    on the first ``partial_rotary_factor`` of a head) and by the gated
+    delta rule elsewhere (``GatedDeltaNet``); every layer's FFN is
+    ``n_experts`` routed experts (top-``top_k`` of a float32 softmax over
+    all of them, weights renormalised over the selected, no bias) plus one
+    shared expert gated by ``sigmoid(x w_sg)``; every RMS norm has its
+    gain about zero (``x^ (1 + g)``). No bias anywhere, no dense layer.
+    ``experts_held`` as ``hybrid_moe_lm``'s. The defaults are
+    Qwen3-Next-80B-A3B's published widths and depth."""
+    layer_types = ["full_attention" if (i + 1) % full_attention_interval == 0
+                   else "linear_attention" for i in range(n_layers)]
+    return _hybrid_decoder(
+        vocab_size, layer_types, 0, d_model, seq_len,
+        block={"n_heads": n_heads, "norm_eps": norm_eps,
+               "norm_zero_centered": True, "rope_theta": rope_theta,
+               "rotary_dim": int(head_dim * partial_rotary_factor),
+               "head_dim": head_dim, "n_kv_heads": n_kv_heads,
+               "qk_norm": True, "attn_gate": True,
+               "conv_kernel": conv_kernel, "linear_k_heads": linear_k_heads,
+               "linear_v_heads": linear_v_heads,
+               "linear_head_dim": linear_k_head_dim,
+               "linear_v_head_dim": linear_v_head_dim},
+        dense={},
+        moe={"ffn": "moe", "ffn_width": expert_width,
+             "n_experts": n_experts, "top_k": top_k,
+             "experts_held": tuple(experts_held), "router": "softmax",
+             "shared_expert_width": shared_expert_width},
+        final_norm=L.RMSNorm(eps=norm_eps, zero_centered=True),
+        updater=updater, seed=seed)

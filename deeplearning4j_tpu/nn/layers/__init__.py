@@ -23,7 +23,7 @@ from deeplearning4j_tpu.nn.layers.vae import (  # noqa: F401
 from deeplearning4j_tpu.nn.layers.objdetect import Yolo2OutputLayer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.centerloss import CenterLossOutputLayer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.attention import (  # noqa: F401
-    LayerNormalization, MultiHeadAttention, RMSNorm, ShortConv,
+    GatedDeltaNet, LayerNormalization, MultiHeadAttention, RMSNorm, ShortConv,
     TransformerBlock,
 )
 from deeplearning4j_tpu.nn.layers.looped import (  # noqa: F401

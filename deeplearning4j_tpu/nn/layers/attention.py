@@ -65,9 +65,13 @@ class LayerNormalization(ParamLayer):
 @dataclasses.dataclass(frozen=True)
 class RMSNorm(ParamLayer):
     """Root-mean-square norm over the last axis (Zhang & Sennrich 2019):
-    ``x / sqrt(mean(x^2) + eps) * gamma``; no mean, no bias."""
+    ``x / sqrt(mean(x^2) + eps) * gamma``; no mean, no bias.
+    ``zero_centered`` stores the gain about zero: ``... * (1 + gamma)``,
+    ``gamma`` starting at 0 (Qwen3-Next's norm; weight decay then pulls the
+    gain towards 1, not towards 0)."""
 
     eps: float = 1e-6
+    zero_centered: bool = False
     activation: object = dataclasses.field(default="identity", kw_only=True)
 
     input_family = None
@@ -79,20 +83,28 @@ class RMSNorm(ParamLayer):
         return input_type
 
     def init(self, key, input_type, dtype=jnp.float32):
-        return {"gamma": jnp.ones((_nfeat(input_type),), dtype)}
+        make = jnp.zeros if self.zero_centered else jnp.ones
+        return {"gamma": make((_nfeat(input_type),), dtype)}
 
     def apply(self, params, state, x, *, train=False, rng=None):
         with jax.named_scope("rmsnorm"):
             ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-            y = x * jax.lax.rsqrt(ms + self.eps) * params["gamma"]
+            gain = params["gamma"] + 1 if self.zero_centered \
+                else params["gamma"]
+            y = x * jax.lax.rsqrt(ms + self.eps) * gain
             return self.activation_fn()(y), state
 
 
-def rope(x, theta):
+def rope(x, theta, rotary_dim=None):
     """Rotary position embedding (Su et al. 2021) of ``x`` [B, T, H, D] at
     positions 0..T-1, in the rotate-half convention over the whole head
     width: pair ``i`` is (x[i], x[i + D/2]) and turns by
-    ``t * theta**(-2i/D)``."""
+    ``t * theta**(-2i/D)``. With ``rotary_dim`` < D (partial rotary) the
+    first ``rotary_dim`` of a head turn so, as a head of that width would,
+    and the rest pass through."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        return jnp.concatenate([rope(x[..., :rotary_dim], theta),
+                                x[..., rotary_dim:]], axis=-1)
     with jax.named_scope("rope"):
         t, d = x.shape[1], x.shape[-1]
         inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
@@ -164,7 +176,12 @@ class MultiHeadAttention(ParamLayer):
     apart (``Wq`` [n_in, H D], ``Wkv`` [n_in, 2 Hkv D]) in place of
     ``Wqkv``; ``qk_norm`` puts an RMSNorm over each head's width on q and
     on k (gains ``q_gamma`` / ``k_gamma`` [D], shared by the heads, eps
-    ``qk_norm_eps``) before the rotation."""
+    ``qk_norm_eps``, about zero with ``qk_norm_zero_centered``) before the
+    rotation; ``rotary_dim`` turns only the first that many of a head
+    (``rope``); ``gate`` (with ``n_kv_heads``) doubles the query
+    projection, ``Wq`` [n_in, H 2D] laid out a head [q | gate], and
+    multiplies the attention's result by ``sigmoid(gate)`` elementwise
+    before ``Wo`` (Qwen3-Next's gated attention)."""
 
     n_out: int = 0     # model dim (also output dim)
     n_heads: int = 4
@@ -175,6 +192,9 @@ class MultiHeadAttention(ParamLayer):
     n_kv_heads: int | None = None
     qk_norm: bool = False
     qk_norm_eps: float = 1e-6
+    qk_norm_zero_centered: bool = False
+    rotary_dim: int | None = None
+    gate: bool = False
     weight_init: object = dataclasses.field(default="xavier", kw_only=True)
 
     input_family = _inputs.RecurrentType
@@ -189,10 +209,14 @@ class MultiHeadAttention(ParamLayer):
         return self.n_out // self.n_heads
 
     def _grouped(self):
-        """Key/value heads where they are fewer than the query heads,
-        else None (plain multi-head: one fused projection)."""
+        """Key/value heads where the projections are apart (they are
+        fewer than the query heads, or the query projection carries the
+        gate), else None (plain multi-head: one fused projection)."""
         kv = self.n_kv_heads
-        if kv is None or kv == self.n_heads:
+        if kv is None and self.gate:
+            raise ValueError("the output gate rides the query projection "
+                             "of the grouped form: set n_kv_heads")
+        if kv is None or (kv == self.n_heads and not self.gate):
             return None
         if self.n_heads % kv:
             raise ValueError(f"n_heads {self.n_heads} is no multiple of "
@@ -219,24 +243,28 @@ class MultiHeadAttention(ParamLayer):
         else:
             kq, kkv = jax.random.split(k1)
             kv_inner = 2 * kv * self._head_dim()
-            p["Wq"] = _init.init_weight(self.weight_init, kq, (n_in, inner),
-                                        n_in, inner, dtype)
+            q_inner = 2 * inner if self.gate else inner
+            p["Wq"] = _init.init_weight(self.weight_init, kq,
+                                        (n_in, q_inner), n_in, q_inner, dtype)
             p["Wkv"] = _init.init_weight(self.weight_init, kkv,
                                          (n_in, kv_inner), n_in, kv_inner,
                                          dtype)
         if self.qk_norm:
-            p["q_gamma"] = jnp.ones((self._head_dim(),), dtype)
-            p["k_gamma"] = jnp.ones((self._head_dim(),), dtype)
+            make = jnp.zeros if self.qk_norm_zero_centered else jnp.ones
+            p["q_gamma"] = make((self._head_dim(),), dtype)
+            p["k_gamma"] = make((self._head_dim(),), dtype)
         if self.bias:
             p["bqkv"] = jnp.zeros((3 * inner,), dtype)
             p["bo"] = jnp.zeros((self.n_out,), dtype)
         return p
 
     def heads(self, params, x):
-        """Project to q,k,v [B,T,H,D]."""
+        """Project to q,k,v [B,T,H,D] and the output gate's logits
+        [B,T,H,D] (None without ``gate``)."""
         b, t, _ = x.shape
         h, d = self.n_heads, self._head_dim()
         kv = self._grouped()
+        gate = None
         if kv is None:
             qkv = matmul(x.reshape(b * t, -1), params["Wqkv"])
             if self.bias:
@@ -245,21 +273,25 @@ class MultiHeadAttention(ParamLayer):
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         else:
             x2 = x.reshape(b * t, -1)
-            q = matmul(x2, params["Wq"]).reshape(b, t, h, d)
+            q = matmul(x2, params["Wq"]).reshape(b, t, h, -1)
+            if self.gate:
+                q, gate = q[..., :d], q[..., d:]
             k_v = matmul(x2, params["Wkv"]).reshape(b, t, 2, kv, d)
             k, v = k_v[:, :, 0], k_v[:, :, 1]
         if self.qk_norm:
-            norm = RMSNorm(eps=self.qk_norm_eps)
+            norm = RMSNorm(eps=self.qk_norm_eps,
+                           zero_centered=self.qk_norm_zero_centered)
             q, _ = norm.apply({"gamma": params["q_gamma"]}, {}, q)
             k, _ = norm.apply({"gamma": params["k_gamma"]}, {}, k)
         if self.rope_theta is not None:
-            q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
-        if kv is not None:
+            q = rope(q, self.rope_theta, self.rotary_dim)
+            k = rope(k, self.rope_theta, self.rotary_dim)
+        if kv is not None and kv != h:
             # each key/value head serves its group of query heads; autodiff
             # sums the group's gradients back onto the one head
             k = jnp.repeat(k, h // kv, axis=2)
             v = jnp.repeat(v, h // kv, axis=2)
-        return q, k, v
+        return q, k, v, gate
 
     def out_proj(self, params, attn):
         b, t, h, d = attn.shape
@@ -269,12 +301,27 @@ class MultiHeadAttention(ParamLayer):
         return y.reshape(b, t, self.n_out)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        q, k, v = self.heads(params, x)
+        q, k, v, gate = self.heads(params, x)
         attn = dot_product_attention(q, k, v, mask=mask, causal=self.causal)
+        if gate is not None:
+            with jax.named_scope("attn_gate"):
+                attn = attn * jax.nn.sigmoid(gate).astype(attn.dtype)
         y = self.out_proj(params, attn)
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
         return y, state
+
+
+def _causal_taps(z, w):
+    """Depthwise causal convolution of ``z`` [B,T,C] with ``w`` [C,taps]:
+    zeros before the sequence's start, the last tap meeting the present
+    position."""
+    t, taps = z.shape[1], w.shape[1]
+    c = z * w[:, taps - 1]
+    for back in range(1, taps):
+        past = jnp.pad(z, ((0, 0), (back, 0), (0, 0)))[:, :t]
+        c = c + past * w[:, taps - 1 - back]
+    return c
 
 
 @register_config
@@ -321,13 +368,116 @@ class ShortConv(ParamLayer):
             bcx = bcx.reshape(b, t, 3, d)
             gate_b, gate_c, xx = bcx[:, :, 0], bcx[:, :, 1], bcx[:, :, 2]
             z = gate_b * xx
-            w = params["conv_w"].astype(z.dtype)
-            c = z * w[:, self.kernel - 1]
-            for back in range(1, self.kernel):
-                past = jnp.pad(z, ((0, 0), (back, 0), (0, 0)))[:, :t]
-                c = c + past * w[:, self.kernel - 1 - back]
+            c = _causal_taps(z, params["conv_w"].astype(z.dtype))
             y = matmul((gate_c * c).reshape(b * t, d), params["W_out"])
             y = y.reshape(b, t, d)
+            if mask is not None:
+                y = y * mask[..., None].astype(y.dtype)
+            return y, state
+
+
+@register_config
+@dataclasses.dataclass(frozen=True)
+class GatedDeltaNet(ParamLayer):
+    """The gated delta rule as a sequence mixer over [B,T,F] (Qwen3-Next's
+    linear attention; Yang et al., arXiv:2412.06464), ``k_heads`` key heads
+    of ``head_dim`` serving ``v_heads`` value heads of ``v_head_dim`` (None
+    = ``head_dim``), value head ``j`` reading key head ``j // (v_heads //
+    k_heads)``:
+
+    ``[q | k | v | z] = x W_qkvz`` and ``[b | a] = x W_ba``, each part
+    whole and its heads in order; ``[q | k | v] = silu(conv(.))``, a
+    depthwise causal convolution of ``conv_kernel`` taps over the channels
+    in that order (zeros before the sequence's start, the last tap meeting
+    the present position, no bias); ``beta = sigmoid(b)``; ``g = -exp(A_log)
+    softplus(a + dt_bias)`` in float32; ``q = l2norm(q) / sqrt(head_dim)``,
+    ``k = l2norm(k)`` (``x rsqrt(sum x^2 + 1e-6)``); the recurrence
+    ``S = exp(g) S; S += beta k (v - S^T k)^T; o = S^T q`` a value head in
+    its chunkwise form (ops/gated_delta.py); a head ``o = o / sqrt(mean(o^2)
+    + norm_eps) * norm_w * silu(z)``; ``out = o W_out``. ``A_log`` starts
+    at ``log U(0, 16)``, ``dt_bias`` and ``norm_w`` at 1."""
+
+    n_out: int = 0
+    k_heads: int = 16
+    v_heads: int = 32
+    head_dim: int = 128
+    v_head_dim: int | None = None
+    conv_kernel: int = 4
+    norm_eps: float = 1e-6
+    weight_init: object = dataclasses.field(default="xavier", kw_only=True)
+
+    input_family = _inputs.RecurrentType
+
+    WEIGHT_KEYS = ("W_qkvz", "W_ba", "conv_w", "W_out")
+    BIAS_KEYS = ("dt_bias",)
+
+    L2NORM_EPS = 1e-6
+
+    def _widths(self):
+        """(key width, value width) over all heads."""
+        dv = self.v_head_dim or self.head_dim
+        return self.k_heads * self.head_dim, self.v_heads * dv
+
+    def output_type(self, input_type):
+        return _inputs.RecurrentType(self.n_out, input_type.timesteps)
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        n_in = input_type.size
+        kw, vw = self._widths()
+        k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+
+        def weight(k, shape, fan_in, fan_out):
+            return _init.init_weight(self.weight_init, k, shape, fan_in,
+                                     fan_out, dtype)
+
+        proj, conv = 2 * kw + 2 * vw, 2 * kw + vw
+        return {
+            "W_qkvz": weight(k1, (n_in, proj), n_in, proj),
+            "W_ba": weight(k2, (n_in, 2 * self.v_heads), n_in,
+                           2 * self.v_heads),
+            "conv_w": weight(k3, (conv, self.conv_kernel), self.conv_kernel,
+                             1),
+            "A_log": jnp.log(jax.random.uniform(
+                k4, (self.v_heads,), dtype, 1e-3, 16.0)),
+            "dt_bias": jnp.ones((self.v_heads,), dtype),
+            "norm_w": jnp.ones((vw // self.v_heads,), dtype),
+            "W_out": weight(k5, (vw, self.n_out), vw, self.n_out),
+        }
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.ops.gated_delta import gated_delta_rule
+        with jax.named_scope("gdn"):
+            b, t, _ = x.shape
+            kw, vw = self._widths()
+            hk, hv = self.k_heads, self.v_heads
+            _, ad = _dtypes.compute_dtypes_for(x.dtype)
+            x2 = x.reshape(b * t, -1)
+            qkvz = matmul(x2, params["W_qkvz"]).reshape(b, t, -1)
+            ba = matmul(x2, params["W_ba"]).reshape(b, t, 2, hv).astype(ad)
+            qkv, z = qkvz[..., :2 * kw + vw], qkvz[..., 2 * kw + vw:]
+            with jax.named_scope("gdn_conv"):
+                qkv = jax.nn.silu(_causal_taps(
+                    qkv, params["conv_w"].astype(qkv.dtype)))
+            q = qkv[..., :kw].reshape(b, t, hk, -1)
+            k = qkv[..., kw:2 * kw].reshape(b, t, hk, -1)
+            v = qkv[..., 2 * kw:].reshape(b, t, hv, -1)
+
+            def l2norm(u):
+                return u * jax.lax.rsqrt(
+                    jnp.sum(jnp.square(u), -1, keepdims=True)
+                    + self.L2NORM_EPS)
+
+            q = l2norm(q) * (self.head_dim ** -0.5)
+            k = l2norm(k)
+            beta = jax.nn.sigmoid(ba[:, :, 0])
+            g = -jnp.exp(params["A_log"].astype(ad)) * jax.nn.softplus(
+                ba[:, :, 1] + params["dt_bias"].astype(ad))
+            o = gated_delta_rule(q, k, v, g, beta)
+            o, _ = RMSNorm(eps=self.norm_eps).apply(
+                {"gamma": params["norm_w"]}, {}, o)
+            o = o * jax.nn.silu(z.reshape(o.shape))
+            y = matmul(o.reshape(b * t, vw), params["W_out"])
+            y = y.reshape(b, t, self.n_out)
             if mask is not None:
                 y = y * mask[..., None].astype(y.dtype)
             return y, state
@@ -341,20 +491,29 @@ class TransformerBlock(Layer):
     The defaults are the original pre-norm block (LayerNorm, biased fused
     QKV, a ``mlp_ratio`` x GELU MLP). The other fields define other
     published blocks on the same code: ``norm`` "layer" | "rms" (with
-    ``norm_eps``, None = the norm's own default); ``sandwich`` adds a norm
-    after the mixer and after the FFN, before each residual add
-    (``ln1_post`` / ``ln2_post``); ``bias=False`` drops every bias;
-    ``rope_theta``, ``head_dim``, ``n_kv_heads`` and ``qk_norm`` (with
-    ``norm_eps``) go to the attention; ``mixer`` "attention" |
-    "short_conv" puts a ``ShortConv`` of length ``conv_kernel`` in the
-    attention's place (parameters under ``conv``, not ``mha``); ``ffn``
+    ``norm_eps``, None = the norm's own default; ``norm_zero_centered``
+    the RMS norms' gains about zero, the attention's q/k norms too);
+    ``sandwich`` adds a norm after the mixer and after the FFN, before
+    each residual add (``ln1_post`` / ``ln2_post``); ``bias=False`` drops
+    every bias; ``rope_theta``, ``rotary_dim``, ``head_dim``,
+    ``n_kv_heads``, ``qk_norm`` (with ``norm_eps``) and ``attn_gate`` go to
+    the attention; ``mixer`` "attention" | "short_conv" | "gated_delta"
+    puts in the attention's place a ``ShortConv`` of length
+    ``conv_kernel`` (parameters under ``conv``, not ``mha``) or a
+    ``GatedDeltaNet`` of ``linear_k_heads`` key and ``linear_v_heads``
+    value heads of ``linear_head_dim`` / ``linear_v_head_dim``, its
+    convolution ``conv_kernel`` taps (parameters under ``gdn``); ``ffn``
     "mlp" | "gated" (``act(x Wg) * (x Wu)`` then ``Wd``) | "moe" of width
     ``ffn_width`` (None = ``n_out * mlp_ratio``). ``"moe"`` is a dropless
-    top-``top_k`` sigmoid router over ``n_experts`` gated experts of that
-    width, of which this layer holds ``experts_held`` = (first, end) (()
-    = all of them) and computes their part of the result
-    (``moe.routed_experts``); ``expert_bias`` and the last step's
-    ``moe_load`` / ``moe_elsewhere`` live in the layer's state."""
+    top-``top_k`` router (``router`` "sigmoid" | "softmax") over
+    ``n_experts`` gated experts of that width, of which this layer holds
+    ``experts_held`` = (first, end) (() = all of them) and computes their
+    part of the result (``moe.routed_experts``); the last step's
+    ``moe_load`` / ``moe_elsewhere`` and the sigmoid router's
+    ``expert_bias`` live in the layer's state. ``shared_expert_width`` > 0
+    adds the block's shared expert, a gated FFN of that width over every
+    token times ``sigmoid(x w_sg)`` (``moe_shared_*``; held whole whatever
+    ``experts_held`` says, as every chip of a deployment would)."""
 
     n_out: int = 0
     n_heads: int = 4
@@ -378,8 +537,20 @@ class TransformerBlock(Layer):
     top_k: int = 1
     experts_held: tuple = ()
     routed_scale: float = 1.0
+    router: str = "sigmoid"
+    shared_expert_width: int = 0
+    norm_zero_centered: bool = False
+    rotary_dim: int | None = None
+    attn_gate: bool = False
+    linear_k_heads: int = 0
+    linear_v_heads: int = 0
+    linear_head_dim: int = 0
+    linear_v_head_dim: int | None = None
 
     input_family = _inputs.RecurrentType
+
+    MIXER_KEYS = {"attention": "mha", "short_conv": "conv",
+                  "gated_delta": "gdn"}
 
     def _held(self):
         """(first, end) of the experts this layer holds."""
@@ -389,11 +560,20 @@ class TransformerBlock(Layer):
                              f"lie in 0..{self.n_experts}")
         return int(first), int(end)
 
+    def _eps(self, field):
+        """``norm_eps`` as the keyword ``field`` of a part that takes one;
+        nothing where the part's own default stands."""
+        return {} if self.norm_eps is None else {field: self.norm_eps}
+
     def _norm(self):
         if self.norm not in ("layer", "rms"):
             raise ValueError(f"norm is 'layer' or 'rms', got {self.norm!r}")
-        cls = LayerNormalization if self.norm == "layer" else RMSNorm
-        return cls() if self.norm_eps is None else cls(eps=self.norm_eps)
+        kw = self._eps("eps")
+        if self.norm == "layer":
+            if self.norm_zero_centered:
+                raise ValueError("norm_zero_centered is the RMS norm's")
+            return LayerNormalization(**kw)
+        return RMSNorm(zero_centered=self.norm_zero_centered, **kw)
 
     def _parts(self):
         """(norm, mixer, norm); the mixer's parameters sit under
@@ -401,21 +581,29 @@ class TransformerBlock(Layer):
         if self.mixer == "short_conv":
             mixer = ShortConv(n_out=self.n_out, kernel=self.conv_kernel,
                               weight_init=self.weight_init)
+        elif self.mixer == "gated_delta":
+            mixer = GatedDeltaNet(
+                n_out=self.n_out, k_heads=self.linear_k_heads,
+                v_heads=self.linear_v_heads, head_dim=self.linear_head_dim,
+                v_head_dim=self.linear_v_head_dim,
+                conv_kernel=self.conv_kernel, weight_init=self.weight_init,
+                **self._eps("norm_eps"))
         elif self.mixer == "attention":
-            qk = ({} if self.norm_eps is None
-                  else {"qk_norm_eps": self.norm_eps})
             mixer = MultiHeadAttention(
                 n_out=self.n_out, n_heads=self.n_heads, causal=self.causal,
                 bias=self.bias, rope_theta=self.rope_theta,
                 head_dim=self.head_dim, n_kv_heads=self.n_kv_heads,
-                qk_norm=self.qk_norm, weight_init=self.weight_init, **qk)
+                qk_norm=self.qk_norm,
+                qk_norm_zero_centered=self.norm_zero_centered,
+                rotary_dim=self.rotary_dim, gate=self.attn_gate,
+                weight_init=self.weight_init, **self._eps("qk_norm_eps"))
         else:
-            raise ValueError("mixer is 'attention' or 'short_conv', got "
-                             f"{self.mixer!r}")
+            raise ValueError("mixer is 'attention', 'short_conv' or "
+                             f"'gated_delta', got {self.mixer!r}")
         return self._norm(), mixer, self._norm()
 
     def _mixer_key(self):
-        return "conv" if self.mixer == "short_conv" else "mha"
+        return self.MIXER_KEYS[self.mixer]
 
     def output_type(self, input_type):
         return _inputs.RecurrentType(self.n_out, input_type.timesteps)
@@ -429,6 +617,11 @@ class TransformerBlock(Layer):
         if self.bias and self.ffn != "mlp":
             raise ValueError(f"the {self.ffn} FFN has no biases: set "
                              "bias=False")
+        if self.router not in ("sigmoid", "softmax"):
+            raise ValueError("router is 'sigmoid' or 'softmax', got "
+                             f"{self.router!r}")
+        if self.shared_expert_width and self.ffn != "moe":
+            raise ValueError("the shared expert belongs to ffn='moe'")
         ln1, mha, ln2 = self._parts()
         k1, k2, k3, k4 = jax.random.split(key, 4)
         hidden = self.ffn_width or self.n_out * self.mlp_ratio
@@ -461,6 +654,13 @@ class TransformerBlock(Layer):
             p["moe_Wg"] = experts(kg, self.n_out, hidden)
             p["moe_Wu"] = experts(ku, self.n_out, hidden)
             p["moe_Wd"] = experts(k4, hidden, self.n_out)
+            if self.shared_expert_width:
+                ks = jax.random.split(jax.random.fold_in(k3, 1), 4)
+                fs = self.shared_expert_width
+                p["moe_shared_Wg"] = weight(ks[0], self.n_out, fs)
+                p["moe_shared_Wu"] = weight(ks[1], self.n_out, fs)
+                p["moe_shared_Wd"] = weight(ks[2], fs, self.n_out)
+                p["moe_shared_gate"] = weight(ks[3], self.n_out, 1)
         else:
             p["mlp_W1"] = weight(k3, self.n_out, hidden)
             p["mlp_W2"] = weight(k4, hidden, self.n_out)
@@ -473,25 +673,37 @@ class TransformerBlock(Layer):
         if self.ffn != "moe":
             return {}
         first, end = self._held()
-        return {"expert_bias": jnp.zeros((self.n_experts,), dtype),
-                "moe_load": jnp.zeros((end - first,), dtype),
-                "moe_elsewhere": jnp.zeros((1,), dtype)}
+        counts = {"moe_load": jnp.zeros((end - first,), dtype),
+                  "moe_elsewhere": jnp.zeros((1,), dtype)}
+        if self.router == "softmax":     # no bias moves its selection
+            return counts
+        return {"expert_bias": jnp.zeros((self.n_experts,), dtype), **counts}
 
     def _moe(self, params, state, h):
-        """The routed experts' part of the result and the state with this
-        step's row counts."""
+        """The routed experts' part of the result, with the shared
+        expert's where the block has one, and the state with this step's
+        row counts."""
         from deeplearning4j_tpu.nn import activations as _act
         from deeplearning4j_tpu.nn.layers import moe as _moe
-        if "expert_bias" not in state:
+        if "moe_load" not in state:
             raise ValueError(
-                "ffn='moe' keeps its expert bias and its load in the "
-                "layer's state; this caller hands the block none")
+                "ffn='moe' keeps its load (and the sigmoid router its "
+                "expert bias) in the layer's state; this caller hands the "
+                "block none")
+        act = _act.get(self.activation)
         with jax.named_scope("moe"):
             y, load, elsewhere = _moe.routed_experts(
                 h, params["moe_router"], params["moe_Wg"], params["moe_Wu"],
-                params["moe_Wd"], state["expert_bias"], top_k=self.top_k,
-                held=self._held(), scale=self.routed_scale,
-                act=_act.get(self.activation))
+                params["moe_Wd"], state.get("expert_bias"),
+                top_k=self.top_k, held=self._held(),
+                scale=self.routed_scale, act=act, score=self.router)
+            if self.shared_expert_width:
+                with jax.named_scope("moe_shared"):
+                    m = (act(matmul(h, params["moe_shared_Wg"]))
+                         * matmul(h, params["moe_shared_Wu"]))
+                    gate = jax.nn.sigmoid(
+                        matmul(h, params["moe_shared_gate"]))
+                    y = y + gate * matmul(m, params["moe_shared_Wd"])
         dt = state["moe_load"].dtype
         return y, {**state, "moe_load": load.astype(dt),
                    "moe_elsewhere": elsewhere.astype(dt)}

@@ -1,10 +1,11 @@
 """Routed experts, two layers that share no logic.
 
 ``routed_experts`` is ``TransformerBlock``'s ``ffn="moe"``: one chip's
-share of a dropless top-k layer. A float32 sigmoid router scores all the
-experts, an expert bias moves the selection only, the ``N k`` assignments
-are sorted by held expert (those routed elsewhere behind a sentinel) and
-the held experts run as grouped products over the counts
+share of a dropless top-k layer. A float32 router scores all the experts
+(sigmoid scores with an expert bias that moves the selection only, or a
+softmax over all of them), the ``N k`` assignments are sorted by held
+expert (those routed elsewhere behind a sentinel) and the held experts
+run as grouped products over the counts
 (ops/grouped_matmul.py). Every shape is static and no token is dropped;
 the matrix work and, from PR 33, the row movement around it (tokens into
 sorted order, results back, and both backward passes, all written by
@@ -107,13 +108,19 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
-                   top_k, held, scale, act):
+                   top_k, held, scale, act, score="sigmoid"):
     """One chip's share of a dropless top-``top_k`` routed-experts layer.
 
-    ``x`` [N, d]; ``router_w`` [d, E] scores ALL ``E`` experts in float32:
-    ``s = sigmoid(x W_r)``, ``sel = top_k(s + expert_bias)`` (the bias
-    moves the selection only), ``w = s[sel] / (sum(s[sel]) + 1e-6) *
-    scale``, renormalised over the selected experts wherever they live.
+    ``x`` [N, d]; ``router_w`` [d, E] scores ALL ``E`` experts in float32,
+    by one of two score functions. ``score="sigmoid"`` (LFM2): ``s =
+    sigmoid(x W_r)``, ``sel = top_k(s + expert_bias)`` (the bias moves the
+    selection only), ``w = s[sel] / (sum(s[sel]) + 1e-6) * scale``.
+    ``score="softmax"`` (Qwen3-Next): ``s = softmax(x W_r)`` over all
+    ``E``, ``sel = top_k(s)``, ``w = s[sel] / sum(s[sel]) * scale``;
+    ``expert_bias`` is None. Either way the weights are renormalised over
+    the selected experts wherever they live. A shared expert that every
+    token passes through is the block's (``TransformerBlock._moe``), not
+    this layer's: every chip holds it whole, and no routing touches it.
     ``held = (first, end)`` names the experts whose weights
     ``w_gate`` / ``w_up`` [n_held, d, f] and ``w_down`` [n_held, f, d]
     are: the result is ``sum_{j in sel, first <= j < end} w_j E_j(x)``,
@@ -147,10 +154,15 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
     with jax.named_scope("moe_route"):
         logits = jnp.matmul(x.astype(ad), router_w.astype(ad),
                             precision=jax.lax.Precision.HIGHEST)
-        s = jax.nn.sigmoid(logits)
-        _, sel = jax.lax.top_k(s + expert_bias.astype(ad), top_k)
-        w = jnp.take_along_axis(s, sel, axis=-1)
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + ROUTER_EPS) * scale
+        if score == "softmax":
+            s = jax.nn.softmax(logits, axis=-1)
+            w, sel = jax.lax.top_k(s, top_k)
+            w = w / jnp.sum(w, axis=-1, keepdims=True) * scale
+        else:
+            s = jax.nn.sigmoid(logits)
+            _, sel = jax.lax.top_k(s + expert_bias.astype(ad), top_k)
+            w = jnp.take_along_axis(s, sel, axis=-1)
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + ROUTER_EPS) * scale
         local = sel.reshape(-1).astype(jnp.int32) - first
         here = (local >= 0) & (local < n_held)
         local = jnp.where(here, local, n_held)
@@ -161,11 +173,13 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
         r = jnp.sum(sizes)
         with jax.named_scope("moe_permute"):
             xs = _dispatch(top_k, cd, x.astype(ad), order // top_k, r)
+    # what the shapes say a group holds: N k assignments over E experts
+    rows = xs.shape[0] // router_w.shape[1]
     with jax.named_scope("moe_experts"):
-        g = grouped_matmul(xs, w_gate, sizes, cd)
-        u = grouped_matmul(xs, w_up, sizes, cd)
+        g = grouped_matmul(xs, w_gate, sizes, cd, rows)
+        u = grouped_matmul(xs, w_up, sizes, cd, rows)
         h = (act(g.astype(ad)) * u.astype(ad)).astype(cd)
-        ys = grouped_matmul(h, w_down, sizes, ad)
+        ys = grouped_matmul(h, w_down, sizes, ad, rows)
     with jax.named_scope("moe_route"), jax.named_scope("moe_permute"):
         y = _combine(ys, w, order, inv, r)
     return y.astype(x.dtype), sizes, counts[n_held:]

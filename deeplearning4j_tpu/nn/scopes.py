@@ -7,9 +7,12 @@ pass's part of it in `transpose(jvp(...))`, so one scope names a layer's
 forward and its backward. The grammar (PERF.md section 3 lists who reads
 what): `L<ii>.<LayerClass>` per MultiLayerNetwork layer,
 `V.<vertex>.<Class>` per ComputationGraph vertex, `attn` / `mlp` inside a
-transformer block (`short_conv` inside `attn` where that is the mixer;
-`moe` inside `mlp` where the FFN is routed experts, with `moe_route` and
-`moe_experts` inside it), `loss`, `grad_norm`, `updater`, `health`, and
+transformer block (`short_conv` inside `attn` where that is the mixer,
+or `gdn` with `gdn_conv` and `gdn_core` inside it where the mixer is the
+gated delta rule; `attn_gate` around gated attention's output gate; `moe`
+inside `mlp` where the FFN is routed experts, with `moe_route` and
+`moe_experts` inside it and `moe_shared` beside them where the block has
+a shared expert), `loss`, `grad_norm`, `updater`, `health`, and
 `<kernel>.fwd` / `<kernel>.bwd` around the Pallas kernels. Anything
 outside `[A-Za-z0-9_.-]` in a name becomes `_`.
 """

@@ -61,6 +61,12 @@ from deeplearning4j_tpu.ops import spmd as _spmd
 
 _LANE = 128
 _NEG_INF = -1e30
+#: the widest head the kernels take: two lane tiles. The accumulators are
+#: [D, block] float32, so VMEM grows with D; 256 is the widest a cell runs
+#: (Qwen3-Next's full attention: [16, 4096, 256] float32, forward 1.26 ms
+#: = 55% of its least time, the split backward 1.79 + 1.43 ms; PERF.md
+#: section 5, PR 34) and the widest compiled for the chip
+_MAX_HEAD = 256
 
 
 def backend_is_tpu():
@@ -75,7 +81,9 @@ def backend_is_tpu():
 # T=8192 (23x — the [B,H,T,T] logits start thrashing HBM). That window
 # timed the forward kernel as it was before PR 26 (padded to 128 lanes,
 # 3.2x slower at T 1024, D 64): the crossover may now lie below 1024 and
-# has not been measured again. A constant until a sweep at the benchmark's
+# has not been measured again, at width 64 or at the widths the cells run
+# since (128 from PR 27, 256 from PR 34: every cell's T is 1024 or more, so
+# none sits on the XLA side). A constant until a sweep at the benchmark's
 # shapes moves it.
 _MIN_SEQ = 1024
 
@@ -102,7 +110,10 @@ def resolve_attention(q_shape, k_shape, mask, dtype):
     """The whole dispatch decision, from what the call shows: None where
     the XLA path should run, else the ``(block_q, block_k)`` to run the
     kernel with. A TPU backend; self-attention shapes only (KV-cache
-    decode goes naive); head_dim <= 128; a float dtype; masks only as
+    decode goes naive); head_dim <= 256 (``_MAX_HEAD``; wider goes naive:
+    measured on the chip at 64, 128 and, from PR 34, 256, where the
+    backward takes its split form from T 2,048 for float32 operands and
+    from T 4,096 for bfloat16); a float dtype; masks only as
     key-side [B, Tk] padding, the reference's masking contract
     (MaskedReductionUtil.java) — arbitrary-rank score masks go naive; and
     the measured crossover ``_MIN_SEQ``. Another block for another shape
@@ -116,7 +127,7 @@ def resolve_attention(q_shape, k_shape, mask, dtype):
             return None
     if tuple(q_shape) != tuple(k_shape):
         return None
-    if q_shape[-1] > _LANE:
+    if q_shape[-1] > _MAX_HEAD:
         return None
     if not jnp.issubdtype(dtype, jnp.floating):
         return None

@@ -41,27 +41,38 @@ _gmm = importlib.import_module(
     "jax.experimental.pallas.ops.tpu.megablox.gmm")
 
 # row tile, contraction tile, column tile: the best of seven measured at
-# [32768, 2048] x [8, 2048, 1536] (the call above); the transposed product
-# of the weight gradient keeps its output tile in VMEM and takes 512 cubed
+# [32768, 2048] x [8, 2048, 1536] with 512 rows a group (the call above);
+# the transposed product of the weight gradient keeps its output tile in
+# VMEM and takes 512 cubed. A kernel visits every (group, row tile) pair
+# that holds a row, so groups far smaller than the tile pay for a tile
+# each: the row tile follows the rows a group expects, down to 128
+# (PERF.md section 6, PR 34: at [40960, 2048] x [16, 2048, 512] with 80
+# rows a group, forward and backward of an expert FFN 3.53 ms at 512,
+# 3.32 at 256, 3.22 at 128, 3.27 at 64; at 400 rows 4.23 / 4.05 / 3.95 /
+# 4.37; a weight-gradient tile of 256 loses at every load)
 _TM, _TK, _TN = 512, 2048, 512
+_TM_LEAST = 128
 _T_WGRAD = 512
 
 
-def _row_tile(m):
-    """The largest power of two up to ``_TM`` that divides ``m``: the
-    kernels take whole row tiles only."""
-    t = _TM
+def _row_tile(m, rows_a_group):
+    """The smallest power of two from ``_TM_LEAST`` up to ``_TM`` that
+    holds ``rows_a_group``, halved until it divides ``m``: the kernels
+    take whole row tiles only."""
+    t = _TM_LEAST
+    while t < min(rows_a_group, _TM):
+        t *= 2
     while m % t:
         t //= 2
     return t
 
 
-def _tiling(m, k, n):
-    return _row_tile(m), min(_TK, k), min(_TN, n)
+def _tiling(tm, k, n):
+    return tm, min(_TK, k), min(_TN, n)
 
 
-def _wgrad_tiling(m, k, n):
-    return min(_row_tile(m), _T_WGRAD), min(_T_WGRAD, k), min(_T_WGRAD, n)
+def _wgrad_tiling(tm, k, n):
+    return min(tm, _T_WGRAD), min(_T_WGRAD, k), min(_T_WGRAD, n)
 
 
 def _kernel(fn, *args, **kw):
@@ -72,40 +83,41 @@ def _kernel(fn, *args, **kw):
         return fn(*args, **kw)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _grouped(x, w, group_sizes, out_dtype, interpret):
-    m, k = x.shape
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _grouped(x, w, group_sizes, out_dtype, interpret, tm):
     return _kernel(_gmm.gmm, x, w.astype(x.dtype), group_sizes, out_dtype,
-                   _tiling(m, k, w.shape[2]), interpret=interpret)
+                   _tiling(tm, x.shape[1], w.shape[2]), interpret=interpret)
 
 
-def _grouped_fwd(x, w, group_sizes, out_dtype, interpret):
-    return (_grouped(x, w, group_sizes, out_dtype, interpret),
+def _grouped_fwd(x, w, group_sizes, out_dtype, interpret, tm):
+    return (_grouped(x, w, group_sizes, out_dtype, interpret, tm),
             (x, w, group_sizes))
 
 
-def _grouped_bwd(out_dtype, interpret, res, g):
+def _grouped_bwd(out_dtype, interpret, tm, res, g):
     x, w, group_sizes = res
-    m, k = x.shape
-    n = w.shape[2]
+    k, n = x.shape[1], w.shape[2]
     g = g.astype(x.dtype)  # the matrix units round an operand anyway
     dx = _kernel(_gmm.gmm, g, w.astype(x.dtype), group_sizes, x.dtype,
-                 _tiling(m, n, k), transpose_rhs=True, interpret=interpret)
+                 _tiling(tm, n, k), transpose_rhs=True, interpret=interpret)
     dw = _kernel(_gmm.tgmm, x.swapaxes(0, 1), g, group_sizes, jnp.float32,
-                 _wgrad_tiling(m, k, n), interpret=interpret)
+                 _wgrad_tiling(tm, k, n), interpret=interpret)
     return dx, dw.astype(w.dtype), None
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def grouped_matmul(x, w, group_sizes, out_dtype):
+def grouped_matmul(x, w, group_sizes, out_dtype, rows_a_group=None):
     """``x`` [M, K] times ``w`` [G, K, N] by groups of rows -> [M, N] of
     ``out_dtype``, accumulated in float32. ``group_sizes`` int32 [G] sums
     to at most M; group ``g`` is the rows from ``sum(sizes[:g])``. ``x`` is
     float32 or bfloat16 and ``w`` is read at ``x``'s dtype. Differentiable
     in ``x`` and ``w`` (the weight gradient is accumulated in float32 over
     a group's rows, returned in ``w``'s own dtype, and exactly zero for an
-    empty group)."""
+    empty group). ``rows_a_group``, where the caller's shapes say how many
+    rows a group expects (a router's ``N k / E``), sizes the row tile; the
+    result does not depend on it."""
+    tm = _row_tile(x.shape[0], _TM if rows_a_group is None else rows_a_group)
     return _grouped(x, w, group_sizes.astype(jnp.int32), out_dtype,
-                    not _ap.backend_is_tpu())
+                    not _ap.backend_is_tpu(), tm)

@@ -32,7 +32,7 @@ SYMBOLS = {
         "VariationalAutoencoder", "Yolo2OutputLayer",
         "CenterLossOutputLayer", "TransformerBlock", "MultiHeadAttention",
         "LayerNormalization", "MoETransformerBlock", "RMSNorm",
-        "LoopedStack", "LoopedLMOutputLayer", "ShortConv"],
+        "LoopedStack", "LoopedLMOutputLayer", "ShortConv", "GatedDeltaNet"],
     "deeplearning4j_tpu.nn.multilayer": ["MultiLayerNetwork"],
     "deeplearning4j_tpu.nn.listeners": [
         "ScoreIterationListener", "PerformanceListener",
@@ -72,7 +72,8 @@ SYMBOLS = {
         "alexnet", "darknet19", "facenet_nn4_small2", "googlenet",
         "inception_resnet_v1", "lenet", "resnet50", "simple_cnn",
         "text_generation_lstm", "tiny_yolo", "vgg16", "vgg19",
-        "transformer_lm", "looped_lm", "hybrid_moe_lm"],
+        "transformer_lm", "looped_lm", "hybrid_moe_lm",
+        "gated_delta_moe_lm"],
     "deeplearning4j_tpu.parallel": [
         "ParallelTrainer", "MeshSpec", "make_mesh"],
     "deeplearning4j_tpu.parallel.inference": ["ParallelInference"],

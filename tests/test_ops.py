@@ -566,14 +566,16 @@ class TestResolveAttention:
         (Q, Q, np.ones((2, T, T)), np.float32, None),      # a score mask
         (Q, Q, np.ones((T,)), np.float32, None),
         (Q, Q, np.ones((3, T)), np.float32, None),         # another batch
-        ((2, T, 2, 256), (2, T, 2, 256), None, np.float32, None),  # d > 128
+        ((2, T, 2, 256), (2, T, 2, 256), None, np.float32, (512, 512)),
+        ((2, T, 2, 512), (2, T, 2, 512), None, np.float32, None),  # d > 256
         ((2, T, 2, 128), (2, T, 2, 128), None, np.float32, (512, 512)),
         ((2, 1, 2, 64), Q, None, np.float32, None),        # KV-cache decode
         (Q, (2, 2 * T, 2, 64), None, np.float32, None),    # Tq != Tk
         (Q, Q, None, np.int32, None),
         (Q, Q, None, jnp.bfloat16, (512, 512)),
     ], ids=["plain", "key_mask", "rank3_mask", "rank1_mask",
-            "mask_of_another_batch", "head_256", "head_128", "decode",
+            "mask_of_another_batch", "head_256", "head_512", "head_128",
+            "decode",
             "tq_ne_tk", "int32", "bf16"])
     def test_each_structural_gate(self, q, k, mask, dtype, want):
         assert attention_pallas.resolve_attention(q, k, mask, dtype) == want
@@ -1156,6 +1158,7 @@ class TestDefaultDispatchKernelsLowerForTpu:
         (4, 1024, 16, 64, True, False),   # the gpt2m-train-t1024 cell's
         (4, 4096, 8, 64, True, False),    # the longcontext shape
         (1, 2048, 4, 128, True, False),   # full-lane head dim
+        (1, 4096, 16, 256, True, False),  # the qwen3next-train-t4096 cell's
         (4, 1024, 8, 64, False, True),    # [B, Tk] key-padding mask
         (2, 3000, 8, 64, True, True),     # ragged T, padded inside
     ])
@@ -1184,6 +1187,7 @@ class TestDefaultDispatchKernelsLowerForTpu:
         (4, 1024, 16, 64, ("fused",)),    # the gpt2m-train-t1024 cell's
         (2, 2048, 16, 128, ("fused",)),   # the ouro-train-t2048 cell's
         (1, 8192, 2, 128, ("dkv", "dq")),  # a head's dq past VMEM
+        (1, 4096, 16, 256, ("dkv", "dq")),  # the qwen3next-train-t4096 cell's
     ])
     def test_flash_backward_is_a_kernel_under_its_scope(self, b, t, h, d,
                                                         kernels):
