@@ -421,6 +421,36 @@ def _flash_backward_time(name, *, b, t, h, d, interpret, iters=20):
             "wall_s": round(time.perf_counter() - t0, 1)}
 
 
+def _gated_delta_case(name, *, b, t, hk, hv, d, interpret, tol):
+    """The gated delta rule's two kernels alone against the ``jax.numpy``
+    chunkwise form: outputs and the five gradients, q and k normalised as
+    the mixer hands them over, decays from all but kept to all but
+    forgotten inside a few tokens."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops import gated_delta as _gd
+
+    keys = jax.random.split(jax.random.PRNGKey(t + d), 5)
+    q, k = (jax.random.normal(key, (b, t, hk, d), jnp.float32)
+            for key in keys[:2])
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True)) / d ** 0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True))
+    v = jax.random.normal(keys[2], (b, t, hv, d), jnp.float32)
+    g = -jnp.abs(jax.random.normal(keys[3], (b, t, hv), jnp.float32)) * (
+        10.0 ** jnp.linspace(-3.0, 0.5, hv, dtype=jnp.float32))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, t, hv),
+                                            jnp.float32))
+    if not interpret:
+        _expect(_gd.resolve_gated_delta(q.shape, v.shape, v.dtype),
+                f"{name}: the dispatch gate does not admit this shape")
+
+    def kernels(q, k, v, g, beta):
+        return _gd.gated_delta_kernels(q, k, v, g, beta, interpret=interpret)
+
+    return _compare(name, kernels, _gd._chunked, (q, k, v, g, beta), tol)
+
+
 def _lstm_case(name, *, t, b, hsz, peephole, masked, interpret, tol):
     import jax
     import jax.numpy as jnp
@@ -605,7 +635,8 @@ def _gated_delta_step_case(name, *, t, vocab, tol):
     the naive branch (the recurrence token by token as the benchmark's
     plain reference runs it, attention in ``jax.numpy``, the grouped
     products as ``jax.lax.ragged_dot``, the row movement as plain
-    gathers)."""
+    gathers). From PR 35 the recurrence is two kernels a gated-delta
+    layer, ``gdn_fwd`` and ``gdn_bwd``."""
     import jax
     import jax.numpy as jnp
 
@@ -627,12 +658,12 @@ def _gated_delta_step_case(name, *, t, vocab, tol):
     step = net.make_train_step(donate=False)
     text = step.lower(net.params, net.state, net.opt_state, x, labels, 0,
                       jax.random.PRNGKey(0), None).compile().as_text()
-    n_calls, want = text.count("tpu_custom_call"), 3 + 3 * 13
+    n_calls, want = text.count("tpu_custom_call"), 3 + 3 * 13 + 2 * 2
     _expect(n_calls == want,
             f"{name}: compiled train step holds {n_calls} "
             f"tpu_custom_call(s), expected {want}")
     for kernel in ("flash_attn_fwd", "flash_attn_bwd_dkv",
-                   "flash_attn_bwd_dq"):
+                   "flash_attn_bwd_dq", "gdn_fwd", "gdn_bwd"):
         _expect(kernel in text, f"{name}: no {kernel} in the compiled step")
     state = net.state
 
@@ -673,7 +704,8 @@ def kernel_cases(interpret):
              ("lstm_resident_peephole_masked",
               dict(t=4, b=8, hsz=128, peephole=True, masked=True)),
              ("lstm_tiled_masked", dict(t=3, b=8, hsz=640, peephole=False,
-                                        masked=True))])
+                                        masked=True))],
+            [("gated_delta_kernels", dict(b=1, t=100, hk=1, hv=2, d=128))])
     return (
         [("flash_causal_t4096_h8_d64",
           dict(b=1, t=4096, h=8, d=64, causal=True, masked=False,
@@ -701,22 +733,31 @@ def kernel_cases(interpret):
          ("lstm_tiled_h1024",
           dict(t=32, b=64, hsz=1024, peephole=False, masked=False)),
          ("lstm_tiled_h1024_peephole_masked",
-          dict(t=32, b=64, hsz=1024, peephole=True, masked=True))])
+          dict(t=32, b=64, hsz=1024, peephole=True, masked=True))],
+        [("gated_delta_t4096_h16_32_d128",   # qwen3next-train-t4096's call
+          dict(b=1, t=4096, hk=16, hv=32, d=128))])
 
 
 def kernels_phase(*, interpret, tol):
     t0 = time.perf_counter()
-    flash, lstm = kernel_cases(interpret)
+    flash, lstm, gated_delta = kernel_cases(interpret)
     results = [_flash_case(n, interpret=interpret, tol=tol, **kw)
                for n, kw in flash]
+    results += [_gated_delta_case(n, interpret=interpret, tol=tol, **kw)
+                for n, kw in gated_delta]
     if not interpret:  # through the dispatch: nothing to choose off the chip
         results.append(_looped_block_case(
             "looped_lm_t2048_h16_d128", b=2, t=2048, width=2048, h=16, d=128,
             ffn=5632, interpret=False, tol=tol))
         results.append(_hybrid_step_case(
             "hybrid_moe_lm_t2048_3layers", t=2048, vocab=1024, tol=tol))
+        # three layers of bfloat16 rounding on each branch: the gradients
+        # read 0.0299 with the recurrence in jax.numpy (PR 34) and 0.0362
+        # with the kernels (PR 35), both forms 0.003-0.004 a layer from the
+        # float32 recurrence (PERF.md section 6): 0.03 was at the edge
         results.append(_gated_delta_step_case(
-            "gated_delta_moe_lm_t2048_3layers", t=2048, vocab=1024, tol=tol))
+            "gated_delta_moe_lm_t2048_3layers", t=2048, vocab=1024,
+            tol={**tol, "bwd": 0.05}))
         results += [   # the two train cells' calls
             _flash_backward_time("flash_bwd_t1024_h16_d64_f32", b=4, t=1024,
                                  h=16, d=64, interpret=False),

@@ -67,8 +67,8 @@ def test_kernels_phase_in_interpret_mode(kernel_dispatch):
                                   tol={"fwd": 1e-4, "bwd": 1e-4})
     names = {r["kernel"] for r in doc["results"]}
     assert {"flash_causal", "flash_padding_mask", "flash_block",
-            "lstm_resident", "lstm_resident_peephole_masked",
-            "lstm_tiled_masked"} == names
+            "gated_delta_kernels", "lstm_resident",
+            "lstm_resident_peephole_masked", "lstm_tiled_masked"} == names
 
 
 def test_looped_block_case_at_toy_size():

@@ -1220,6 +1220,28 @@ class TestDefaultDispatchKernelsLowerForTpu:
                 ", ".join([f"tensor<{b * h}x{t}x{d}xbf16>"] * 4)), operands
             assert "bf16" not in results and f"x{d}xf32>" in results, results
 
+    @pytest.mark.parametrize("b,t,hk,hv,d,dtype", [
+        (1, 4096, 16, 32, 128, jnp.float32),  # the qwen3next-train-t4096 cell's
+        (2, 1000, 2, 8, 256, jnp.bfloat16),   # ragged T, four heads of 256
+    ])
+    def test_gated_delta_rule(self, b, t, hk, hv, d, dtype):
+        """Through the dispatch: the recurrence and its gradient are the
+        two kernels, once each, and no loop over the chunk states."""
+        from deeplearning4j_tpu.ops import gated_delta
+        q = jnp.zeros((b, t, hk, d), dtype)
+        v = jnp.zeros((b, t, hv, d), dtype)
+        g = jnp.zeros((b, t, hv), jnp.float32)
+        assert gated_delta.resolve_gated_delta(q.shape, v.shape, dtype)
+
+        def loss(q, k, v, g, beta):
+            return jnp.sum(gated_delta.gated_delta_rule(
+                q, k, v, g, beta).astype(jnp.float32))
+        text = _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                              q, q, v, g, g).as_text()
+        assert "stablehlo.while" not in text
+        assert sorted(re.findall(r'kernel_name = "(gdn_[a-z]+)"', text)) == [
+            "gdn_bwd", "gdn_fwd"]
+
     def test_flash_backward_shards_by_batch_under_the_declared_mesh(
             self, eight_devices):
         """A pallas_call does not partition itself as the scan did: under

@@ -202,3 +202,40 @@ def test_the_jitted_step_is_named_with_the_grammars_version(builder, name):
     net, x, y = _mln(recurrent=builder == "tbptt")
     text = _lower(net, x, y, builder).as_text()
     assert f"module @jit_{name}_{scopes.GRAMMAR} " in text
+
+
+def test_gated_delta_kernels_are_named_inside_gdn_core(monkeypatch):
+    """The recurrence's two kernels in a compiled train step (a one-layer
+    `gated_delta_moe_lm` at lane-aligned head widths, the dispatch's
+    answer forced off the chip): `gdn_fwd` under `gdn` and `gdn_core`
+    outside `transpose(`, `gdn_bwd` under both inside it, as the
+    benchmark's scope matcher reads `gdn_ms.tokens`, `gdn_core_ms.tokens`
+    and `step_bwd_ms.tokens`: a backward kernel outside its scope would
+    leave `gdn_core_ms` reading the forward alone. Read from the compiled
+    step's `op_name`s, which is what a trace shows: the kernels sit in
+    jitted functions of their own (one trace for every layer), and the
+    lowered text names a called function's operations without the
+    caller's scopes."""
+    from benchmark.readers import trace_scope_ms
+    from deeplearning4j_tpu import models
+    from deeplearning4j_tpu.ops import gated_delta
+    monkeypatch.setattr(gated_delta, "resolve_gated_delta", lambda *a: True)
+    net = MultiLayerNetwork(models.gated_delta_moe_lm(
+        16, n_layers=1, d_model=16, n_heads=2, n_kv_heads=1, head_dim=8,
+        linear_k_heads=1, linear_v_heads=2, linear_k_head_dim=128,
+        linear_v_head_dim=128, expert_width=8, shared_expert_width=8,
+        n_experts=4, top_k=2, experts_held=(0, 2), seq_len=64))
+    net.init()
+    x = np.arange(64, dtype=np.int32).reshape(1, 64) % 16
+    text = _lower(net, x, np.roll(x, -1, axis=1), "plain").compile().as_text()
+    # whole paths: an operation inside a reduction's own computation keeps
+    # the called function's relative name in the CPU compiler's text
+    paths = set(re.findall(r'op_name="(jit\(train_step[^"]+)"', text))
+    for kernel, backward in (("gdn_fwd", False), ("gdn_bwd", True)):
+        named = [p for p in paths if re.search(
+            r"(?:^|[/(])" + kernel + r"(?:$|[/)])", p)]
+        assert named, kernel
+        for scope in ("gdn", "gdn_core", r"L01\.TransformerBlock"):
+            match = trace_scope_ms.matcher(
+                {"scope": scope, "backward": backward})
+            assert all(match(p) for p in named), (kernel, scope, named[:3])
