@@ -16,6 +16,13 @@ A ground-up re-design of the capabilities of Eclipse Deeplearning4j
 - ``utils``    — dtype policy, serde registry, checkpointing
 """
 
+import time as _time
+
+#: ``perf_counter`` as the package's first line ran: the mark
+#: ``program_entered`` of ``utils.compile_cache.startup_marks()`` (what a
+#: process spent before it is the interpreter's, jax's and the caller's)
+_PROGRAM_ENTERED = _time.perf_counter()
+
 __version__ = "0.1.0"
 
 from deeplearning4j_tpu.utils import dtypes  # noqa: F401

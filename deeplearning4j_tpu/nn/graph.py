@@ -565,14 +565,15 @@ class ComputationGraph:
         rng = self._rng if rng is None else rng
         dtype = dtype or _dtypes.get_policy().param_dtype
         params, state = {}, {}
-        for name in self._order:
-            v = self._defs[name]
-            in_types = [self._types[i] for i in v.inputs]
-            rng, sub = jax.random.split(rng)
-            params[name] = v.vertex.init(sub, in_types, dtype)
-            state[name] = v.vertex.init_state(in_types, dtype)
-        self.params, self.state = params, state
-        self.opt_state = self.conf.updater.init(params)
+        with _tm.span("net.init"):
+            for name in self._order:
+                v = self._defs[name]
+                in_types = [self._types[i] for i in v.inputs]
+                rng, sub = jax.random.split(rng)
+                params[name] = v.vertex.init(sub, in_types, dtype)
+                state[name] = v.vertex.init_state(in_types, dtype)
+            self.params, self.state = params, state
+            self.opt_state = self.conf.updater.init(params)
         return params, state
 
     def _build_segments(self):

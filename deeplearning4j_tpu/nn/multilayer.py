@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu import telemetry as _tm
 from deeplearning4j_tpu.telemetry import health as _health
 from deeplearning4j_tpu.nn import gradnorm as _gradnorm
 from deeplearning4j_tpu.nn import losses as _losses
@@ -71,12 +72,13 @@ class MultiLayerNetwork:
         rng = self._rng if rng is None else rng
         dtype = dtype or _dtypes.get_policy().param_dtype
         params, state = [], []
-        for layer, in_type in zip(self.conf.layers, self.layer_inputs):
-            rng, sub = jax.random.split(rng)
-            params.append(layer.init(sub, in_type, dtype))
-            state.append(layer.init_state(in_type, dtype))
-        self.params, self.state = params, state
-        self.opt_state = self.conf.updater.init(params)
+        with _tm.span("net.init"):
+            for layer, in_type in zip(self.conf.layers, self.layer_inputs):
+                rng, sub = jax.random.split(rng)
+                params.append(layer.init(sub, in_type, dtype))
+                state.append(layer.init_state(in_type, dtype))
+            self.params, self.state = params, state
+            self.opt_state = self.conf.updater.init(params)
         return params, state
 
     def apply_fn(self, params, state, x, *, train=False, rng=None, mask=None,

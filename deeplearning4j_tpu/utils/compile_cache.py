@@ -38,9 +38,14 @@ Observability: ``compile_cache_total{event=hit|miss|serialize|
 deserialize_fail}`` counts every manifest interaction, and the
 ``time_to_first_step_ms`` / ``time_to_first_request_ms`` gauges record the
 realized cold-start tax (surfaced on ``/health`` and in the ``coldstart``
-bench). All jax interaction goes through :func:`aot_compile` — graftlint
-R3 flags raw ``.lower().compile()`` chains elsewhere, so no compile site
-can silently bypass the manifest tier.
+bench). :func:`startup_marks` keeps where the process started, where the
+program was entered and when the first step was enqueued on one clock, and
+with telemetry on jax's own compile phases land in the tracer's buffer as
+``compile.trace`` / ``compile.lower`` / ``compile.backend`` /
+``compile.cache_load`` spans (:func:`enable_persistent_cache` registers
+the listener). All jax interaction goes through :func:`aot_compile` —
+graftlint R3 flags raw ``.lower().compile()`` chains elsewhere, so no
+compile site can silently bypass the manifest tier.
 """
 
 from __future__ import annotations
@@ -59,11 +64,14 @@ import zipfile
 import jax
 import numpy as np
 
+from deeplearning4j_tpu import _PROGRAM_ENTERED as PROGRAM_ENTERED
+
 __all__ = ["DEFAULT_CACHE_DIR", "WarmManifest", "aot_compile",
            "attach_manifest", "backend_fingerprint",
            "enable_persistent_cache", "fresh_compile",
            "model_fingerprint",
-           "note_first_request", "note_first_step", "signature_of", "status"]
+           "note_first_request", "note_first_step", "signature_of",
+           "startup_marks", "status"]
 
 #: where the persistent compile cache lives when JAX_COMPILATION_CACHE_DIR
 #: is unset: a fixed path under the checkout (never a temporary name, pid or
@@ -79,7 +87,8 @@ def _process_start_anchor():
     """The perf_counter value at PROCESS start — /proc-derived on Linux
     so the first-step/first-request gauges genuinely include interpreter
     + jax import (the documented claim, and the dominant fixed cost on
-    CPU); falls back to module-import time elsewhere."""
+    CPU); falls back to the package's first line elsewhere, and never
+    lies after it (/proc counts in clock ticks)."""
     try:
         with open("/proc/self/stat", "rb") as f:
             # fields after the parenthesized comm; starttime is stat
@@ -90,10 +99,10 @@ def _process_start_anchor():
             uptime_s = float(f.read().split()[0])
         age_s = uptime_s - start_ticks / os.sysconf("SC_CLK_TCK")
         if age_s > 0:
-            return time.perf_counter() - age_s
+            return min(time.perf_counter() - age_s, PROGRAM_ENTERED)
     except Exception:
         pass
-    return time.perf_counter()
+    return PROGRAM_ENTERED
 
 
 #: perf_counter at process start (see _process_start_anchor) — the zero
@@ -123,8 +132,10 @@ def _instruments():
                 "another model/backend, refused at load)"),
             reg.gauge(
                 "time_to_first_step_ms",
-                "wall ms from process start to the first completed train "
-                "dispatch — the realized training cold-start tax"),
+                "wall ms from process start to the return of the first "
+                "train dispatch (traced, lowered, compiled or loaded, and "
+                "enqueued: the device has not finished it) — the realized "
+                "training cold-start tax"),
             reg.gauge(
                 "time_to_first_request_ms",
                 "wall ms from process start to the first served inference "
@@ -149,8 +160,10 @@ def event_counts():
 
 
 def note_first_step():
-    """Stamp ``time_to_first_step_ms`` once per process (first completed
-    train dispatch). Subsequent calls are two dict reads and a branch."""
+    """Stamp ``time_to_first_step_ms`` once per process, as the first
+    train dispatch returns: trace, lowering and compile or load are behind
+    it and the step is enqueued, not finished. Subsequent calls are two
+    dict reads and a branch."""
     return _note_first("step", "time_to_first_step_ms")
 
 
@@ -186,21 +199,83 @@ def reset_marks():
         _first_marks.clear()
 
 
+def startup_marks():
+    """{mark: ``perf_counter`` seconds}, the tracer's clock: where the
+    process started (``process_start``, :data:`PROCESS_T0`), where the
+    package's first line ran (``program_entered``) and, once a fit loop's
+    first dispatch has returned, ``first_step`` (the stamp of
+    :func:`note_first_step`: the step is enqueued, not finished)."""
+    marks = {"process_start": PROCESS_T0, "program_entered": PROGRAM_ENTERED}
+    step_ms = first_marks().get("step")
+    if step_ms is not None:
+        marks["first_step"] = PROCESS_T0 + step_ms / 1e3
+    return marks
+
+
 def status():
     """The /health ``compile_cache`` payload: persistent-cache dir, event
-    counts, and the realized cold-start gauges."""
+    counts, the realized cold-start gauges and the start-up marks."""
     marks = first_marks()
     return {
         "persistent_cache_dir": jax.config.jax_compilation_cache_dir,
         "events": event_counts(),
         "time_to_first_step_ms": marks.get("step"),
         "time_to_first_request_ms": marks.get("request"),
+        "startup_marks": startup_marks(),
     }
 
 
 # ---------------------------------------------------------------------------
 # persistent compilation cache (tier a)
 # ---------------------------------------------------------------------------
+
+#: jax's own clock reads of a compile's phases (``jax/_src/dispatch.py``
+#: ``log_elapsed_time``, ``jax/_src/compiler.py`` around the cache's read)
+#: and the span each becomes in the tracer's buffer
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load",
+}
+
+_compile_listener = None
+
+
+def _listen_to_compile_phases():
+    """Register, once a process, the listener that files jax's compile
+    phases as complete events in the tracer's buffer. jax reports a
+    length (from ``time.time()`` or ``time.monotonic()``); the tracer's
+    clock is ``perf_counter``: an event ends at the listener's own read
+    and starts the reported length before it, so it nests inside the
+    ``fit.dispatch`` or ``net.init`` span that caused it, a jitted
+    function traced inside another's trace inside that one's
+    ``compile.trace``, the cache's load inside its ``compile.backend``.
+    The function's name rides in the event's args (``fun``) and in no
+    registry label. All four events are ``record_event_duration_secs``'s,
+    so one listener serves them."""
+    global _compile_listener
+    if _compile_listener is not None:
+        return
+    from deeplearning4j_tpu import telemetry as _tm
+    tracer = _tm.get_tracer()
+
+    def on_duration(event, duration_secs, fun_name=None, **_):
+        if not _tm.enabled():
+            return
+        name = _COMPILE_SPANS.get(event)
+        if name is None:
+            return
+        dur_us = duration_secs * 1e6
+        tracer.add_complete(
+            name, tracer.now_us() - dur_us, dur_us,
+            None if fun_name is None else {"fun": fun_name})
+
+    with _lock:
+        if _compile_listener is None:
+            jax.monitoring.register_event_duration_secs_listener(on_duration)
+            _compile_listener = on_duration
+
 
 def enable_persistent_cache():
     """Turn on jax's persistent compilation cache and return its directory.
@@ -209,8 +284,11 @@ def enable_persistent_cache():
     no directory is set in code; otherwise the cache lives at
     :data:`DEFAULT_CACHE_DIR`. The min-compile-time and min-entry-size
     thresholds are opened so sub-second compiles persist too (jax's 1 s
-    default would skip most of a cold start's executables).
+    default would skip most of a cold start's executables). Also registers
+    the compile phases' listener (once a process; see
+    :func:`_listen_to_compile_phases`).
     """
+    _listen_to_compile_phases()
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = DEFAULT_CACHE_DIR

@@ -3,8 +3,9 @@
 four: train/serve x cold/warm).
 
 A leg measures the realized cold-start tax — wall time from process start
-(utils/compile_cache.PROCESS_T0, stamped at import) to the first completed
-train dispatch / first served inference request — with the instant-restart
+(utils/compile_cache.PROCESS_T0, stamped at import) to the return of the
+first train dispatch (the step compiled or loaded and enqueued, not
+finished) / first served inference request — with the instant-restart
 tier on:
 
 * both modes share one persistent compilation cache: bench.py starts
