@@ -451,6 +451,37 @@ def _gated_delta_case(name, *, b, t, hk, hv, d, interpret, tol):
     return _compare(name, kernels, _gd._chunked, (q, k, v, g, beta), tol)
 
 
+def _causal_conv_case(name, *, b, t, width, columns, taps, gates, split,
+                      interpret, tol):
+    """The short causal convolution's two kernels alone against the
+    ``jax.numpy`` form under autodiff: the result (whole or in ``split``
+    pieces, with what passes by behind it) and the gradients of the
+    projection and of the taps; ``gates`` the conv mixer's two, else the
+    gated-delta mixer's SiLU."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops import causal_conv as _cc
+
+    kp, kw = jax.random.split(jax.random.PRNGKey(t + columns))
+    p = jax.random.normal(kp, (b, t, width), jnp.float32)
+    w = 0.5 * jax.random.normal(kw, (columns, taps), jnp.float32)
+    kwargs = dict(gate_before=gates, gate_after=gates, activation=not gates)
+    if not interpret:
+        _expect(_cc.resolve_causal_conv(p.shape, w.shape, p.dtype, gates,
+                                        gates, split),
+                f"{name}: the dispatch gate does not admit this shape")
+
+    def kernels(p, w):
+        return _cc.causal_conv_kernels(p, w, split=split,
+                                       interpret=interpret, **kwargs)
+
+    def plain(p, w):
+        return _cc.causal_conv(p, w, split=split, **kwargs)
+
+    return _compare(name, kernels, plain, (p, w), tol)
+
+
 def _lstm_case(name, *, t, b, hsz, peephole, masked, interpret, tol):
     import jax
     import jax.numpy as jnp
@@ -569,10 +600,12 @@ def _hybrid_step_case(name, *, t, vocab, tol):
     experts, a short convolution with 8 more; routing fixed by the expert
     bias, so that no near-tie decides differently on the two branches): the
     compiled train step's
-    kernel count (the flash forward and backward, and thirteen kernels an
+    kernel count (the flash forward and backward, thirteen kernels an
     expert layer: nine grouped products, gate, up and down, each forward,
     for the input gradient and for the weight gradient, and the row
-    movement's two kernels twice each), then logits and every
+    movement's two kernels twice each; from PR 40 two a short
+    convolution, ``causal_conv_fwd`` and ``causal_conv_bwd``), then logits
+    and every
     gradient through the dispatch against the naive branch (attention in
     ``jax.numpy``, the grouped products as ``jax.lax.ragged_dot``, the
     routed layer's row movement as plain gathers of every slot under
@@ -604,7 +637,7 @@ def _hybrid_step_case(name, *, t, vocab, tol):
     step = net.make_train_step(donate=False)
     text = step.lower(net.params, net.state, net.opt_state, x, labels, 0,
                       jax.random.PRNGKey(0), None).compile().as_text()
-    n_calls, want = text.count("tpu_custom_call"), 2 + 2 * 13
+    n_calls, want = text.count("tpu_custom_call"), 2 + 2 * 13 + 2 * 2
     _expect(n_calls == want,
             f"{name}: compiled train step holds {n_calls} "
             f"tpu_custom_call(s), expected {want}")
@@ -636,7 +669,8 @@ def _gated_delta_step_case(name, *, t, vocab, tol):
     plain reference runs it, attention in ``jax.numpy``, the grouped
     products as ``jax.lax.ragged_dot``, the row movement as plain
     gathers). From PR 35 the recurrence is two kernels a gated-delta
-    layer, ``gdn_fwd`` and ``gdn_bwd``."""
+    layer, ``gdn_fwd`` and ``gdn_bwd``, from PR 40 its convolution with
+    SiLU two more, ``causal_conv_fwd`` and ``causal_conv_bwd``."""
     import jax
     import jax.numpy as jnp
 
@@ -658,12 +692,13 @@ def _gated_delta_step_case(name, *, t, vocab, tol):
     step = net.make_train_step(donate=False)
     text = step.lower(net.params, net.state, net.opt_state, x, labels, 0,
                       jax.random.PRNGKey(0), None).compile().as_text()
-    n_calls, want = text.count("tpu_custom_call"), 3 + 3 * 13 + 2 * 2
+    n_calls, want = text.count("tpu_custom_call"), 3 + 3 * 13 + 2 * (2 + 2)
     _expect(n_calls == want,
             f"{name}: compiled train step holds {n_calls} "
             f"tpu_custom_call(s), expected {want}")
     for kernel in ("flash_attn_fwd", "flash_attn_bwd_dkv",
-                   "flash_attn_bwd_dq", "gdn_fwd", "gdn_bwd"):
+                   "flash_attn_bwd_dq", "gdn_fwd", "gdn_bwd",
+                   "causal_conv_fwd", "causal_conv_bwd"):
         _expect(kernel in text, f"{name}: no {kernel} in the compiled step")
     state = net.state
 
@@ -705,7 +740,12 @@ def kernel_cases(interpret):
               dict(t=4, b=8, hsz=128, peephole=True, masked=True)),
              ("lstm_tiled_masked", dict(t=3, b=8, hsz=640, peephole=False,
                                         masked=True))],
-            [("gated_delta_kernels", dict(b=1, t=100, hk=1, hv=2, d=128))])
+            [("gated_delta_kernels", dict(b=1, t=100, hk=1, hv=2, d=128))],
+            [("causal_conv_silu", dict(b=2, t=100, width=512, columns=384,
+                                       taps=4, gates=False,
+                                       split=(128, 256))),
+             ("causal_conv_gates", dict(b=1, t=40, width=768, columns=256,
+                                        taps=3, gates=True, split=()))])
     return (
         [("flash_causal_t4096_h8_d64",
           dict(b=1, t=4096, h=8, d=64, causal=True, masked=False,
@@ -735,16 +775,24 @@ def kernel_cases(interpret):
          ("lstm_tiled_h1024_peephole_masked",
           dict(t=32, b=64, hsz=1024, peephole=True, masked=True))],
         [("gated_delta_t4096_h16_32_d128",   # qwen3next-train-t4096's call
-          dict(b=1, t=4096, hk=16, hv=32, d=128))])
+          dict(b=1, t=4096, hk=16, hv=32, d=128))],
+        [("causal_conv_silu_t4096_c8192",    # qwen3next-train-t4096's call
+          dict(b=1, t=4096, width=12288, columns=8192, taps=4, gates=False,
+               split=(2048, 2048, 4096))),
+         ("causal_conv_gates_t8192_c2048",   # lfm2-train-t8192's call
+          dict(b=1, t=8192, width=6144, columns=2048, taps=3, gates=True,
+               split=()))])
 
 
 def kernels_phase(*, interpret, tol):
     t0 = time.perf_counter()
-    flash, lstm, gated_delta = kernel_cases(interpret)
+    flash, lstm, gated_delta, causal_conv = kernel_cases(interpret)
     results = [_flash_case(n, interpret=interpret, tol=tol, **kw)
                for n, kw in flash]
     results += [_gated_delta_case(n, interpret=interpret, tol=tol, **kw)
                 for n, kw in gated_delta]
+    results += [_causal_conv_case(n, interpret=interpret, tol=tol, **kw)
+                for n, kw in causal_conv]
     if not interpret:  # through the dispatch: nothing to choose off the chip
         results.append(_looped_block_case(
             "looped_lm_t2048_h16_d128", b=2, t=2048, width=2048, h=16, d=128,

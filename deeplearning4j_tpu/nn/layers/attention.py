@@ -312,18 +312,6 @@ class MultiHeadAttention(ParamLayer):
         return y, state
 
 
-def _causal_taps(z, w):
-    """Depthwise causal convolution of ``z`` [B,T,C] with ``w`` [C,taps]:
-    zeros before the sequence's start, the last tap meeting the present
-    position."""
-    t, taps = z.shape[1], w.shape[1]
-    c = z * w[:, taps - 1]
-    for back in range(1, taps):
-        past = jnp.pad(z, ((0, 0), (back, 0), (0, 0)))[:, :t]
-        c = c + past * w[:, taps - 1 - back]
-    return c
-
-
 @register_config
 @dataclasses.dataclass(frozen=True)
 class ShortConv(ParamLayer):
@@ -332,8 +320,10 @@ class ShortConv(ParamLayer):
     x_``, a depthwise causal convolution of length ``kernel`` over time
     (zeros before the sequence's start; tap ``kernel - 1`` meets the
     present position), ``out = (C_ * c) W_out``. No bias and no
-    activation inside. Two shifted multiply-adds that XLA fuses; no
-    kernel."""
+    activation inside. Both gates and the taps are one op on the
+    in-projection's result as it lies (ops/causal_conv.py: two kernels
+    under a ``custom_vjp`` where the shape allows, the ``jax.numpy`` form
+    under autodiff elsewhere)."""
 
     n_out: int = 0
     kernel: int = 3
@@ -361,15 +351,15 @@ class ShortConv(ParamLayer):
         }
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.ops.causal_conv import causal_conv
         with jax.named_scope("short_conv"):
             b, t, _ = x.shape
             d = self.n_out
             bcx = matmul(x.reshape(b * t, -1), params["W_in"])
-            bcx = bcx.reshape(b, t, 3, d)
-            gate_b, gate_c, xx = bcx[:, :, 0], bcx[:, :, 1], bcx[:, :, 2]
-            z = gate_b * xx
-            c = _causal_taps(z, params["conv_w"].astype(z.dtype))
-            y = matmul((gate_c * c).reshape(b * t, d), params["W_out"])
+            gated, _ = causal_conv(bcx.reshape(b, t, 3 * d),
+                                   params["conv_w"], gate_before=True,
+                                   gate_after=True)
+            y = matmul(gated.reshape(b * t, d), params["W_out"])
             y = y.reshape(b, t, d)
             if mask is not None:
                 y = y * mask[..., None].astype(y.dtype)
@@ -389,7 +379,9 @@ class GatedDeltaNet(ParamLayer):
     whole and its heads in order; ``[q | k | v] = silu(conv(.))``, a
     depthwise causal convolution of ``conv_kernel`` taps over the channels
     in that order (zeros before the sequence's start, the last tap meeting
-    the present position, no bias); ``beta = sigmoid(b)``; ``g = -exp(A_log)
+    the present position, no bias), taps and SiLU one op on the
+    projection's leading columns as they lie (ops/causal_conv.py);
+    ``beta = sigmoid(b)``; ``g = -exp(A_log)
     softplus(a + dt_bias)`` in float32; ``q = l2norm(q) / sqrt(head_dim)``,
     ``k = l2norm(k)`` (``x rsqrt(sum x^2 + 1e-6)``); the recurrence
     ``S = exp(g) S; S += beta k (v - S^T k)^T; o = S^T q`` a value head in
@@ -445,6 +437,7 @@ class GatedDeltaNet(ParamLayer):
         }
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.ops.causal_conv import causal_conv
         from deeplearning4j_tpu.ops.gated_delta import gated_delta_rule
         with jax.named_scope("gdn"):
             b, t, _ = x.shape
@@ -454,13 +447,13 @@ class GatedDeltaNet(ParamLayer):
             x2 = x.reshape(b * t, -1)
             qkvz = matmul(x2, params["W_qkvz"]).reshape(b, t, -1)
             ba = matmul(x2, params["W_ba"]).reshape(b, t, 2, hv).astype(ad)
-            qkv, z = qkvz[..., :2 * kw + vw], qkvz[..., 2 * kw + vw:]
             with jax.named_scope("gdn_conv"):
-                qkv = jax.nn.silu(_causal_taps(
-                    qkv, params["conv_w"].astype(qkv.dtype)))
-            q = qkv[..., :kw].reshape(b, t, hk, -1)
-            k = qkv[..., kw:2 * kw].reshape(b, t, hk, -1)
-            v = qkv[..., 2 * kw:].reshape(b, t, hv, -1)
+                (q, k, v), z = causal_conv(qkvz, params["conv_w"],
+                                           activation=True,
+                                           split=(kw, kw, vw))
+            q = q.reshape(b, t, hk, -1)
+            k = k.reshape(b, t, hk, -1)
+            v = v.reshape(b, t, hv, -1)
 
             def l2norm(u):
                 return u * jax.lax.rsqrt(

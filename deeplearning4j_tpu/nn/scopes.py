@@ -9,7 +9,10 @@ what): `L<ii>.<LayerClass>` per MultiLayerNetwork layer,
 `V.<vertex>.<Class>` per ComputationGraph vertex, `attn` / `mlp` inside a
 transformer block (`short_conv` inside `attn` where that is the mixer,
 or `gdn` with `gdn_conv` and `gdn_core` inside it where the mixer is the
-gated delta rule; `attn_gate` around gated attention's output gate; `moe`
+gated delta rule: `gdn_conv` holds the taps and SiLU, which with
+`short_conv`'s gates and taps are the kernels `causal_conv_fwd` and,
+under `transpose(`, `causal_conv_bwd` of ops/causal_conv.py, each with
+the taps' transposition beside it; `attn_gate` around gated attention's output gate; `moe`
 inside `mlp` where the FFN is routed experts, with `moe_route` and
 `moe_experts` inside it and `moe_shared` beside them where the block has
 a shared expert), `loss`, `grad_norm`, `updater`, `health`, and

@@ -67,7 +67,8 @@ def test_kernels_phase_in_interpret_mode(kernel_dispatch):
                                   tol={"fwd": 1e-4, "bwd": 1e-4})
     names = {r["kernel"] for r in doc["results"]}
     assert {"flash_causal", "flash_padding_mask", "flash_block",
-            "gated_delta_kernels", "lstm_resident",
+            "gated_delta_kernels", "causal_conv_silu", "causal_conv_gates",
+            "lstm_resident",
             "lstm_resident_peephole_masked", "lstm_tiled_masked"} == names
 
 

@@ -1242,6 +1242,43 @@ class TestDefaultDispatchKernelsLowerForTpu:
         assert sorted(re.findall(r'kernel_name = "(gdn_[a-z]+)"', text)) == [
             "gdn_bwd", "gdn_fwd"]
 
+    @pytest.mark.parametrize(
+        "b,t,width,columns,taps,gate_before,gate_after,activation,split,"
+        "dtype", [
+            # the qwen3next-train-t4096 cell's: [q | k | v] of x W_qkvz
+            (1, 4096, 12288, 8192, 4, False, False, True,
+             (2048, 2048, 4096), jnp.float32),
+            # the lfm2-train-t8192 cell's: [B | C | x] of x W_in
+            (1, 8192, 6144, 2048, 3, True, True, False, (), jnp.float32),
+            # ragged T, packed rows, one gate, columns behind the parts
+            (2, 1000, 1024, 384, 4, True, False, True, (256, 128),
+             jnp.bfloat16),
+        ])
+    def test_causal_conv(self, b, t, width, columns, taps, gate_before,
+                         gate_after, activation, split, dtype):
+        """Through the dispatch: the convolution with its gates and SiLU
+        and its gradient are the two kernels, once each."""
+        from deeplearning4j_tpu.ops import causal_conv
+        p = jnp.zeros((b, t, width), dtype)
+        w = jnp.zeros((columns, taps), dtype)
+        assert causal_conv.resolve_causal_conv(
+            p.shape, w.shape, dtype, gate_before, gate_after, split)
+
+        def loss(p, w):
+            y, rest = causal_conv.causal_conv(
+                p, w, gate_before=gate_before, gate_after=gate_after,
+                activation=activation, split=split)
+            return sum(jnp.sum(a.astype(jnp.float32))
+                       for a in jax.tree_util.tree_leaves((y, rest)))
+        # with the value: the gradient alone needs no forward, its
+        # residuals being the inputs
+        text = _lower_for_tpu(jax.value_and_grad(loss, argnums=(0, 1)),
+                              p, w).as_text()
+        assert sorted(re.findall(
+            r'kernel_name = "(causal_conv_[a-z]+)"', text)) == [
+                "causal_conv_bwd", "causal_conv_fwd"]
+        assert "stablehlo.pad" not in text
+
     def test_flash_backward_shards_by_batch_under_the_declared_mesh(
             self, eight_devices):
         """A pallas_call does not partition itself as the scan did: under
