@@ -71,6 +71,7 @@ def follow_reference(ref, config, seed, plain, precision="f32"):
         params, m, v = adam_step(params, grads, m, v, step, opt)
         del grads
         losses.append(float(loss))
+    del m, v  # the starting weights are made again below: 12 B, not 20
     end_p = ref.program_layout(params, state)[0]
     start_p, start_s = ref.program_layout(ref.init(seed, model),
                                           ref.init_state(model))
@@ -91,7 +92,9 @@ def _names(tree):
 
 class ProgramReadings:
     """Collects the timed object's readings while set-up drives its first
-    steps; keeps vectors of norms only, no copy of any tree."""
+    steps; keeps vectors of norms only, no copy of any tree, and lets go
+    of the network with its last reading: the reference has the chip to
+    itself."""
 
     def __init__(self, net, opt):
         self.net, self.opt = net, opt
@@ -113,6 +116,7 @@ class ProgramReadings:
     def after_last(self, start_params):
         self.update_norms = np.asarray(
             _program.delta_norms(self.net.params, start_params))
+        self.net = self.start_state = None
 
     def readings(self):
         return {"losses": self.losses, "grad_norms": self.grad_norms,

@@ -1,4 +1,4 @@
-"""Two readings that back a claim in PERF.md and are not part of a run:
+"""Three readings that back a claim in PERF.md and are not part of a run:
 
 `python3 -m benchmark.diagnose plain-fit --workload <cell> --seed <n>`
     the cell's configuration trained as a user would, with none of the
@@ -14,10 +14,21 @@
     still ran beside is the step's real peak, to compare with the
     `memory_peak_bytes` a run reports (`bytes_in_use` plus
     `bytes_reserved`).
+
+`python3 -m benchmark.diagnose budget --workload <cell>`
+    what a configuration asks of a chip, from shapes alone (no chip
+    needed): the parameters the reference's `init` makes, 12 B of each for
+    the program's resident state (float32 weight and Adam's two moments)
+    and 16 B for the reference's (weight, gradient, two moments; its peak
+    of live arrays measured 20.4 B on the chip, PR 42). Each has to fit
+    with its own activations; they never share the chip
+    (`benchmark/README.md`, "How `correct` is decided"). Where a TPU is
+    present its `bytes_limit` stands beside them.
 """
 
 import argparse
 import itertools
+import math
 import sys
 import time
 
@@ -107,16 +118,37 @@ def memory(args):
     return 0
 
 
+def budget(args):
+    _, _, _, config = spec.load_cell(args.workload, args.root)
+    ref = spec.module("reference", config["reference"])
+    shapes = jax.eval_shape(lambda: ref.init(args.seed, config["model"]))
+    n = sum(math.prod(a.shape) for a in jax.tree_util.tree_leaves(shapes))
+    print(f"parameters {n:,} ({n / 1e6:.1f} M), counted from the shapes of "
+          f"the reference's init", flush=True)
+    for who, each in (("program", 12), ("reference", 16)):
+        print(f"{who} {each} B a parameter: {each * n:,} bytes "
+              f"({each * n / 2 ** 30:.3f} GiB), before its activations",
+              flush=True)
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        limit = dev.memory_stats()["bytes_limit"]
+        print(f"bytes_limit {limit:,} ({limit / 2 ** 30:.3f} GiB) on "
+              f"{dev.device_kind}: {limit - 16 * n:,} left for the "
+              f"reference's activations", flush=True)
+    return 0
+
+
 def main(argv=None, root=spec.REPO_ROOT):
     ap = argparse.ArgumentParser(prog="benchmark.diagnose")
-    ap.add_argument("what", choices=("plain-fit", "memory"))
+    ap.add_argument("what", choices=("plain-fit", "memory", "budget"))
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=1)
     ap.add_argument("--steps", type=int, default=80)
     ap.add_argument("--chunk-mib", type=int, default=128)
     args = ap.parse_args(argv)
     args.root = root
-    return {"plain-fit": plain_fit, "memory": memory}[args.what](args)
+    return {"plain-fit": plain_fit, "memory": memory,
+            "budget": budget}[args.what](args)
 
 
 if __name__ == "__main__":

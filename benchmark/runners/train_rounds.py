@@ -43,6 +43,20 @@ def _seeded(ref, seed, model):
     return ref.program_layout(ref.init(seed, model), ref.init_state(model))
 
 
+def _say_reference_start(devices):
+    """What the reference starts beside: the runtime's byte counters of
+    the fullest chip (None on the CPU: printed as 0) and the bytes of
+    every array still alive, which should be the `plain` batches."""
+    s = max((d.memory_stats() or {} for d in devices),
+            key=lambda held: held.get("bytes_in_use", 0)
+            + held.get("bytes_reserved", 0))
+    live = sum(a.nbytes for a in jax.live_arrays())
+    print(f"reference_start bytes_in_use {s.get('bytes_in_use', 0)} "
+          f"bytes_reserved {s.get('bytes_reserved', 0)} "
+          f"bytes_limit {s.get('bytes_limit', 0)} "
+          f"live_arrays_bytes {live}", flush=True)
+
+
 def run(ctx):
     wl, config, seed = ctx.workload, ctx.config, ctx.seed
     ref = spec.module("reference", config["reference"])
@@ -101,6 +115,7 @@ def run(ctx):
     plain = traffic["plain"]
     del driver, items, traffic, net
     gc.collect()
+    _say_reference_start(ctx.devices)
 
     t_ref = time.perf_counter()
     want = check_train.follow_reference(ref, config, seed, plain)
