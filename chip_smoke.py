@@ -452,34 +452,69 @@ def _gated_delta_case(name, *, b, t, hk, hv, d, interpret, tol):
 
 
 def _causal_conv_case(name, *, b, t, width, columns, taps, gates, split,
-                      interpret, tol):
+                      interpret, tol, bias=False):
     """The short causal convolution's two kernels alone against the
     ``jax.numpy`` form under autodiff: the result (whole or in ``split``
     pieces, with what passes by behind it) and the gradients of the
-    projection and of the taps; ``gates`` the conv mixer's two, else the
+    projection, of the taps and, with ``bias`` (the Mamba-2 mixer's), of
+    the bias a column; ``gates`` the conv mixer's two, else the
     gated-delta mixer's SiLU."""
     import jax
     import jax.numpy as jnp
 
     from deeplearning4j_tpu.ops import causal_conv as _cc
 
-    kp, kw = jax.random.split(jax.random.PRNGKey(t + columns))
+    kp, kw, kb = jax.random.split(jax.random.PRNGKey(t + columns), 3)
     p = jax.random.normal(kp, (b, t, width), jnp.float32)
     w = 0.5 * jax.random.normal(kw, (columns, taps), jnp.float32)
+    more = (jax.random.normal(kb, (columns,), jnp.float32),) if bias else ()
     kwargs = dict(gate_before=gates, gate_after=gates, activation=not gates)
     if not interpret:
         _expect(_cc.resolve_causal_conv(p.shape, w.shape, p.dtype, gates,
                                         gates, split),
                 f"{name}: the dispatch gate does not admit this shape")
 
-    def kernels(p, w):
-        return _cc.causal_conv_kernels(p, w, split=split,
+    def kernels(p, w, *bias):
+        return _cc.causal_conv_kernels(p, w, *bias, split=split,
                                        interpret=interpret, **kwargs)
 
-    def plain(p, w):
-        return _cc.causal_conv(p, w, split=split, **kwargs)
+    def plain(p, w, *bias):
+        return _cc.causal_conv(p, w, *bias, split=split, **kwargs)
 
-    return _compare(name, kernels, plain, (p, w), tol)
+    return _compare(name, kernels, plain, (p, w, *more), tol)
+
+
+def _ssd_case(name, *, b, t, h, p, g, n, chunk, tol):
+    """The selective state-space scan's chunkwise form (``ops/ssd.py``,
+    whatever ``resolve_ssd`` chooses here) against the recurrence run
+    position by position (the benchmark's plain reference's): outputs and
+    the six gradients, steps from all but kept to all but forgotten
+    inside a few tokens."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference import nemotron_h as _plain
+    from deeplearning4j_tpu.ops import ssd as _ssd
+
+    keys = jax.random.split(jax.random.PRNGKey(t + n), 5)
+    x = jax.random.normal(keys[0], (b, t, h, p), jnp.float32)
+    dt = jax.nn.softplus(3.0 * jax.random.normal(keys[1], (b, t, h),
+                                                 jnp.float32))
+    a = -jnp.arange(1, h + 1, dtype=jnp.float32) / 8.0
+    bm, cm = (jax.random.normal(key, (b, t, g, n), jnp.float32) * n ** -0.5
+              for key in keys[2:4])
+    d = jax.random.normal(keys[4], (h,), jnp.float32)
+
+    def chunkwise(x, dt, a, bm, cm, d):
+        return _ssd.ssd(x, dt, a, bm, cm, d, chunk=chunk)
+
+    def position_by_position(x, dt, a, bm, cm, d):
+        bh, ch = (jnp.repeat(u, h // g, axis=2) for u in (bm, cm))
+        return jax.vmap(lambda x, dt, bh, ch: _plain.selective_scan(
+            x, dt, a, bh, ch, d))(x, dt, bh, ch)
+
+    return _compare(name, chunkwise, position_by_position,
+                    (x, dt, a, bm, cm, d), tol)
 
 
 def _lstm_case(name, *, t, b, hsz, peephole, masked, interpret, tol):
@@ -745,7 +780,12 @@ def kernel_cases(interpret):
                                        taps=4, gates=False,
                                        split=(128, 256))),
              ("causal_conv_gates", dict(b=1, t=40, width=768, columns=256,
-                                        taps=3, gates=True, split=()))])
+                                        taps=3, gates=True, split=())),
+             ("causal_conv_bias_silu", dict(b=1, t=40, width=384,
+                                            columns=384, taps=4, gates=False,
+                                            split=(128, 256), bias=True))],
+            [("ssd_chunkwise", dict(b=1, t=100, h=4, p=8, g=2, n=16,
+                                    chunk=32))])
     return (
         [("flash_causal_t4096_h8_d64",
           dict(b=1, t=4096, h=8, d=64, causal=True, masked=False,
@@ -781,18 +821,24 @@ def kernel_cases(interpret):
                split=(2048, 2048, 4096))),
          ("causal_conv_gates_t8192_c2048",   # lfm2-train-t8192's call
           dict(b=1, t=8192, width=6144, columns=2048, taps=3, gates=True,
-               split=()))])
+               split=())),
+         ("causal_conv_bias_silu_t4096_c6144",   # nemotron3nano's call
+          dict(b=1, t=4096, width=6144, columns=6144, taps=4, gates=False,
+               split=(4096, 1024, 1024), bias=True))],
+        [("ssd_t4096_h64_p64_g8_n128",       # nemotron3nano-train-packed's
+          dict(b=1, t=4096, h=64, p=64, g=8, n=128, chunk=128))])
 
 
 def kernels_phase(*, interpret, tol):
     t0 = time.perf_counter()
-    flash, lstm, gated_delta, causal_conv = kernel_cases(interpret)
+    flash, lstm, gated_delta, causal_conv, ssd = kernel_cases(interpret)
     results = [_flash_case(n, interpret=interpret, tol=tol, **kw)
                for n, kw in flash]
     results += [_gated_delta_case(n, interpret=interpret, tol=tol, **kw)
                 for n, kw in gated_delta]
     results += [_causal_conv_case(n, interpret=interpret, tol=tol, **kw)
                 for n, kw in causal_conv]
+    results += [_ssd_case(n, tol=tol, **kw) for n, kw in ssd]
     if not interpret:  # through the dispatch: nothing to choose off the chip
         results.append(_looped_block_case(
             "looped_lm_t2048_h16_d128", b=2, t=2048, width=2048, h=16, d=128,

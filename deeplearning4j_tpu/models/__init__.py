@@ -6,7 +6,7 @@ from deeplearning4j_tpu.models.resnet import (  # noqa: F401
 from deeplearning4j_tpu.models.vgg import vgg16, vgg19  # noqa: F401
 from deeplearning4j_tpu.models.misc import (  # noqa: F401
     alexnet, darknet19, gated_delta_moe_lm, hybrid_moe_lm, looped_lm,
-    simple_cnn,
+    simple_cnn, state_space_moe_lm,
     text_generation_lstm, tiny_yolo, transformer_lm,
 )
 from deeplearning4j_tpu.models.inception import (  # noqa: F401

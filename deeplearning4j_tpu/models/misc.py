@@ -164,34 +164,41 @@ def looped_lm(vocab_size, n_layers=4, d_model=2048, n_heads=16, head_dim=None,
     )
 
 
-#: `layer_types` entry -> `TransformerBlock.mixer`
+#: `layer_types` entry -> `TransformerBlock.mixer`; the last is the
+#: single-part layers' FFN without a mixer
 _MIXERS = {"conv": "short_conv", "full_attention": "attention",
-           "linear_attention": "gated_delta"}
+           "linear_attention": "gated_delta", "mamba2": "mamba2",
+           "ffn": "none"}
 
 
 def _hybrid_decoder(vocab_size, layer_types, num_dense_layers, d_model,
-                    seq_len, block, dense, moe, final_norm, updater, seed):
+                    seq_len, block, dense, moe, final_norm, updater, seed,
+                    single_part=False):
     """The one loop behind the hybrid decoders: a token embedding, a
     pre-norm ``TransformerBlock`` a layer whose mixer is what
     ``layer_types[i]`` names (``"conv"``: the gated short convolution,
     ``"full_attention"``: softmax attention, ``"linear_attention"``: the
-    gated delta rule) and whose FFN takes the fields ``dense`` for the
-    first ``num_dense_layers`` layers and ``moe`` for the rest, on top of
-    ``block``'s; ``final_norm`` and an untied softmax head under
-    ``sparse_mcxent``."""
+    gated delta rule, ``"mamba2"``: the state-space mixer, ``"ffn"``:
+    none) and whose FFN takes the fields ``dense`` for the first
+    ``num_dense_layers`` layers and ``moe`` for the rest, on top of
+    ``block``'s; with ``single_part`` a layer is one part alone, a mixer
+    without an FFN or (``"ffn"``) the reverse; ``final_norm`` and an
+    untied softmax head under ``sparse_mcxent``."""
     from deeplearning4j_tpu.nn.initializers import Distribution
     init = Distribution(kind="normal", std=0.02)
     blocks = []
     for i, kind in enumerate(layer_types):
         if kind not in _MIXERS:
             raise ValueError(
-                f"layer_types[{i}] is one of {sorted(_MIXERS)} (the gated "
-                "short convolution, softmax attention, the gated delta "
-                f"rule), got {kind!r}")
-        blocks.append(L.TransformerBlock(
-            n_out=d_model, causal=True, activation="silu", norm="rms",
-            bias=False, mixer=_MIXERS[kind], weight_init=init,
-            **{**block, **(dense if i < num_dense_layers else moe)}))
+                f"layer_types[{i}] is one of {sorted(_MIXERS)}, got "
+                f"{kind!r}")
+        ffn = dense if i < num_dense_layers else moe
+        if single_part and kind != "ffn":
+            ffn = {"ffn": "none"}
+        blocks.append(L.TransformerBlock(**{
+            "n_out": d_model, "causal": True, "activation": "silu",
+            "norm": "rms", "bias": False, "mixer": _MIXERS[kind],
+            "weight_init": init, **block, **ffn}))
     return NeuralNetConfig(
         seed=seed,
         updater=updater or U.Adam(learning_rate=3e-4)).list(
@@ -281,3 +288,59 @@ def gated_delta_moe_lm(vocab_size, n_layers=48, full_attention_interval=4,
              "shared_expert_width": shared_expert_width},
         final_norm=L.RMSNorm(eps=norm_eps, zero_centered=True),
         updater=updater, seed=seed)
+
+
+#: `hybrid_override_pattern` character -> `_hybrid_decoder`'s layer kind
+_PATTERN = {"M": "mamba2", "*": "full_attention", "E": "ffn"}
+
+#: NVIDIA-Nemotron-3-Nano-30B-A3B's 52 layers
+NEMOTRON_3_NANO_PATTERN = \
+    "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+def state_space_moe_lm(vocab_size, pattern=NEMOTRON_3_NANO_PATTERN,
+                       d_model=2688, n_heads=32, n_kv_heads=2, head_dim=128,
+                       ssm_heads=64, ssm_head_dim=64, ssm_groups=8,
+                       ssm_state=128, ssm_chunk=128, conv_kernel=4,
+                       expert_width=1856, shared_expert_width=3712,
+                       n_experts=128, top_k=6, routed_scale=2.5,
+                       experts_held=(), seq_len=4096, norm_eps=1e-5,
+                       updater=None, seed=12345):
+    """Hybrid state-space / attention mixture-of-experts decoder (the
+    Nemotron-H family's ``nemotron_h``; net-new), through the loop
+    ``hybrid_moe_lm`` runs: every layer is ONE part behind one RMSNorm,
+    ``h <- h + part(norm(h))``, and ``pattern`` names it a character a
+    layer: ``M`` a Mamba-2 mixer (``Mamba2Mixer``), ``*`` causal
+    grouped-query attention with no positional encoding, no QK-norm and
+    no gate, ``E`` a mixture of ``n_experts`` UNGATED experts
+    ``relu(x Wu)^2 Wd`` (top-``top_k`` by sigmoid scores plus a correction
+    bias that moves the selection only, weights renormalised over the
+    selected and times ``routed_scale``) plus one shared expert of the
+    same form over every token, without a gate; a final RMSNorm and an
+    untied softmax head under ``sparse_mcxent``. No bias but the
+    convolution's. The Mamba-2 out-projections start divided by the root
+    of the depth (``rescale_prenorm_residual``). ``experts_held`` as
+    ``hybrid_moe_lm``'s. The defaults
+    are NVIDIA-Nemotron-3-Nano-30B-A3B's published widths and its 52-layer
+    pattern."""
+    unknown = sorted(set(pattern) - set(_PATTERN))
+    if unknown:
+        raise ValueError(f"pattern is made of {sorted(_PATTERN)} (Mamba-2, "
+                         f"attention, experts), got {unknown}")
+    return _hybrid_decoder(
+        vocab_size, [_PATTERN[c] for c in pattern], 0, d_model, seq_len,
+        block={"n_heads": n_heads, "norm_eps": norm_eps, "head_dim": head_dim,
+               "n_kv_heads": n_kv_heads, "conv_kernel": conv_kernel,
+               "ssm_heads": ssm_heads, "ssm_head_dim": ssm_head_dim,
+               "ssm_groups": ssm_groups, "ssm_state": ssm_state,
+               "ssm_chunk": ssm_chunk, "ssm_out_scale": len(pattern) ** -0.5,
+               "activation": "relu2"},
+        dense={},
+        moe={"ffn": "moe", "ffn_width": expert_width,
+             "n_experts": n_experts, "top_k": top_k,
+             "experts_held": tuple(experts_held),
+             "routed_scale": routed_scale, "expert_gated": False,
+             "shared_expert_width": shared_expert_width,
+             "shared_expert_gate": False},
+        final_norm=L.RMSNorm(eps=norm_eps), updater=updater, seed=seed,
+        single_part=True)

@@ -96,6 +96,11 @@ def thresholdedrelu(x, theta=1.0):
     return jnp.where(x > theta, x, 0.0)
 
 
+def relu2(x):
+    """Squared ReLU (So et al. 2021, "Primer"; the Nemotron-H experts)."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def mish(x):
     return x * jnp.tanh(jax.nn.softplus(x))
 
@@ -105,6 +110,7 @@ _CATALOG = {
     "linear": identity,
     "relu": relu,
     "relu6": relu6,
+    "relu2": relu2,
     "leakyrelu": leakyrelu,
     "elu": elu,
     "selu": selu,

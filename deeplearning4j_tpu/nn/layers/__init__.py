@@ -23,8 +23,8 @@ from deeplearning4j_tpu.nn.layers.vae import (  # noqa: F401
 from deeplearning4j_tpu.nn.layers.objdetect import Yolo2OutputLayer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.centerloss import CenterLossOutputLayer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.attention import (  # noqa: F401
-    GatedDeltaNet, LayerNormalization, MultiHeadAttention, RMSNorm, ShortConv,
-    TransformerBlock,
+    GatedDeltaNet, LayerNormalization, Mamba2Mixer, MultiHeadAttention,
+    RMSNorm, ShortConv, TransformerBlock,
 )
 from deeplearning4j_tpu.nn.layers.looped import (  # noqa: F401
     LoopedLMOutputLayer, LoopedStack,

@@ -476,6 +476,132 @@ class GatedDeltaNet(ParamLayer):
             return y, state
 
 
+def gated_group_norm(y, z, w, groups, eps):
+    """Mamba-2's gated RMS norm over [..., F]: ``u = y * silu(z)``, each
+    of the ``groups`` runs of ``F / groups`` channels normed by its own
+    root mean square (``u / sqrt(mean(u^2) + eps)``), times the gain ``w``
+    [F]."""
+    with jax.named_scope("rmsnorm"):
+        u = y * jax.nn.silu(z)
+        g = u.reshape(*u.shape[:-1], groups, -1)
+        g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True)
+                              + eps)
+        return g.reshape(u.shape) * w
+
+
+@register_config
+@dataclasses.dataclass(frozen=True)
+class Mamba2Mixer(ParamLayer):
+    """Mamba-2's selective state-space mixer over [B,T,F] (Dao & Gu,
+    arXiv:2405.21060; the ``M`` layers of the Nemotron-H family):
+    ``heads`` heads of ``head_dim`` (``d_inner`` = their product),
+    ``groups`` groups of ``B`` and ``C`` of ``state`` each, head ``h``
+    reading group ``h // (heads / groups)``.
+
+    ``[z | x B C | dt] = u W_in`` (widths ``d_inner | d_inner + 2 groups
+    state | heads``; one matrix, three products, so that each part lies
+    where its consumer reads it); ``[x | B | C] = silu(conv(.) + conv_b)``,
+    a depthwise causal convolution of ``conv_kernel`` taps with a bias
+    (ops/causal_conv.py); ``dt = softplus(dt + dt_bias)`` and ``A =
+    -exp(A_log)`` a head, in float32; the recurrence ``S = exp(dt A) S +
+    dt x B^T; y = S C + D x`` in its chunkwise form at ``chunk`` positions
+    (ops/ssd.py); the grouped gated norm ``gated_group_norm(y, z)`` with
+    gain ``norm_w``; ``out = y W_out``. No projection bias.
+
+    The layer's own initialisation: ``A_log = log(1..heads)``, ``D`` 1,
+    ``dt_bias`` the inverse softplus of ``exp(U(log 1e-3, log 0.1))``
+    floored at 1e-4 (``DT_RANGE``, ``DT_FLOOR``: the family's published
+    ``time_step_*``), ``conv_b`` 0, ``norm_w`` 1, ``W_out`` times
+    ``out_scale`` (the family divides it by the root of the depth)."""
+
+    n_out: int = 0
+    heads: int = 64
+    head_dim: int = 64
+    groups: int = 8
+    state: int = 128
+    conv_kernel: int = 4
+    chunk: int = 128
+    norm_eps: float = 1e-5
+    out_scale: float = 1.0
+    weight_init: object = dataclasses.field(default="xavier", kw_only=True)
+
+    input_family = _inputs.RecurrentType
+
+    WEIGHT_KEYS = ("W_in", "conv_w", "W_out")
+    BIAS_KEYS = ("conv_b", "dt_bias")
+
+    DT_RANGE = (1e-3, 0.1)
+    DT_FLOOR = 1e-4
+
+    def _widths(self):
+        """(d_inner, the convolution's channels)."""
+        if self.heads % self.groups:
+            raise ValueError(f"{self.heads} heads are no multiple of "
+                             f"{self.groups} groups")
+        inner = self.heads * self.head_dim
+        return inner, inner + 2 * self.groups * self.state
+
+    def output_type(self, input_type):
+        return _inputs.RecurrentType(self.n_out, input_type.timesteps)
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        n_in = input_type.size
+        inner, conv = self._widths()
+        k1, k2, k3, k4 = jax.random.split(key, 4)
+
+        def weight(k, shape, fan_in, fan_out):
+            return _init.init_weight(self.weight_init, k, shape, fan_in,
+                                     fan_out, dtype)
+
+        proj = inner + conv + self.heads
+        lo, hi = self.DT_RANGE
+        dt = jnp.exp(jax.random.uniform(k3, (self.heads,), dtype,
+                                        jnp.log(lo), jnp.log(hi)))
+        dt = jnp.maximum(dt, self.DT_FLOOR)
+        return {
+            "W_in": weight(k1, (n_in, proj), n_in, proj),
+            "conv_w": weight(k2, (conv, self.conv_kernel), self.conv_kernel,
+                             1),
+            "conv_b": jnp.zeros((conv,), dtype),
+            "A_log": jnp.log(jnp.arange(1, self.heads + 1, dtype=dtype)),
+            "D": jnp.ones((self.heads,), dtype),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),   # softplus^-1(dt)
+            "norm_w": jnp.ones((inner,), dtype),
+            "W_out": self.out_scale * weight(k4, (inner, self.n_out), inner,
+                                             self.n_out),
+        }
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.ops.causal_conv import causal_conv
+        from deeplearning4j_tpu.ops.ssd import ssd
+        with jax.named_scope("ssm"):
+            b, t, _ = x.shape
+            inner, conv = self._widths()
+            h, g = self.heads, self.groups
+            _, ad = _dtypes.compute_dtypes_for(x.dtype)
+            x2 = x.reshape(b * t, -1)
+            w_in = params["W_in"]
+            z = matmul(x2, w_in[:, :inner]).reshape(b, t, inner)
+            xbc = matmul(x2, w_in[:, inner:inner + conv]).reshape(b, t, conv)
+            dt = matmul(x2, w_in[:, inner + conv:]).reshape(b, t, h)
+            with jax.named_scope("ssm_conv"):
+                (xs, bs, cs), _ = causal_conv(
+                    xbc, params["conv_w"], params["conv_b"], activation=True,
+                    split=(inner, g * self.state, g * self.state))
+            dt = jax.nn.softplus(dt.astype(ad) + params["dt_bias"].astype(ad))
+            y = ssd(xs.reshape(b, t, h, -1), dt,
+                    -jnp.exp(params["A_log"].astype(ad)),
+                    bs.reshape(b, t, g, -1), cs.reshape(b, t, g, -1),
+                    params["D"], chunk=self.chunk)
+            y = gated_group_norm(y.reshape(b, t, inner), z, params["norm_w"],
+                                 g, self.norm_eps)
+            y = matmul(y.reshape(b * t, inner), params["W_out"])
+            y = y.reshape(b, t, self.n_out)
+            if mask is not None:
+                y = y * mask[..., None].astype(y.dtype)
+            return y, state
+
+
 @register_config
 @dataclasses.dataclass(frozen=True)
 class TransformerBlock(Layer):
@@ -490,22 +616,33 @@ class TransformerBlock(Layer):
     each residual add (``ln1_post`` / ``ln2_post``); ``bias=False`` drops
     every bias; ``rope_theta``, ``rotary_dim``, ``head_dim``,
     ``n_kv_heads``, ``qk_norm`` (with ``norm_eps``) and ``attn_gate`` go to
-    the attention; ``mixer`` "attention" | "short_conv" | "gated_delta"
-    puts in the attention's place a ``ShortConv`` of length
-    ``conv_kernel`` (parameters under ``conv``, not ``mha``) or a
+    the attention; ``mixer`` "attention" | "short_conv" | "gated_delta" |
+    "mamba2" puts in the attention's place a ``ShortConv`` of length
+    ``conv_kernel`` (parameters under ``conv``, not ``mha``), a
     ``GatedDeltaNet`` of ``linear_k_heads`` key and ``linear_v_heads``
     value heads of ``linear_head_dim`` / ``linear_v_head_dim``, its
-    convolution ``conv_kernel`` taps (parameters under ``gdn``); ``ffn``
-    "mlp" | "gated" (``act(x Wg) * (x Wu)`` then ``Wd``) | "moe" of width
-    ``ffn_width`` (None = ``n_out * mlp_ratio``). ``"moe"`` is a dropless
-    top-``top_k`` router (``router`` "sigmoid" | "softmax") over
-    ``n_experts`` gated experts of that width, of which this layer holds
+    convolution ``conv_kernel`` taps (parameters under ``gdn``), or a
+    ``Mamba2Mixer`` of ``ssm_heads`` heads of ``ssm_head_dim``,
+    ``ssm_groups`` groups of ``ssm_state``, chunks of ``ssm_chunk``, its
+    convolution ``conv_kernel`` taps and its out-projection initialised
+    times ``ssm_out_scale`` (parameters under ``ssm``); ``ffn`` "mlp" |
+    "gated" (``act(x Wg) * (x Wu)`` then ``Wd``) | "moe" of width
+    ``ffn_width`` (None = ``n_out * mlp_ratio``). **Either half may be
+    absent**: ``mixer="none"`` is a block of norm -> FFN -> residual
+    alone, ``ffn="none"`` one of norm -> mixer -> residual alone (the
+    single-part layers of the Nemotron-H family); the absent half has no
+    norm, no parameters, no scope and no residual add. ``"moe"`` is a
+    dropless top-``top_k`` router (``router`` "sigmoid" | "softmax") over
+    ``n_experts`` experts of that width, gated (``act(x Wg) * (x Wu)``
+    then ``Wd``) or, with ``expert_gated=False``, ungated (``act(x Wu)``
+    then ``Wd``, no ``moe_Wg``), of which this layer holds
     ``experts_held`` = (first, end) (() = all of them) and computes their
     part of the result (``moe.routed_experts``); the last step's
     ``moe_load`` / ``moe_elsewhere`` and the sigmoid router's
     ``expert_bias`` live in the layer's state. ``shared_expert_width`` > 0
-    adds the block's shared expert, a gated FFN of that width over every
-    token times ``sigmoid(x w_sg)`` (``moe_shared_*``; held whole whatever
+    adds the block's shared expert, an FFN of that width and the experts'
+    form over every token, times ``sigmoid(x w_sg)`` unless
+    ``shared_expert_gate=False`` (``moe_shared_*``; held whole whatever
     ``experts_held`` says, as every chip of a deployment would)."""
 
     n_out: int = 0
@@ -539,11 +676,19 @@ class TransformerBlock(Layer):
     linear_v_heads: int = 0
     linear_head_dim: int = 0
     linear_v_head_dim: int | None = None
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_chunk: int = 128
+    ssm_out_scale: float = 1.0
+    expert_gated: bool = True
+    shared_expert_gate: bool = True
 
     input_family = _inputs.RecurrentType
 
     MIXER_KEYS = {"attention": "mha", "short_conv": "conv",
-                  "gated_delta": "gdn"}
+                  "gated_delta": "gdn", "mamba2": "ssm"}
 
     def _held(self):
         """(first, end) of the experts this layer holds."""
@@ -570,8 +715,17 @@ class TransformerBlock(Layer):
 
     def _parts(self):
         """(norm, mixer, norm); the mixer's parameters sit under
-        ``_mixer_key()``."""
-        if self.mixer == "short_conv":
+        ``_mixer_key()``, and it is None where the block has none."""
+        if self.mixer == "none":
+            mixer = None
+        elif self.mixer == "mamba2":
+            mixer = Mamba2Mixer(
+                n_out=self.n_out, heads=self.ssm_heads,
+                head_dim=self.ssm_head_dim, groups=self.ssm_groups,
+                state=self.ssm_state, conv_kernel=self.conv_kernel,
+                chunk=self.ssm_chunk, out_scale=self.ssm_out_scale,
+                weight_init=self.weight_init, **self._eps("norm_eps"))
+        elif self.mixer == "short_conv":
             mixer = ShortConv(n_out=self.n_out, kernel=self.conv_kernel,
                               weight_init=self.weight_init)
         elif self.mixer == "gated_delta":
@@ -591,8 +745,9 @@ class TransformerBlock(Layer):
                 rotary_dim=self.rotary_dim, gate=self.attn_gate,
                 weight_init=self.weight_init, **self._eps("qk_norm_eps"))
         else:
-            raise ValueError("mixer is 'attention', 'short_conv' or "
-                             f"'gated_delta', got {self.mixer!r}")
+            raise ValueError("mixer is 'attention', 'short_conv', "
+                             "'gated_delta', 'mamba2' or 'none', got "
+                             f"{self.mixer!r}")
         return self._norm(), mixer, self._norm()
 
     def _mixer_key(self):
@@ -604,10 +759,14 @@ class TransformerBlock(Layer):
     def init(self, key, input_type, dtype=jnp.float32):
         assert input_type.size == self.n_out, \
             "TransformerBlock requires input size == n_out (residual)"
-        if self.ffn not in ("mlp", "gated", "moe"):
-            raise ValueError("ffn is 'mlp', 'gated' or 'moe', got "
+        if self.ffn not in ("mlp", "gated", "moe", "none"):
+            raise ValueError("ffn is 'mlp', 'gated', 'moe' or 'none', got "
                              f"{self.ffn!r}")
-        if self.bias and self.ffn != "mlp":
+        if self.ffn == self.mixer == "none":
+            raise ValueError("a block has a mixer, an FFN or both")
+        if self.sandwich and "none" in (self.ffn, self.mixer):
+            raise ValueError("the sandwich norms belong to a whole block")
+        if self.bias and self.ffn not in ("mlp", "none"):
             raise ValueError(f"the {self.ffn} FFN has no biases: set "
                              "bias=False")
         if self.router not in ("sigmoid", "softmax"):
@@ -624,9 +783,12 @@ class TransformerBlock(Layer):
             return _init.init_weight(self.weight_init, k, (n_in, n_out),
                                      n_in, n_out, dtype)
 
-        p = {"ln1": ln1.init(k1, it, dtype),
-             self._mixer_key(): mha.init(k1, it, dtype),
-             "ln2": ln2.init(k2, it, dtype)}
+        p = {}
+        if mha is not None:
+            p.update({"ln1": ln1.init(k1, it, dtype),
+                      self._mixer_key(): mha.init(k1, it, dtype)})
+        if self.ffn != "none":
+            p["ln2"] = ln2.init(k2, it, dtype)
         if self.sandwich:
             p["ln1_post"] = ln1.init(k1, it, dtype)
             p["ln2_post"] = ln2.init(k2, it, dtype)
@@ -644,20 +806,23 @@ class TransformerBlock(Layer):
                                   jax.random.split(k, end - first)])
 
             p["moe_router"] = weight(kr, self.n_out, self.n_experts)
-            p["moe_Wg"] = experts(kg, self.n_out, hidden)
+            if self.expert_gated:
+                p["moe_Wg"] = experts(kg, self.n_out, hidden)
             p["moe_Wu"] = experts(ku, self.n_out, hidden)
             p["moe_Wd"] = experts(k4, hidden, self.n_out)
             if self.shared_expert_width:
                 ks = jax.random.split(jax.random.fold_in(k3, 1), 4)
                 fs = self.shared_expert_width
-                p["moe_shared_Wg"] = weight(ks[0], self.n_out, fs)
+                if self.expert_gated:
+                    p["moe_shared_Wg"] = weight(ks[0], self.n_out, fs)
                 p["moe_shared_Wu"] = weight(ks[1], self.n_out, fs)
                 p["moe_shared_Wd"] = weight(ks[2], fs, self.n_out)
-                p["moe_shared_gate"] = weight(ks[3], self.n_out, 1)
-        else:
+                if self.shared_expert_gate:
+                    p["moe_shared_gate"] = weight(ks[3], self.n_out, 1)
+        elif self.ffn == "mlp":
             p["mlp_W1"] = weight(k3, self.n_out, hidden)
             p["mlp_W2"] = weight(k4, hidden, self.n_out)
-        if self.bias:
+        if self.bias and self.ffn == "mlp":
             p["mlp_b1"] = jnp.zeros((hidden,), dtype)
             p["mlp_b2"] = jnp.zeros((self.n_out,), dtype)
         return p
@@ -686,17 +851,24 @@ class TransformerBlock(Layer):
         act = _act.get(self.activation)
         with jax.named_scope("moe"):
             y, load, elsewhere = _moe.routed_experts(
-                h, params["moe_router"], params["moe_Wg"], params["moe_Wu"],
-                params["moe_Wd"], state.get("expert_bias"),
-                top_k=self.top_k, held=self._held(),
-                scale=self.routed_scale, act=act, score=self.router)
+                h, params["moe_router"], params.get("moe_Wg"),
+                params["moe_Wu"], params["moe_Wd"],
+                state.get("expert_bias"), top_k=self.top_k,
+                held=self._held(), scale=self.routed_scale, act=act,
+                score=self.router)
             if self.shared_expert_width:
                 with jax.named_scope("moe_shared"):
-                    m = (act(matmul(h, params["moe_shared_Wg"]))
-                         * matmul(h, params["moe_shared_Wu"]))
-                    gate = jax.nn.sigmoid(
-                        matmul(h, params["moe_shared_gate"]))
-                    y = y + gate * matmul(m, params["moe_shared_Wd"])
+                    if self.expert_gated:
+                        m = (act(matmul(h, params["moe_shared_Wg"]))
+                             * matmul(h, params["moe_shared_Wu"]))
+                    else:
+                        m = act(matmul(h, params["moe_shared_Wu"]))
+                    if self.shared_expert_gate:
+                        gate = jax.nn.sigmoid(
+                            matmul(h, params["moe_shared_gate"]))
+                        y = y + gate * matmul(m, params["moe_shared_Wd"])
+                    else:
+                        y = y + matmul(m, params["moe_shared_Wd"])
         dt = state["moe_load"].dtype
         return y, {**state, "moe_load": load.astype(dt),
                    "moe_elsewhere": elsewhere.astype(dt)}
@@ -714,12 +886,16 @@ class TransformerBlock(Layer):
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         ln1, mha, ln2 = self._parts()
-        with jax.named_scope("attn"):
-            h, _ = ln1.apply(params["ln1"], {}, x)
-            attn, _ = mha.apply(params[self._mixer_key()], {}, h, mask=mask)
-            if self.sandwich:
-                attn, _ = ln1.apply(params["ln1_post"], {}, attn)
-            x = x + attn
+        if mha is not None:
+            with jax.named_scope("attn"):
+                h, _ = ln1.apply(params["ln1"], {}, x)
+                attn, _ = mha.apply(params[self._mixer_key()], {}, h,
+                                    mask=mask)
+                if self.sandwich:
+                    attn, _ = ln1.apply(params["ln1_post"], {}, attn)
+                x = x + attn
+        if self.ffn == "none":
+            return x, state
         with jax.named_scope("mlp"):
             h, _ = ln2.apply(params["ln2"], {}, x)
             b, t, f = h.shape

@@ -124,8 +124,10 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
     ``held = (first, end)`` names the experts whose weights
     ``w_gate`` / ``w_up`` [n_held, d, f] and ``w_down`` [n_held, f, d]
     are: the result is ``sum_{j in sel, first <= j < end} w_j E_j(x)``,
-    ``E_j(x) = (act(x Wg_j) * (x Wu_j)) Wd_j``; what the other experts
-    would add is left out (their chips add it).
+    ``E_j(x) = (act(x Wg_j) * (x Wu_j)) Wd_j``, or, with ``w_gate`` None
+    (ungated experts: the Nemotron-H family's ReLU^2 ones), ``E_j(x) =
+    act(x Wu_j) Wd_j``, two grouped products and not three; what the
+    other experts would add is left out (their chips add it).
 
     No token is dropped and every shape is static: the ``N k``
     assignments are sorted by held expert, those routed elsewhere behind
@@ -176,9 +178,13 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
     # what the shapes say a group holds: N k assignments over E experts
     rows = xs.shape[0] // router_w.shape[1]
     with jax.named_scope("moe_experts"):
-        g = grouped_matmul(xs, w_gate, sizes, cd, rows)
-        u = grouped_matmul(xs, w_up, sizes, cd, rows)
-        h = (act(g.astype(ad)) * u.astype(ad)).astype(cd)
+        if w_gate is None:
+            u = grouped_matmul(xs, w_up, sizes, cd, rows)
+            h = act(u.astype(ad)).astype(cd)
+        else:
+            g = grouped_matmul(xs, w_gate, sizes, cd, rows)
+            u = grouped_matmul(xs, w_up, sizes, cd, rows)
+            h = (act(g.astype(ad)) * u.astype(ad)).astype(cd)
         ys = grouped_matmul(h, w_down, sizes, ad, rows)
     with jax.named_scope("moe_route"), jax.named_scope("moe_permute"):
         y = _combine(ys, w, order, inv, r)

@@ -12,7 +12,12 @@ or `gdn` with `gdn_conv` and `gdn_core` inside it where the mixer is the
 gated delta rule: `gdn_conv` holds the taps and SiLU, which with
 `short_conv`'s gates and taps are the kernels `causal_conv_fwd` and,
 under `transpose(`, `causal_conv_bwd` of ops/causal_conv.py, each with
-the taps' transposition beside it; `attn_gate` around gated attention's output gate; `moe`
+the taps' transposition beside it; or `ssm` with `ssm_conv` and `ssd_core`
+inside it where the mixer is Mamba-2: `ssm_conv` holds the taps with
+their bias and SiLU, the same two kernels, `ssd_core` the selective scan
+in its chunkwise form, ops/ssd.py; `attn_gate` around gated attention's
+output gate; a block whose mixer or FFN is absent has no `attn` or no
+`mlp`; `moe`
 inside `mlp` where the FFN is routed experts, with `moe_route` and
 `moe_experts` inside it and `moe_shared` beside them where the block has
 a shared expert), `loss`, `grad_norm`, `updater`, `health`, and

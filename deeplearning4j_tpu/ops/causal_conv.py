@@ -8,11 +8,13 @@ x]``, whatever follows them passing through untouched:
 
     z    = before * x                      (where gate_before)
     pre  = sum_j w[:, taps-1-j] * shift_j(z)     zeros before the start
+           + bias                          (where there is one, a column)
     y    = after * silu(pre)               (where gate_after / activation)
 
 ``ShortConv`` is both gates over three taps, ``GatedDeltaNet`` four taps
 and SiLU over the first ``2 kw + vw`` columns of ``x W_qkvz`` with ``z``'s
-columns behind them. ``causal_conv`` returns ``(y, rest)``: ``rest`` is
+columns behind them, ``Mamba2Mixer`` four taps, a bias and SiLU over all
+of its ``[x | B | C]`` projection. ``causal_conv`` returns ``(y, rest)``: ``rest`` is
 ``p``'s columns past the parts (None where there are none), so that the
 backward writes the gradient of the WHOLE projection, the three parts'
 or the convolution's beside the rest's, into one [B, T, W] array and no
@@ -23,8 +25,8 @@ producer wrote it: no slice is copied out of ``y`` for a kernel that
 wants ``v`` alone, and no pad-and-add joins the three gradients.
 
 **The kernels** (``causal_conv_fwd``, ``causal_conv_bwd``, under one
-``jax.custom_vjp`` whose residuals are ``p`` and ``w`` and nothing
-else): grid (batch, blocks of rows), the rows in order, a block all ``C``
+``jax.custom_vjp`` whose residuals are ``p``, ``w`` and the bias and
+nothing else): grid (batch, blocks of rows), the rows in order, a block all ``C``
 columns of each part wide, read out of ``p`` where it lies (a part is a
 block index of the column axis) and as many rows as ``_VMEM`` allows
 with every block double-buffered (64 at the benchmark's [4096, 8192],
@@ -98,9 +100,11 @@ def _cut(y, split):
     return tuple(y[..., e - n:e] for n, e in zip(split, ends)) or y
 
 
-def _plain(p, w, gate_before, gate_after, activation, split):
+def _plain(p, w, bias, gate_before, gate_after, activation, split):
     before, after, x, rest = _parts(p, w.shape[0], gate_before, gate_after)
     y = causal_taps(x if before is None else before * x, w.astype(p.dtype))
+    if bias is not None:
+        y = y + bias.astype(p.dtype)
     if activation:
         y = jax.nn.silu(y)
     return _cut(y if after is None else after * y, split), rest
@@ -130,10 +134,10 @@ def _supported(p_shape, w_shape, dtype, gate_before, gate_after, split):
     return _rows(t, width, c, dtype) > 0
 
 
-def causal_conv(p, w, *, gate_before=False, gate_after=False,
+def causal_conv(p, w, bias=None, *, gate_before=False, gate_after=False,
                 activation=False, split=()):
-    """``p`` [B, T, W] a projection's result, ``w`` [C, taps]; see the
-    module's text for the columns' order. Returns ``(y [B, T, C], rest
+    """``p`` [B, T, W] a projection's result, ``w`` [C, taps], ``bias``
+    [C] or None; see the module's text for the columns' order. Returns ``(y [B, T, C], rest
     [B, T, W - parts C] or None)`` in ``p``'s dtype, ``y`` a tuple of
     [B, T, n] for the ``n`` of ``split``."""
     split = tuple(split)
@@ -146,10 +150,10 @@ def causal_conv(p, w, *, gate_before=False, gate_after=False,
     if resolve_causal_conv(p.shape, w.shape, p.dtype, gate_before,
                            gate_after, split):
         return causal_conv_kernels(
-            p, w, gate_before=gate_before, gate_after=gate_after,
+            p, w, bias, gate_before=gate_before, gate_after=gate_after,
             activation=activation, split=split,
             interpret=not _ap.backend_is_tpu())
-    return _plain(p, w, gate_before, gate_after, activation, split)
+    return _plain(p, w, bias, gate_before, gate_after, activation, split)
 
 
 def _rows(t, width, c, dtype):
@@ -199,13 +203,13 @@ def _chunks(widths, body):
         start += n
 
 
-def _fwd_kernel(*refs, rows, gate_before, gate_after, activation):
+def _fwd_kernel(*refs, rows, bias, gate_before, gate_after, activation):
     refs = list(refs)
     w_ref = refs.pop(0)
     before_ref = refs.pop(0) if gate_before else None
     after_ref = refs.pop(0) if gate_after else None
     x_ref, *y_refs, carry = refs
-    taps = w_ref.shape[0]
+    taps = w_ref.shape[0] - bias       # the bias is the row after the taps
 
     @pl.when(pl.program_id(1) == 0)
     def _():
@@ -219,6 +223,8 @@ def _fwd_kernel(*refs, rows, gate_before, gate_after, activation):
         carry[:, cols] = z[rows - _HALO:]
         pre = sum(_shifted(ext, rows, j) * w_ref[taps - 1 - j:taps - j, cols]
                   for j in range(taps))
+        if bias:
+            pre = pre + w_ref[taps:, cols]
         if activation:
             pre = jax.nn.silu(pre)
         if gate_after:
@@ -228,14 +234,15 @@ def _fwd_kernel(*refs, rows, gate_before, gate_after, activation):
     _chunks([y.shape[-1] for y in y_refs], chunk)
 
 
-def _bwd_kernel(*refs, rows, t, halo, gate_before, gate_after, activation):
+def _bwd_kernel(*refs, rows, t, halo, bias, gate_before, gate_after,
+                activation):
     refs = list(refs)
     w_ref = refs.pop(0)
     before_ref, before_halo = (refs.pop(0), refs.pop(0)) if gate_before \
         else (None, None)
     after_ref = refs.pop(0) if gate_after else None
     x_ref, x_halo, *dy_refs, dp_ref, dw_ref, carry = refs
-    taps, c = w_ref.shape
+    taps, c = w_ref.shape[0] - bias, w_ref.shape[1]
     # the result's pieces' gradients, then the gradient of what passed by
     drest_ref = dy_refs.pop() if dp_ref.shape[-1] > (
         1 + gate_before + gate_after) * c else None
@@ -270,6 +277,8 @@ def _bwd_kernel(*refs, rows, t, halo, gate_before, gate_after, activation):
         d = dy_refs[k][0, :, own].astype(_F32)
         pre = sum(past[j] * w_ref[taps - 1 - j:taps - j, cols]
                   for j in range(taps))
+        if bias:
+            pre = pre + w_ref[taps:, cols]
         if activation:
             pre, slope = _silu_and_slope(pre)
         if gate_after:
@@ -287,6 +296,8 @@ def _bwd_kernel(*refs, rows, t, halo, gate_before, gate_after, activation):
                  * w_ref[taps - 1 - j:taps - j, cols] for j in range(taps))
         for j in range(taps):
             dw_ref[0, taps - 1 - j, :, cols] += _folded(d * past[j])
+        if bias:        # its gradient: the pre-activation's, summed
+            dw_ref[0, taps, :, cols] += _folded(d)
         if gate_before:
             store(cols.start, cols.size, dz * x)
             dz = dz * before
@@ -320,35 +331,45 @@ def _params():
 # ``_run_fwd`` and ``_run_bwd`` are jitted functions of their own, as in
 # ops/gated_delta.py: a model's layers then share one trace and one
 # lowering of each kernel
+def _taps_rows(w, bias):
+    """The taps as rows [taps, C] in float32, the bias one row more."""
+    rows = w.T.astype(_F32)
+    if bias is None:
+        return rows
+    return jnp.concatenate([rows, bias[None].astype(_F32)])
+
+
 @functools.partial(jax.jit, static_argnames=(
     "gate_before", "gate_after", "activation", "split", "interpret"))
-def _run_fwd(p, w, gate_before, gate_after, activation, split, interpret):
+def _run_fwd(p, w, bias, gate_before, gate_after, activation, split,
+             interpret):
     b, t, width, c, parts, rows, n = _geometry(p, w, gate_before, gate_after)
-    taps = w.shape[1]
+    wrows = w.shape[1] + (bias is not None)   # taps, and the bias
     a_part = [pl.BlockSpec((1, rows, c), functools.partial(
         lambda k, b, i: (b, i, k), k)) for k in range(parts)]
     ys = pl.pallas_call(
-        functools.partial(_fwd_kernel, rows=rows, gate_before=gate_before,
-                          gate_after=gate_after, activation=activation),
+        functools.partial(_fwd_kernel, rows=rows, bias=bias is not None,
+                          gate_before=gate_before, gate_after=gate_after,
+                          activation=activation),
         out_shape=[jax.ShapeDtypeStruct((b, t, m), p.dtype)
                    for m in split or (c,)], grid=(b, n),
-        in_specs=[pl.BlockSpec((taps, c), lambda b, i: (0, 0))] + a_part,
+        in_specs=[pl.BlockSpec((wrows, c), lambda b, i: (0, 0))] + a_part,
         out_specs=[pl.BlockSpec((1, rows, m), lambda b, i: (b, i, 0))
                    for m in split or (c,)],
         scratch_shapes=[pltpu.VMEM((_HALO, c), _F32)],
         compiler_params=_params(), interpret=interpret,
         name="causal_conv_fwd")(
-            w.T.astype(_F32), *[p] * parts)
+            _taps_rows(w, bias), *[p] * parts)
     return tuple(ys) if split else ys[0]
 
 
 @functools.partial(jax.jit, static_argnames=(
     "gate_before", "gate_after", "activation", "interpret"))
-def _run_bwd(p, w, dys, drest, gate_before, gate_after, activation,
+def _run_bwd(p, w, bias, dys, drest, gate_before, gate_after, activation,
              interpret):
     """``dys`` the gradients of the result's pieces, one if it is whole."""
     b, t, width, c, parts, rows, n = _geometry(p, w, gate_before, gate_after)
-    taps = w.shape[1]
+    wrows = w.shape[1] + (bias is not None)   # taps, and the bias
     halo = _HALO * 4 // p.dtype.itemsize      # a sublane tile of the dtype
     per = rows // halo
 
@@ -364,8 +385,8 @@ def _run_bwd(p, w, dys, drest, gate_before, gate_after, activation,
             (1, halo, c),
             lambda b, i: (b, jnp.maximum(at(i) * per - 1, 0), k))]
 
-    in_specs = [pl.BlockSpec((taps, c), lambda b, i: (0, 0))]
-    args = [w.T.astype(_F32)]
+    in_specs = [pl.BlockSpec((wrows, c), lambda b, i: (0, 0))]
+    args = [_taps_rows(w, bias)]
     if gate_before:
         in_specs += with_halo(0)
         args += [p, p]
@@ -382,46 +403,51 @@ def _run_bwd(p, w, dys, drest, gate_before, gate_after, activation,
         args.append(drest)
     dp, dw = pl.pallas_call(
         functools.partial(_bwd_kernel, rows=rows, t=t, halo=halo,
-                          gate_before=gate_before, gate_after=gate_after,
-                          activation=activation),
+                          bias=bias is not None, gate_before=gate_before,
+                          gate_after=gate_after, activation=activation),
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype),
-                   jax.ShapeDtypeStruct((b, taps, 8, c), _F32)],
+                   jax.ShapeDtypeStruct((b, wrows, 8, c), _F32)],
         grid=(b, n), in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, rows, width), lambda b, i: (b, at(i), 0)),
-                   pl.BlockSpec((1, taps, 8, c), lambda b, i: (b, 0, 0, 0))],
+                   pl.BlockSpec((1, wrows, 8, c), lambda b, i: (b, 0, 0, 0))],
         scratch_shapes=[pltpu.VMEM((_HALO, c), _F32)],
         compiler_params=_params(), interpret=interpret,
         name="causal_conv_bwd")(*args)
-    return dp, jnp.sum(dw, axis=(0, 2)).T.astype(w.dtype)
+    dw = jnp.sum(dw, axis=(0, 2))
+    if bias is None:
+        return dp, dw.T.astype(w.dtype), None
+    return dp, dw[:-1].T.astype(w.dtype), dw[-1].astype(bias.dtype)
 
 
-def causal_conv_kernels(p, w, *, gate_before=False, gate_after=False,
-                        activation=False, split=(), interpret=False):
+def causal_conv_kernels(p, w, bias=None, *, gate_before=False,
+                        gate_after=False, activation=False, split=(),
+                        interpret=False):
     """``causal_conv`` as the two kernels, whatever the backend;
     ``interpret=True`` runs them in the interpreter, off the chip."""
     if not _supported(p.shape, w.shape, p.dtype, gate_before, gate_after,
                       split):
         raise ValueError(f"no kernel for p {p.shape} {p.dtype}, w {w.shape}, "
                          f"split {split}")
-    return _kernels(p, w, gate_before, gate_after, activation, tuple(split),
-                    interpret)
+    return _kernels(p, w, bias, gate_before, gate_after, activation,
+                    tuple(split), interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
-def _kernels(p, w, gate_before, gate_after, activation, split, interpret):
-    return _kernels_fwd(p, w, gate_before, gate_after, activation, split,
-                        interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _kernels(p, w, bias, gate_before, gate_after, activation, split,
+             interpret):
+    return _kernels_fwd(p, w, bias, gate_before, gate_after, activation,
+                        split, interpret)[0]
 
 
-def _kernels_fwd(p, w, gate_before, gate_after, activation, split,
+def _kernels_fwd(p, w, bias, gate_before, gate_after, activation, split,
                  interpret):
     # the kernels index with 32-bit integers; under the tests' x64 mode
     # their Python constants would trace as 64-bit beside them
     with jax.enable_x64(False):
-        y = _run_fwd(p, w, gate_before, gate_after, activation, split,
+        y = _run_fwd(p, w, bias, gate_before, gate_after, activation, split,
                      interpret)
     rest = _parts(p, w.shape[0], gate_before, gate_after)[3]
-    return (y, rest), (p, w)
+    return (y, rest), (p, w, bias)
 
 
 def _kernels_bwd(gate_before, gate_after, activation, split, interpret, res,

@@ -431,11 +431,15 @@ class TestBoundedPubsub:
         try:
             sub = NDArraySubscriber("t", port=broker.port, buffer=2)
             pub = NDArrayPublisher("t", port=broker.port)
-            for i in range(8):
+            # the broker registers a subscription some time after the
+            # constructor returns, and what is published before that is
+            # lost: keep publishing until enough has arrived to overflow
+            deadline, i = time.time() + 10, 0
+            while sub.dropped < 6 - 2 and time.time() < deadline:
                 pub.publish(np.full((4,), i, np.float32))
-            deadline = time.time() + 10
-            while sub.dropped < 6 and time.time() < deadline:
+                i += 1
                 time.sleep(0.02)
+            time.sleep(0.3)  # the last publish has landed or been dropped
             assert sub.dropped >= 6 - 2  # all but the buffered tail
             # the survivors are the NEWEST payloads, decodable
             age, arr, _ts = sub.receive_timed(timeout=2)
