@@ -484,17 +484,14 @@ def _causal_conv_case(name, *, b, t, width, columns, taps, gates, split,
     return _compare(name, kernels, plain, (p, w, *more), tol)
 
 
-def _ssd_case(name, *, b, t, h, p, g, n, chunk, tol):
-    """The selective state-space scan's chunkwise form (``ops/ssd.py``,
-    whatever ``resolve_ssd`` chooses here) against the recurrence run
-    position by position (the benchmark's plain reference's): outputs and
-    the six gradients, steps from all but kept to all but forgotten
-    inside a few tokens."""
+def _ssd_inputs(b, t, h, p, g, n):
+    """(x, dt, A, B, C, D) with steps from all but kept to all but
+    forgotten inside a few tokens, and the recurrence run position by
+    position over them (the benchmark's plain reference's)."""
     import jax
     import jax.numpy as jnp
 
     from benchmark.reference import nemotron_h as _plain
-    from deeplearning4j_tpu.ops import ssd as _ssd
 
     keys = jax.random.split(jax.random.PRNGKey(t + n), 5)
     x = jax.random.normal(keys[0], (b, t, h, p), jnp.float32)
@@ -505,16 +502,53 @@ def _ssd_case(name, *, b, t, h, p, g, n, chunk, tol):
               for key in keys[2:4])
     d = jax.random.normal(keys[4], (h,), jnp.float32)
 
-    def chunkwise(x, dt, a, bm, cm, d):
-        return _ssd.ssd(x, dt, a, bm, cm, d, chunk=chunk)
-
     def position_by_position(x, dt, a, bm, cm, d):
         bh, ch = (jnp.repeat(u, h // g, axis=2) for u in (bm, cm))
         return jax.vmap(lambda x, dt, bh, ch: _plain.selective_scan(
             x, dt, a, bh, ch, d))(x, dt, bh, ch)
 
-    return _compare(name, chunkwise, position_by_position,
-                    (x, dt, a, bm, cm, d), tol)
+    return (x, dt, a, bm, cm, d), position_by_position
+
+
+def _ssd_case(name, *, b, t, h, p, g, n, chunk, tol):
+    """The selective state-space scan's chunkwise form (``ops/ssd.py``,
+    whatever ``resolve_ssd`` chooses here) against the recurrence run
+    position by position: outputs and the six gradients."""
+    from deeplearning4j_tpu.ops import ssd as _ssd
+
+    args, position_by_position = _ssd_inputs(b, t, h, p, g, n)
+
+    def chunkwise(x, dt, a, bm, cm, d):
+        return _ssd.ssd(x, dt, a, bm, cm, d, chunk=chunk)
+
+    return _compare(name, chunkwise, position_by_position, args, tol)
+
+
+def _ssd_kernels_case(name, *, b, t, h, p, g, n, chunk, interpret, tol):
+    """The scan's two kernels alone against the ``jax.numpy`` chunkwise
+    form and against the recurrence run position by position: outputs and
+    the six gradients. On the chip ``resolve_ssd`` has to choose them."""
+    from deeplearning4j_tpu.ops import ssd as _ssd
+
+    args, position_by_position = _ssd_inputs(b, t, h, p, g, n)
+    if not interpret:
+        x, bm = args[0], args[3]
+        _expect(_ssd.resolve_ssd(x.shape, bm.shape, x.dtype, chunk)
+                is _ssd._kernels,
+                f"{name}: the dispatch does not choose the kernels here")
+
+    def kernels(x, dt, a, bm, cm, d):
+        return _ssd.ssd_kernels(x, dt, a, bm, cm, d, chunk=chunk,
+                                interpret=interpret)
+
+    def chunked(x, dt, a, bm, cm, d):
+        return _ssd._chunked(x, dt, a, bm, cm, d, chunk)
+
+    out = _compare(name, kernels, chunked, args, tol)
+    far = _compare(name, kernels, position_by_position, args, tol)
+    return {**out, "recurrence_fwd_rel_err": far["fwd_rel_err"],
+            "recurrence_bwd_rel_err": far["bwd_rel_err"],
+            "wall_s": round(out["wall_s"] + far["wall_s"], 1)}
 
 
 def _lstm_case(name, *, t, b, hsz, peephole, masked, interpret, tol):
@@ -785,7 +819,9 @@ def kernel_cases(interpret):
                                             columns=384, taps=4, gates=False,
                                             split=(128, 256), bias=True))],
             [("ssd_chunkwise", dict(b=1, t=100, h=4, p=8, g=2, n=16,
-                                    chunk=32))])
+                                    chunk=32))],
+            [("ssd_kernels", dict(b=1, t=150, h=4, p=64, g=2, n=128,
+                                  chunk=128))])
     return (
         [("flash_causal_t4096_h8_d64",
           dict(b=1, t=4096, h=8, d=64, causal=True, masked=False,
@@ -826,12 +862,17 @@ def kernel_cases(interpret):
           dict(b=1, t=4096, width=6144, columns=6144, taps=4, gates=False,
                split=(4096, 1024, 1024), bias=True))],
         [("ssd_t4096_h64_p64_g8_n128",       # nemotron3nano-train-packed's
-          dict(b=1, t=4096, h=64, p=64, g=8, n=128, chunk=128))])
+          dict(b=1, t=4096, h=64, p=64, g=8, n=128, chunk=128))],
+        [("ssd_kernels_t4096_h64_p64_g8_n128",   # the same call
+          dict(b=1, t=4096, h=64, p=64, g=8, n=128, chunk=128)),
+         ("ssd_kernels_t1000_ragged",
+          dict(b=2, t=1000, h=64, p=64, g=8, n=128, chunk=128))])
 
 
 def kernels_phase(*, interpret, tol):
     t0 = time.perf_counter()
-    flash, lstm, gated_delta, causal_conv, ssd = kernel_cases(interpret)
+    flash, lstm, gated_delta, causal_conv, ssd, ssd_kernels = kernel_cases(
+        interpret)
     results = [_flash_case(n, interpret=interpret, tol=tol, **kw)
                for n, kw in flash]
     results += [_gated_delta_case(n, interpret=interpret, tol=tol, **kw)
@@ -839,6 +880,8 @@ def kernels_phase(*, interpret, tol):
     results += [_causal_conv_case(n, interpret=interpret, tol=tol, **kw)
                 for n, kw in causal_conv]
     results += [_ssd_case(n, tol=tol, **kw) for n, kw in ssd]
+    results += [_ssd_kernels_case(n, interpret=interpret, tol=tol, **kw)
+                for n, kw in ssd_kernels]
     if not interpret:  # through the dispatch: nothing to choose off the chip
         results.append(_looped_block_case(
             "looped_lm_t2048_h16_d128", b=2, t=2048, width=2048, h=16, d=128,

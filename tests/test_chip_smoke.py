@@ -68,7 +68,8 @@ def test_kernels_phase_in_interpret_mode(kernel_dispatch):
     names = {r["kernel"] for r in doc["results"]}
     assert {"flash_causal", "flash_padding_mask", "flash_block",
             "gated_delta_kernels", "causal_conv_silu", "causal_conv_gates",
-            "causal_conv_bias_silu", "ssd_chunkwise", "lstm_resident",
+            "causal_conv_bias_silu", "ssd_chunkwise", "ssd_kernels",
+            "lstm_resident",
             "lstm_resident_peephole_masked", "lstm_tiled_masked"} == names
 
 

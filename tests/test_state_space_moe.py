@@ -270,8 +270,10 @@ def test_without_a_bias_the_op_gives_what_it_gave_before(monkeypatch):
 
 def test_the_cells_mixer_takes_the_kernels_for_its_convolution(monkeypatch):
     """The Mamba-2 mixer at the cell's widths, length and dtypes lowers for
-    the TPU with one convolution kernel a pass under `ssm_conv`, the
-    recurrence under `ssd_core`, and no `pad` left beside the kernels."""
+    the TPU with one convolution kernel a pass under `ssm_conv`, one kernel
+    of the recurrence a pass under `ssd_core` (`ssd_fwd` outside
+    `transpose(`, `ssd_bwd` inside it), and no `pad` left beside the
+    kernels."""
     monkeypatch.setattr(attention_pallas, "backend_is_tpu", lambda: True)
     layer = L.Mamba2Mixer(n_out=2688)
     params = jax.eval_shape(lambda: layer.init(
@@ -283,16 +285,51 @@ def test_the_cells_mixer_takes_the_kernels_for_its_convolution(monkeypatch):
     with jax.enable_x64(False):
         text = jax.jit(jax.grad(loss)).trace(params, x).lower(
             lowering_platforms=("tpu",)).as_text(debug_info=True)
-    calls = re.findall(r'kernel_name = "(causal_conv_[a-z]+)"', text)
-    assert sorted(calls) == ["causal_conv_bwd", "causal_conv_fwd"], calls
+    calls = re.findall(r'kernel_name = "([a-z_]+)"', text)
+    assert sorted(calls) == ["causal_conv_bwd", "causal_conv_fwd", "ssd_bwd",
+                             "ssd_fwd"], calls
     paths = set(re.findall(r'loc\("([^"]+)"', text))
-    assert [p for p in paths if "jvp(ssm)/ssm_conv" in p and "_run_fwd" in p
-            and "transpose(" not in p]
-    assert [p for p in paths if "transpose(jvp(ssm))/ssm_conv" in p
-            and "_run_bwd" in p]
-    assert [p for p in paths if "jvp(ssm)/ssd_core/" in p]
-    assert [p for p in paths if "transpose(jvp(ssm))/ssd_core/" in p]
+    for scope in ("ssm_conv", "ssd_core"):
+        assert [p for p in paths if f"jvp(ssm)/{scope}" in p
+                and "_run_fwd" in p and "transpose(" not in p], scope
+        assert [p for p in paths if f"transpose(jvp(ssm))/{scope}" in p
+                and "_run_bwd" in p], scope
+        assert not [p for p in paths if f"jvp(ssm)/{scope}" in p
+                    and "_run_bwd" in p and "transpose(" not in p], scope
     assert not [p for p in paths if "ssm_conv" in p and "/pad" in p]
+
+
+def test_the_scans_kernels_are_named_inside_ssd_core(monkeypatch):
+    """The recurrence's two kernels in a compiled train step (a one-layer
+    `state_space_moe_lm` at lane-aligned widths, the dispatch's answer
+    forced off the chip): `ssd_fwd` under `ssm` and `ssd_core` outside
+    `transpose(`, `ssd_bwd` under both inside it, as the benchmark's scope
+    matcher reads `ssm_ms.tokens`, `ssd_core_ms.tokens` and
+    `step_bwd_ms.tokens`: a backward kernel outside its scope would leave
+    `ssd_core_ms` reading the forward alone. Read from the compiled step's
+    `op_name`s, as `test_step_scopes.py` reads `gdn_fwd` and `gdn_bwd`."""
+    from benchmark.readers import trace_scope_ms
+    from deeplearning4j_tpu.ops import ssd
+    monkeypatch.setattr(ssd, "resolve_ssd", lambda *a: ssd._kernels)
+    net = MultiLayerNetwork(models.state_space_moe_lm(
+        16, pattern="M", d_model=16, n_heads=2, n_kv_heads=1, head_dim=8,
+        ssm_heads=2, ssm_head_dim=64, ssm_groups=1, ssm_state=128,
+        ssm_chunk=128, expert_width=8, shared_expert_width=8, n_experts=4,
+        top_k=2, seq_len=128))
+    net.init()
+    x = np.arange(128, dtype=np.int32).reshape(1, 128) % 16
+    text = net.make_train_step(donate=False).lower(
+        net.params, net.state, net.opt_state, x, np.roll(x, -1, axis=1), 0,
+        jax.random.PRNGKey(0)).compile().as_text()
+    paths = set(re.findall(r'op_name="(jit\(train_step[^"]+)"', text))
+    for kernel, backward in (("ssd_fwd", False), ("ssd_bwd", True)):
+        named = [p for p in paths if re.search(
+            r"(?:^|[/(])" + kernel + r"(?:$|[/)])", p)]
+        assert named, kernel
+        for scope in ("ssm", "ssd_core", r"L01\.TransformerBlock"):
+            match = trace_scope_ms.matcher(
+                {"scope": scope, "backward": backward})
+            assert all(match(p) for p in named), (kernel, scope, named[:3])
 
 
 # ---------------------------------------------------------------------------
