@@ -652,8 +652,10 @@ class StepDriver:
             if self._use_health:
                 self._hm.flush(apply_policy=apply_policy)
             if self.instrumented and self._reg.enabled:
-                # the routed-experts layers' counts of the last step
+                # the routed-experts layers' counts of the last step, and
+                # the terms a head keeps apart of its loss
                 _tm.note_routing(self.net.state)
+                _tm.note_loss_terms(self.net.state)
 
     def checkpoint(self, path, *, buckets=None, save_updater=True):
         """``sync()`` then write one resumable ``save_bundle`` unit —

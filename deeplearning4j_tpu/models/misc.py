@@ -168,22 +168,29 @@ def looped_lm(vocab_size, n_layers=4, d_model=2048, n_heads=16, head_dim=None,
 #: single-part layers' FFN without a mixer
 _MIXERS = {"conv": "short_conv", "full_attention": "attention",
            "linear_attention": "gated_delta", "mamba2": "mamba2",
-           "ffn": "none"}
+           "latent_attention": "latent_attention", "ffn": "none"}
 
 
 def _hybrid_decoder(vocab_size, layer_types, num_dense_layers, d_model,
                     seq_len, block, dense, moe, final_norm, updater, seed,
-                    single_part=False):
+                    single_part=False, mtp_weight=None):
     """The one loop behind the hybrid decoders: a token embedding, a
     pre-norm ``TransformerBlock`` a layer whose mixer is what
     ``layer_types[i]`` names (``"conv"``: the gated short convolution,
     ``"full_attention"``: softmax attention, ``"linear_attention"``: the
-    gated delta rule, ``"mamba2"``: the state-space mixer, ``"ffn"``:
-    none) and whose FFN takes the fields ``dense`` for the first
+    gated delta rule, ``"mamba2"``: the state-space mixer,
+    ``"latent_attention"``: multi-head latent attention, the fifth,
+    ``"ffn"``: none) and whose FFN takes the fields ``dense`` for the first
     ``num_dense_layers`` layers and ``moe`` for the rest, on top of
     ``block``'s; with ``single_part`` a layer is one part alone, a mixer
     without an FFN or (``"ffn"``) the reverse; ``final_norm`` and an
-    untied softmax head under ``sparse_mcxent``."""
+    untied softmax head under ``sparse_mcxent``. With ``mtp_weight`` the
+    head is a ``MultiTokenLMOutputLayer`` instead, which holds ``final_norm``'s
+    gain itself (its module reads the state before that norm), runs one
+    more block of the last layer's kind as its multi-token-prediction
+    module, and reads the embedding table through the configuration's one
+    ``ParamTie``."""
+    from deeplearning4j_tpu.nn.conf.network import ParamTie
     from deeplearning4j_tpu.nn.initializers import Distribution
     init = Distribution(kind="normal", std=0.02)
     blocks = []
@@ -199,16 +206,23 @@ def _hybrid_decoder(vocab_size, layer_types, num_dense_layers, d_model,
             "n_out": d_model, "causal": True, "activation": "silu",
             "norm": "rms", "bias": False, "mixer": _MIXERS[kind],
             "weight_init": init, **block, **ffn}))
+    if mtp_weight is None:
+        head, ties = [final_norm,
+                      L.RnnOutputLayer(n_out=vocab_size, loss="sparse_mcxent",
+                                       has_bias=False, weight_init=init)], ()
+    else:
+        head = [L.MultiTokenLMOutputLayer(
+            n_out=vocab_size, block=blocks[-1], norm_eps=final_norm.eps,
+            weight_init=init, mtp_weight=mtp_weight)]
+        ties = (ParamTie(layer=len(blocks) + 1, name="embed",
+                         source_layer=0, source_name="W"),)
     return NeuralNetConfig(
         seed=seed,
         updater=updater or U.Adam(learning_rate=3e-4)).list(
         L.EmbeddingSequenceLayer(n_in=vocab_size, n_out=d_model,
                                  weight_init=init),
-        *blocks,
-        final_norm,
-        L.RnnOutputLayer(n_out=vocab_size, loss="sparse_mcxent",
-                         has_bias=False, weight_init=init),
-        input_type=I.RecurrentType(1, seq_len),
+        *blocks, *head,
+        input_type=I.RecurrentType(1, seq_len), ties=ties,
     )
 
 
@@ -344,3 +358,47 @@ def state_space_moe_lm(vocab_size, pattern=NEMOTRON_3_NANO_PATTERN,
              "shared_expert_gate": False},
         final_norm=L.RMSNorm(eps=norm_eps), updater=updater, seed=seed,
         single_part=True)
+
+
+def latent_moe_lm(vocab_size, n_layers=47, num_dense_layers=1, d_model=2048,
+                  n_heads=20, q_rank=768, kv_rank=512, nope_dim=192,
+                  rope_dim=64, v_dim=256, ffn_width=10240, expert_width=1536,
+                  shared_expert_width=1536, n_experts=64, top_k=4,
+                  routed_scale=1.8, experts_held=(), mtp_weight=0.3,
+                  seq_len=4096, rope_theta=1e6, norm_eps=1e-5, updater=None,
+                  seed=12345):
+    """Latent-attention mixture-of-experts decoder with a
+    multi-token-prediction module (the GLM-4.7-Flash family's
+    ``glm4_moe_lite``, after DeepSeek-V3; net-new), through the loop
+    ``hybrid_moe_lm`` runs: every layer mixes by multi-head latent
+    attention (``LatentAttention``: queries through a latent of ``q_rank``,
+    keys and values from one of ``kv_rank``, a head ``nope_dim`` +
+    ``rope_dim`` wide with the rotary key shared by the heads, values
+    ``v_dim``); its FFN is a dense gated SiLU FFN for the first
+    ``num_dense_layers`` layers and, for the rest, ``n_experts`` routed
+    gated SiLU experts (top-``top_k`` by sigmoid scores plus a correction
+    bias that moves the selection only, weights renormalised over the
+    selected and times ``routed_scale``) plus one shared expert of the
+    same form over every token, added ungated. The head is a
+    ``MultiTokenLMOutputLayer``: the final RMSNorm, an untied softmax
+    head, and one module of the last layer's kind that predicts the token
+    after the next at ``mtp_weight``, sharing the embedding table (a
+    ``ParamTie``) and the head. No bias anywhere. ``experts_held`` as
+    ``hybrid_moe_lm``'s, the module's mixture too. The defaults are
+    GLM-4.7-Flash's published widths and depth."""
+    return _hybrid_decoder(
+        vocab_size, ["latent_attention"] * n_layers, num_dense_layers,
+        d_model, seq_len,
+        block={"n_heads": n_heads, "norm_eps": norm_eps,
+               "rope_theta": rope_theta, "q_rank": q_rank,
+               "kv_rank": kv_rank, "nope_dim": nope_dim,
+               "rope_dim": rope_dim, "v_dim": v_dim},
+        dense={"ffn": "gated", "ffn_width": ffn_width},
+        moe={"ffn": "moe", "ffn_width": expert_width,
+             "n_experts": n_experts, "top_k": top_k,
+             "experts_held": tuple(experts_held),
+             "routed_scale": routed_scale,
+             "shared_expert_width": shared_expert_width,
+             "shared_expert_gate": False},
+        final_norm=L.RMSNorm(eps=norm_eps), updater=updater, seed=seed,
+        mtp_weight=mtp_weight)

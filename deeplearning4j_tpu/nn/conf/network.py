@@ -26,6 +26,24 @@ _CASCADE_FIELDS = ("activation", "weight_init", "bias_init", "l1", "l2",
 
 @serde.register_config
 @dataclasses.dataclass(frozen=True)
+class ParamTie:
+    """Layer ``layer`` is given, under the name ``name``, layer
+    ``source_layer``'s parameter ``source_name``: one leaf in the
+    parameter tree, at its owner, read by both layers (a language model's
+    embedding table read again by its output layer). The gradient of the
+    leaf is the sum over its uses; the updater, ``num_params()`` and a
+    checkpoint see it once. The reading layer's ``init`` does not make
+    the parameter; ``MultiLayerNetwork`` hands it over in every forward
+    pass."""
+
+    layer: int = 0
+    name: str = ""
+    source_layer: int = 0
+    source_name: str = ""
+
+
+@serde.register_config
+@dataclasses.dataclass(frozen=True)
 class MultiLayerConfiguration:
     """Immutable, JSON-round-trippable sequential-network config."""
 
@@ -43,6 +61,9 @@ class MultiLayerConfiguration:
     # "jax.checkpoint / rematerialisation" bullet; no reference analog —
     # workspaces solved a different memory problem)
     gradient_checkpointing: bool = False
+    # parameters one layer reads from another (ParamTie), each a single
+    # leaf at its owner
+    ties: tuple = ()
 
     def to_json(self, indent=2):
         return serde.to_json(self, indent=indent)
@@ -89,7 +110,7 @@ class NeuralNetConfig:
 
     def list(self, *layers, input_type=None, backprop_type="standard",
              tbptt_fwd_length=20, tbptt_back_length=20,
-             gradient_checkpointing=False) -> MultiLayerConfiguration:
+             gradient_checkpointing=False, ties=()) -> MultiLayerConfiguration:
         cascaded = tuple(self._cascade(l) for l in layers)
         return MultiLayerConfiguration(
             layers=cascaded, input_type=input_type,
@@ -99,6 +120,7 @@ class NeuralNetConfig:
             backprop_type=backprop_type, tbptt_fwd_length=tbptt_fwd_length,
             tbptt_back_length=tbptt_back_length, seed=self.seed,
             gradient_checkpointing=gradient_checkpointing,
+            ties=tuple(ties),
         )
 
     def _cascade(self, layer):

@@ -23,10 +23,13 @@ from deeplearning4j_tpu.nn.layers.vae import (  # noqa: F401
 from deeplearning4j_tpu.nn.layers.objdetect import Yolo2OutputLayer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.centerloss import CenterLossOutputLayer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.attention import (  # noqa: F401
-    GatedDeltaNet, LayerNormalization, Mamba2Mixer, MultiHeadAttention,
-    RMSNorm, ShortConv, TransformerBlock,
+    GatedDeltaNet, LatentAttention, LayerNormalization, Mamba2Mixer,
+    MultiHeadAttention, RMSNorm, ShortConv, TransformerBlock,
 )
 from deeplearning4j_tpu.nn.layers.looped import (  # noqa: F401
     LoopedLMOutputLayer, LoopedStack,
+)
+from deeplearning4j_tpu.nn.layers.multitoken import (  # noqa: F401
+    MultiTokenLMOutputLayer,
 )
 from deeplearning4j_tpu.nn.layers.moe import MoETransformerBlock  # noqa: F401

@@ -77,15 +77,45 @@ class MultiLayerNetwork:
                 rng, sub = jax.random.split(rng)
                 params.append(layer.init(sub, in_type, dtype))
                 state.append(layer.init_state(in_type, dtype))
+            self._check_ties(params)
             self.params, self.state = params, state
             self.opt_state = self.conf.updater.init(params)
         return params, state
+
+    def _check_ties(self, params):
+        for tie in self.conf.ties:
+            if tie.source_name not in params[tie.source_layer]:
+                raise ValueError(
+                    f"{tie}: layer {tie.source_layer} has no parameter "
+                    f"{tie.source_name!r} (has "
+                    f"{sorted(params[tie.source_layer])})")
+            if tie.name in params[tie.layer]:
+                raise ValueError(
+                    f"{tie}: layer {tie.layer} makes a parameter "
+                    f"{tie.name!r} of its own; a tied parameter has one "
+                    "owner")
+
+    def _tied(self, params):
+        """``params`` as the layers read them: every ``ParamTie`` of the
+        configuration resolved, the reading layer's dict holding the
+        owner's leaf under the tie's name. The tree itself (what the
+        updater, ``num_params()`` and a checkpoint see) holds the leaf
+        once; autodiff sums its uses' gradients onto it."""
+        if not self.conf.ties:
+            return params
+        out = list(params)
+        for tie in self.conf.ties:
+            out[tie.layer] = {
+                **out[tie.layer],
+                tie.name: params[tie.source_layer][tie.source_name]}
+        return out
 
     def apply_fn(self, params, state, x, *, train=False, rng=None, mask=None,
                  layer_limit=None, logits=False):
         """Forward pass. Returns (output, new_state); with ``logits`` the
         last layer hands out its pre-activation output instead."""
         new_state = list(state)
+        params = self._tied(params)
         cur_type = self.conf.input_type
         n = len(self.conf.layers) if layer_limit is None else layer_limit
         for i in range(n):
@@ -163,7 +193,8 @@ class MultiLayerNetwork:
                                              layer_limit=len(self.conf.layers) - 1)
             with jax.named_scope("loss"):
                 loss, preds, out_state = out_layer.loss_from_features(
-                    params[-1], state[-1], feats, y, lm, train=train)
+                    self._tied(params)[-1], state[-1], feats, y, lm,
+                    train=train)
             new_state = list(new_state)
             new_state[-1] = out_state
         else:
@@ -202,6 +233,7 @@ class MultiLayerNetwork:
         pre-activation output, as in ``apply_fn``."""
         new_state = list(state)
         new_carries = list(carries)
+        params = self._tied(params)
         cur_type = self.conf.input_type
         last = len(self.conf.layers) - 1
         for i, layer in enumerate(self.conf.layers):
@@ -482,12 +514,13 @@ class MultiLayerNetwork:
         x = jnp.asarray(x)
         cur_type = self.conf.input_type
         state = list(self.state)
+        params = self._tied(self.params)
         for i, layer in enumerate(self.conf.layers):
             fam = layer.input_family
             if fam is not None and not isinstance(cur_type, fam):
                 x = _inputs.adapt(x, cur_type, fam)
                 cur_type = _inputs.adapted_type(cur_type, fam)
-            x, state[i] = layer.apply(self.params[i], state[i], x, train=train)
+            x, state[i] = layer.apply(params[i], state[i], x, train=train)
             cur_type = layer.output_type(cur_type)
             acts.append(x)
         return acts

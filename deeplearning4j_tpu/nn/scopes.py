@@ -15,12 +15,17 @@ under `transpose(`, `causal_conv_bwd` of ops/causal_conv.py, each with
 the taps' transposition beside it; or `ssm` with `ssm_conv` and `ssd_core`
 inside it where the mixer is Mamba-2: `ssm_conv` holds the taps with
 their bias and SiLU, the same two kernels, `ssd_core` the selective scan
-in its chunkwise form, ops/ssd.py; `attn_gate` around gated attention's
+in its chunkwise form, ops/ssd.py; or `mla` where the mixer is latent
+attention: the projections, the latent norms, the rotations and the
+flash kernels' own scopes inside it; `attn_gate` around gated attention's
 output gate; a block whose mixer or FFN is absent has no `attn` or no
 `mlp`; `moe`
 inside `mlp` where the FFN is routed experts, with `moe_route` and
 `moe_experts` inside it and `moe_shared` beside them where the block has
-a shared expert), `loss`, `grad_norm`, `updater`, `health`, and
+a shared expert), `loss` (with `lm_head` around a multi-token head's
+product and cross-entropy, and `mtp` around its module: the block's own
+`attn` / `mlp` scopes and the second `lm_head` inside it), `grad_norm`,
+`updater`, `health`, and
 `<kernel>.fwd` / `<kernel>.bwd` around the Pallas kernels. Anything
 outside `[A-Za-z0-9_.-]` in a name becomes `_`.
 """

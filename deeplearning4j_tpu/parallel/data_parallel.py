@@ -320,7 +320,7 @@ class ParallelTrainer:
             # the HOST template so an unstreamable net fails loudly at
             # placement, not as an opaque trace error inside the scan
             self._trunk = streamable_trunk(self.net, params, state)
-            if (self._trunk is None
+            if (self._trunk is None or self.net.conf.ties
                     or hasattr(self.net.conf.layers[-1],
                                "loss_from_features")):
                 raise ValueError(

@@ -191,6 +191,9 @@ class PipelinedNetwork:
         assert conf.gradient_normalization in (None, "none"), \
             "PipelinedNetwork does not apply gradient normalization; " \
             "clip on the sequential MultiLayerNetwork path"
+        assert not conf.ties, \
+            "a tied parameter is read by two layers, which may lie on " \
+            "two stages; not stageable"
         assert not hasattr(conf.layers[-1], "loss_from_features"), \
             "feature-loss heads (CenterLossOutputLayer) need the " \
             "pre-head activations MultiLayerNetwork.loss_fn threads " \

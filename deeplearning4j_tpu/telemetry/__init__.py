@@ -79,7 +79,7 @@ from deeplearning4j_tpu.telemetry.tracectx import TraceContext
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry", "Tracer",
            "DEFAULT_BUCKETS", "get_registry", "get_tracer", "span",
            "write_jsonl", "enable", "disable", "enabled", "reset",
-           "series_map", "note_routing",
+           "series_map", "note_routing", "note_loss_terms",
            "health", "devices", "flight", "scorepipe", "ScorePipeline",
            "NumericsError", "tracectx", "TraceContext",
            "federate", "timeline", "profiling", "slo", "goodput",
@@ -159,18 +159,20 @@ def train_metrics():
             reg.gauge("train_score", "last training score (loss)"))
 
 
-def _routing_states(state):
-    """The routed-experts layers' states in a net's state tree (a list a
-    layer, a dict a vertex): the dicts that hold ``moe_load``."""
+def _states_holding(state, key):
+    """The dicts that hold ``key`` in a net's state tree (a list a layer,
+    a dict a vertex, a layer's own dicts nested inside): ``moe_load`` marks
+    a routed-experts layer's state, ``loss_terms`` a head that keeps the
+    parts of its loss apart."""
     if isinstance(state, dict):
-        if "moe_load" in state:
+        if key in state:
             yield state
         else:
             for v in state.values():
-                yield from _routing_states(v)
+                yield from _states_holding(v, key)
     elif isinstance(state, (list, tuple)):
         for v in state:
-            yield from _routing_states(v)
+            yield from _states_holding(v, key)
 
 
 def note_routing(state):
@@ -189,7 +191,7 @@ def note_routing(state):
     if not reg.enabled:
         return
     found = [(s["moe_load"], s["moe_elsewhere"])
-             for s in _routing_states(state)]
+             for s in _states_holding(state, "moe_load")]
     if not found:
         return
     import jax
@@ -211,3 +213,22 @@ def note_routing(state):
     reg.gauge("moe_load_mean_rows",
               "mean rows a held expert, summed over the expert layers, "
               "last sampled step").set(mean)
+
+
+def note_loss_terms(state):
+    """The terms of the last step's loss that a layer left apart in its
+    state (``loss_terms``: a multi-token head's ``main`` and ``mtp``), as
+    the gauges ``train_loss_term_<name>``. One small fetch, beside
+    ``note_routing``'s and under the same condition."""
+    reg = get_registry()
+    if not reg.enabled:
+        return
+    found = [s["loss_terms"] for s in _states_holding(state, "loss_terms")]
+    if not found:
+        return
+    import jax
+    for terms in jax.device_get(found):
+        for name, value in terms.items():
+            reg.gauge(f"train_loss_term_{name}",
+                      f"the term {name!r} of the last sampled step's "
+                      "loss, before its weight").set(float(value))
