@@ -345,7 +345,10 @@ def _compare(name, kernel_fn, ref_fn, args, tol):
             "wall_s": round(time.perf_counter() - t0, 1)}
 
 
-def _flash_case(name, *, b, t, h, d, causal, masked, block, interpret, tol):
+def _flash_case(name, *, b, t, h, d, causal, masked, block, interpret, tol,
+                backward=None):
+    """``backward``: the form ``_run_bwd`` has to choose for this call
+    ("fused" or "split"), by the kernels' names in the traced gradient."""
     import jax
     import jax.numpy as jnp
 
@@ -389,6 +392,18 @@ def _flash_case(name, *, b, t, h, d, causal, masked, block, interpret, tol):
         def ref(q, k, v):
             return dot_product_attention(q, k, v, mask=mask,
                                          causal=causal).astype(q.dtype)
+    if backward is not None:
+        traced = str(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(kernel(*a).astype(jnp.float32)),
+            argnums=(0, 1, 2)))(q, k, v))
+        for form, names in (("fused", ("flash_attn_bwd_fused",)),
+                            ("split", ("flash_attn_bwd_dkv",
+                                       "flash_attn_bwd_dq"))):
+            for wanted in names:
+                _expect((wanted in traced) == (form == backward),
+                        f"{name}: the backward should be {backward} alone, "
+                        f"and {wanted} is "
+                        f"{'' if wanted in traced else 'not '}there")
     return _compare(name, kernel, ref, (q, k, v), tol)
 
 
@@ -731,9 +746,9 @@ def _gated_delta_step_case(name, *, t, vocab, tol):
     shared expert; router weights zero, so that every probability is
     1/512, the ten lowest-numbered experts are chosen on both branches and
     no near-tie decides differently): the compiled train step's kernel
-    count (the flash forward and the split backward's two kernels, and
-    thirteen kernels an expert layer as ``_hybrid_step_case`` counts
-    them), then logits and every gradient through the dispatch against
+    count (the flash forward and, from PR 46, the fused backward's one
+    kernel, and thirteen kernels an expert layer as ``_hybrid_step_case``
+    counts them), then logits and every gradient through the dispatch against
     the naive branch (the recurrence token by token as the benchmark's
     plain reference runs it, attention in ``jax.numpy``, the grouped
     products as ``jax.lax.ragged_dot``, the row movement as plain
@@ -761,14 +776,16 @@ def _gated_delta_step_case(name, *, t, vocab, tol):
     step = net.make_train_step(donate=False)
     text = step.lower(net.params, net.state, net.opt_state, x, labels, 0,
                       jax.random.PRNGKey(0), None).compile().as_text()
-    n_calls, want = text.count("tpu_custom_call"), 3 + 3 * 13 + 2 * (2 + 2)
+    n_calls, want = text.count("tpu_custom_call"), 2 + 3 * 13 + 2 * (2 + 2)
     _expect(n_calls == want,
             f"{name}: compiled train step holds {n_calls} "
             f"tpu_custom_call(s), expected {want}")
-    for kernel in ("flash_attn_fwd", "flash_attn_bwd_dkv",
-                   "flash_attn_bwd_dq", "gdn_fwd", "gdn_bwd",
-                   "causal_conv_fwd", "causal_conv_bwd"):
+    for kernel in ("flash_attn_fwd", "flash_attn_bwd_fused", "gdn_fwd",
+                   "gdn_bwd", "causal_conv_fwd", "causal_conv_bwd"):
         _expect(kernel in text, f"{name}: no {kernel} in the compiled step")
+    for kernel in ("flash_attn_bwd_dkv", "flash_attn_bwd_dq"):
+        _expect(kernel not in text,
+                f"{name}: {kernel} in the compiled step")
     state = net.state
 
     def logits(params):
@@ -798,7 +815,7 @@ def kernel_cases(interpret):
         fl = dict(b=2, t=128, h=1, d=16)
         return (
             [("flash_causal", dict(fl, causal=True, masked=False,
-                                   block=False)),
+                                   block=False, backward="fused")),
              ("flash_padding_mask", dict(fl, causal=False, masked=True,
                                          block=False)),
              ("flash_block", dict(fl, causal=True, masked=False,
@@ -832,6 +849,16 @@ def kernel_cases(interpret):
          ("flash_causal_t2048_h4_d128",
           dict(b=1, t=2048, h=4, d=128, causal=True, masked=False,
                block=False)),
+         # the two width-256 cells' length: one backward kernel a head,
+         # 19.06 MiB of VMEM asked of Mosaic at float32 inputs (PR 46)
+         ("flash_causal_t4096_h4_d256",
+          dict(b=1, t=4096, h=4, d=256, causal=True, masked=False,
+               block=False, backward="fused")),
+         # past the budget, where no cell is: the split form still
+         # compiles and matches
+         ("flash_causal_t8192_h4_d256_split",
+          dict(b=1, t=8192, h=4, d=256, causal=True, masked=False,
+               block=False, backward="split")),
          ("flash_padding_mask_t1024",
           dict(b=4, t=1024, h=8, d=64, causal=False, masked=True,
                block=False)),
@@ -895,11 +922,13 @@ def kernels_phase(*, interpret, tol):
         results.append(_gated_delta_step_case(
             "gated_delta_moe_lm_t2048_3layers", t=2048, vocab=1024,
             tol={**tol, "bwd": 0.05}))
-        results += [   # the two train cells' calls
+        results += [   # three train cells' calls (the last: glm47flash's)
             _flash_backward_time("flash_bwd_t1024_h16_d64_f32", b=4, t=1024,
                                  h=16, d=64, interpret=False),
             _flash_backward_time("flash_bwd_t2048_h16_d128_f32", b=2, t=2048,
-                                 h=16, d=128, interpret=False)]
+                                 h=16, d=128, interpret=False),
+            _flash_backward_time("flash_bwd_t4096_h20_d256_f32", b=1, t=4096,
+                                 h=20, d=256, interpret=False)]
     results += [_lstm_case(n, interpret=interpret, tol=tol, **kw)
                 for n, kw in lstm]
     return _say({"phase": "kernels", **_device_doc(), "interpret": interpret,

@@ -36,9 +36,12 @@ Kernel design (FlashAttention-style online softmax, TPU-first):
   jax.custom_vjp over a second kernel in the same layout and with the same
   causal skipping (``_bwd_kernel``): a piece's probabilities are recomputed
   from (q, k, v, lse) in VMEM, so the gradient costs no [BH, T, Bk]
-  temporary in HBM. One kernel a head with dq^T resident in VMEM where a
-  head's dq fits there (five products a piece), else a dK/dV kernel and a
-  dQ kernel (seven): ``_run_bwd`` chooses from the shape.
+  temporary in HBM. One kernel a head with dq^T resident in VMEM (five
+  products a piece) where what it holds there, counted from the shape and
+  the dtypes (``bwd_vmem_bytes``), is within ``_VMEM_BUDGET``: every cell's
+  call, width 256 at T 4,096 among them, for which Mosaic is asked for the
+  19 MiB it counts. Past the budget a dK/dV kernel and a dQ kernel (seven
+  products): ``_run_bwd`` chooses.
 
 ``interpret=True`` runs the same kernel on CPU for tests (slow);
 ``resolve_attention`` is the one place that chooses between this kernel
@@ -64,8 +67,9 @@ _NEG_INF = -1e30
 #: the widest head the kernels take: two lane tiles. The accumulators are
 #: [D, block] float32, so VMEM grows with D; 256 is the widest a cell runs
 #: (Qwen3-Next's full attention: [16, 4096, 256] float32, forward 1.26 ms
-#: = 55% of its least time, the split backward 1.79 + 1.43 ms; PERF.md
-#: section 5, PR 34) and the widest compiled for the chip
+#: = 55% of its least time, PERF.md section 5, PR 34; the backward alone
+#: 2.38 ms as one kernel a head where the split form's two took 3.39, PR
+#: 46) and the widest compiled for the chip
 _MAX_HEAD = 256
 
 
@@ -112,9 +116,9 @@ def resolve_attention(q_shape, k_shape, mask, dtype):
     kernel with. A TPU backend; self-attention shapes only (KV-cache
     decode goes naive); head_dim <= 256 (``_MAX_HEAD``; wider goes naive:
     measured on the chip at 64, 128 and, from PR 34, 256, where the
-    backward takes its split form from T 2,048 for float32 operands and
-    from T 4,096 for bfloat16); a float dtype; masks only as
-    key-side [B, Tk] padding, the reference's masking contract
+    backward is one kernel a head to T 4,096 (bfloat16 inputs: 6,656)
+    and takes its split form past that: ``_run_bwd``); a float dtype; masks
+    only as key-side [B, Tk] padding, the reference's masking contract
     (MaskedReductionUtil.java) — arbitrary-rank score masks go naive; and
     the measured crossover ``_MIN_SEQ``. Another block for another shape
     is a branch on the shape here, with the chip run that justifies it in
@@ -589,29 +593,65 @@ def _bwd_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask, form,
                     dq_s[i] * scale).T.astype(dq_ref.dtype)
 
 
-#: what one kernel may hold in VMEM: Mosaic's scoped limit is 16 MiB, and
-#: ops/lstm_pallas.py's ``supported`` keeps the same margin under it
-_VMEM_BUDGET = 14 * 1024 * 1024
+#: what Mosaic gives a kernel that asks for nothing (its default scoped
+#: limit on the v5e, of 128 MiB of VMEM), and what a call keeps free above
+#: its own count for what the compiler adds (``bwd_vmem_bytes`` has not read
+#: under the compiler's own need at any shape probed)
+_VMEM_DEFAULT = 16 * 1024 * 1024
+_VMEM_MARGIN = 1024 * 1024
+
+#: the largest count (``bwd_vmem_bytes``) the fused backward takes: set by
+#: measurement and not by the chip's 128 MiB, which would hold far more.
+#: Every shape a cell, chip_smoke.py or a test names whose count lies
+#: between what the default limit admitted (14 MiB) and here was timed in
+#: both forms on the v5e and the fused one won, the largest of them [*,
+#: 4096, 256] float32 at 19.06 MiB (PERF.md section 6, PR 46). Past it
+#: (T 8,192 at width 256 counts 31 MiB) the call stays split: the fused
+#: form won there too when timed alone, but no step has run it
+_VMEM_BUDGET = 20 * 1024 * 1024
 
 
-def bwd_vmem_bytes(form, t_pad, d, block_q, block_k, itemsize):
+def _bwd_compute_dtype(dtype, interpret):
+    """The dtype the backward's operands (q, k, v, g) enter the kernel in.
+    The matrix units round a float32 operand to bfloat16 as they take it
+    (default precision, as the forward's products and the einsums this
+    kernel replaced): rounded before the call the gradients are the same to
+    the bit (PERF.md, PR 28) and XLA keeps q, k, v for the backward at half
+    the bytes, as it did for the scan. The interpreter multiplies in
+    float32."""
+    return jnp.dtype(jnp.bfloat16 if dtype == jnp.float32 and not interpret
+                     else dtype)
+
+
+def bwd_vmem_bytes(form, t_pad, d, block_q, block_k, operand_size, grad_size):
     """VMEM the backward holds in one grid step, in the form it would take
-    ("fused", or "split": the larger of its two kernels): the operand and
-    gradient blocks twice (the pipeline's two buffers; a block keeps the
-    head's own width), the float32 accumulators, the product pieces in
-    flight and, fused, a whole head's dq: its output block twice and its
-    float32 accumulator, ``t_pad * d`` each. Checked against the compiler's
-    own count at [*, 8192, 128] float32, where it refuses the fused form by
-    0.46 MiB of 16 (PERF.md, PR 28)."""
+    ("fused", or "split": the larger of its two kernels). The operand
+    blocks (q, g, k, v) twice (the pipeline's two buffers) at
+    ``operand_size``, the compute dtype's (``_bwd_compute_dtype``: 2 for
+    float32 inputs on the chip); the gradient blocks twice at
+    ``grad_size``, the inputs' own; the float32 accumulators, the product
+    pieces in flight and, fused, a whole head's dq: its output block twice
+    at ``grad_size`` and its float32 accumulator, ``t_pad * d`` each. A
+    [rows, d] block lies in VMEM in whole lane tiles, so a width under 128
+    costs 128 there (the accumulators are [d, rows]: whole sublane tiles).
+    The one count: ``_run_bwd`` chooses the form by it and
+    ``_run_bwd_local`` asks Mosaic for it. Checked against the least
+    ``vmem_limit_bytes`` the compiler takes the fused kernel at (compiled
+    for the v5e, 16 heads; PERF.md section 6, PR 46): 19.06 MiB here and
+    there at [*, 4096, 256] float32, 16.56 against 15.72 at [*, 8192, 128]
+    (PR 28's compiler said 16.46 as it refused the default 16), 14.31
+    against 12.73 at [*, 8192, 64], 24.31 against 23.21 at [*, 16384, 64]:
+    never under it."""
+    lanes = -(-d // _LANE) * _LANE
     d8 = -(-d // 8) * 8
     pieces = 2 * _SCORES_AHEAD * _sub_tile(block_q) * _sub_tile(block_k) * 4
-    blocks = 2 * itemsize * d * (2 * block_q + 2 * block_k)   # q g k v
-    small = 2 * 8 * 4 * (block_q + block_k)                   # stats, mask
-    dkv = 2 * itemsize * d * 2 * block_k + 2 * d8 * block_k * 4
+    blocks = 2 * operand_size * lanes * (2 * block_q + 2 * block_k)  # q g k v
+    small = 2 * 8 * 4 * (block_q + block_k)                      # stats, mask
+    dkv = 2 * grad_size * lanes * 2 * block_k + 2 * d8 * block_k * 4
     if form == "fused":
         return blocks + small + pieces + dkv + (
-            2 * itemsize * d * t_pad + d8 * t_pad * 4)
-    dq = 2 * itemsize * d * block_q + d8 * block_q * 4
+            2 * grad_size * lanes * t_pad + d8 * t_pad * 4)
+    dq = 2 * grad_size * lanes * block_q + d8 * block_q * 4
     return blocks + small + pieces + max(dkv, dq)
 
 
@@ -619,17 +659,18 @@ def _run_bwd(res, g, g_lse, h, causal, scale, block_q, block_k, interpret):
     """(dq, dk, dv) [BH, T, D] from the forward's residuals and the
     cotangent ``g`` [BH, T, D]; ``g_lse`` [BH, T] or None is the cotangent
     on the log-sum-exp output (``flash_attention_block``). One kernel a
-    head with dq resident ("fused", five products a piece) where a head's
-    dq fits VMEM beside the blocks, else a dK/dV and a dQ kernel that each
-    recompute the probabilities ("split", seven products): chosen here,
-    from the shape. Once a batch shard under a declared mesh, as
-    ``_run_fwd``."""
+    head with dq resident ("fused", five products a piece) where what that
+    holds in VMEM (``bwd_vmem_bytes``) is within ``_VMEM_BUDGET``, else a
+    dK/dV and a dQ kernel that each recompute the probabilities ("split",
+    seven products): chosen here, from the shape and the dtypes. Once a
+    batch shard under a declared mesh, as ``_run_fwd``."""
     q, k, v, mask, out, lse = res
     if mask is not None and mask.shape[-1] == 0:   # zero-width = unmasked
         mask = None
     _, t, d = q.shape
     form = "fused" if bwd_vmem_bytes(
         "fused", _geometry(t, block_q, block_k)[2], d, block_q, block_k,
+        _bwd_compute_dtype(q.dtype, interpret).itemsize,
         q.dtype.itemsize) <= _VMEM_BUDGET else "split"
     arrays = [q, k, v, out, lse, g]
     arrays += [] if g_lse is None else [g_lse]
@@ -662,18 +703,24 @@ def _run_bwd_local(q, k, v, out, lse, g, g_lse, mask, h, causal, scale,
     # padded query rows have q = g = 0 and lse = delta = 0: p = 1, ds = 0,
     # nothing reaches dk or dv
     stats = _pad_to(jnp.stack([lse.astype(f32), delta], axis=1), t_pad, 2)
-    # the matrix units round a float32 operand to bfloat16 as they take it
-    # (default precision, as the forward's products and the einsums this
-    # kernel replaced): rounded here the gradients are the same to the bit
-    # (PERF.md, PR 28) and XLA keeps q, k, v for the backward at half the
-    # bytes, as it did for the scan. The interpreter multiplies in float32
-    cd = jnp.bfloat16 if q.dtype == f32 and not interpret else q.dtype
+    cd = _bwd_compute_dtype(q.dtype, interpret)
     operands = [_pad_to(x.astype(cd), t_pad, 1) for x in (q, k, v, g)]
     operands.append(stats)
     if mask is not None:
         operands.append(jnp.broadcast_to(
             _pad_to(mask.astype(f32), t_pad, 1)[:, None, :],
             (bh // h, 8, t_pad)))
+
+    # Mosaic is asked for VMEM only where this form's count, with the
+    # margin, passes what it gives unasked: a branch on the count, which
+    # the code observes. A call under it carries no compiler parameter, so
+    # a step made of such calls (the fused form at widths 64 and 128 to T
+    # 8,192 and 4,096, every split call) lowers the same whatever is asked
+    # for elsewhere
+    need = bwd_vmem_bytes(form, t_pad, d, block_q, block_k, cd.itemsize,
+                          q.dtype.itemsize) + _VMEM_MARGIN
+    asked = None if need <= _VMEM_DEFAULT else pltpu.CompilerParams(
+        vmem_limit_bytes=need)
 
     def call(form):
         kernel = functools.partial(
@@ -723,7 +770,7 @@ def _run_bwd_local(q, k, v, out, lse, g, g_lse, mask, h, causal, scale,
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=[jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype)]
             * len(out_specs), scratch_shapes=scratch, interpret=interpret,
-            name="flash_attn_bwd_" + form)(*operands)
+            name="flash_attn_bwd_" + form, compiler_params=asked)(*operands)
 
     if form == "fused":
         dq, dk, dv = call("fused")

@@ -802,7 +802,12 @@ class TestFlashBackwardKernel:
                                 (64, 256, 128, "split"),
                                 (64, 256, 256, "split"))
         for causal in (False, True) for masked in (False, True)
-        for t in (512, 300)])      # whole blocks; a ragged tail
+        for t in (512, 300)] + [   # whole blocks; a ragged tail
+        # the width the two width-256 cells run fused from ISSUE 46
+        (256, True, masked, t, 256, 256, form)
+        for masked, form in ((False, "fused"), (True, "fused"),
+                             (False, "split"))
+        for t in (512, 300)])
     def test_gradients_match_naive(self, d, causal, masked, t, block_q,
                                    block_k, form):
         args, want, empty = self._case(d, causal, masked, t, seed=d + t)
@@ -840,33 +845,102 @@ class TestFlashBackwardKernel:
         with pytest.raises(AssertionError):
             self._check(got, want, empty)
 
-    @pytest.mark.parametrize("t,d,itemsize,form", [
-        (1024, 64, 4, "fused"),      # the gpt2m-train-t1024 cell's call
-        (2048, 128, 4, "fused"),     # the ouro-train-t2048 cell's
-        (4096, 64, 2, "fused"),      # the longcontext configuration's
-        (8192, 128, 4, "split"),     # refused fused by the TPU compiler
-        (65536, 128, 2, "split")])
-    def test_form_follows_the_shape(self, monkeypatch, t, d, itemsize, form):
-        """``_run_bwd`` takes the fused form while a head's dq fits VMEM
-        beside the blocks and the split one past that; the count it
-        decides by is the tuner's validity model too."""
+    @pytest.mark.parametrize("t,d,dtype,interpret,form", [
+        (1024, 64, "float32", False, "fused"),    # gpt2m-train-t1024's call
+        (2048, 128, "float32", False, "fused"),   # ouro-train-t2048's
+        (8192, 64, "float32", False, "fused"),    # lfm2-train-t8192's
+        (4096, 128, "float32", False, "fused"),   # nemotron3nano's
+        (4096, 64, "bfloat16", False, "fused"),   # the longcontext one's
+        # from ISSUE 46, timed in both forms on the chip first (PERF.md):
+        (4096, 256, "float32", False, "fused"),   # glm47flash's, qwen3next's
+        (2048, 256, "float32", False, "fused"),   # chip_smoke.py's 3 layers
+        (4096, 256, "bfloat16", False, "fused"),
+        (8192, 128, "float32", False, "fused"),   # past the default limit
+        # past the budget: nobody timed the fused form there
+        (8192, 256, "float32", False, "split"),
+        (16384, 64, "float32", False, "split"),   # 64 wide costs 128 lanes
+        (65536, 128, "bfloat16", False, "split"),
+        # the interpreter multiplies in float32: 4 B operands, 21.06 MiB
+        (4096, 256, "float32", True, "split")])
+    def test_form_follows_the_shape(self, monkeypatch, t, d, dtype,
+                                    interpret, form):
+        """``_run_bwd`` takes the fused form while what it holds in VMEM,
+        operands at the compute dtype's size and gradients at the inputs',
+        is within the budget and the split one past that; the count it
+        decides by is the one ``_run_bwd_local`` asks Mosaic for."""
         seen = []
         monkeypatch.setattr(
             attention_pallas, "_run_bwd_local",
             lambda *a: seen.append(a[-1]) or (a[0], a[1], a[2]))
-        x = jax.ShapeDtypeStruct(
-            (2, t, d), jnp.float32 if itemsize == 4 else jnp.bfloat16)
+        dtype = jnp.dtype(dtype)
+        x = jax.ShapeDtypeStruct((2, t, d), dtype)
         lse = jax.ShapeDtypeStruct((2, t), jnp.float32)
         jax.eval_shape(
             lambda q, lse: attention_pallas._run_bwd(
                 (q, q, q, None, q, lse), q, None, 1, True, 0.125, 512, 512,
-                False), x, lse)
+                interpret), x, lse)
         assert seen == [form]
+        sizes = (attention_pallas._bwd_compute_dtype(dtype,
+                                                     interpret).itemsize,
+                 dtype.itemsize)
+        assert sizes[0] == (4 if interpret else 2)
         fused = attention_pallas.bwd_vmem_bytes("fused", t, d, 512, 512,
-                                                itemsize)
+                                                *sizes)
         assert (fused <= attention_pallas._VMEM_BUDGET) == (form == "fused")
         assert attention_pallas.bwd_vmem_bytes(
-            "split", t, d, 512, 512, itemsize) < 6 * 2 ** 20
+            "split", t, d, 512, 512, *sizes) < 10 * 2 ** 20
+
+    @pytest.mark.parametrize("t,d,sizes,mib", [
+        (4096, 256, (2, 4), 19.0625),   # operands 2, stats 0.06, pieces 2,
+                                        # dk/dv 2 + 1, dq 8 + 4
+        (4096, 256, (4, 4), 21.0625),   # what ISSUE 46 found it counting
+        (8192, 128, (2, 4), 16.5625),   # the compiler's own said 16.46
+        (2048, 256, (2, 4), 13.0625),
+        (8192, 64, (2, 4), 14.3125),    # in whole lane tiles: as 128 wide,
+        (16384, 64, (2, 4), 24.3125),   # but for the [64, T] accumulator
+        (4096, 256, (2, 2), 14.0625)])
+    def test_the_count_is_pinned(self, t, d, sizes, mib):
+        assert attention_pallas.bwd_vmem_bytes(
+            "fused", t, d, 512, 512, *sizes) == int(mib * 2 ** 20)
+
+    @staticmethod
+    def _backward_calls(t, d, dtype=jnp.float32, interpret=False):
+        """The ``pallas_call`` equations of one backward, through
+        ``_run_bwd`` (traced, never lowered: the CPU has no Mosaic)."""
+        x = jax.ShapeDtypeStruct((2, t, d), dtype)
+        lse = jax.ShapeDtypeStruct((2, t), jnp.float32)
+        jaxpr = jax.make_jaxpr(
+            lambda q, lse: attention_pallas._run_bwd(
+                (q, q, q, None, q, lse), q, None, 1, True, 0.125, 512, 512,
+                interpret))(x, lse)
+        return [e for e in jaxpr.jaxpr.eqns
+                if e.primitive.name == "pallas_call"]
+
+    @pytest.mark.parametrize("t,d,names", [
+        (1024, 64, ["flash_attn_bwd_fused"]),
+        (8192, 64, ["flash_attn_bwd_fused"]),
+        (4096, 128, ["flash_attn_bwd_fused"]),
+        (2048, 256, ["flash_attn_bwd_fused"]),
+        (8192, 256, ["flash_attn_bwd_dkv", "flash_attn_bwd_dq"])])
+    def test_under_the_default_limit_mosaic_is_asked_for_nothing(
+            self, t, d, names):
+        """The cells whose backward was fused before ISSUE 46 keep the call
+        they had, and so does every split call."""
+        calls = self._backward_calls(t, d)
+        assert [e.params["name"] for e in calls] == names
+        assert all(not e.params["compiler_params"] for e in calls)
+
+    @pytest.mark.parametrize("t,d", [(4096, 256), (8192, 128)])
+    def test_past_the_default_limit_the_fused_call_asks_for_its_count(
+            self, t, d):
+        (call,) = self._backward_calls(t, d)
+        assert call.params["name"] == "flash_attn_bwd_fused"
+        asked = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+        count = attention_pallas.bwd_vmem_bytes("fused", t, d, 512, 512, 2, 4)
+        assert attention_pallas._VMEM_DEFAULT < count \
+            + attention_pallas._VMEM_MARGIN == asked
+        assert asked <= attention_pallas._VMEM_BUDGET \
+            + attention_pallas._VMEM_MARGIN
 
     def test_custom_vjp_reaches_the_split_form(self, monkeypatch):
         """Through ``flash_attention``'s custom_vjp with the budget at 0:
@@ -1186,12 +1260,15 @@ class TestDefaultDispatchKernelsLowerForTpu:
     @pytest.mark.parametrize("b,t,h,d,kernels", [
         (4, 1024, 16, 64, ("fused",)),    # the gpt2m-train-t1024 cell's
         (2, 2048, 16, 128, ("fused",)),   # the ouro-train-t2048 cell's
-        (1, 8192, 2, 128, ("dkv", "dq")),  # a head's dq past VMEM
-        (1, 4096, 16, 256, ("dkv", "dq")),  # the qwen3next-train-t4096 cell's
+        # past the default limit, Mosaic asked for the count (ISSUE 46):
+        (1, 8192, 2, 128, ("fused",)),
+        (1, 4096, 16, 256, ("fused",)),   # the qwen3next-train-t4096 cell's
+        (1, 4096, 20, 256, ("fused",)),   # the glm47flash-train-t4096 cell's
+        (1, 8192, 2, 256, ("dkv", "dq")),  # a head's dq past the budget
     ])
     def test_flash_backward_is_a_kernel_under_its_scope(self, b, t, h, d,
                                                         kernels):
-        """float32 operands, as both cells hand them: the gradient holds
+        """float32 operands, as the cells hand them: the gradient holds
         the backward kernel(s) under ``flash_attn.bwd`` and no loop."""
         q = jnp.zeros((b, t, h, d), jnp.float32)
 

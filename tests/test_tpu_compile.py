@@ -1,0 +1,92 @@
+"""The flash backward compiled for a described v5e, Mosaic and XLA:TPU and
+all, with no chip (ISSUE 46): what lowering alone (test_ops.py's
+TestDefaultDispatchKernelsLowerForTpu) cannot show is whether Mosaic takes
+the kernel in the VMEM ``_run_bwd_local`` asks for, and that is what chooses
+the form. About two seconds a case.
+
+The TPU's library is loaded inside a fixture and by this file alone: one
+process at a time may hold it, so nothing here runs at import or at
+collection, and every compile is in the test's own process."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning4j_tpu.ops import attention_pallas
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    jcc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    jcc.reset_cache()
+
+
+def _compiled_backward(one_chip, bh, t, d, dtype, form=None):
+    """The compiled text of one causal backward at the dispatch's blocks,
+    in the form ``_run_bwd`` chooses or, with ``form``, in that one."""
+    x = jax.ShapeDtypeStruct((bh, t, d), dtype, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((bh, t), jnp.float32, sharding=one_chip)
+
+    def bwd(q, k, v, out, lse, g):
+        if form is None:
+            return attention_pallas._run_bwd(
+                (q, k, v, None, out, lse), g, None, 1, True, d ** -0.5, 512,
+                512, False)
+        return attention_pallas._run_bwd_local(
+            q, k, v, out, lse, g, None, None, 1, True, d ** -0.5, 512, 512,
+            False, form)
+    # the chip runs with 32-bit defaults; conftest's float64 mode would put
+    # f64 constants into the kernel body, which Mosaic refuses to cast
+    with jax.enable_x64(False):
+        return jax.jit(bwd).lower(x, x, x, x, lse, x).compile().as_text()
+
+
+@pytest.mark.parametrize("bh,t,d,dtype,kernels", [
+    # the cells' calls: the two at width 256 ask Mosaic for 20.06 MiB
+    (20, 4096, 256, jnp.float32, ("fused",)),     # glm47flash-train-t4096
+    (16, 4096, 256, jnp.float32, ("fused",)),     # qwen3next-train-t4096
+    (32, 8192, 64, jnp.float32, ("fused",)),      # lfm2: 14.31 MiB, unasked
+    (32, 4096, 128, jnp.float32, ("fused",)),     # nemotron3nano
+    (16, 2048, 256, jnp.float32, ("fused",)),     # chip_smoke.py's 3 layers
+    (16, 4096, 256, jnp.bfloat16, ("fused",)),    # 14.06 MiB, unasked
+    (32, 8192, 128, jnp.float32, ("fused",)),     # 16.56 MiB, asked
+    # past the budget
+    (8, 8192, 256, jnp.float32, ("dkv", "dq")),
+    (8, 16384, 64, jnp.float32, ("dkv", "dq")),
+])
+def test_the_chosen_backward_compiles_for_the_chip(
+        one_chip, no_compile_cache, bh, t, d, dtype, kernels):
+    text = _compiled_backward(one_chip, bh, t, d, dtype)
+    assert text.count("tpu_custom_call") >= len(kernels)
+    for form in ("fused", "dkv", "dq"):
+        assert ("flash_attn_bwd_" + form in text) == (form in kernels), form
+
+
+def test_mosaic_refuses_the_width_256_backward_at_its_default(
+        one_chip, no_compile_cache, monkeypatch):
+    """The control: without the ask the compiler refuses the call the two
+    width-256 cells make, which is why the rule used to split it."""
+    monkeypatch.setattr(attention_pallas, "_VMEM_DEFAULT", 1 << 40)
+    # 12 heads: ``_run_bwd_local`` keeps its traces by shape, and the
+    # cells' shapes above were traced with the ask
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compiled_backward(one_chip, 12, 4096, 256, jnp.float32, "fused")
