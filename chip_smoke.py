@@ -436,6 +436,52 @@ def _flash_backward_time(name, *, b, t, h, d, interpret, iters=20):
             "wall_s": round(time.perf_counter() - t0, 1)}
 
 
+def _flash_rounded_once_case(name, *, b, t, h, d, interpret):
+    """Both flash kernels read q, k and v rounded once to bfloat16
+    (``attention_pallas._operand_dtype``), which is what the matrix units
+    did to a float32 operand as they took it: so a causal call on float32
+    inputs and one on the same inputs rounded to bfloat16 and widened
+    again give the same out, dq, dk and dv to the bit. The digests name
+    the bits, so that two checkouts' records compare. Under the
+    interpreter the operands stay float32 and nothing is expected."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops import attention_pallas as _ap
+
+    t0 = time.perf_counter()
+    q, k, v, g = (jax.random.normal(key, (b, t, h, d), jnp.float32)
+                  for key in jax.random.split(jax.random.PRNGKey(t + d), 4))
+
+    @jax.jit
+    def run(q, k, v, g):
+        out, pullback = jax.vjp(
+            lambda q, k, v: _ap.flash_attention(q, k, v, causal=True,
+                                                interpret=interpret), q, k, v)
+        return (out, *pullback(g))
+    names = ("out", "dq", "dk", "dv")
+    given = dict(zip(names, run(q, k, v, g)))
+    rounded = dict(zip(names, run(*(
+        x.astype(jnp.bfloat16).astype(jnp.float32) for x in (q, k, v)), g)))
+    for n in names:
+        _expect(given[n].dtype == jnp.float32
+                and bool(jnp.isfinite(given[n]).all()),
+                f"{name}: {n} is {given[n].dtype} or not finite")
+    equal = {n: bool(jnp.array_equal(given[n], rounded[n])) for n in names}
+    _expect(interpret or all(equal.values()),
+            f"{name}: float32 inputs and the same rounded to bfloat16 "
+            f"differ: {equal}")
+    return {"kernel": name, "bit_equal": equal,
+            "max_abs_diff": {n: float(jnp.abs(given[n] - rounded[n]).max())
+                             for n in names},
+            "sha256": {n: hashlib.sha256(
+                np.asarray(given[n]).tobytes()).hexdigest()[:16]
+                for n in names},
+            "wall_s": round(time.perf_counter() - t0, 1)}
+
+
 def _gated_delta_case(name, *, b, t, hk, hv, d, interpret, tol):
     """The gated delta rule's two kernels alone against the ``jax.numpy``
     chunkwise form: outputs and the five gradients, q and k normalised as
@@ -928,7 +974,14 @@ def kernels_phase(*, interpret, tol):
             _flash_backward_time("flash_bwd_t2048_h16_d128_f32", b=2, t=2048,
                                  h=16, d=128, interpret=False),
             _flash_backward_time("flash_bwd_t4096_h20_d256_f32", b=1, t=4096,
-                                 h=20, d=256, interpret=False)]
+                                 h=20, d=256, interpret=False),
+            # glm47flash's call and gpt2m's: [20, 4096, 256], [64, 1024, 64]
+            _flash_rounded_once_case("flash_rounded_once_t4096_h20_d256",
+                                     b=1, t=4096, h=20, d=256,
+                                     interpret=False),
+            _flash_rounded_once_case("flash_rounded_once_t1024_h16_d64",
+                                     b=4, t=1024, h=16, d=64,
+                                     interpret=False)]
     results += [_lstm_case(n, interpret=interpret, tol=tol, **kw)
                 for n, kw in lstm]
     return _say({"phase": "kernels", **_device_doc(), "interpret": interpret,

@@ -42,6 +42,12 @@ Kernel design (FlashAttention-style online softmax, TPU-first):
   call, width 256 at T 4,096 among them, for which Mosaic is asked for the
   19 MiB it counts. Past the budget a dK/dV kernel and a dQ kernel (seven
   products): ``_run_bwd`` chooses.
+* Both kernels read ONE copy of q, k and v, rounded once to the dtype the
+  matrix units multiply in (``_operand_dtype``: bfloat16 for float32
+  callers on the chip) inside the forward's custom_vjp rule: the forward
+  kernel's operands are the backward's residuals, the backward rounds
+  only the cotangent, and the output and the three gradients keep the
+  caller's dtype.
 
 ``interpret=True`` runs the same kernel on CPU for tests (slow);
 ``resolve_attention`` is the one place that chooses between this kernel
@@ -289,9 +295,9 @@ def _attn_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask,
         the tile takes the length mask (when the call pads T) and the
         key-padding mask (when the call has one)."""
         def run():
-            # MXU inputs stay in the native dtype (bf16 under the mixed
-            # policy, 4x the f32 matmul rate on v5e) with f32 accumulation;
-            # only the softmax state is f32
+            # MXU inputs stay in the dtype they arrive in (``_operand_dtype``:
+            # bf16 on the chip, 4x the f32 matmul rate on v5e) with f32
+            # accumulation; only the softmax state is f32
             q = q_ref[0]                                     # [Bq, D]
             if fold:
                 q = q * scale
@@ -375,29 +381,35 @@ def _geometry(t, block_q, block_k):
     return block_q, block_k, -(-t // step) * step
 
 
-def _run_fwd(q, k, v, mask, h, causal, scale, block_q, block_k, interpret):
-    """q,k,v: [BH, T, D]; mask: None, or [B, T] f32 key-validity (1=valid)
-    with B = BH // h — the kernel indexes it per batch element (b // h) so
-    heads share one mask block. A zero-width [B, 0] mask means "no mask"
-    (the custom_vjp needs a real array operand; unmasked calls pay no mask
-    traffic in the kernel). Returns (out [BH, T, D], lse [BH, T]). Under a
-    declared device mesh the kernel runs once per batch shard (ops/spmd.py:
-    the bh = b * h + head fold is batch-major, so a contiguous BH shard is
-    a contiguous B shard and the mask shards with it)."""
+def _run_fwd(q, k, v, mask, h, causal, scale, block_q, block_k, interpret,
+             out_dtype=None):
+    """q,k,v: [BH, T, D], as the kernel reads them (the custom_vjp rules
+    hand them over rounded, ``_as_operands``); mask: None, or [B, T] f32
+    key-validity (1=valid) with B = BH // h — the kernel indexes it per
+    batch element (b // h) so heads share one mask block. A zero-width
+    [B, 0] mask means "no mask" (the custom_vjp needs a real array operand;
+    unmasked calls pay no mask traffic in the kernel). Returns (out
+    [BH, T, D] in ``out_dtype``, the caller's, q's where none is named;
+    lse [BH, T]). Under a declared device mesh the kernel runs once per
+    batch shard (ops/spmd.py: the bh = b * h + head fold is batch-major, so
+    a contiguous BH shard is a contiguous B shard and the mask shards with
+    it)."""
     if mask is not None and mask.shape[-1] == 0:
         mask = None
     arrays = (q, k, v) if mask is None else (q, k, v, mask)
+    out_dtype = jnp.dtype(q.dtype if out_dtype is None else out_dtype)
 
     def local(q, k, v, mask=None):
         return _run_fwd_local(q, k, v, mask, h, causal, scale, block_q,
-                              block_k, interpret)
+                              block_k, interpret, out_dtype)
     return _spmd.per_batch_shard(local, arrays, (0,) * len(arrays), (0, 0))
 
 
 @jax.named_scope("flash_attn.fwd")
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9), inline=True)
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10),
+                   inline=True)
 def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
-                   interpret):
+                   interpret, out_dtype):
     # jitted and inlined: the kernel body is some hundreds of operations
     # unrolled, and a model calls it once a layer with the same shapes, so
     # it is traced once and its equations are copied into each caller under
@@ -441,7 +453,7 @@ def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
             pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t_pad, d), out_dtype),
             jax.ShapeDtypeStruct((bh, 8, t_pad), jnp.float32),
         ],
         scratch_shapes=scratch,
@@ -453,17 +465,41 @@ def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
     return out[:, :t], lse[:, 0, :t]
 
 
+def _operand_dtype(dtype, interpret):
+    """The dtype q, k and v (and, in the backward, g) enter a kernel in,
+    for a caller whose arrays are ``dtype``. The matrix units round a
+    float32 operand to bfloat16 as they take it (default precision, as the
+    einsums of the XLA path): rounded before the call the results are the
+    same (the gradients to the bit on the chip, PERF.md, PR 28), the
+    kernels read half the bytes and the step keeps one copy of every head
+    array, not a float32 one for the forward beside a bfloat16 one for the
+    backward. bfloat16 stays; float64 keeps its width; the interpreter
+    multiplies in float32."""
+    return jnp.dtype(jnp.bfloat16 if dtype == jnp.float32 and not interpret
+                     else dtype)
+
+
+def _as_operands(interpret, *arrays):
+    """``arrays`` rounded once to ``_operand_dtype``: what the forward
+    kernel reads, the residuals and what the backward kernel reads are
+    this one copy. Applied inside the custom_vjp rules, so no gradient
+    passes back through the cast."""
+    cd = _operand_dtype(arrays[0].dtype, interpret)
+    return tuple(x.astype(cd) for x in arrays)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _attention(q, k, v, mask, causal, scale, block_q, block_k, interpret, h):
-    out, _ = _run_fwd(q, k, v, mask, h, causal, scale, block_q, block_k,
-                      interpret)
-    return out
+    return _attention_fwd(q, k, v, mask, causal, scale, block_q, block_k,
+                          interpret, h)[0]
 
 
 def _attention_fwd(q, k, v, mask, causal, scale, block_q, block_k,
                    interpret, h):
+    out_dtype = q.dtype
+    q, k, v = _as_operands(interpret, q, k, v)
     out, lse = _run_fwd(q, k, v, mask, h, causal, scale, block_q, block_k,
-                        interpret)
+                        interpret, out_dtype)
     return out, (q, k, v, mask, out, lse)
 
 
@@ -611,26 +647,14 @@ _VMEM_MARGIN = 1024 * 1024
 _VMEM_BUDGET = 20 * 1024 * 1024
 
 
-def _bwd_compute_dtype(dtype, interpret):
-    """The dtype the backward's operands (q, k, v, g) enter the kernel in.
-    The matrix units round a float32 operand to bfloat16 as they take it
-    (default precision, as the forward's products and the einsums this
-    kernel replaced): rounded before the call the gradients are the same to
-    the bit (PERF.md, PR 28) and XLA keeps q, k, v for the backward at half
-    the bytes, as it did for the scan. The interpreter multiplies in
-    float32."""
-    return jnp.dtype(jnp.bfloat16 if dtype == jnp.float32 and not interpret
-                     else dtype)
-
-
 def bwd_vmem_bytes(form, t_pad, d, block_q, block_k, operand_size, grad_size):
     """VMEM the backward holds in one grid step, in the form it would take
     ("fused", or "split": the larger of its two kernels). The operand
     blocks (q, g, k, v) twice (the pipeline's two buffers) at
-    ``operand_size``, the compute dtype's (``_bwd_compute_dtype``: 2 for
-    float32 inputs on the chip); the gradient blocks twice at
-    ``grad_size``, the inputs' own; the float32 accumulators, the product
-    pieces in flight and, fused, a whole head's dq: its output block twice
+    ``operand_size``, the residuals' (``_operand_dtype``: 2 for float32
+    callers on the chip); the gradient blocks twice at ``grad_size``, the
+    cotangent's, which is the caller's; the float32 accumulators, the
+    product pieces in flight and, fused, a whole head's dq: its block twice
     at ``grad_size`` and its float32 accumulator, ``t_pad * d`` each. A
     [rows, d] block lies in VMEM in whole lane tiles, so a width under 128
     costs 128 there (the accumulators are [d, rows]: whole sublane tiles).
@@ -656,7 +680,8 @@ def bwd_vmem_bytes(form, t_pad, d, block_q, block_k, operand_size, grad_size):
 
 
 def _run_bwd(res, g, g_lse, h, causal, scale, block_q, block_k, interpret):
-    """(dq, dk, dv) [BH, T, D] from the forward's residuals and the
+    """(dq, dk, dv) [BH, T, D] in ``g``'s dtype (the caller's) from the
+    forward's residuals (q, k, v as the forward kernel read them) and the
     cotangent ``g`` [BH, T, D]; ``g_lse`` [BH, T] or None is the cotangent
     on the log-sum-exp output (``flash_attention_block``). One kernel a
     head with dq resident ("fused", five products a piece) where what that
@@ -670,8 +695,7 @@ def _run_bwd(res, g, g_lse, h, causal, scale, block_q, block_k, interpret):
     _, t, d = q.shape
     form = "fused" if bwd_vmem_bytes(
         "fused", _geometry(t, block_q, block_k)[2], d, block_q, block_k,
-        _bwd_compute_dtype(q.dtype, interpret).itemsize,
-        q.dtype.itemsize) <= _VMEM_BUDGET else "split"
+        q.dtype.itemsize, g.dtype.itemsize) <= _VMEM_BUDGET else "split"
     arrays = [q, k, v, out, lse, g]
     arrays += [] if g_lse is None else [g_lse]
     arrays += [] if mask is None else [mask]
@@ -703,8 +727,9 @@ def _run_bwd_local(q, k, v, out, lse, g, g_lse, mask, h, causal, scale,
     # padded query rows have q = g = 0 and lse = delta = 0: p = 1, ds = 0,
     # nothing reaches dk or dv
     stats = _pad_to(jnp.stack([lse.astype(f32), delta], axis=1), t_pad, 2)
-    cd = _bwd_compute_dtype(q.dtype, interpret)
-    operands = [_pad_to(x.astype(cd), t_pad, 1) for x in (q, k, v, g)]
+    # q, k, v are the forward's own operands; the cotangent alone is
+    # rounded here, to their dtype
+    operands = [_pad_to(x, t_pad, 1) for x in (q, k, v, g.astype(q.dtype))]
     operands.append(stats)
     if mask is not None:
         operands.append(jnp.broadcast_to(
@@ -717,8 +742,8 @@ def _run_bwd_local(q, k, v, out, lse, g, g_lse, mask, h, causal, scale,
     # a step made of such calls (the fused form at widths 64 and 128 to T
     # 8,192 and 4,096, every split call) lowers the same whatever is asked
     # for elsewhere
-    need = bwd_vmem_bytes(form, t_pad, d, block_q, block_k, cd.itemsize,
-                          q.dtype.itemsize) + _VMEM_MARGIN
+    need = bwd_vmem_bytes(form, t_pad, d, block_q, block_k, q.dtype.itemsize,
+                          g.dtype.itemsize) + _VMEM_MARGIN
     asked = None if need <= _VMEM_DEFAULT else pltpu.CompilerParams(
         vmem_limit_bytes=need)
 
@@ -768,7 +793,7 @@ def _run_bwd_local(q, k, v, out, lse, g, g_lse, mask, h, causal, scale,
             out_specs, scratch = [q_spec], [pltpu.VMEM((1, d, block_q), f32)]
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-            out_shape=[jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype)]
+            out_shape=[jax.ShapeDtypeStruct((bh, t_pad, d), g.dtype)]
             * len(out_specs), scratch_shapes=scratch, interpret=interpret,
             name="flash_attn_bwd_" + form, compiler_params=asked)(*operands)
 
@@ -802,19 +827,15 @@ def flash_attention_block(q, k, v, causal, scale, interpret):
     parallel/sequence.py. The lse output lets the caller combine blocks by
     log-sum-exp; its cotangent is handled exactly (_run_bwd_local). The
     blocks are ``resolve_attention``'s, as ``flash_attention``'s are."""
-    b, t, h, d = q.shape
-    bq, bk = _resolved(q, k, None)
-    out, lse = _run_fwd(_fold_heads(q), _fold_heads(k), _fold_heads(v),
-                        None, h, causal, scale, bq, bk, interpret)
-    return _unfold_heads(out, b, h), lse.reshape(b, h, t)
+    return _flash_block_fwd(q, k, v, causal, scale, interpret)[0]
 
 
 def _flash_block_fwd(q, k, v, causal, scale, interpret):
     b, t, h, d = q.shape
     bq, bk = _resolved(q, k, None)
-    qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
+    qf, kf, vf = _as_operands(interpret, *map(_fold_heads, (q, k, v)))
     out, lse = _run_fwd(qf, kf, vf, None, h, causal, scale, bq, bk,
-                        interpret)
+                        interpret, q.dtype)
     return (_unfold_heads(out, b, h), lse.reshape(b, h, t)), \
         (qf, kf, vf, out, lse, b, h, bq, bk)
 

@@ -91,6 +91,18 @@ def test_flash_backward_time_at_toy_size(kernel_dispatch):
     assert r["kernel"] == "bwd_toy" and r["bwd_ms"] > 0
 
 
+def test_flash_rounded_once_case_at_toy_size(kernel_dispatch):
+    """The interpreter multiplies in float32, so rounding the inputs shows
+    (the case expects nothing there); the record names all four arrays."""
+    with kernel_dispatch():
+        r = smoke._flash_rounded_once_case("rounded_toy", b=1, t=128, h=2,
+                                           d=16, interpret=True)
+    assert set(r["bit_equal"]) == set(r["sha256"]) == {"out", "dq", "dk",
+                                                       "dv"}
+    assert not any(r["bit_equal"].values())
+    assert 0 < r["max_abs_diff"]["out"] < 0.05
+
+
 def test_multichip_phase_on_the_virtual_mesh(tmp_path, eight_devices):
     doc = smoke.multichip_phase(
         lambda: smoke.build_net(**TOY), vocab=TOY["vocab"],

@@ -865,24 +865,27 @@ class TestFlashBackwardKernel:
     def test_form_follows_the_shape(self, monkeypatch, t, d, dtype,
                                     interpret, form):
         """``_run_bwd`` takes the fused form while what it holds in VMEM,
-        operands at the compute dtype's size and gradients at the inputs',
+        operands at the residuals' size and gradients at the cotangent's,
         is within the budget and the split one past that; the count it
         decides by is the one ``_run_bwd_local`` asks Mosaic for."""
         seen = []
         monkeypatch.setattr(
             attention_pallas, "_run_bwd_local",
-            lambda *a: seen.append(a[-1]) or (a[0], a[1], a[2]))
+            lambda *a: seen.append(a[-1]) or (a[5], a[5], a[5]))
         dtype = jnp.dtype(dtype)
-        x = jax.ShapeDtypeStruct((2, t, d), dtype)
+        # the residuals are what the forward kernel read; the cotangent
+        # and the forward's output are the caller's
+        cd = attention_pallas._operand_dtype(dtype, interpret)
+        x = jax.ShapeDtypeStruct((2, t, d), cd)
+        g = jax.ShapeDtypeStruct((2, t, d), dtype)
         lse = jax.ShapeDtypeStruct((2, t), jnp.float32)
-        jax.eval_shape(
-            lambda q, lse: attention_pallas._run_bwd(
-                (q, q, q, None, q, lse), q, None, 1, True, 0.125, 512, 512,
-                interpret), x, lse)
+        grads = jax.eval_shape(
+            lambda q, g, lse: attention_pallas._run_bwd(
+                (q, q, q, None, g, lse), g, None, 1, True, 0.125, 512, 512,
+                interpret), x, g, lse)
         assert seen == [form]
-        sizes = (attention_pallas._bwd_compute_dtype(dtype,
-                                                     interpret).itemsize,
-                 dtype.itemsize)
+        assert all(a.dtype == dtype for a in grads)
+        sizes = (cd.itemsize, dtype.itemsize)
         assert sizes[0] == (4 if interpret else 2)
         fused = attention_pallas.bwd_vmem_bytes("fused", t, d, 512, 512,
                                                 *sizes)
@@ -905,14 +908,17 @@ class TestFlashBackwardKernel:
 
     @staticmethod
     def _backward_calls(t, d, dtype=jnp.float32, interpret=False):
-        """The ``pallas_call`` equations of one backward, through
-        ``_run_bwd`` (traced, never lowered: the CPU has no Mosaic)."""
-        x = jax.ShapeDtypeStruct((2, t, d), dtype)
+        """The ``pallas_call`` equations of one backward for a caller in
+        ``dtype``, through ``_run_bwd`` (traced, never lowered: the CPU has
+        no Mosaic)."""
+        x = jax.ShapeDtypeStruct(
+            (2, t, d), attention_pallas._operand_dtype(dtype, interpret))
+        g = jax.ShapeDtypeStruct((2, t, d), dtype)
         lse = jax.ShapeDtypeStruct((2, t), jnp.float32)
         jaxpr = jax.make_jaxpr(
-            lambda q, lse: attention_pallas._run_bwd(
-                (q, q, q, None, q, lse), q, None, 1, True, 0.125, 512, 512,
-                interpret))(x, lse)
+            lambda q, g, lse: attention_pallas._run_bwd(
+                (q, q, q, None, g, lse), g, None, 1, True, 0.125, 512, 512,
+                interpret))(x, g, lse)
         return [e for e in jaxpr.jaxpr.eqns
                 if e.primitive.name == "pallas_call"]
 
@@ -1296,6 +1302,84 @@ class TestDefaultDispatchKernelsLowerForTpu:
             assert operands.startswith(
                 ", ".join([f"tensor<{b * h}x{t}x{d}xbf16>"] * 4)), operands
             assert "bf16" not in results and f"x{d}xf32>" in results, results
+
+    @staticmethod
+    def _attention_loss(entry, interpret=False):
+        """The sum of an entry's outputs, through the dispatch (the layers'
+        call) or the ring block: every output takes a cotangent."""
+        from deeplearning4j_tpu.nn.layers.attention import \
+            dot_product_attention
+
+        def loss(q, k, v):
+            if entry == "dot_product_attention":
+                return jnp.sum(dot_product_attention(q, k, v, causal=True))
+            out, lse = attention_pallas.flash_attention_block(
+                q, k, v, True, 0.125, interpret)
+            return jnp.sum(out) + jnp.sum(lse).astype(out.dtype)
+        return jax.grad(loss, argnums=(0, 1, 2))
+
+    @pytest.mark.parametrize("entry", ["dot_product_attention",
+                                       "flash_attention_block"])
+    @pytest.mark.parametrize("dtype,rounded", [("float32", 4),
+                                               ("bfloat16", 0)])
+    def test_both_flash_kernels_read_one_rounded_copy(self, entry, dtype,
+                                                      rounded):
+        """ISSUE 47. From float32 callers q, k and v are rounded to
+        bfloat16 once, in the forward's rule: the forward kernel and the
+        backward kernel take those, the cotangent is the fourth and last
+        rounding, and the forward's result and the three gradients are
+        float32. From bfloat16 callers nothing is rounded."""
+        b, t, h, d = 1, 1024, 2, 64
+        q = jnp.zeros((b, t, h, d), dtype)
+        text = _lower_for_tpu(self._attention_loss(entry), q, q, q).as_text()
+        head = f"tensor<{b * h}x{t}x{d}x"
+        name = {"float32": "f32", "bfloat16": "bf16"}[dtype]
+        calls = {}
+        for ln in text.splitlines():
+            m = re.search(r'kernel_name = "(flash_attn_[a-z_]+)"', ln)
+            if m:
+                calls[m.group(1)] = ln.split(" : (")[1].split(") -> ")
+        assert sorted(calls) == ["flash_attn_bwd_fused", "flash_attn_fwd"]
+        operands, results = calls["flash_attn_fwd"]
+        assert operands == ", ".join([head + "bf16>"] * 3), operands
+        assert results.startswith(f"({head}{name}>, "), results
+        operands, results = calls["flash_attn_bwd_fused"]
+        assert operands.startswith(", ".join([head + "bf16>"] * 4)), operands
+        assert results.count(f"{head}{name}>") == 3, results
+        assert len(re.findall(
+            r"stablehlo\.convert [^\n]*-> " + re.escape(head + "bf16>"),
+            text)) == rounded
+
+    @pytest.mark.parametrize("entry", ["dot_product_attention",
+                                       "flash_attention_block"])
+    @pytest.mark.parametrize("dtype,interpret,operand", [
+        ("float64", False, "float64"),    # keeps its width
+        ("float32", True, "float32"),     # the interpreter multiplies in f32
+        ("bfloat16", True, "bfloat16"),
+        ("float32", False, "bfloat16")])  # the control: the chip's rule
+    def test_flash_operands_keep_their_dtype_off_the_matrix_units(
+            self, monkeypatch, entry, dtype, interpret, operand):
+        """Traced, not lowered (the interpreter and float64 are not the
+        chip's): what each ``pallas_call`` reads and writes."""
+        if interpret:
+            flash = attention_pallas.flash_attention
+            monkeypatch.setattr(
+                attention_pallas, "flash_attention",
+                lambda *a, **kw: flash(*a, **kw, interpret=True))
+        q = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.dtype(dtype))
+        jaxpr = jax.make_jaxpr(self._attention_loss(entry, interpret))(
+            q, q, q)
+        calls = {e.params["name"]: e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"}
+        assert sorted(calls) == ["flash_attn_bwd_fused", "flash_attn_fwd"]
+        fwd, bwd = calls["flash_attn_fwd"], calls["flash_attn_bwd_fused"]
+        assert [str(a.aval.dtype) for a in fwd.invars] == [operand] * 3
+        assert [str(a.aval.dtype) for a in bwd.invars[:4]] == [operand] * 4
+        # the same arrays, not a second copy: the backward's q, k, v are
+        # the forward's operands
+        assert bwd.invars[:3] == fwd.invars
+        assert str(fwd.outvars[0].aval.dtype) == dtype
+        assert [str(a.aval.dtype) for a in bwd.outvars] == [dtype] * 3
 
     @pytest.mark.parametrize("b,t,hk,hv,d,dtype", [
         (1, 4096, 16, 32, 128, jnp.float32),  # the qwen3next-train-t4096 cell's

@@ -1,18 +1,25 @@
-"""The flash backward compiled for a described v5e, Mosaic and XLA:TPU and
-all, with no chip (ISSUE 46): what lowering alone (test_ops.py's
+"""The flash kernels compiled for a described v5e, Mosaic and XLA:TPU and
+all, with no chip: what lowering alone (test_ops.py's
 TestDefaultDispatchKernelsLowerForTpu) cannot show is whether Mosaic takes
-the kernel in the VMEM ``_run_bwd_local`` asks for, and that is what chooses
-the form. About two seconds a case.
+the backward in the VMEM ``_run_bwd_local`` asks for, which is what chooses
+the form (ISSUE 46), and what XLA keeps of q, k and v around the two calls
+of a whole attention layer (ISSUE 47). Two to five seconds a case.
 
 The TPU's library is loaded inside a fixture and by this file alone: one
 process at a time may hold it, so nothing here runs at import or at
 collection, and every compile is in the test's own process."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+from deeplearning4j_tpu.nn.conf import inputs
+from deeplearning4j_tpu.nn.layers.attention import (LatentAttention,
+                                                    MultiHeadAttention)
 from deeplearning4j_tpu.ops import attention_pallas
+from deeplearning4j_tpu.utils import dtypes
 
 
 @pytest.fixture(scope="module")
@@ -41,8 +48,13 @@ def no_compile_cache():
 
 
 def _compiled_backward(one_chip, bh, t, d, dtype, form=None):
-    """The compiled text of one causal backward at the dispatch's blocks,
-    in the form ``_run_bwd`` chooses or, with ``form``, in that one."""
+    """The compiled text of one causal backward for a caller in ``dtype``
+    at the dispatch's blocks, in the form ``_run_bwd`` chooses or, with
+    ``form``, in that one. The residuals q, k, v are what the forward
+    kernel read (``_operand_dtype``); out and g are the caller's."""
+    r = jax.ShapeDtypeStruct(
+        (bh, t, d), attention_pallas._operand_dtype(dtype, False),
+        sharding=one_chip)
     x = jax.ShapeDtypeStruct((bh, t, d), dtype, sharding=one_chip)
     lse = jax.ShapeDtypeStruct((bh, t), jnp.float32, sharding=one_chip)
 
@@ -57,7 +69,7 @@ def _compiled_backward(one_chip, bh, t, d, dtype, form=None):
     # the chip runs with 32-bit defaults; conftest's float64 mode would put
     # f64 constants into the kernel body, which Mosaic refuses to cast
     with jax.enable_x64(False):
-        return jax.jit(bwd).lower(x, x, x, x, lse, x).compile().as_text()
+        return jax.jit(bwd).lower(r, r, r, x, lse, x).compile().as_text()
 
 
 @pytest.mark.parametrize("bh,t,d,dtype,kernels", [
@@ -90,3 +102,59 @@ def test_mosaic_refuses_the_width_256_backward_at_its_default(
     # cells' shapes above were traced with the ask
     with pytest.raises(Exception, match="(?i)vmem"):
         _compiled_backward(one_chip, 12, 4096, 256, jnp.float32, "fused")
+
+
+@pytest.fixture
+def on_the_chip(monkeypatch):
+    """The dispatch as the cells meet it: the backend gate open and the
+    TPU training policy (bfloat16 products, float32 results)."""
+    monkeypatch.setattr(attention_pallas, "backend_is_tpu", lambda: True)
+    dtypes.bf16_policy()
+    yield
+    dtypes.f32_policy()
+
+
+@pytest.mark.parametrize("layer,shape,heads", [
+    # one layer of gpt2m-train-t1024: [4, 1024, 1024], 16 heads of 64
+    (MultiHeadAttention(n_out=1024, n_heads=16, causal=True),
+     (4, 1024, 1024), (64, 1024, 64)),
+    # one of glm47flash-train-t4096: [1, 4096, 2048], 20 heads of 192 + 64
+    (LatentAttention(n_out=2048, n_heads=20, q_rank=768, kv_rank=512,
+                     nope_dim=192, rope_dim=64, v_dim=256, causal=True,
+                     rope_theta=1e6, norm_eps=1e-5),
+     (1, 4096, 2048), (20, 4096, 256)),
+], ids=["gpt2m", "glm47flash"])
+def test_a_layer_keeps_one_rounded_copy_of_its_heads(
+        one_chip, no_compile_cache, on_the_chip, layer, shape, heads):
+    """Forward and backward of one attention layer from float32
+    activations: the forward kernel reads no float32 head array, and under
+    ``flash_attn.bwd`` nothing but the cotangent is rounded (the parent
+    rounded q, k and v there again, beside the float32 copies the forward
+    read)."""
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda key: layer.init(
+                key, inputs.RecurrentType(shape[2], shape[1])),
+            jax.random.PRNGKey(0)))
+
+    def loss(params, x):
+        y, _ = layer.apply(params, {}, x, train=True)
+        return jnp.sum(y * y)
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, sds(shape)).compile().as_text()
+    head = "[" + ",".join(map(str, heads)) + "]"
+    (fwd,) = [ln for ln in text.splitlines()
+              if ln.lstrip().startswith("%flash_attn_fwd")
+              and "tpu_custom_call" in ln]
+    operands = fwd.split("operand_layout_constraints=")[1].split(
+        "frontend_attributes")[0]
+    assert operands.count("bf16" + head) == 3, operands
+    assert "f32" + head not in operands, operands
+    assert fwd.lstrip().split(" = ")[1].startswith("(f32" + head), fwd
+    rounded = [ln for ln in text.splitlines()
+               if re.search(r"= bf16" + re.escape(head) + r"\S* convert\(", ln)
+               and "flash_attn.bwd" in ln]
+    assert len(rounded) <= 1, rounded
