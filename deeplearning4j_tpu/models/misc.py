@@ -10,7 +10,11 @@ from __future__ import annotations
 from deeplearning4j_tpu.nn import layers as L
 from deeplearning4j_tpu.nn import updaters as U
 from deeplearning4j_tpu.nn.conf import inputs as I
-from deeplearning4j_tpu.nn.conf.network import NeuralNetConfig
+from deeplearning4j_tpu.nn.conf.network import NeuralNetConfig, ParamTie
+from deeplearning4j_tpu.nn.initializers import Distribution
+
+#: the language models' initialisation of every matrix
+_NORMAL_02 = Distribution(kind="normal", std=0.02)
 
 
 def simple_cnn(height=48, width=48, channels=3, n_classes=10, updater=None, seed=12345):
@@ -123,12 +127,14 @@ def transformer_lm(vocab_size, n_layers=4, d_model=256, n_heads=4,
     tier's flagship config and the fused-attention bench target). Input:
     [B, T] (or [B, T, 1]) integer token ids; output: per-timestep vocab
     softmax trained with cross-entropy."""
+    attention = L.MultiHeadAttention(n_out=d_model, n_heads=n_heads,
+                                     causal=True)
     return NeuralNetConfig(seed=seed,
                            updater=updater or U.Adam(learning_rate=3e-4)).list(
         L.EmbeddingSequenceLayer(n_in=vocab_size, n_out=d_model,
                                  add_positional=True),
-        *[L.TransformerBlock(n_out=d_model, n_heads=n_heads,
-                             mlp_ratio=mlp_ratio, causal=True)
+        *[L.TransformerBlock(n_out=d_model, mixer=attention,
+                             mlp_ratio=mlp_ratio)
           for _ in range(n_layers)],
         L.RnnOutputLayer(n_out=vocab_size, loss="mcxent"),
         input_type=I.RecurrentType(1, seq_len),
@@ -146,81 +152,58 @@ def looped_lm(vocab_size, n_layers=4, d_model=2048, n_heads=16, head_dim=None,
     (``LoopedLMOutputLayer``). Input: [B, T] integer token ids; labels:
     [B, T] integer next-token ids. The defaults are Ouro-2.6B's published
     widths at four of its 48 layers."""
-    from deeplearning4j_tpu.nn.initializers import Distribution
-    init = Distribution(kind="normal", std=0.02)
     block = L.TransformerBlock(
-        n_out=d_model, n_heads=n_heads, causal=True, activation="silu",
-        norm="rms", norm_eps=norm_eps, sandwich=True, bias=False,
-        rope_theta=rope_theta, head_dim=head_dim, ffn="gated",
-        ffn_width=ffn_width, weight_init=init)
+        n_out=d_model,
+        mixer=L.MultiHeadAttention(
+            n_out=d_model, n_heads=n_heads, causal=True, bias=False,
+            rope_theta=rope_theta, head_dim=head_dim,
+            weight_init=_NORMAL_02),
+        activation="silu", norm="rms", norm_eps=norm_eps, sandwich=True,
+        bias=False, ffn="gated", ffn_width=ffn_width, weight_init=_NORMAL_02)
     return NeuralNetConfig(seed=seed,
                            updater=updater or U.Adam(learning_rate=3e-4)).list(
         L.EmbeddingSequenceLayer(n_in=vocab_size, n_out=d_model,
-                                 weight_init=init),
+                                 weight_init=_NORMAL_02),
         L.LoopedStack(blocks=(block,) * n_layers, passes=passes,
                       final_norm=L.RMSNorm(eps=norm_eps)),
-        L.LoopedLMOutputLayer(n_out=vocab_size, beta=beta, weight_init=init),
+        L.LoopedLMOutputLayer(n_out=vocab_size, beta=beta,
+                              weight_init=_NORMAL_02),
         input_type=I.RecurrentType(1, seq_len),
     )
 
 
-#: `layer_types` entry -> `TransformerBlock.mixer`; the last is the
-#: single-part layers' FFN without a mixer
-_MIXERS = {"conv": "short_conv", "full_attention": "attention",
-           "linear_attention": "gated_delta", "mamba2": "mamba2",
-           "latent_attention": "latent_attention", "ffn": "none"}
-
-
-def _hybrid_decoder(vocab_size, layer_types, num_dense_layers, d_model,
-                    seq_len, block, dense, moe, final_norm, updater, seed,
-                    single_part=False, mtp_weight=None):
+def _hybrid_decoder(vocab_size, d_model, seq_len, layers, block, final_norm,
+                    updater, seed, mtp_weight=None):
     """The one loop behind the hybrid decoders: a token embedding, a
-    pre-norm ``TransformerBlock`` a layer whose mixer is what
-    ``layer_types[i]`` names (``"conv"``: the gated short convolution,
-    ``"full_attention"``: softmax attention, ``"linear_attention"``: the
-    gated delta rule, ``"mamba2"``: the state-space mixer,
-    ``"latent_attention"``: multi-head latent attention, the fifth,
-    ``"ffn"``: none) and whose FFN takes the fields ``dense`` for the first
-    ``num_dense_layers`` layers and ``moe`` for the rest, on top of
-    ``block``'s; with ``single_part`` a layer is one part alone, a mixer
-    without an FFN or (``"ffn"``) the reverse; ``final_norm`` and an
-    untied softmax head under ``sparse_mcxent``. With ``mtp_weight`` the
+    pre-norm ``TransformerBlock`` for each (mixer, FFN fields) pair of
+    ``layers`` (the mixer a layer object or None, the FFN fields on top of
+    ``block``'s, which every layer shares), ``final_norm`` and an untied
+    softmax head under ``sparse_mcxent``. With ``mtp_weight`` the
     head is a ``MultiTokenLMOutputLayer`` instead, which holds ``final_norm``'s
     gain itself (its module reads the state before that norm), runs one
     more block of the last layer's kind as its multi-token-prediction
     module, and reads the embedding table through the configuration's one
     ``ParamTie``."""
-    from deeplearning4j_tpu.nn.conf.network import ParamTie
-    from deeplearning4j_tpu.nn.initializers import Distribution
-    init = Distribution(kind="normal", std=0.02)
-    blocks = []
-    for i, kind in enumerate(layer_types):
-        if kind not in _MIXERS:
-            raise ValueError(
-                f"layer_types[{i}] is one of {sorted(_MIXERS)}, got "
-                f"{kind!r}")
-        ffn = dense if i < num_dense_layers else moe
-        if single_part and kind != "ffn":
-            ffn = {"ffn": "none"}
-        blocks.append(L.TransformerBlock(**{
-            "n_out": d_model, "causal": True, "activation": "silu",
-            "norm": "rms", "bias": False, "mixer": _MIXERS[kind],
-            "weight_init": init, **block, **ffn}))
+    blocks = [L.TransformerBlock(**{
+        "n_out": d_model, "mixer": mixer, "activation": "silu",
+        "norm": "rms", "bias": False, "weight_init": _NORMAL_02, **block,
+        **ffn}) for mixer, ffn in layers]
     if mtp_weight is None:
         head, ties = [final_norm,
                       L.RnnOutputLayer(n_out=vocab_size, loss="sparse_mcxent",
-                                       has_bias=False, weight_init=init)], ()
+                                       has_bias=False,
+                                       weight_init=_NORMAL_02)], ()
     else:
         head = [L.MultiTokenLMOutputLayer(
             n_out=vocab_size, block=blocks[-1], norm_eps=final_norm.eps,
-            weight_init=init, mtp_weight=mtp_weight)]
+            weight_init=_NORMAL_02, mtp_weight=mtp_weight)]
         ties = (ParamTie(layer=len(blocks) + 1, name="embed",
                          source_layer=0, source_name="W"),)
     return NeuralNetConfig(
         seed=seed,
         updater=updater or U.Adam(learning_rate=3e-4)).list(
         L.EmbeddingSequenceLayer(n_in=vocab_size, n_out=d_model,
-                                 weight_init=init),
+                                 weight_init=_NORMAL_02),
         *blocks, *head,
         input_type=I.RecurrentType(1, seq_len), ties=ties,
     )
@@ -236,27 +219,35 @@ def hybrid_moe_lm(vocab_size, layer_types=("conv", "full_attention"),
     ``lfm2_moe``; net-new): a token embedding, one pre-norm block a layer
     whose mixer is a gated short convolution (``"conv"``) or grouped-query
     attention with QK-norm and rotary positions (``"full_attention"``) as
-    ``layer_types`` says (``"linear_attention"``, the third kind the loop
-    takes, needs the widths ``gated_delta_moe_lm`` passes), and whose FFN
-    is a dense gated SiLU FFN for the first ``num_dense_layers`` layers
-    and ``n_experts`` routed experts (top-``top_k``, sigmoid scores, an
-    expert bias that moves the selection only, weights renormalised over
-    the selected) for the rest; a final RMSNorm and an untied softmax head
+    ``layer_types`` says, and whose FFN is a dense gated SiLU FFN for the
+    first ``num_dense_layers`` layers and ``n_experts`` routed experts
+    (top-``top_k``, sigmoid scores, an expert bias that moves the
+    selection only, weights renormalised over the selected) for the rest; a final RMSNorm and an untied softmax head
     under ``sparse_mcxent``. No bias anywhere. ``experts_held`` = (first,
     end) is the share of every expert layer that this network holds (() =
     all). Input: [B, T] integer token ids; labels: [B, T] integer
     next-token ids. The defaults are LFM2-24B-A2B's published widths."""
+    mixers = {
+        "conv": L.ShortConv(n_out=d_model, kernel=conv_kernel,
+                            weight_init=_NORMAL_02),
+        "full_attention": L.MultiHeadAttention(
+            n_out=d_model, n_heads=n_heads, causal=True, bias=False,
+            rope_theta=rope_theta, head_dim=head_dim, n_kv_heads=n_kv_heads,
+            qk_norm=True, qk_norm_eps=norm_eps, weight_init=_NORMAL_02)}
+    for i, kind in enumerate(layer_types):
+        if kind not in mixers:
+            raise ValueError(
+                f"layer_types[{i}] is one of {sorted(mixers)}, got "
+                f"{kind!r}")
+    dense = {"ffn": "gated", "ffn_width": ffn_width}
+    moe = {"ffn": "moe", "ffn_width": expert_width, "n_experts": n_experts,
+           "top_k": top_k, "experts_held": tuple(experts_held),
+           "routed_scale": routed_scale}
     return _hybrid_decoder(
-        vocab_size, layer_types, num_dense_layers, d_model, seq_len,
-        block={"n_heads": n_heads, "norm_eps": norm_eps,
-               "rope_theta": rope_theta, "head_dim": head_dim,
-               "n_kv_heads": n_kv_heads, "qk_norm": True,
-               "conv_kernel": conv_kernel},
-        dense={"ffn": "gated", "ffn_width": ffn_width},
-        moe={"ffn": "moe", "ffn_width": expert_width,
-             "n_experts": n_experts, "top_k": top_k,
-             "experts_held": tuple(experts_held),
-             "routed_scale": routed_scale},
+        vocab_size, d_model, seq_len,
+        [(mixers[kind], dense if i < num_dense_layers else moe)
+         for i, kind in enumerate(layer_types)],
+        block={"norm_eps": norm_eps},
         final_norm=L.RMSNorm(eps=norm_eps), updater=updater, seed=seed)
 
 
@@ -282,30 +273,27 @@ def gated_delta_moe_lm(vocab_size, n_layers=48, full_attention_interval=4,
     gain about zero (``x^ (1 + g)``). No bias anywhere, no dense layer.
     ``experts_held`` as ``hybrid_moe_lm``'s. The defaults are
     Qwen3-Next-80B-A3B's published widths and depth."""
-    layer_types = ["full_attention" if (i + 1) % full_attention_interval == 0
-                   else "linear_attention" for i in range(n_layers)]
+    attention = L.MultiHeadAttention(
+        n_out=d_model, n_heads=n_heads, causal=True, bias=False,
+        rope_theta=rope_theta, head_dim=head_dim, n_kv_heads=n_kv_heads,
+        qk_norm=True, qk_norm_eps=norm_eps, qk_norm_zero_centered=True,
+        rotary_dim=int(head_dim * partial_rotary_factor), gate=True,
+        weight_init=_NORMAL_02)
+    delta = L.GatedDeltaNet(
+        n_out=d_model, k_heads=linear_k_heads, v_heads=linear_v_heads,
+        head_dim=linear_k_head_dim, v_head_dim=linear_v_head_dim,
+        conv_kernel=conv_kernel, norm_eps=norm_eps, weight_init=_NORMAL_02)
+    moe = {"ffn": "moe", "ffn_width": expert_width, "n_experts": n_experts,
+           "top_k": top_k, "experts_held": tuple(experts_held),
+           "router": "softmax", "shared_expert_width": shared_expert_width}
     return _hybrid_decoder(
-        vocab_size, layer_types, 0, d_model, seq_len,
-        block={"n_heads": n_heads, "norm_eps": norm_eps,
-               "norm_zero_centered": True, "rope_theta": rope_theta,
-               "rotary_dim": int(head_dim * partial_rotary_factor),
-               "head_dim": head_dim, "n_kv_heads": n_kv_heads,
-               "qk_norm": True, "attn_gate": True,
-               "conv_kernel": conv_kernel, "linear_k_heads": linear_k_heads,
-               "linear_v_heads": linear_v_heads,
-               "linear_head_dim": linear_k_head_dim,
-               "linear_v_head_dim": linear_v_head_dim},
-        dense={},
-        moe={"ffn": "moe", "ffn_width": expert_width,
-             "n_experts": n_experts, "top_k": top_k,
-             "experts_held": tuple(experts_held), "router": "softmax",
-             "shared_expert_width": shared_expert_width},
+        vocab_size, d_model, seq_len,
+        [(attention if (i + 1) % full_attention_interval == 0 else delta,
+          moe) for i in range(n_layers)],
+        block={"norm_eps": norm_eps, "norm_zero_centered": True},
         final_norm=L.RMSNorm(eps=norm_eps, zero_centered=True),
         updater=updater, seed=seed)
 
-
-#: `hybrid_override_pattern` character -> `_hybrid_decoder`'s layer kind
-_PATTERN = {"M": "mamba2", "*": "full_attention", "E": "ffn"}
 
 #: NVIDIA-Nemotron-3-Nano-30B-A3B's 52 layers
 NEMOTRON_3_NANO_PATTERN = \
@@ -337,27 +325,31 @@ def state_space_moe_lm(vocab_size, pattern=NEMOTRON_3_NANO_PATTERN,
     ``hybrid_moe_lm``'s. The defaults
     are NVIDIA-Nemotron-3-Nano-30B-A3B's published widths and its 52-layer
     pattern."""
-    unknown = sorted(set(pattern) - set(_PATTERN))
+    alone = {"ffn": "none"}
+    parts = {
+        "M": (L.Mamba2Mixer(
+            n_out=d_model, heads=ssm_heads, head_dim=ssm_head_dim,
+            groups=ssm_groups, state=ssm_state, conv_kernel=conv_kernel,
+            chunk=ssm_chunk, norm_eps=norm_eps,
+            out_scale=len(pattern) ** -0.5, weight_init=_NORMAL_02), alone),
+        "*": (L.MultiHeadAttention(
+            n_out=d_model, n_heads=n_heads, causal=True, bias=False,
+            head_dim=head_dim, n_kv_heads=n_kv_heads,
+            weight_init=_NORMAL_02), alone),
+        "E": (None, {
+            "ffn": "moe", "ffn_width": expert_width, "n_experts": n_experts,
+            "top_k": top_k, "experts_held": tuple(experts_held),
+            "routed_scale": routed_scale, "expert_gated": False,
+            "shared_expert_width": shared_expert_width,
+            "shared_expert_gate": False})}
+    unknown = sorted(set(pattern) - set(parts))
     if unknown:
-        raise ValueError(f"pattern is made of {sorted(_PATTERN)} (Mamba-2, "
+        raise ValueError(f"pattern is made of {sorted(parts)} (Mamba-2, "
                          f"attention, experts), got {unknown}")
     return _hybrid_decoder(
-        vocab_size, [_PATTERN[c] for c in pattern], 0, d_model, seq_len,
-        block={"n_heads": n_heads, "norm_eps": norm_eps, "head_dim": head_dim,
-               "n_kv_heads": n_kv_heads, "conv_kernel": conv_kernel,
-               "ssm_heads": ssm_heads, "ssm_head_dim": ssm_head_dim,
-               "ssm_groups": ssm_groups, "ssm_state": ssm_state,
-               "ssm_chunk": ssm_chunk, "ssm_out_scale": len(pattern) ** -0.5,
-               "activation": "relu2"},
-        dense={},
-        moe={"ffn": "moe", "ffn_width": expert_width,
-             "n_experts": n_experts, "top_k": top_k,
-             "experts_held": tuple(experts_held),
-             "routed_scale": routed_scale, "expert_gated": False,
-             "shared_expert_width": shared_expert_width,
-             "shared_expert_gate": False},
-        final_norm=L.RMSNorm(eps=norm_eps), updater=updater, seed=seed,
-        single_part=True)
+        vocab_size, d_model, seq_len, [parts[c] for c in pattern],
+        block={"norm_eps": norm_eps, "activation": "relu2"},
+        final_norm=L.RMSNorm(eps=norm_eps), updater=updater, seed=seed)
 
 
 def latent_moe_lm(vocab_size, n_layers=47, num_dense_layers=1, d_model=2048,
@@ -386,19 +378,20 @@ def latent_moe_lm(vocab_size, n_layers=47, num_dense_layers=1, d_model=2048,
     ``ParamTie``) and the head. No bias anywhere. ``experts_held`` as
     ``hybrid_moe_lm``'s, the module's mixture too. The defaults are
     GLM-4.7-Flash's published widths and depth."""
+    latent = L.LatentAttention(
+        n_out=d_model, n_heads=n_heads, q_rank=q_rank, kv_rank=kv_rank,
+        nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim, causal=True,
+        rope_theta=rope_theta, norm_eps=norm_eps, weight_init=_NORMAL_02)
+    dense = {"ffn": "gated", "ffn_width": ffn_width}
+    moe = {"ffn": "moe", "ffn_width": expert_width, "n_experts": n_experts,
+           "top_k": top_k, "experts_held": tuple(experts_held),
+           "routed_scale": routed_scale,
+           "shared_expert_width": shared_expert_width,
+           "shared_expert_gate": False}
     return _hybrid_decoder(
-        vocab_size, ["latent_attention"] * n_layers, num_dense_layers,
-        d_model, seq_len,
-        block={"n_heads": n_heads, "norm_eps": norm_eps,
-               "rope_theta": rope_theta, "q_rank": q_rank,
-               "kv_rank": kv_rank, "nope_dim": nope_dim,
-               "rope_dim": rope_dim, "v_dim": v_dim},
-        dense={"ffn": "gated", "ffn_width": ffn_width},
-        moe={"ffn": "moe", "ffn_width": expert_width,
-             "n_experts": n_experts, "top_k": top_k,
-             "experts_held": tuple(experts_held),
-             "routed_scale": routed_scale,
-             "shared_expert_width": shared_expert_width,
-             "shared_expert_gate": False},
+        vocab_size, d_model, seq_len,
+        [(latent, dense if i < num_dense_layers else moe)
+         for i in range(n_layers)],
+        block={"norm_eps": norm_eps},
         final_norm=L.RMSNorm(eps=norm_eps), updater=updater, seed=seed,
         mtp_weight=mtp_weight)

@@ -22,10 +22,17 @@ from deeplearning4j_tpu.nn.layers.vae import (  # noqa: F401
 )
 from deeplearning4j_tpu.nn.layers.objdetect import Yolo2OutputLayer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.centerloss import CenterLossOutputLayer  # noqa: F401
-from deeplearning4j_tpu.nn.layers.attention import (  # noqa: F401
-    GatedDeltaNet, LatentAttention, LayerNormalization, Mamba2Mixer,
-    MultiHeadAttention, RMSNorm, ShortConv, TransformerBlock,
+from deeplearning4j_tpu.nn.layers.norms import (  # noqa: F401
+    LayerNormalization, RMSNorm,
 )
+from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention  # noqa: F401
+from deeplearning4j_tpu.nn.layers.mixers.short_conv import ShortConv  # noqa: F401
+from deeplearning4j_tpu.nn.layers.mixers.gated_delta import GatedDeltaNet  # noqa: F401
+from deeplearning4j_tpu.nn.layers.mixers.mamba2 import Mamba2Mixer  # noqa: F401
+from deeplearning4j_tpu.nn.layers.mixers.latent_attention import (  # noqa: F401
+    LatentAttention,
+)
+from deeplearning4j_tpu.nn.layers.block import TransformerBlock  # noqa: F401
 from deeplearning4j_tpu.nn.layers.looped import (  # noqa: F401
     LoopedLMOutputLayer, LoopedStack,
 )

@@ -37,9 +37,9 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn import activations as _act
 from deeplearning4j_tpu.nn import initializers as _init
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
-from deeplearning4j_tpu.nn.layers.attention import (LayerNormalization,
-                                                    MultiHeadAttention)
+from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention
 from deeplearning4j_tpu.nn.layers.base import Layer
+from deeplearning4j_tpu.nn.layers.norms import LayerNormalization
 from deeplearning4j_tpu.ops.grouped_matmul import grouped_matmul
 from deeplearning4j_tpu.ops.moe_rows import rows_back, rows_into_order
 from deeplearning4j_tpu.utils import dtypes as _dtypes

@@ -19,9 +19,9 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn import initializers as _init
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
-from deeplearning4j_tpu.nn.layers.attention import RMSNorm
 from deeplearning4j_tpu.nn.layers.base import ParamLayer
 from deeplearning4j_tpu.nn.layers.core import matmul
+from deeplearning4j_tpu.nn.layers.norms import RMSNorm
 from deeplearning4j_tpu.utils.serde import register_config
 
 

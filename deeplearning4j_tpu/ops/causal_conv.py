@@ -1,7 +1,7 @@
 """The short depthwise causal convolution of the conv and gated-delta
 mixers, with the elementwise work beside it, as one op (Pallas, TPU).
 
-What the two layers ask for (nn/layers/attention.py), on the leading
+What the two layers ask for (under nn/layers/mixers/), on the leading
 columns of a projection's result ``p`` [B, T, W] as it lies, ``C =
 w.shape[0]`` columns a part in the order ``[gate before | gate after |
 x]``, whatever follows them passing through untouched:

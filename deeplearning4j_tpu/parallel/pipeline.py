@@ -367,8 +367,10 @@ class PipelineParallelLM:
             f"{n_layers} layers not divisible into {self.n_stages} stages"
         self.embed = L.EmbeddingSequenceLayer(n_in=vocab_size, n_out=d_model,
                                               add_positional=True)
-        self.block = L.TransformerBlock(n_out=d_model, n_heads=n_heads,
-                                        mlp_ratio=mlp_ratio, causal=True)
+        self.block = L.TransformerBlock(
+            n_out=d_model, mlp_ratio=mlp_ratio,
+            mixer=L.MultiHeadAttention(n_out=d_model, n_heads=n_heads,
+                                       causal=True))
         self.updater = updater or U.Adam(learning_rate=3e-4)
         self.seed = seed
         self.remat = remat

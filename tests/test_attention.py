@@ -14,13 +14,16 @@ from deeplearning4j_tpu.nn import layers as L
 from deeplearning4j_tpu.nn import updaters as U
 from deeplearning4j_tpu.nn.conf import inputs as I
 from deeplearning4j_tpu.nn.conf.network import NeuralNetConfig
-from deeplearning4j_tpu.nn.layers.attention import (LayerNormalization, MultiHeadAttention,
-                                                    TransformerBlock, dot_product_attention)
+from deeplearning4j_tpu.nn.layers.attention import (MultiHeadAttention,
+                                                    dot_product_attention)
+from deeplearning4j_tpu.nn.layers.block import TransformerBlock
+from deeplearning4j_tpu.nn.layers.norms import LayerNormalization
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.parallel import MeshSpec, make_mesh
 from deeplearning4j_tpu.parallel.sequence import (make_ring_attention_fn,
                                                   ring_self_attention,
                                                   ulysses_self_attention)
+from deeplearning4j_tpu.utils import serde
 from deeplearning4j_tpu.utils.gradcheck import check_gradients
 
 F64 = jnp.float64
@@ -148,7 +151,8 @@ class TestAttentionLayers:
         y_cls = (x[:, :, 0].sum(1) > 0).astype(int)
         y = np.eye(2)[y_cls]
         conf = NeuralNetConfig(seed=2, updater=U.Adam(learning_rate=0.01)).list(
-            TransformerBlock(n_out=f, n_heads=2),
+            TransformerBlock(n_out=f, mixer=MultiHeadAttention(n_out=f,
+                                                               n_heads=2)),
             L.GlobalPoolingLayer(mode="avg"),
             L.OutputLayer(n_out=2, loss="mcxent"),
             input_type=I.RecurrentType(f, t),
@@ -158,6 +162,48 @@ class TestAttentionLayers:
         s0 = net.score(x, y)
         net.fit(x, y, epochs=30)
         assert net.score(x, y) < s0 * 0.7
+
+
+_MIXERS = [
+    MultiHeadAttention(n_out=32, n_heads=4, causal=True, bias=False),
+    L.ShortConv(n_out=32, kernel=3),
+    L.GatedDeltaNet(n_out=32, k_heads=2, v_heads=4, head_dim=8),
+    L.Mamba2Mixer(n_out=32, heads=4, head_dim=8, groups=2, state=16, chunk=8),
+    L.LatentAttention(n_out=32, n_heads=4, q_rank=24, kv_rank=16,
+                      nope_dim=12, rope_dim=4, v_dim=16, causal=True),
+]
+
+
+@pytest.mark.parametrize("mixer", _MIXERS, ids=lambda m: type(m).__name__)
+def test_a_block_holds_its_mixer_and_knows_only_where_its_parameters_sit(
+        mixer):
+    """The block's whole knowledge of a mixer: its output width, its
+    ``param_key``, ``init`` and ``apply``. The mixer travels nested
+    through JSON, draws its parameters from the key ``ln1`` is drawn
+    from, and one of another width is refused."""
+    block = TransformerBlock(n_out=32, mixer=mixer, norm="rms", bias=False,
+                             ffn="gated", ffn_width=48, activation="silu")
+    again = serde.from_json(serde.to_json(block))
+    assert again == block and type(again.mixer) is type(mixer)
+    assert len({type(m).param_key for m in _MIXERS}) == len(_MIXERS)
+    it = I.RecurrentType(32, 8)
+    key = jax.random.PRNGKey(5)
+    p = again.init(key, it)
+    assert set(p) == {"ln1", "ln2", "mlp_Wg", "mlp_Wu", "mlp_Wd",
+                      type(mixer).param_key}
+    own = mixer.init(jax.random.split(key, 4)[0], it)
+    got = p[mixer.param_key]
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(own)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(own)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 8, 32))
+    y, _ = again.apply(p, {}, x)
+    assert y.shape == x.shape and bool(jnp.all(jnp.isfinite(y)))
+    with pytest.raises(ValueError, match="residual is 16 wide"):
+        TransformerBlock(n_out=16, mixer=mixer).init(
+            key, I.RecurrentType(16, 8))
 
 
 class TestTransformerLM:

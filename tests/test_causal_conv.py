@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.nn.layers.attention import GatedDeltaNet, ShortConv
+from deeplearning4j_tpu.nn.layers.mixers.gated_delta import GatedDeltaNet
+from deeplearning4j_tpu.nn.layers.mixers.short_conv import ShortConv
 from deeplearning4j_tpu.ops import attention_pallas, causal_conv
 
 CONFIGS = os.path.join(os.path.dirname(__file__), "..", "benchmark",

@@ -383,9 +383,11 @@ def test_the_grouped_products_row_tile_follows_the_rows_a_group_expects():
 
 def _block(**kw):
     return L.TransformerBlock(**{**dict(
-        n_out=D, n_heads=2, causal=True, activation="silu", norm="rms",
-        norm_eps=1e-6, bias=False, ffn="moe", ffn_width=F, n_experts=E,
-        top_k=K, experts_held=(64, 96), router="softmax"), **kw})
+        n_out=D, mixer=L.MultiHeadAttention(n_out=D, n_heads=2, causal=True,
+                                            bias=False),
+        activation="silu", norm="rms", norm_eps=1e-6, bias=False, ffn="moe",
+        ffn_width=F, n_experts=E, top_k=K, experts_held=(64, 96),
+        router="softmax"), **kw})
 
 
 def test_the_blocks_mixture_is_routed_plus_gated_shared(layer):
@@ -440,27 +442,34 @@ TOY = dict(n_layers=4, d_model=32, n_heads=4, n_kv_heads=2, head_dim=16,
 def test_the_factory_builds_the_published_pattern():
     conf = models.gated_delta_moe_lm(64, **TOY)
     blocks = conf.layers[1:-2]
-    assert [b.mixer for b in blocks] == ["gated_delta"] * 3 + ["attention"]
+    assert [type(b.mixer).__name__ for b in blocks] == \
+        ["GatedDeltaNet"] * 3 + ["MultiHeadAttention"]
     assert all(b.ffn == "moe" and b.router == "softmax"
                and b.shared_expert_width == 16 and b.norm_zero_centered
-               and b.attn_gate and b.rotary_dim == 4 for b in blocks)
+               for b in blocks)
+    gated = blocks[3].mixer
+    assert gated.gate and gated.rotary_dim == 4 \
+        and gated.qk_norm_zero_centered
     assert conf.layers[-2].zero_centered
     full = models.gated_delta_moe_lm(151936)
-    kinds = [b.mixer for b in full.layers[1:-2]]
-    assert len(kinds) == 48 and kinds.count("attention") == 12
-    assert all(k == "attention" for k in kinds[3::4])
-    assert full.layers[4].rotary_dim == 64 and full.layers[4].head_dim == 256
-    # the loop is one: LFM2's factory names every kind it takes
-    with pytest.raises(ValueError, match="linear_attention"):
-        models.hybrid_moe_lm(64, layer_types=("conv", "mamba"))
+    kinds = [type(b.mixer).__name__ for b in full.layers[1:-2]]
+    assert len(kinds) == 48 and kinds.count("MultiHeadAttention") == 12
+    assert all(k == "MultiHeadAttention" for k in kinds[3::4])
+    assert full.layers[4].mixer.rotary_dim == 64 \
+        and full.layers[4].mixer.head_dim == 256
+    # LFM2's factory names the kinds it takes, and the delta rule's widths
+    # are not among its arguments
+    with pytest.raises(ValueError, match="full_attention"):
+        models.hybrid_moe_lm(64, layer_types=("conv", "linear_attention"))
     back = MultiLayerConfiguration.from_json(conf.to_json())
     assert back == conf
 
 
 def test_the_older_blocks_are_as_they_were():
     """New fields default to the old arithmetic and the old trees."""
-    block = L.TransformerBlock(n_out=16, n_heads=2, norm="rms", bias=False,
-                               ffn="moe", ffn_width=8, n_experts=4, top_k=2)
+    block = L.TransformerBlock(
+        n_out=16, mixer=L.MultiHeadAttention(n_out=16, n_heads=2, bias=False),
+        norm="rms", bias=False, ffn="moe", ffn_width=8, n_experts=4, top_k=2)
     state = block.init_state(I.RecurrentType(16, 8))
     assert set(state) == {"expert_bias", "moe_load", "moe_elsewhere"}
     p = block.init(jax.random.PRNGKey(0), I.RecurrentType(16, 8),
@@ -469,8 +478,8 @@ def test_the_older_blocks_are_as_they_were():
                       "moe_Wd"}
     assert float(p["ln1"]["gamma"].min()) == 1.0
     assert set(p["mha"]) == {"Wqkv", "Wo"}
-    with pytest.raises(ValueError, match="gated_delta"):
-        L.TransformerBlock(n_out=16, mixer="mamba").init(
+    with pytest.raises(ValueError, match="MIGRATION.md"):
+        L.TransformerBlock(n_out=16, mixer="gated_delta").init(
             jax.random.PRNGKey(0), I.RecurrentType(16, 8), jnp.float32)
 
 

@@ -350,12 +350,13 @@ def _primitives(jaxpr, into=None):
 
 
 @pytest.mark.parametrize("block,keys,dots", [
-    (L.TransformerBlock(n_out=16, n_heads=2, causal=True),
+    (L.TransformerBlock(n_out=16, mixer=L.MultiHeadAttention(
+        n_out=16, n_heads=2, causal=True)),
      {"ln1", "mha", "ln2", "mlp_W1", "mlp_W2", "mlp_b1", "mlp_b2"}, 6),
-    (L.TransformerBlock(n_out=16, n_heads=2, causal=True, activation="silu",
-                        norm="rms", norm_eps=1e-6, sandwich=True, bias=False,
-                        rope_theta=1e6, head_dim=8, ffn="gated",
-                        ffn_width=24),
+    (L.TransformerBlock(n_out=16, mixer=L.MultiHeadAttention(
+        n_out=16, n_heads=2, causal=True, bias=False, rope_theta=1e6,
+        head_dim=8), activation="silu", norm="rms", norm_eps=1e-6,
+        sandwich=True, bias=False, ffn="gated", ffn_width=24),
      {"ln1", "ln1_post", "mha", "ln2", "ln2_post", "mlp_Wg", "mlp_Wu",
       "mlp_Wd"}, 7),
 ], ids=["gpt2-block", "ouro-block"])
@@ -391,13 +392,14 @@ def test_serde_round_trip_of_the_new_fields():
     again = MultiLayerConfiguration.from_json(conf.to_json())
     assert again == conf
     block = again.layers[2]
-    assert (block.mixer, block.ffn, block.experts_held, block.top_k,
-            block.n_kv_heads, block.qk_norm) == (
-        "attention", "moe", (2, 6), 2, 2, True)
-    assert again.layers[1].mixer == "short_conv"
+    assert (type(block.mixer).__name__, block.ffn, block.experts_held,
+            block.top_k, block.mixer.n_kv_heads, block.mixer.qk_norm) == (
+        "MultiHeadAttention", "moe", (2, 6), 2, 2, True)
+    assert type(again.layers[1].mixer).__name__ == "ShortConv"
     assert again.layers[-1].has_bias is False
-    with pytest.raises(ValueError, match="mixer is"):
-        L.TransformerBlock(n_out=16, mixer="ssm").init(
+    # a configuration saved before the block held its mixer
+    with pytest.raises(ValueError, match="MIGRATION.md"):
+        L.TransformerBlock(n_out=16, mixer="short_conv").init(
             jax.random.PRNGKey(0), I.RecurrentType(16, 8))
     with pytest.raises(ValueError, match="experts_held"):
         L.TransformerBlock(n_out=16, bias=False, ffn="moe", n_experts=4,

@@ -126,18 +126,16 @@ def test_a_value_width_that_is_not_the_querys_is_refused():
 
 def test_the_block_takes_the_fifth_mixer_and_refuses_a_sixth():
     block = L.TransformerBlock(
-        n_out=32, n_heads=4, causal=True, norm="rms", bias=False,
-        mixer="latent_attention", q_rank=24, kv_rank=16, nope_dim=12,
-        rope_dim=4, v_dim=16, rope_theta=1e4, ffn="gated", ffn_width=48,
-        activation="silu")
+        n_out=32, mixer=_mixer(), norm="rms", bias=False, ffn="gated",
+        ffn_width=48, activation="silu")
     it = I.RecurrentType(32, 8)
     p = block.init(jax.random.PRNGKey(0), it)
     assert set(p) == {"ln1", "ln2", "mla", "mlp_Wg", "mlp_Wu", "mlp_Wd"}
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 32))
     y, _ = block.apply(p, {}, x)
     assert y.shape == x.shape and bool(jnp.all(jnp.isfinite(y)))
-    with pytest.raises(ValueError, match="'latent_attention'"):
-        dataclasses.replace(block, mixer="sixth").init(
+    with pytest.raises(ValueError, match="MIGRATION.md"):
+        dataclasses.replace(block, mixer="latent_attention").init(
             jax.random.PRNGKey(0), it)
 
 
@@ -371,18 +369,17 @@ def test_weight_zero_is_the_trunk_alone_loss_and_gradients():
     """`mtp_weight` 0 against the same decoder under a final RMSNorm and
     `RnnOutputLayer`: the loss and every gradient the two nets share."""
     tied = _tied_net(mtp_weight=0.0)
-    kw = {k: v for k, v in TOY.items()
-          if k not in ("vocab_size", "n_layers", "num_dense_layers",
-                       "d_model", "seq_len")}
+    latent = L.LatentAttention(
+        n_out=32, n_heads=TOY["n_heads"], q_rank=16, kv_rank=8, nope_dim=12,
+        rope_dim=4, v_dim=16, causal=True, rope_theta=1e6, norm_eps=1e-5,
+        weight_init=tied.conf.layers[0].weight_init)
     plain_conf = _hybrid_decoder(
-        80, ["latent_attention"] * 2, 1, 32, 16,
-        block={"n_heads": kw["n_heads"], "norm_eps": 1e-5,
-               "rope_theta": 1e6, "q_rank": 16, "kv_rank": 8,
-               "nope_dim": 12, "rope_dim": 4, "v_dim": 16},
-        dense={"ffn": "gated", "ffn_width": 48},
-        moe={"ffn": "moe", "ffn_width": 24, "n_experts": 8, "top_k": 2,
-             "experts_held": (2, 6), "routed_scale": 1.8,
-             "shared_expert_width": 24, "shared_expert_gate": False},
+        80, 32, 16,
+        [(latent, {"ffn": "gated", "ffn_width": 48}),
+         (latent, {"ffn": "moe", "ffn_width": 24, "n_experts": 8,
+                   "top_k": 2, "experts_held": (2, 6), "routed_scale": 1.8,
+                   "shared_expert_width": 24, "shared_expert_gate": False})],
+        block={"norm_eps": 1e-5},
         final_norm=L.RMSNorm(eps=1e-5), updater=None, seed=12345)
     assert plain_conf.layers[:3] == tied.conf.layers[:3]
     plain = MultiLayerNetwork(plain_conf)

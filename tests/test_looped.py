@@ -27,9 +27,11 @@ IT = I.RecurrentType(D, T)
 
 def _block(**kw):
     return L.TransformerBlock(
-        n_out=D, n_heads=H, causal=True, activation="silu", norm="rms",
-        norm_eps=1e-6, sandwich=True, bias=False, rope_theta=1e6,
-        head_dim=HD, ffn="gated", ffn_width=F, **kw)
+        n_out=D, mixer=L.MultiHeadAttention(
+            n_out=D, n_heads=H, causal=True, bias=False, rope_theta=1e6,
+            head_dim=HD),
+        activation="silu", norm="rms", norm_eps=1e-6, sandwich=True,
+        bias=False, ffn="gated", ffn_width=F, **kw)
 
 
 def _net(**kw):
@@ -122,7 +124,8 @@ def _parent_block_apply(params, x, n_heads):
 
 
 def test_the_default_block_is_the_parents_bit_for_bit():
-    block = L.TransformerBlock(n_out=32, n_heads=4, causal=True)
+    block = L.TransformerBlock(n_out=32, mixer=L.MultiHeadAttention(
+        n_out=32, n_heads=4, causal=True))
     key = jax.random.PRNGKey(3)
     got = block.init(key, I.RecurrentType(32, 8))
     want = _parent_block_init(key, 32, 4)

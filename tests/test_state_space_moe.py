@@ -20,7 +20,7 @@ from deeplearning4j_tpu.nn import activations
 from deeplearning4j_tpu.nn import layers as L
 from deeplearning4j_tpu.nn.conf import inputs as I
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
-from deeplearning4j_tpu.nn.layers import attention as A
+from deeplearning4j_tpu.nn.layers.mixers import mamba2 as A
 from deeplearning4j_tpu.nn.layers import moe
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.ops import attention_pallas, causal_conv
@@ -103,27 +103,31 @@ def test_the_mixer_is_the_references(t, chunk):
 # a block with one half absent
 # ---------------------------------------------------------------------------
 
+_MAMBA = L.Mamba2Mixer(n_out=32, heads=4, head_dim=8, groups=2, state=16,
+                       chunk=8, conv_kernel=4)
+_ATTENTION = L.MultiHeadAttention(n_out=32, n_heads=4, causal=True,
+                                  bias=False, head_dim=8, n_kv_heads=2)
+
+
 def _block(**kw):
     return L.TransformerBlock(**{
-        "n_out": 32, "n_heads": 4, "causal": True, "norm": "rms",
-        "bias": False, "head_dim": 8, "n_kv_heads": 2, "ssm_heads": 4,
-        "ssm_head_dim": 8, "ssm_groups": 2, "ssm_state": 16,
-        "ssm_chunk": 8, "conv_kernel": 4, "activation": "relu2", **kw})
+        "n_out": 32, "mixer": _ATTENTION, "norm": "rms", "bias": False,
+        "activation": "relu2", **kw})
 
 
 @pytest.mark.parametrize("kw,keys,scopes,absent", [
-    ({"mixer": "mamba2", "ffn": "none"}, {"ln1", "ssm"},
+    ({"mixer": _MAMBA, "ffn": "none"}, {"ln1", "ssm"},
      {"attn", "ssm", "ssm_conv", "ssd_core"}, {"mlp", "moe"}),
-    ({"mixer": "attention", "ffn": "none"}, {"ln1", "mha"}, {"attn"},
+    ({"mixer": _ATTENTION, "ffn": "none"}, {"ln1", "mha"}, {"attn"},
      {"mlp", "moe", "ssm"}),
-    ({"mixer": "none", "ffn": "moe", "ffn_width": 24, "n_experts": 16,
+    ({"mixer": None, "ffn": "moe", "ffn_width": 24, "n_experts": 16,
       "top_k": 3, "experts_held": (4, 12), "expert_gated": False,
       "shared_expert_width": 16, "shared_expert_gate": False},
      {"ln2", "moe_router", "moe_Wu", "moe_Wd", "moe_shared_Wu",
       "moe_shared_Wd"},
      {"mlp", "moe", "moe_route", "moe_experts", "moe_shared"},
      {"attn", "ssm"}),
-    ({"mixer": "none", "ffn": "gated", "ffn_width": 24},
+    ({"mixer": None, "ffn": "gated", "ffn_width": 24},
      {"ln2", "mlp_Wg", "mlp_Wu", "mlp_Wd"}, {"mlp"}, {"attn", "moe"})],
     ids=["mamba2-alone", "attention-alone", "mixture-alone", "ffn-alone"])
 def test_a_block_with_one_half_absent(kw, keys, scopes, absent):
@@ -142,10 +146,10 @@ def test_a_block_with_one_half_absent(kw, keys, scopes, absent):
     assert scopes <= seen and not absent & seen, (scopes - seen,
                                                   absent & seen)
     y, _ = block.apply(p, state, x)
-    norm, mixer, _ = block._parts()
+    norm, mixer = block._norm(), block.mixer
     part_in, _ = norm.apply(p["ln1" if mixer is not None else "ln2"], {}, x)
     if mixer is not None:
-        part, _ = mixer.apply(p[block._mixer_key()], {}, part_in)
+        part, _ = mixer.apply(p[mixer.param_key], {}, part_in)
     elif kw["ffn"] == "moe":
         part, _ = block._moe(p, state, part_in.reshape(24, 32))
     else:
@@ -156,10 +160,10 @@ def test_a_block_with_one_half_absent(kw, keys, scopes, absent):
 
 def test_a_block_with_neither_half_and_unknown_kinds_are_refused():
     it = I.RecurrentType(32, 12)
-    for kw, match in (({"mixer": "none", "ffn": "none"}, "or both"),
-                      ({"mixer": "mamba", "ffn": "none"}, "mixer is"),
-                      ({"mixer": "none", "ffn": "dense"}, "ffn is"),
-                      ({"mixer": "none", "ffn": "gated", "sandwich": True},
+    for kw, match in (({"mixer": None, "ffn": "none"}, "or both"),
+                      ({"mixer": "mamba2", "ffn": "none"}, "MIGRATION.md"),
+                      ({"mixer": None, "ffn": "dense"}, "ffn is"),
+                      ({"mixer": None, "ffn": "gated", "sandwich": True},
                        "whole block")):
         with pytest.raises(ValueError, match=match):
             _block(**kw).init(jax.random.PRNGKey(0), it, jnp.float32)
@@ -438,7 +442,7 @@ def test_the_shares_add_up_to_the_uncut_layer(layer):
     np.testing.assert_allclose(sum(parts) + ref.shared_expert(u, p, "f32"),
                                whole, rtol=2e-5, atol=2e-6)
     # the block's shared expert is the reference's: ungated, no gate on it
-    block = _block(mixer="none", ffn="moe", ffn_width=F, n_experts=E,
+    block = _block(mixer=None, ffn="moe", ffn_width=F, n_experts=E,
                    top_k=K, routed_scale=2.5, expert_gated=False,
                    shared_expert_width=FS, shared_expert_gate=False,
                    n_out=D)
@@ -459,28 +463,30 @@ def test_the_shares_add_up_to_the_uncut_layer(layer):
 def test_the_factory_reads_the_pattern():
     conf = models.state_space_moe_lm(64, **TOY)
     blocks = conf.layers[1:-2]
-    assert [(b.mixer, b.ffn) for b in blocks] == [
-        {"M": ("mamba2", "none"), "*": ("attention", "none"),
-         "E": ("none", "moe")}[c] for c in "MEMEM*EME"]
-    assert all(b.rope_theta is None and not b.qk_norm for b in blocks)
+    kinds = [(type(b.mixer).__name__, b.ffn) for b in blocks]
+    assert kinds == [
+        {"M": ("Mamba2Mixer", "none"), "*": ("MultiHeadAttention", "none"),
+         "E": ("NoneType", "moe")}[c] for c in "MEMEM*EME"]
+    assert blocks[5].mixer.rope_theta is None and not blocks[5].mixer.qk_norm
     assert all(not b.expert_gated and not b.shared_expert_gate
                and b.routed_scale == 2.5 and b.router == "sigmoid"
                for b in blocks if b.ffn == "moe")
-    assert blocks[0].ssm_out_scale == pytest.approx(1 / 3)
+    assert blocks[0].mixer.out_scale == pytest.approx(1 / 3)
     with pytest.raises(ValueError, match="pattern is made of"):
         models.state_space_moe_lm(64, **{**TOY, "pattern": "MEX"})
     # the defaults are the published widths and the 52-layer pattern
     full = models.state_space_moe_lm(131072)
-    kinds = [(b.mixer, b.ffn) for b in full.layers[1:-2]]
+    kinds = [(type(b.mixer).__name__, b.ffn) for b in full.layers[1:-2]]
     assert len(kinds) == 52
-    assert (kinds.count(("mamba2", "none")), kinds.count(("none", "moe")),
-            kinds.count(("attention", "none"))) == (23, 23, 6)
+    assert (kinds.count(("Mamba2Mixer", "none")),
+            kinds.count(("NoneType", "moe")),
+            kinds.count(("MultiHeadAttention", "none"))) == (23, 23, 6)
     e = full.layers[2]
     assert (e.n_out, e.ffn_width, e.shared_expert_width, e.n_experts,
             e.top_k) == (2688, 1856, 3712, 128, 6)
-    m = full.layers[1]
-    assert (m.ssm_heads, m.ssm_head_dim, m.ssm_groups, m.ssm_state,
-            m.ssm_chunk, m.conv_kernel) == (64, 64, 8, 128, 128, 4)
+    m = full.layers[1].mixer
+    assert (m.heads, m.head_dim, m.groups, m.state, m.chunk,
+            m.conv_kernel) == (64, 64, 8, 128, 128, 4)
 
 
 def test_serde_and_a_fit():

@@ -158,7 +158,8 @@ def test_softmax_heads_loss_is_named_inside_loss(make, builder):
 def test_transformer_block_names_its_halves():
     net = MultiLayerNetwork(NeuralNetConfig(
         seed=3, updater=U.Sgd(learning_rate=0.1)).list(
-        L.TransformerBlock(n_out=8, n_heads=2, causal=True),
+        L.TransformerBlock(n_out=8, mixer=L.MultiHeadAttention(
+            n_out=8, n_heads=2, causal=True)),
         L.RnnOutputLayer(n_out=3, loss="mcxent"),
         input_type=I.RecurrentType(8, T)))
     net.init()

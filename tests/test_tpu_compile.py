@@ -16,8 +16,9 @@ import jax.numpy as jnp
 import pytest
 
 from deeplearning4j_tpu.nn.conf import inputs
-from deeplearning4j_tpu.nn.layers.attention import (LatentAttention,
-                                                    MultiHeadAttention)
+from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention
+from deeplearning4j_tpu.nn.layers.mixers.latent_attention import \
+    LatentAttention
 from deeplearning4j_tpu.ops import attention_pallas
 from deeplearning4j_tpu.utils import dtypes
 

@@ -461,8 +461,9 @@ class TestTraceSurfaces:
 class TestServingTraces:
     def test_request_trace_decomposes_latency(self, traced):
         """One submitted request under load yields one connected trace
-        spanning submit->queue->drain->device->resolve; queue-wait + the
-        device-side phase spans decompose the recorded latency_s."""
+        spanning submit->queue->drain->device->resolve: every phase a
+        closed child of the request's root, which covers the recorded
+        latency_s."""
         from deeplearning4j_tpu.serving import ServingEngine
         net = _mlp(n_in=5, n_out=3)
         engine = ServingEngine(net, input_spec=(5,), buckets=(1, 2, 4))
@@ -485,19 +486,24 @@ class TestServingTraces:
             assert name in by, f"missing child span {name}"
         # every child parents under the request root: one connected trace
         root, = by["serving.request"]
-        for name, spans in by.items():
-            if name != "serving.request":
-                assert all(s["parent_id"] is not None for s in spans)
-        # decomposition: queue-wait + device-batch phases + resolve cover
-        # the recorded end-to-end latency (small structural gaps allowed:
-        # drain-loop filtering between pop and assemble)
-        decomposed = sum(
-            s["dur_s"] for name, spans in by.items() for s in spans
-            if name != "serving.request")
-        assert decomposed >= 0.5 * worst.latency_s
-        assert decomposed <= 1.5 * worst.latency_s
-        # the trace's own root duration brackets the latency it explains
-        assert doc["duration_s"] >= 0.9 * worst.latency_s
+        assert root["parent_id"] is None
+        parent = {s["span_id"]: s["parent_id"] for s in doc["spans"]}
+        for s in doc["spans"]:
+            at = s["span_id"]
+            while parent[at] is not None:
+                at = parent[at]
+            assert at == root["span_id"], s
+        # and is closed inside it. One clock, nested intervals (the trace
+        # opens before submit stamps the request and closes after the
+        # device is done), so this holds whatever the host's load; 1e-9
+        # is the documents' rounding
+        assert doc["duration_s"] is not None
+        for s in doc["spans"]:
+            assert s["dur_s"] is not None and s["dur_s"] >= 0, s
+            assert s["t0_s"] >= 0, s
+            assert s["t0_s"] + s["dur_s"] <= doc["duration_s"] + 2e-9, s
+        # the root's duration is not under the latency it explains
+        assert doc["duration_s"] >= worst.latency_s - 1e-9
         assert tracectx.open_trace_count() == 0
 
     def test_latency_histogram_tail_exemplar_links_to_ring(self, traced):
