@@ -5,7 +5,8 @@ from deeplearning4j_tpu.models.resnet import (  # noqa: F401
     resnet50, resnet50_mln)
 from deeplearning4j_tpu.models.vgg import vgg16, vgg19  # noqa: F401
 from deeplearning4j_tpu.models.misc import (  # noqa: F401
-    alexnet, darknet19, gated_delta_moe_lm, hybrid_moe_lm, latent_moe_lm,
+    alexnet, block_diffusion_moe_lm, darknet19, gated_delta_moe_lm,
+    hybrid_moe_lm, latent_moe_lm,
     looped_lm,
     simple_cnn, state_space_moe_lm,
     text_generation_lstm, tiny_yolo, transformer_lm,

@@ -173,7 +173,7 @@ def looped_lm(vocab_size, n_layers=4, d_model=2048, n_heads=16, head_dim=None,
 
 
 def _hybrid_decoder(vocab_size, d_model, seq_len, layers, block, final_norm,
-                    updater, seed, mtp_weight=None):
+                    updater, seed, mtp_weight=None, block_diffusion=None):
     """The one loop behind the hybrid decoders: a token embedding, a
     pre-norm ``TransformerBlock`` for each (mixer, FFN fields) pair of
     ``layers`` (the mixer a layer object or None, the FFN fields on top of
@@ -183,12 +183,17 @@ def _hybrid_decoder(vocab_size, d_model, seq_len, layers, block, final_norm,
     gain itself (its module reads the state before that norm), runs one
     more block of the last layer's kind as its multi-token-prediction
     module, and reads the embedding table through the configuration's one
-    ``ParamTie``."""
+    ``ParamTie``. With ``block_diffusion`` (a ``BlockDiffusionInput``) that
+    layer stands before the embedding and the head is a
+    ``BlockDiffusionLMOutputLayer`` behind ``final_norm``."""
     blocks = [L.TransformerBlock(**{
         "n_out": d_model, "mixer": mixer, "activation": "silu",
         "norm": "rms", "bias": False, "weight_init": _NORMAL_02, **block,
         **ffn}) for mixer, ffn in layers]
-    if mtp_weight is None:
+    if block_diffusion is not None:
+        head, ties = [final_norm, L.BlockDiffusionLMOutputLayer(
+            n_out=vocab_size, weight_init=_NORMAL_02)], ()
+    elif mtp_weight is None:
         head, ties = [final_norm,
                       L.RnnOutputLayer(n_out=vocab_size, loss="sparse_mcxent",
                                        has_bias=False,
@@ -202,6 +207,7 @@ def _hybrid_decoder(vocab_size, d_model, seq_len, layers, block, final_norm,
     return NeuralNetConfig(
         seed=seed,
         updater=updater or U.Adam(learning_rate=3e-4)).list(
+        *([] if block_diffusion is None else [block_diffusion]),
         L.EmbeddingSequenceLayer(n_in=vocab_size, n_out=d_model,
                                  weight_init=_NORMAL_02),
         *blocks, *head,
@@ -395,3 +401,49 @@ def latent_moe_lm(vocab_size, n_layers=47, num_dense_layers=1, d_model=2048,
         block={"norm_eps": norm_eps},
         final_norm=L.RMSNorm(eps=norm_eps), updater=updater, seed=seed,
         mtp_weight=mtp_weight)
+
+
+def block_diffusion_moe_lm(vocab_size, n_layers=48, d_model=2048, n_heads=32,
+                           n_kv_heads=4, head_dim=128, expert_width=768,
+                           n_experts=128, top_k=8, experts_held=(),
+                           block_len=4, mask_id=None, noise_seed=0,
+                           noise_eps=1e-3, recompute_experts=False,
+                           seq_len=4096, rope_theta=1e6, norm_eps=1e-6,
+                           updater=None, seed=12345):
+    """Mixture-of-experts decoder trained by block diffusion (the SDAR
+    family's ``sdar_moe``: Qwen3-MoE's layer under BD3-LM's objective,
+    arXiv:2510.06303, arXiv:2503.09573; net-new), through the loop
+    ``hybrid_moe_lm`` runs: every layer mixes by grouped-query attention
+    with QK-norm and rotary positions and routes ``n_experts`` gated SiLU
+    experts (top-``top_k`` of a float32 softmax over all of them, weights
+    renormalised over the selected; no shared expert, no bias). A
+    ``BlockDiffusionInput`` before the embedding masks each token of a
+    block of ``block_len`` with the block's own probability (``mask_id``,
+    None = the last row of the vocabulary; the draw keyed by
+    ``noise_seed`` and the layer's step counter) and hands the decoder the
+    noised and the clean copy side by side, 2 ``seq_len`` positions, which
+    every attention layer masks by ``BlockDiffusion(seq_len, block_len)``;
+    a ``BlockDiffusionLMOutputLayer`` reads the noised copy's rows.
+    Outside training the network is the plain causal decoder.
+    ``recompute_experts`` makes every layer's routed part again in the
+    backward pass (``TransformerBlock.recompute_moe``: 2 ``seq_len``
+    positions go through every mixture). Input: [B, ``seq_len``] integer
+    token ids; labels: the same ids.
+    ``experts_held`` as ``hybrid_moe_lm``'s. The defaults are
+    SDAR-30B-A3B-Chat's published widths and depth."""
+    attention = L.MultiHeadAttention(
+        n_out=d_model, n_heads=n_heads, causal=True, bias=False,
+        rope_theta=rope_theta, head_dim=head_dim, n_kv_heads=n_kv_heads,
+        qk_norm=True, qk_norm_eps=norm_eps,
+        block_diffusion=(seq_len, block_len), weight_init=_NORMAL_02)
+    moe = {"ffn": "moe", "ffn_width": expert_width, "n_experts": n_experts,
+           "top_k": top_k, "experts_held": tuple(experts_held),
+           "router": "softmax", "recompute_moe": bool(recompute_experts)}
+    return _hybrid_decoder(
+        vocab_size, d_model, seq_len, [(attention, moe)] * n_layers,
+        block={"norm_eps": norm_eps}, final_norm=L.RMSNorm(eps=norm_eps),
+        updater=updater, seed=seed,
+        block_diffusion=L.BlockDiffusionInput(
+            seq_len=seq_len, block_len=block_len,
+            mask_id=vocab_size - 1 if mask_id is None else mask_id,
+            noise_seed=noise_seed, eps=noise_eps))

@@ -39,4 +39,7 @@ from deeplearning4j_tpu.nn.layers.looped import (  # noqa: F401
 from deeplearning4j_tpu.nn.layers.multitoken import (  # noqa: F401
     MultiTokenLMOutputLayer,
 )
+from deeplearning4j_tpu.nn.layers.block_diffusion import (  # noqa: F401
+    BlockDiffusionInput, BlockDiffusionLMOutputLayer,
+)
 from deeplearning4j_tpu.nn.layers.moe import MoETransformerBlock  # noqa: F401

@@ -29,20 +29,23 @@ from deeplearning4j_tpu.utils import dtypes as _dtypes
 from deeplearning4j_tpu.utils.serde import register_config
 
 
-def rope(x, theta, rotary_dim=None):
+def rope(x, theta, rotary_dim=None, positions=None):
     """Rotary position embedding (Su et al. 2021) of ``x`` [B, T, H, D] at
-    positions 0..T-1, in the rotate-half convention over the whole head
-    width: pair ``i`` is (x[i], x[i + D/2]) and turns by
+    ``positions`` [T] (None: 0..T-1), in the rotate-half convention over
+    the whole head width: pair ``i`` is (x[i], x[i + D/2]) and turns by
     ``t * theta**(-2i/D)``. With ``rotary_dim`` < D (partial rotary) the
     first ``rotary_dim`` of a head turn so, as a head of that width would,
     and the rest pass through."""
     if rotary_dim is not None and rotary_dim < x.shape[-1]:
-        return jnp.concatenate([rope(x[..., :rotary_dim], theta),
-                                x[..., rotary_dim:]], axis=-1)
+        return jnp.concatenate(
+            [rope(x[..., :rotary_dim], theta, positions=positions),
+             x[..., rotary_dim:]], axis=-1)
     with jax.named_scope("rope"):
         t, d = x.shape[1], x.shape[-1]
         inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-        ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+        at = jnp.arange(t, dtype=jnp.float32) if positions is None \
+            else positions.astype(jnp.float32)
+        ang = at[:, None] * inv[None, :]
         cos = jnp.cos(ang)[None, :, None, :].astype(x.dtype)
         sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
         x1, x2 = x[..., :d // 2], x[..., d // 2:]
@@ -50,22 +53,27 @@ def rope(x, theta, rotary_dim=None):
                                axis=-1)
 
 
-def dot_product_attention(q, k, v, *, mask=None, causal=False, scale=None):
+def dot_product_attention(q, k, v, *, mask=None, causal=False, scale=None,
+                          geometry=None):
     """q,k,v: [B, T, H, D]. Returns [B, T, H, D]. bf16 matmuls, f32 softmax.
+    ``geometry`` (``attention_pallas.BlockDiffusion``) is a score mask of
+    its own in ``causal``'s place.
 
     On TPU, attention (incl. [B, Tk] key-padding-masked batches) dispatches
     to the fused flash kernel (ops/attention_pallas.py) — O(T*D) HBM
     traffic instead of the [B,H,T,T] logits tensor; the dispatch seam
-    mirrors the LSTM fused path."""
+    mirrors the LSTM fused path. The XLA path below takes a geometry as
+    its dense boolean mask."""
     from deeplearning4j_tpu.ops import attention_pallas as _ap
     # the kernel needs a static scale; read once per trace, and jit keeps
     # the chosen blocks in the compiled step
-    blocks = (_ap.resolve_attention(q.shape, k.shape, mask, q.dtype)
+    blocks = (_ap.resolve_attention(q.shape, k.shape, mask, q.dtype,
+                                    geometry)
               if scale is None or isinstance(scale, (int, float)) else None)
     if blocks is not None:
         return _ap.flash_attention(q, k, v, mask=mask, causal=causal,
                                    scale=scale, block_q=blocks[0],
-                                   block_k=blocks[1])
+                                   block_k=blocks[1], geometry=geometry)
     cd, ad = _dtypes.compute_dtypes_for(q.dtype)
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / jnp.sqrt(jnp.asarray(d, ad))
@@ -75,6 +83,8 @@ def dot_product_attention(q, k, v, *, mask=None, causal=False, scale=None):
         tq, tk = logits.shape[-2], logits.shape[-1]
         causal_mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
         logits = jnp.where(causal_mask, logits, -jnp.inf)
+    if geometry is not None:
+        logits = jnp.where(geometry.dense(), logits, -jnp.inf)
     if mask is not None:
         # mask: [B, Tk] -> key-side masking
         logits = jnp.where(mask[:, None, None, :] > 0, logits, -jnp.inf)
@@ -115,7 +125,14 @@ class MultiHeadAttention(ParamLayer):
     (``rope``); ``gate`` (with ``n_kv_heads``) doubles the query
     projection, ``Wq`` [n_in, H 2D] laid out a head [q | gate], and
     multiplies the attention's result by ``sigmoid(gate)`` elementwise
-    before ``Wo`` (Qwen3-Next's gated attention). As a block's mixer its
+    before ``Wo`` (Qwen3-Next's gated attention). ``block_diffusion`` =
+    (seq_len, block_len) makes the layer the attention of a
+    block-diffusion training step: with ``train=True`` the input is what
+    ``BlockDiffusionInput`` made in training, the two copies of a
+    sequence, ``2 seq_len`` positions masked and rotated as
+    ``attention_pallas.BlockDiffusion`` lays them out; with
+    ``train=False`` that layer passed the ids through, and this one is
+    what it is without the field, at any length. As a block's mixer its
     parameters sit under ``mha``."""
 
     n_out: int = 0     # model dim (also output dim)
@@ -130,6 +147,7 @@ class MultiHeadAttention(ParamLayer):
     qk_norm_zero_centered: bool = False
     rotary_dim: int | None = None
     gate: bool = False
+    block_diffusion: tuple = ()
     weight_init: object = dataclasses.field(default="xavier", kw_only=True)
 
     input_family = _inputs.RecurrentType
@@ -163,6 +181,22 @@ class MultiHeadAttention(ParamLayer):
                              "set bias=False")
         return kv
 
+    def _geometry(self, t, train):
+        """The score mask's geometry for ``t`` positions: None (a plain
+        sequence) outside training or without the field; in training
+        ``BlockDiffusionInput`` has doubled the sequence, and the layer
+        masks and rotates it as that layer laid it out."""
+        if not (train and self.block_diffusion):
+            return None
+        from deeplearning4j_tpu.ops.attention_pallas import BlockDiffusion
+        geometry = BlockDiffusion(*map(int, self.block_diffusion))
+        if t != 2 * geometry.seq_len:
+            raise ValueError(
+                f"block_diffusion {tuple(self.block_diffusion)}: a training "
+                f"step brings the two copies of a sequence, "
+                f"{2 * geometry.seq_len} positions; got {t}")
+        return geometry
+
     def output_type(self, input_type):
         return _inputs.RecurrentType(self.n_out, input_type.timesteps)
 
@@ -195,9 +229,10 @@ class MultiHeadAttention(ParamLayer):
             p["bo"] = jnp.zeros((self.n_out,), dtype)
         return p
 
-    def heads(self, params, x):
+    def heads(self, params, x, geometry=None):
         """Project to q,k,v [B,T,H,D] and the output gate's logits
-        [B,T,H,D] (None without ``gate``)."""
+        [B,T,H,D] (None without ``gate``); rotated at ``geometry``'s
+        positions where one is given, else at 0..T-1."""
         b, t, _ = x.shape
         h, d = self.n_heads, self._head_dim()
         kv = self._grouped()
@@ -221,8 +256,9 @@ class MultiHeadAttention(ParamLayer):
             q, _ = norm.apply({"gamma": params["q_gamma"]}, {}, q)
             k, _ = norm.apply({"gamma": params["k_gamma"]}, {}, k)
         if self.rope_theta is not None:
-            q = rope(q, self.rope_theta, self.rotary_dim)
-            k = rope(k, self.rope_theta, self.rotary_dim)
+            at = None if geometry is None else geometry.positions()
+            q = rope(q, self.rope_theta, self.rotary_dim, at)
+            k = rope(k, self.rope_theta, self.rotary_dim, at)
         if kv is not None and kv != h:
             # each key/value head serves its group of query heads; autodiff
             # sums the group's gradients back onto the one head
@@ -238,8 +274,11 @@ class MultiHeadAttention(ParamLayer):
         return y.reshape(b, t, self.n_out)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        q, k, v, gate = self.heads(params, x)
-        attn = dot_product_attention(q, k, v, mask=mask, causal=self.causal)
+        geometry = self._geometry(x.shape[1], train)
+        q, k, v, gate = self.heads(params, x, geometry)
+        attn = dot_product_attention(
+            q, k, v, mask=mask, causal=self.causal and geometry is None,
+            geometry=geometry)
         if gate is not None:
             with jax.named_scope("attn_gate"):
                 attn = attn * jax.nn.sigmoid(gate).astype(attn.dtype)

@@ -127,6 +127,40 @@ def pop_aux_losses(loss, states):
     return loss, out
 
 
+def pop_loss_mask(states):
+    """(the loss mask a layer handed on or None, cleaned states).
+
+    DL4J's ``Layer.feedForwardMaskArray`` contract in its ``Passthrough``
+    state, by ``pop_aux_losses``' mechanism: a layer that makes the
+    per-position weights of the loss inside the network (a
+    block-diffusion input layer: which tokens it masked, over their noise
+    level) stashes them in its per-step state under ``"loss_mask"``; the
+    layers after it do not apply them, and the container's loss function
+    pops them here and hands them to the output layer where no label mask
+    is fed. The last layer that stashed one wins. ``states`` is a list of
+    per-layer dicts (MultiLayerNetwork)."""
+    out, mask = list(states), None
+    for i, s in enumerate(states):
+        if isinstance(s, dict) and "loss_mask" in s:
+            s = dict(s)
+            mask = s.pop("loss_mask")
+            out[i] = s
+    return mask, out
+
+
+def refuse_loss_mask_layers(layers, who):
+    """Raise where a layer of ``layers`` hands the loss its weights
+    (``hands_loss_mask``: it stashes ``loss_mask``) and ``who`` is a loss
+    path that does not pop them: the weights would be dropped and the key
+    would stay in the carried state."""
+    for layer in layers:
+        if getattr(layer, "hands_loss_mask", False):
+            raise ValueError(
+                f"{type(layer).__name__} hands the loss its per-position "
+                f"weights, which {who} does not read; train it through "
+                "MultiLayerNetwork.fit / make_train_step")
+
+
 def dropout_mask(rng, x, rate):
     """Inverted dropout: scale retained units by 1/(1-rate)."""
     keep = 1.0 - rate

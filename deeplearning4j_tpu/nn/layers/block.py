@@ -7,6 +7,7 @@ them. The FFN half (dense, gated, routed experts) is still the block's own
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +53,11 @@ class TransformerBlock(Layer):
     ``expert_bias`` live in the layer's state. ``shared_expert_width`` > 0
     adds a shared expert of that width and the experts' form over every
     token, times ``sigmoid(x w_sg)`` unless ``shared_expert_gate=False``
-    (``moe_shared_*``; held whole whatever ``experts_held`` says)."""
+    (``moe_shared_*``; held whole whatever ``experts_held`` says).
+    ``recompute_moe`` keeps nothing of the routed experts' part for the
+    backward pass but its inputs (``jax.checkpoint``): the sorted buffers,
+    sized for all ``N k`` assignments, are made again there, which changes
+    what is kept and nothing of what is computed."""
 
     n_out: int = 0
     mixer: Layer | None = None
@@ -74,6 +79,7 @@ class TransformerBlock(Layer):
     expert_gated: bool = True
     shared_expert_width: int = 0
     shared_expert_gate: bool = True
+    recompute_moe: bool = False
 
     input_family = _inputs.RecurrentType
 
@@ -202,13 +208,16 @@ class TransformerBlock(Layer):
                 "expert bias) in the layer's state; this caller hands the "
                 "block none")
         act = _act.get(self.activation)
+        routed = functools.partial(
+            _moe.routed_experts, top_k=self.top_k, held=self._held(),
+            scale=self.routed_scale, act=act, score=self.router)
+        if self.recompute_moe:
+            routed = jax.checkpoint(routed)
         with jax.named_scope("moe"):
-            y, load, elsewhere = _moe.routed_experts(
+            y, load, elsewhere = routed(
                 h, params["moe_router"], params.get("moe_Wg"),
                 params["moe_Wu"], params["moe_Wd"],
-                state.get("expert_bias"), top_k=self.top_k,
-                held=self._held(), scale=self.routed_scale, act=act,
-                score=self.router)
+                state.get("expert_bias"))
             if self.shared_expert_width:
                 with jax.named_scope("moe_shared"):
                     if self.expert_gated:
@@ -243,7 +252,7 @@ class TransformerBlock(Layer):
             with jax.named_scope("attn"):
                 h, _ = norm.apply(params["ln1"], {}, x)
                 attn, _ = mixer.apply(params[mixer.param_key], {}, h,
-                                      mask=mask)
+                                      train=train, mask=mask)
                 if self.sandwich:
                     attn, _ = norm.apply(params["ln1_post"], {}, attn)
                 x = x + attn

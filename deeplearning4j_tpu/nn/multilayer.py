@@ -185,12 +185,21 @@ class MultiLayerNetwork:
         computeGradientAndScore at MultiLayerNetwork.java:2255 + calcL1/calcL2).
         Returns (loss, (new_state, predictions))."""
         out_layer = self.conf.layers[-1]
-        lm = label_mask if label_mask is not None else mask
+
+        def loss_mask(new_state):
+            """The mask the loss reads: the fed label mask, else the one a
+            layer handed on (``pop_loss_mask``), else the fed mask."""
+            handed, new_state = _base.pop_loss_mask(new_state)
+            if label_mask is not None:
+                return label_mask, new_state
+            return (mask if handed is None else handed), new_state
+
         if hasattr(out_layer, "loss_from_features"):
             # center-loss style heads need their input features for the loss
             feats, new_state = self.apply_fn(params, state, x, train=train,
                                              rng=rng, mask=mask,
                                              layer_limit=len(self.conf.layers) - 1)
+            lm, new_state = loss_mask(new_state)
             with jax.named_scope("loss"):
                 loss, preds, out_state = out_layer.loss_from_features(
                     self._tied(params)[-1], state[-1], feats, y, lm,
@@ -208,6 +217,7 @@ class MultiLayerNetwork:
             preds, new_state = self.apply_fn(
                 params, state, x, train=train, rng=rng, mask=mask,
                 logits=logits_loss is not None)
+            lm, new_state = loss_mask(new_state)
             with jax.named_scope("loss"):
                 loss = (logits_loss or out_layer.compute_loss)(preds, y, lm)
             if logits_loss is not None:
@@ -262,6 +272,7 @@ class MultiLayerNetwork:
 
     def make_tbptt_step(self, jit=True):
         conf = self.conf
+        _base.refuse_loss_mask_layers(conf.layers, "the truncated-BPTT step")
 
         def tbptt_step(params, state, opt_state, carries, x, y, step, rng, mask=None):
             carries = jax.tree_util.tree_map(jax.lax.stop_gradient, carries)

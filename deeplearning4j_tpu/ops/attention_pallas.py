@@ -32,6 +32,13 @@ Kernel design (FlashAttention-style online softmax, TPU-first):
   and whole pieces are told apart at trace time. The length mask applies
   only where T is padded and the tile holds the tail; the key padding
   mask on every tile of a masked call.
+* Block diffusion (``BlockDiffusion(seq_len, block_len)``, the training
+  mask of BD3-LM, arXiv:2503.09573): the sequence is a noised copy of
+  ``seq_len`` tokens followed by a clean copy, and three quarters of the
+  [2T, 2T] square is dead by whole tiles. ``_walk_block_diffusion`` tells
+  a tile dead (skipped, not fetched), whole (no mask) or cut (on its
+  copy's diagonal: blocks compared on the diagonal pieces alone) from the
+  grid position, as the causal walk does from the diagonal.
 * The kernel also emits the log-sum-exp per row. Backward is a
   jax.custom_vjp over a second kernel in the same layout and with the same
   causal skipping (``_bwd_kernel``): a piece's probabilities are recomputed
@@ -57,8 +64,10 @@ backend (the backend check is shared with ops/lstm_pallas.py).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +86,57 @@ _NEG_INF = -1e30
 #: 2.38 ms as one kernel a head where the split form's two took 3.39, PR
 #: 46) and the widest compiled for the chip
 _MAX_HEAD = 256
+
+
+class BlockDiffusion(NamedTuple):
+    """The score mask of block-diffusion training over ``2 seq_len``
+    positions, a noised copy of a sequence (positions 0..T-1) followed by
+    its clean copy (T..2T-1), in blocks of ``block_len`` tokens, ``b(i) =
+    (i mod T) // block_len``: a noised query sees the noised keys of its
+    own block and the clean keys of the blocks before it; a clean query
+    the clean keys of its own block and of those before it (BD3-LM's
+    ``M_BD``, ``M_OBC``, ``M_BC``). No clean query sees a noised key and
+    every query sees itself. The noised copy lies first so that, keys
+    walked in order, every query has met a key it sees (its own block's)
+    before the first piece that is cut away whole for it."""
+
+    seq_len: int
+    block_len: int
+
+    def dense(self):
+        """The mask as a boolean [2T, 2T] array (queries down, keys
+        along): what the XLA path applies and the tests compare with."""
+        t, l = self.seq_len, self.block_len
+        pos = jnp.arange(2 * t)
+        clean, blk = pos >= t, (pos % t) // l
+        q_clean, k_clean = clean[:, None], clean[None, :]
+        q_blk, k_blk = blk[:, None], blk[None, :]
+        return jnp.where(
+            q_clean, k_clean & (k_blk <= q_blk),
+            jnp.where(k_clean, k_blk < q_blk, k_blk == q_blk))
+
+    def join(self, noised, clean):
+        """The two copies [B, T, ...] side by side, [B, 2T, ...]: the one
+        place that says which lies first."""
+        return jnp.concatenate([noised, clean], axis=1)
+
+    def noised_rows(self, x):
+        """The noised copy's T rows of ``x`` [B, 2T, ...]."""
+        return x[:, :self.seq_len]
+
+    def positions(self):
+        """[2T] the position each row stands at: both copies at 0..T-1."""
+        return jnp.tile(jnp.arange(self.seq_len), 2)
+
+    def fits(self, t_total, block_q, block_k):
+        """Whether the kernels can walk this geometry in these blocks:
+        the call is the doubled sequence, each copy is whole tiles of one
+        square block, and a block of tokens never straddles a piece."""
+        sub = _sub_tile(min(block_q, block_k))
+        return (t_total == 2 * self.seq_len and block_q == block_k
+                and self.seq_len % block_q == 0
+                and sub % self.block_len == 0
+                and self.block_len & (self.block_len - 1) == 0)
 
 
 def backend_is_tpu():
@@ -116,7 +176,7 @@ _BLOCK_K = 512
 _SCORES_AHEAD = 16
 
 
-def resolve_attention(q_shape, k_shape, mask, dtype):
+def resolve_attention(q_shape, k_shape, mask, dtype, geometry=None):
     """The whole dispatch decision, from what the call shows: None where
     the XLA path should run, else the ``(block_q, block_k)`` to run the
     kernel with. A TPU backend; self-attention shapes only (KV-cache
@@ -126,10 +186,15 @@ def resolve_attention(q_shape, k_shape, mask, dtype):
     and takes its split form past that: ``_run_bwd``); a float dtype; masks
     only as key-side [B, Tk] padding, the reference's masking contract
     (MaskedReductionUtil.java) — arbitrary-rank score masks go naive; and
-    the measured crossover ``_MIN_SEQ``. Another block for another shape
-    is a branch on the shape here, with the chip run that justifies it in
-    PERF.md."""
+    the measured crossover ``_MIN_SEQ``. A ``geometry`` (``BlockDiffusion``)
+    stays on the kernel where its copies are whole tiles
+    (``BlockDiffusion.fits``) and the call has no key mask. Another block
+    for another shape is a branch on the shape here, with the chip run
+    that justifies it in PERF.md."""
     if not backend_is_tpu():
+        return None
+    if geometry is not None and (mask is not None or not geometry.fits(
+            q_shape[1], *_geometry(q_shape[1], _BLOCK_Q, _BLOCK_K)[:2])):
         return None
     if mask is not None:
         mshape = tuple(getattr(mask, "shape", ()))
@@ -146,11 +211,11 @@ def resolve_attention(q_shape, k_shape, mask, dtype):
     return _BLOCK_Q, _BLOCK_K
 
 
-def _resolved(q, k, mask):
+def _resolved(q, k, mask, geometry=None):
     """``resolve_attention`` for a caller that has already chosen the
     kernel: a call the dispatch would hand to XLA is refused, not given a
     geometry of its own."""
-    blocks = resolve_attention(q.shape, k.shape, mask, q.dtype)
+    blocks = resolve_attention(q.shape, k.shape, mask, q.dtype, geometry)
     if blocks is None:
         raise ValueError(
             f"flash attention does not take q {q.shape}, k {k.shape} "
@@ -189,12 +254,22 @@ def _when(pred, fn):
         pl.when(pred)(fn)
 
 
+def _cut(causal_mask):
+    """A tile's kind of cut: None, "diag" or "iota" (the causal walk's),
+    "same" or "under" (a block-diffusion tile on its copy's diagonal,
+    which travels as a tuple with what its pieces compare)."""
+    return causal_mask[0] if isinstance(causal_mask, tuple) else causal_mask
+
+
 def _pieces(block_q, block_k, sub_q, sub_k, causal_mask):
     """(r0, c0) of a tile's [sub_q, sub_k] pieces, rows outermost; on a
-    "diag" tile the pieces wholly above the diagonal are left out."""
+    "diag" or "under" tile the pieces wholly above the diagonal are left
+    out, on a "same" tile all but the diagonal's."""
+    kind = _cut(causal_mask)
     return [(r0, c0) for r0 in range(0, block_q, sub_q)
             for c0 in range(0, block_k, sub_k)
-            if not (causal_mask == "diag" and c0 > r0 + sub_q - 1)]
+            if not (kind in ("diag", "under") and c0 > r0 + sub_q - 1)
+            and not (kind == "same" and c0 != r0)]
 
 
 def _issued_ahead(pieces, product):
@@ -217,6 +292,17 @@ def _piece_valid(causal_mask, t_true, mask_ref, iq, j, block_q, block_k, r0,
     ``j``). ``causal_mask`` is the tile's (see ``tile``); ``t_true`` the
     true length where the tile takes the length mask, else None;
     ``mask_ref`` the key-padding block where it takes that one, else None."""
+    if isinstance(causal_mask, tuple):
+        # a block-diffusion tile on its copy's diagonal (square pieces,
+        # whole blocks of tokens in each): under the diagonal piece a
+        # piece is whole; on it a key's block is compared with the
+        # query's, within the piece
+        if c0 != r0:
+            return None
+        kind, shift, *seen = causal_mask
+        key = jax.lax.broadcasted_iota(jnp.int32, (sub_k, 1), 0) >> shift
+        query = jax.lax.broadcasted_iota(jnp.int32, (1, sub_q), 1) >> shift
+        return key == query if kind == "same" else key < query + seen[0]
     masks = []
     cut = causal_mask == "iota" or (
         causal_mask == "diag" and c0 + sub_k - 1 > r0)
@@ -235,14 +321,58 @@ def _piece_valid(causal_mask, t_true, mask_ref, iq, j, block_q, block_k, r0,
     return functools.reduce(jnp.logical_and, masks) if masks else None
 
 
+def _walk_block_diffusion(tile, geometry, iq, j, block):
+    """``_walk_tiles`` under a ``BlockDiffusion`` geometry (square blocks,
+    each copy ``half`` whole tiles, no key mask and no padding:
+    ``BlockDiffusion.fits``). Dead, and so neither run nor fetched: every
+    clean-query x noised-key tile, the noised x noised tiles off the
+    diagonal, and the tiles above its copy's diagonal in the two quarters
+    with clean keys. Whole: the clean-key tiles under that diagonal. Cut,
+    on the diagonal pieces alone: the noised x noised diagonal ("same":
+    a key of the query's own block) and the clean-key diagonals ("under":
+    a block before the query's, or up to its own for a clean query),
+    which share one body."""
+    half = geometry.seq_len // block
+    shift = geometry.block_len.bit_length() - 1
+    noised_q = iq < half
+    ii = jnp.where(noised_q, iq, iq - half)    # the tile's place in its copy
+    jj = j - half
+    _when(_all(j >= half, jj < ii), tile(None, False))
+    _when(_all(noised_q, j == iq), tile(("same", shift), False))
+    _when(_all(j >= half, jj == ii),
+          tile(("under", shift, jnp.where(noised_q, 0, 1)), False))
+
+
+def _block_diffusion_key_block(geometry, block, i, j):
+    """The key block that step ``j`` of query block ``i`` names: ``j``
+    where the tile is live, else the live one nearest before it (or the
+    first), so that nothing is fetched for a dead step."""
+    half = geometry.seq_len // block
+    ii = jnp.where(i < half, i, i - half)
+    return jnp.where(j < half, jnp.where(i < half, i, half),
+                     jnp.minimum(j, half + ii))
+
+
+def _block_diffusion_query_block(geometry, block, j, i):
+    """The query block that step ``i`` of key block ``j`` names, as
+    ``_block_diffusion_key_block``: a noised key block meets its own
+    query block alone; a clean one the noised query blocks from its place
+    on and the clean ones from its own on."""
+    half = geometry.seq_len // block
+    return jnp.where(j < half, j, jnp.where(
+        i < half, jnp.maximum(i, j - half), jnp.maximum(i, j)))
+
+
 def _walk_tiles(tile, causal, ragged, has_mask, iq, j, block_q, block_k,
-                t_true):
+                t_true, geometry=None):
     """Run the grid step (query block ``iq``, key block ``j``) as the one
     ``tile(causal_mask, key_masks)`` body it needs, or none above the
     diagonal: masks only where a tile needs them, the length mask where
     the call pads T and this key block holds the tail, the key-padding
     mask on every tile of a masked call, the causal mask on the diagonal.
     Shared by the forward and the backward kernel."""
+    if geometry is not None:
+        return _walk_block_diffusion(tile, geometry, iq, j, block_q)
     tail = _all(ragged, (j + 1) * block_k > t_true)
     keyed = True if has_mask else tail
     if not causal:
@@ -260,7 +390,7 @@ def _walk_tiles(tile, causal, ragged, has_mask, iq, j, block_q, block_k,
 
 
 def _attn_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask,
-                 q_ref, k_ref, v_ref, *rest):
+                 q_ref, k_ref, v_ref, *rest, geometry=None):
     """Keys run down the sublanes and queries along the lanes: the score
     piece is k @ q^T, [sub_k, sub_q], so a query's running max and sum are
     one lane of a [1, block_q] row (a reduction over keys is elementwise
@@ -348,7 +478,7 @@ def _attn_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask,
         return run
 
     _walk_tiles(tile, causal, ragged, has_mask, iq, j, block_q, block_k,
-                t_true)
+                t_true, geometry)
 
     @pl.when(j == nk - 1)
     def _():
@@ -382,7 +512,7 @@ def _geometry(t, block_q, block_k):
 
 
 def _run_fwd(q, k, v, mask, h, causal, scale, block_q, block_k, interpret,
-             out_dtype=None):
+             out_dtype=None, geometry=None):
     """q,k,v: [BH, T, D], as the kernel reads them (the custom_vjp rules
     hand them over rounded, ``_as_operands``); mask: None, or [B, T] f32
     key-validity (1=valid) with B = BH // h — the kernel indexes it per
@@ -401,15 +531,15 @@ def _run_fwd(q, k, v, mask, h, causal, scale, block_q, block_k, interpret,
 
     def local(q, k, v, mask=None):
         return _run_fwd_local(q, k, v, mask, h, causal, scale, block_q,
-                              block_k, interpret, out_dtype)
+                              block_k, interpret, out_dtype, geometry)
     return _spmd.per_batch_shard(local, arrays, (0,) * len(arrays), (0, 0))
 
 
 @jax.named_scope("flash_attn.fwd")
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10),
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10, 11),
                    inline=True)
 def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
-                   interpret, out_dtype):
+                   interpret, out_dtype, geometry=None):
     # jitted and inlined: the kernel body is some hundreds of operations
     # unrolled, and a model calls it once a layer with the same shapes, so
     # it is traced once and its equations are copied into each caller under
@@ -423,45 +553,57 @@ def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
     sub_q, sub_k = _sub_tile(block_q), _sub_tile(block_k)
     kernel = functools.partial(_attn_kernel, t, t_pad != t, causal, scale,
                                sub_q, sub_k, mask is not None)
+    if geometry is not None:
+        kernel = functools.partial(kernel, geometry=geometry)
 
     def kv_block(i, j):
         # a key block above the diagonal is skipped by the kernel; naming
         # the last live block again keeps the pipeline from fetching it
+        if geometry is not None:
+            return _block_diffusion_key_block(geometry, block_q, i, j)
         return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k) \
             if causal else j
 
     scratch = [pltpu.VMEM((1, block_q), jnp.float32),
                pltpu.VMEM((1, block_q), jnp.float32),
                pltpu.VMEM((d, block_q), jnp.float32)]
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda b, i, j: (b, kv_block(i, j), 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda b, i, j: (b, kv_block(i, j), 0)),
-        ] + ([
-            # mask rides in as [B, 8, t_pad] f32 — the 8-sublane broadcast
-            # satisfies the TPU (8, 128) tile rule like the lse output block
-            pl.BlockSpec((1, 8, block_k),
-                         lambda b, i, j: (b // h, 0, kv_block(i, j))),
-        ] if mask is not None else []),
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t_pad, d), out_dtype),
-            jax.ShapeDtypeStruct((bh, 8, t_pad), jnp.float32),
-        ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-        name="flash_attn_fwd",
-    )(qp, kp, vp, *(() if mask is None else (
-        jnp.broadcast_to(_pad_to(mask.astype(jnp.float32), t_pad, 1)
-                         [:, None, :], (bh // h, 8, t_pad)),)))
+    # every forward kernel stands under flash_attn.fwd/flash_attn_fwd in a
+    # trace (the causal one by its own name); one under a geometry carries
+    # its own name inside that scope, which tells it from the causal ones
+    name, under = ("flash_attn_fwd", contextlib.nullcontext()) \
+        if geometry is None else (
+            "flash_attn_bd_fwd", jax.named_scope("flash_attn_fwd"))
+    with under:
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_k, d),
+                             lambda b, i, j: (b, kv_block(i, j), 0)),
+                pl.BlockSpec((1, block_k, d),
+                             lambda b, i, j: (b, kv_block(i, j), 0)),
+            ] + ([
+                # mask rides in as [B, 8, t_pad] f32 — the 8-sublane
+                # broadcast satisfies the TPU (8, 128) tile rule like the
+                # lse output block
+                pl.BlockSpec((1, 8, block_k),
+                             lambda b, i, j: (b // h, 0, kv_block(i, j))),
+            ] if mask is not None else []),
+            out_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, t_pad, d), out_dtype),
+                jax.ShapeDtypeStruct((bh, 8, t_pad), jnp.float32),
+            ],
+            scratch_shapes=scratch,
+            interpret=interpret,
+            name=name,
+        )(qp, kp, vp, *(() if mask is None else (
+            jnp.broadcast_to(_pad_to(mask.astype(jnp.float32), t_pad, 1)
+                             [:, None, :], (bh // h, 8, t_pad)),)))
     return out[:, :t], lse[:, 0, :t]
 
 
@@ -488,23 +630,24 @@ def _as_operands(interpret, *arrays):
     return tuple(x.astype(cd) for x in arrays)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _attention(q, k, v, mask, causal, scale, block_q, block_k, interpret, h):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _attention(q, k, v, mask, causal, scale, block_q, block_k, interpret, h,
+               geometry):
     return _attention_fwd(q, k, v, mask, causal, scale, block_q, block_k,
-                          interpret, h)[0]
+                          interpret, h, geometry)[0]
 
 
 def _attention_fwd(q, k, v, mask, causal, scale, block_q, block_k,
-                   interpret, h):
+                   interpret, h, geometry):
     out_dtype = q.dtype
     q, k, v = _as_operands(interpret, q, k, v)
     out, lse = _run_fwd(q, k, v, mask, h, causal, scale, block_q, block_k,
-                        interpret, out_dtype)
+                        interpret, out_dtype, geometry)
     return out, (q, k, v, mask, out, lse)
 
 
 def _bwd_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask, form,
-                q_ref, k_ref, v_ref, g_ref, st_ref, *rest):
+                q_ref, k_ref, v_ref, g_ref, st_ref, *rest, geometry=None):
     """The backward in the forward's orientation: a piece's probabilities
     are recomputed as p^T = exp(k q^T * scale - lse), [sub_k, sub_q], from
     the residuals, so ``lse`` and ``delta`` (``st_ref`` rows 0 and 1) are
@@ -610,7 +753,7 @@ def _bwd_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask, form,
         return run
 
     _walk_tiles(tile, causal, ragged, has_mask, iq, j, block_q, block_k,
-                t_true)
+                t_true, geometry)
 
     # rows meet lanes once, as a block closes. dk took the scale with q
     # where it folds; dq always owes it
@@ -679,7 +822,8 @@ def bwd_vmem_bytes(form, t_pad, d, block_q, block_k, operand_size, grad_size):
     return blocks + small + pieces + max(dkv, dq)
 
 
-def _run_bwd(res, g, g_lse, h, causal, scale, block_q, block_k, interpret):
+def _run_bwd(res, g, g_lse, h, causal, scale, block_q, block_k, interpret,
+             geometry=None):
     """(dq, dk, dv) [BH, T, D] in ``g``'s dtype (the caller's) from the
     forward's residuals (q, k, v as the forward kernel read them) and the
     cotangent ``g`` [BH, T, D]; ``g_lse`` [BH, T] or None is the cotangent
@@ -704,16 +848,16 @@ def _run_bwd(res, g, g_lse, h, causal, scale, block_q, block_k, interpret):
         return _run_bwd_local(
             q, k, v, out, lse, g, None if g_lse is None else rest[0],
             None if mask is None else rest[-1], h, causal, scale, block_q,
-            block_k, interpret, form)
+            block_k, interpret, form, geometry)
     return _spmd.per_batch_shard(local, arrays, (0,) * len(arrays),
                                  (0, 0, 0))
 
 
 @jax.named_scope("flash_attn.bwd")
-@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13, 14),
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13, 14, 15),
                    inline=True)
 def _run_bwd_local(q, k, v, out, lse, g, g_lse, mask, h, causal, scale,
-                   block_q, block_k, interpret, form):
+                   block_q, block_k, interpret, form, geometry=None):
     # jitted and inlined for the reason _run_fwd_local is
     bh, t, d = q.shape
     f32 = jnp.float32
@@ -751,6 +895,8 @@ def _run_bwd_local(q, k, v, out, lse, g, g_lse, mask, h, causal, scale,
         kernel = functools.partial(
             _bwd_kernel, t, t_pad != t, causal, scale, _sub_tile(block_q),
             _sub_tile(block_k), mask is not None, form)
+        if geometry is not None:
+            kernel = functools.partial(kernel, geometry=geometry)
         # a step above the diagonal names the nearest live block again, so
         # nothing is fetched for it
         if form == "dq":
@@ -760,12 +906,17 @@ def _run_bwd_local(q, k, v, out, lse, g, g_lse, mask, h, causal, scale,
                 return i
 
             def k_at(b, i, j):
+                if geometry is not None:
+                    return _block_diffusion_key_block(geometry, block_q, i, j)
                 return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k) \
                     if causal else j
         else:
             grid = (bh, nk, nq)
 
             def q_at(b, j, i):
+                if geometry is not None:
+                    return _block_diffusion_query_block(geometry, block_q,
+                                                        j, i)
                 return jnp.maximum(i, (j * block_k) // block_q) \
                     if causal else i
 
@@ -795,7 +946,9 @@ def _run_bwd_local(q, k, v, out, lse, g, g_lse, mask, h, causal, scale,
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=[jax.ShapeDtypeStruct((bh, t_pad, d), g.dtype)]
             * len(out_specs), scratch_shapes=scratch, interpret=interpret,
-            name="flash_attn_bwd_" + form, compiler_params=asked)(*operands)
+            name=("flash_attn_bwd_" if geometry is None
+                  else "flash_attn_bd_bwd_") + form,
+            compiler_params=asked)(*operands)
 
     if form == "fused":
         dq, dk, dv = call("fused")
@@ -804,9 +957,10 @@ def _run_bwd_local(q, k, v, out, lse, g, g_lse, mask, h, causal, scale,
     return dq[:, :t], dk[:, :t], dv[:, :t]
 
 
-def _attention_bwd(causal, scale, block_q, block_k, interpret, h, res, g):
+def _attention_bwd(causal, scale, block_q, block_k, interpret, h, geometry,
+                   res, g):
     dq, dk, dv = _run_bwd(res, g, None, h, causal, scale, block_q, block_k,
-                          interpret)
+                          interpret, geometry)
     return dq, dk, dv, jnp.zeros_like(res[3])
 
 
@@ -858,7 +1012,8 @@ _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
 def flash_attention(q, k, v, *, mask=None, causal=False, scale=None,
-                    block_q=None, block_k=None, interpret=False):
+                    block_q=None, block_k=None, interpret=False,
+                    geometry=None):
     """Fused attention over [B, T, H, D] self-attention inputs (same
     contract as nn/layers/attention.py dot_product_attention minus
     cross-length decode). ``mask``: optional [B, Tk] key-side padding mask
@@ -866,12 +1021,24 @@ def flash_attention(q, k, v, *, mask=None, causal=False, scale=None,
     there — 0 is what the downstream masked-output multiply expects).
     ``block_q``/``block_k`` default to ``resolve_attention``'s, and a
     call it would hand to XLA is then refused; explicit values are taken
-    as given (the dispatch passes what it resolved; tests name their own)."""
+    as given (the dispatch passes what it resolved; tests name their own).
+    ``geometry`` (``BlockDiffusion``) is the score mask in ``causal``'s
+    place, over the doubled sequence it describes; it takes no key mask,
+    and blocks it does not fit (``BlockDiffusion.fits``) are refused."""
     b, t, h, d = q.shape
     if block_q is None or block_k is None:
-        rq, rk = _resolved(q, k, mask)
+        rq, rk = _resolved(q, k, mask, geometry)
         block_q = rq if block_q is None else block_q
         block_k = rk if block_k is None else block_k
+    if geometry is not None:
+        if causal or mask is not None:
+            raise ValueError("a geometry is the whole score mask: it takes "
+                             "neither causal nor a key mask beside it")
+        bq, bk, _ = _geometry(t, block_q, block_k)
+        if not geometry.fits(t, bq, bk):
+            raise ValueError(
+                f"{geometry} over {t} positions does not lie in whole "
+                f"square tiles of {bq} x {bk} (BlockDiffusion.fits)")
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
     # custom_vjp needs an array operand in every slot: a zero-width [B, 0]
@@ -879,5 +1046,6 @@ def flash_attention(q, k, v, *, mask=None, causal=False, scale=None,
     maskf = (jnp.zeros((b, 0), jnp.float32) if mask is None
              else mask.astype(jnp.float32))
     out = _attention(_fold_heads(q), _fold_heads(k), _fold_heads(v), maskf,
-                     causal, float(scale), block_q, block_k, interpret, h)
+                     causal, float(scale), block_q, block_k, interpret, h,
+                     geometry)
     return _unfold_heads(out, b, h)

@@ -319,6 +319,9 @@ class ParallelTrainer:
             # the streamed step needs the stacked-slab trunk; detect it on
             # the HOST template so an unstreamable net fails loudly at
             # placement, not as an opaque trace error inside the scan
+            from deeplearning4j_tpu.nn.layers import base as _lbase
+            _lbase.refuse_loss_mask_layers(self.net.conf.layers,
+                                           "shard_params='fsdp_stream'")
             self._trunk = streamable_trunk(self.net, params, state)
             if (self._trunk is None or self.net.conf.ties
                     or hasattr(self.net.conf.layers[-1],

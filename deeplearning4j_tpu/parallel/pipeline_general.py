@@ -81,6 +81,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
+from deeplearning4j_tpu.nn.layers import base as _lbase
 
 
 # the SAME mask-awareness predicate MultiLayerNetwork uses — the
@@ -194,6 +195,7 @@ class PipelinedNetwork:
         assert not conf.ties, \
             "a tied parameter is read by two layers, which may lie on " \
             "two stages; not stageable"
+        _lbase.refuse_loss_mask_layers(conf.layers, "PipelinedNetwork")
         assert not hasattr(conf.layers[-1], "loss_from_features"), \
             "feature-loss heads (CenterLossOutputLayer) need the " \
             "pre-head activations MultiLayerNetwork.loss_fn threads " \

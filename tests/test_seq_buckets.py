@@ -422,7 +422,7 @@ class TestCrossoverPerSeqBucket:
         from deeplearning4j_tpu.ops import attention_pallas as _ap
         seen = []
 
-        def spy(q_shape, k_shape, mask, dtype):
+        def spy(q_shape, k_shape, mask, dtype, geometry=None):
             seen.append(int(q_shape[1]))
             return None               # always take the naive (CPU) path
 
