@@ -701,26 +701,29 @@ def _gather_back(ys, w, order, inv, r):
 @contextlib.contextmanager
 def _naive_routed_layers():
     """The routed layer's naive branch for the length of a trace: the
-    grouped products as ``jax.lax.ragged_dot``, the row movement as plain
-    gathers of every slot under autodiff."""
+    expert FFN as three ``jax.lax.ragged_dot`` with the activation between
+    them under autodiff, the row movement as plain gathers of every slot."""
     import jax
-    import jax.numpy as jnp
 
     from deeplearning4j_tpu.nn.layers import moe as _moe
-    from deeplearning4j_tpu.utils import dtypes as _dtypes
 
-    cd, _ = _dtypes.compute_dtypes_for(jnp.float32)
-    saved = _moe.grouped_matmul, _moe._dispatch, _moe._combine
-    _moe.grouped_matmul = (
-        lambda a, w, sizes, out, rows=None: jax.lax.ragged_dot(
-            a.astype(cd), w.astype(cd), sizes, preferred_element_type=out))
+    def three_products(xs, w_gate, w_up, w_down, sizes, act, out, rows=None):
+        dot = lambda a, w, to: jax.lax.ragged_dot(
+            a, w.astype(a.dtype), sizes, preferred_element_type=to)
+        h = dot(xs, w_up, xs.dtype).astype(out)
+        h = act(h) if w_gate is None else act(
+            dot(xs, w_gate, xs.dtype).astype(out)) * h
+        return dot(h.astype(xs.dtype), w_down, out)
+
+    saved = _moe.expert_ffn, _moe._dispatch, _moe._combine
+    _moe.expert_ffn = three_products
     _moe._dispatch = lambda k, dtype, x, tok, r: _rows_inside(
         x[tok], r).astype(dtype)
     _moe._combine = _gather_back
     try:
         yield
     finally:
-        _moe.grouped_matmul, _moe._dispatch, _moe._combine = saved
+        _moe.expert_ffn, _moe._dispatch, _moe._combine = saved
 
 
 def _hybrid_step_case(name, *, t, vocab, tol):
@@ -730,9 +733,10 @@ def _hybrid_step_case(name, *, t, vocab, tol):
     experts, a short convolution with 8 more; routing fixed by the expert
     bias, so that no near-tie decides differently on the two branches): the
     compiled train step's
-    kernel count (the flash forward and backward, thirteen kernels an
-    expert layer: nine grouped products, gate, up and down, each forward,
-    for the input gradient and for the weight gradient, and the row
+    kernel count (the flash forward and backward, twelve kernels an
+    expert layer: six grouped products, gate with up as one and down, each
+    forward, for the input gradient and for the weight gradient, the
+    activation's two, ``moe_act_fwd`` and ``moe_act_bwd``, and the row
     movement's two kernels twice each; from PR 40 two a short
     convolution, ``causal_conv_fwd`` and ``causal_conv_bwd``), then logits
     and every
@@ -767,7 +771,7 @@ def _hybrid_step_case(name, *, t, vocab, tol):
     step = net.make_train_step(donate=False)
     text = step.lower(net.params, net.state, net.opt_state, x, labels, 0,
                       jax.random.PRNGKey(0), None).compile().as_text()
-    n_calls, want = text.count("tpu_custom_call"), 2 + 2 * 13 + 2 * 2
+    n_calls, want = text.count("tpu_custom_call"), 2 + 2 * 12 + 2 * 2
     _expect(n_calls == want,
             f"{name}: compiled train step holds {n_calls} "
             f"tpu_custom_call(s), expected {want}")
@@ -793,7 +797,7 @@ def _gated_delta_step_case(name, *, t, vocab, tol):
     1/512, the ten lowest-numbered experts are chosen on both branches and
     no near-tie decides differently): the compiled train step's kernel
     count (the flash forward and, from PR 46, the fused backward's one
-    kernel, and thirteen kernels an expert layer as ``_hybrid_step_case``
+    kernel, and twelve kernels an expert layer as ``_hybrid_step_case``
     counts them), then logits and every gradient through the dispatch against
     the naive branch (the recurrence token by token as the benchmark's
     plain reference runs it, attention in ``jax.numpy``, the grouped
@@ -822,7 +826,7 @@ def _gated_delta_step_case(name, *, t, vocab, tol):
     step = net.make_train_step(donate=False)
     text = step.lower(net.params, net.state, net.opt_state, x, labels, 0,
                       jax.random.PRNGKey(0), None).compile().as_text()
-    n_calls, want = text.count("tpu_custom_call"), 2 + 3 * 13 + 2 * (2 + 2)
+    n_calls, want = text.count("tpu_custom_call"), 2 + 3 * 12 + 2 * (2 + 2)
     _expect(n_calls == want,
             f"{name}: compiled train step holds {n_calls} "
             f"tpu_custom_call(s), expected {want}")

@@ -5,12 +5,14 @@ share of a dropless top-k layer. A float32 router scores all the experts
 (sigmoid scores with an expert bias that moves the selection only, or a
 softmax over all of them), the ``N k`` assignments are sorted by held
 expert (those routed elsewhere behind a sentinel) and the held experts
-run as grouped products over the counts
-(ops/grouped_matmul.py). Every shape is static and no token is dropped;
-the matrix work and, from PR 33, the row movement around it (tokens into
-sorted order, results back, and both backward passes, all written by
-hand) follow the rows really routed here: two kernels (ops/moe_rows.py)
-whose grids skip the row tiles past the count read from the group sizes.
+run as grouped products over the counts with the gate's activation
+between them (ops/expert_ffn.py over ops/grouped_matmul.py's kernels).
+Every shape is static and no token is dropped; the matrix work, from PR 33
+the row movement around it (tokens into sorted order, results back, and
+both backward passes, all written by hand; ops/moe_rows.py) and from PR 50
+the activation between the products, forward and backward, follow the rows
+really routed here: kernels whose grids skip the row tiles past the count
+read from the group sizes.
 
 ``MoETransformerBlock`` is the older Switch-style block monolith, kept
 for its users (ROADMAP D6(a)). Reference analog: none, DL4J has no MoE;
@@ -40,7 +42,7 @@ from deeplearning4j_tpu.nn.conf import inputs as _inputs
 from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention
 from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.nn.layers.norms import LayerNormalization
-from deeplearning4j_tpu.ops.grouped_matmul import grouped_matmul
+from deeplearning4j_tpu.ops.expert_ffn import expert_ffn
 from deeplearning4j_tpu.ops.moe_rows import rows_back, rows_into_order
 from deeplearning4j_tpu.utils import dtypes as _dtypes
 from deeplearning4j_tpu.utils.serde import register_config
@@ -134,18 +136,29 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
     a sentinel, and the counts per held expert are the group sizes of the
     grouped products. The sorted buffers are sized for ``N k`` rows;
     ``r = sum(sizes)`` of them (between 0 and ``N k``) lie inside a group,
-    and that is how many the layer moves and multiplies: the grouped
-    products touch the row tiles of the groups, and so do the two kernels
-    of the row movement under the scope ``moe_permute`` (ops/moe_rows.py:
-    ``moe_rows_fwd`` brings token rows into sorted order and, in the
-    backward pass, the result's gradient, with the weights' gradient
-    taken from the same rows; ``moe_rows_back`` adds the weighted results
-    back at their tokens and, in the backward pass, the sorted rows'
-    gradient). Slots from the tile after ``r`` on are left unwritten in
-    every sorted buffer and nothing reads them. What still walks all
-    ``N k`` slots is small: the two sorts, the weights in sorted order and
-    their gradient back (scalars) and, under ``moe_experts``, the
-    activation's passes.
+    and that is how many the layer moves, multiplies and activates: the
+    grouped products touch the row tiles of the groups, and so do the two
+    kernels of the row movement under the scope ``moe_permute``
+    (ops/moe_rows.py: ``moe_rows_fwd`` brings token rows into sorted order
+    and, in the backward pass, the result's gradient, with the weights'
+    gradient taken from the same rows; ``moe_rows_back`` adds the weighted
+    results back at their tokens and, in the backward pass, the sorted
+    rows' gradient) and, from PR 50, the two kernels of the activation
+    under ``moe_experts`` (ops/expert_ffn.py: ``moe_act_fwd``,
+    ``moe_act_bwd``; the expert FFN is one function there with its
+    backward written by hand). Gate and up are ONE grouped product over
+    ``Wg ‖ Wu`` [n_held, d, 2 f], joined at the compute dtype from the
+    float32 leaves: the activation kernels read and write one
+    [N k, 2 f] array, and the input gradient is one product over ``K =
+    2 f`` whose float32 accumulator does the sum that two products left to
+    a pass over all ``N k`` rows of [N k, d]. Slots from the tile after
+    ``r`` on are left unwritten in every sorted buffer and nothing reads
+    them. What still walks all ``N k`` slots is small, and none of it is
+    under ``moe_experts``: the two sorts, and the weights in sorted order
+    with their gradient back (scalars). What ``moe_experts`` holds beside
+    its kernels is the weights' own traffic, the float32 leaves rounded
+    (and gate joined with up) once a pass that reads them and the float32
+    gradients written.
 
     Returns ``(y [N, d], load [n_held], elsewhere [1])``: the counts of
     assignments per held expert and of those routed to experts not held.
@@ -178,14 +191,7 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
     # what the shapes say a group holds: N k assignments over E experts
     rows = xs.shape[0] // router_w.shape[1]
     with jax.named_scope("moe_experts"):
-        if w_gate is None:
-            u = grouped_matmul(xs, w_up, sizes, cd, rows)
-            h = act(u.astype(ad)).astype(cd)
-        else:
-            g = grouped_matmul(xs, w_gate, sizes, cd, rows)
-            u = grouped_matmul(xs, w_up, sizes, cd, rows)
-            h = (act(g.astype(ad)) * u.astype(ad)).astype(cd)
-        ys = grouped_matmul(h, w_down, sizes, ad, rows)
+        ys = expert_ffn(xs, w_gate, w_up, w_down, sizes, act, ad, rows)
     with jax.named_scope("moe_route"), jax.named_scope("moe_permute"):
         y = _combine(ys, w, order, inv, r)
     return y.astype(x.dtype), sizes, counts[n_held:]

@@ -24,6 +24,20 @@ the last group, which these kernels leave unwritten.
 
 Rows past the last group are NOT computed and the result holds whatever
 the buffer held there: callers mask them (``jnp.where``, not a product).
+
+From PR 50 the routed layer reaches these kernels through
+``ops/expert_ffn.py``, one function for the expert FFN with its backward
+written by hand over ``grouped_matmul`` and the two halves of its backward
+pass, ``input_gradient`` and ``weight_gradient`` (the down product's
+operand is made again between its two): gate and up are ONE product there
+(``Wg ‖ Wu`` joined to [G, d, 2 f], so the input gradient's sum over the
+two happens in the product's float32 accumulator and the ``add_any`` over
+[M, d] that two ``_grouped_bwd`` results needed is gone), and the
+activation between the products is two kernels that skip the row tiles
+past the groups as these do. Nothing under the scope ``moe_experts`` walks
+all ``M`` slots of a sorted buffer any more; what does, in the layer, is
+the two sorts and the weights' scalars in sorted order
+(``moe.routed_experts``).
 """
 
 from __future__ import annotations
@@ -57,10 +71,10 @@ _T_WGRAD = 512
 
 def _row_tile(m, rows_a_group):
     """The smallest power of two from ``_TM_LEAST`` up to ``_TM`` that
-    holds ``rows_a_group``, halved until it divides ``m``: the kernels
-    take whole row tiles only."""
+    holds ``rows_a_group`` (``_TM`` where the caller knows none), halved
+    until it divides ``m``: the kernels take whole row tiles only."""
     t = _TM_LEAST
-    while t < min(rows_a_group, _TM):
+    while t < min(_TM if rows_a_group is None else rows_a_group, _TM):
         t *= 2
     while m % t:
         t //= 2
@@ -68,7 +82,15 @@ def _row_tile(m, rows_a_group):
 
 
 def _tiling(tm, k, n):
-    return tm, min(_TK, k), min(_TN, n)
+    """A contraction between one and two ``_TK`` long is cut into two equal
+    tiles where they are whole lane tiles (3072 = 2 x 1536: the input
+    gradient over gate ‖ up at f 1536, 1.47 ms a call against 1.97 where
+    the kernel cuts 2048 + 1024 and masks the short tile; PERF.md section
+    6, PR 50); otherwise the kernel's own cut stands (2688)."""
+    tk = min(_TK, k)
+    if _TK < k < 2 * _TK and k % 256 == 0:
+        tk = k // 2
+    return tm, tk, min(_TN, n)
 
 
 def _wgrad_tiling(tm, k, n):
@@ -83,6 +105,12 @@ def _kernel(fn, *args, **kw):
         return fn(*args, **kw)
 
 
+def _how(m, rows_a_group):
+    """What the caller's shapes and the backend decide for a product over
+    ``m`` rows: the interpreter off the chip, and the row tile."""
+    return not _ap.backend_is_tpu(), _row_tile(m, rows_a_group)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _grouped(x, w, group_sizes, out_dtype, interpret, tm):
     return _kernel(_gmm.gmm, x, w.astype(x.dtype), group_sizes, out_dtype,
@@ -94,15 +122,24 @@ def _grouped_fwd(x, w, group_sizes, out_dtype, interpret, tm):
             (x, w, group_sizes))
 
 
+def _dx(g, w, group_sizes, dtype, interpret, tm):
+    g = g.astype(dtype)  # the matrix units round an operand anyway
+    return _kernel(_gmm.gmm, g, w.astype(dtype), group_sizes, dtype,
+                   _tiling(tm, w.shape[2], w.shape[1]), transpose_rhs=True,
+                   interpret=interpret)
+
+
+def _dw(x, g, group_sizes, dtype, interpret, tm):
+    dw = _kernel(_gmm.tgmm, x.swapaxes(0, 1), g.astype(x.dtype), group_sizes,
+                 jnp.float32, _wgrad_tiling(tm, x.shape[1], g.shape[1]),
+                 interpret=interpret)
+    return dw.astype(dtype)
+
+
 def _grouped_bwd(out_dtype, interpret, tm, res, g):
     x, w, group_sizes = res
-    k, n = x.shape[1], w.shape[2]
-    g = g.astype(x.dtype)  # the matrix units round an operand anyway
-    dx = _kernel(_gmm.gmm, g, w.astype(x.dtype), group_sizes, x.dtype,
-                 _tiling(tm, n, k), transpose_rhs=True, interpret=interpret)
-    dw = _kernel(_gmm.tgmm, x.swapaxes(0, 1), g, group_sizes, jnp.float32,
-                 _wgrad_tiling(tm, k, n), interpret=interpret)
-    return dx, dw.astype(w.dtype), None
+    return (_dx(g, w, group_sizes, x.dtype, interpret, tm),
+            _dw(x, g, group_sizes, w.dtype, interpret, tm), None)
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
@@ -118,6 +155,22 @@ def grouped_matmul(x, w, group_sizes, out_dtype, rows_a_group=None):
     empty group). ``rows_a_group``, where the caller's shapes say how many
     rows a group expects (a router's ``N k / E``), sizes the row tile; the
     result does not depend on it."""
-    tm = _row_tile(x.shape[0], _TM if rows_a_group is None else rows_a_group)
     return _grouped(x, w, group_sizes.astype(jnp.int32), out_dtype,
-                    not _ap.backend_is_tpu(), tm)
+                    *_how(x.shape[0], rows_a_group))
+
+
+def input_gradient(g, w, group_sizes, dtype, rows_a_group=None):
+    """The first half of ``grouped_matmul``'s backward pass, for a caller
+    that writes its own (ops/expert_ffn.py): ``g`` [M, N], the result's
+    gradient, against ``w`` [G, K, N] transposed -> [M, K] of ``dtype``,
+    both read at ``dtype``."""
+    return _dx(g, w, group_sizes.astype(jnp.int32), dtype,
+               *_how(g.shape[0], rows_a_group))
+
+
+def weight_gradient(x, g, group_sizes, dtype, rows_a_group=None):
+    """The second half: ``x`` [M, K] and ``g`` [M, N], read at ``x``'s
+    dtype -> [G, K, N], accumulated in float32 over a group's rows,
+    returned as ``dtype``, exactly zero for an empty group."""
+    return _dw(x, g, group_sizes.astype(jnp.int32), dtype,
+               *_how(x.shape[0], rows_a_group))

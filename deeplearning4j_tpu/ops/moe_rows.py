@@ -61,13 +61,18 @@ def _columns(n, d, itemsize):
                if d % c == 0 and (n * c * itemsize <= _RESIDENT or c == 128))
 
 
+def live_tile(i, r, tile):
+    """Row tile of a sorted buffer that grid step ``i`` holds: its own
+    while that has a row below ``r``, the last such tile after it (a block
+    that does not change is neither fetched nor written again)."""
+    return jnp.minimum(i, jnp.maximum((r - 1) // tile, 0))
+
+
 def _live(tile):
-    """Index map of a [tile, columns] block of a sorted buffer: the grid
-    step's own row tile while it holds a row below ``r``, the last such
-    tile after it (a block that does not change is neither fetched nor
-    written again)."""
+    """Index map of a [tile, columns] block of a sorted buffer
+    (``live_tile``)."""
     def index(c, i, tok_ref, r_ref, *_):
-        return jnp.minimum(i, jnp.maximum((r_ref[0] - 1) // tile, 0)), c
+        return live_tile(i, r_ref[0], tile), c
     return index
 
 

@@ -21,7 +21,9 @@ from deeplearning4j_tpu.nn import layers as L
 from deeplearning4j_tpu.nn.conf import inputs as I
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers import moe
+from deeplearning4j_tpu.nn import activations
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+from deeplearning4j_tpu.ops import expert_ffn as ffn
 from deeplearning4j_tpu.ops import moe_rows
 from deeplearning4j_tpu.ops.grouped_matmul import grouped_matmul
 
@@ -128,29 +130,47 @@ def test_the_bias_moves_the_selection_and_not_the_weights(layer):
                                atol=1e-6)
 
 
-def test_grouped_matmul_follows_the_groups(layer):
+# the layer's widths; a contraction of 3072 (gate ‖ up at f 1536), which
+# `_tiling` cuts into two tiles of 1536, in the product and, as the
+# result's width, in its input gradient
+@pytest.mark.parametrize("k_, n_", [(D, F), (3072, 128), (128, 3072)],
+                         ids=["layer", "k3072", "n3072"])
+def test_grouped_matmul_follows_the_groups(layer, k_, n_):
     k = jax.random.split(jax.random.PRNGKey(5), 3)
-    x = jax.random.normal(k[0], (64, D), jnp.float32)
-    w = jax.random.normal(k[1], (4, D, F), jnp.float32)
-    r = jax.random.normal(k[2], (64, F), jnp.float32)
+    x = jax.random.normal(k[0], (64, k_), jnp.float32)
+    w = jax.random.normal(k[1], (4, k_, n_), jnp.float32)
+    r = jax.random.normal(k[2], (64, n_), jnp.float32)
     sizes = jnp.array([17, 0, 30, 5], jnp.int32)   # 52 of 64 rows
     valid = (jnp.arange(64) < 52)[:, None]
 
     def through(f):
         return lambda x, w: jnp.sum(jnp.where(valid, f(x, w), 0.0) * r)
 
+    def close(got, want):
+        np.testing.assert_allclose(
+            got, want, rtol=1e-5,
+            atol=1e-5 * max(1.0, float(jnp.abs(want).max())))
+
     mine = through(lambda x, w: grouped_matmul(x, w, sizes, jnp.float32))
     want = through(lambda x, w: jax.lax.ragged_dot(
         x, w, sizes, precision="highest"))
-    np.testing.assert_allclose(
-        grouped_matmul(x, w, sizes, jnp.float32)[:52],
-        jax.lax.ragged_dot(x, w, sizes, precision="highest")[:52],
-        rtol=1e-5, atol=1e-5)
+    close(grouped_matmul(x, w, sizes, jnp.float32)[:52],
+          jax.lax.ragged_dot(x, w, sizes, precision="highest")[:52])
     (dx, dw), (dx_w, dw_w) = (jax.grad(f, argnums=(0, 1))(x, w)
                               for f in (mine, want))
-    np.testing.assert_allclose(dx[:52], dx_w[:52], rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(dw, dw_w, rtol=1e-5, atol=1e-5)
+    close(dx[:52], dx_w[:52])
+    close(dw, dw_w)
     assert float(jnp.abs(dw[1]).max()) == 0.0      # the empty group
+
+
+def test_a_contraction_past_one_tile_is_cut_in_two_equal_ones():
+    from deeplearning4j_tpu.ops import grouped_matmul as gm
+    assert gm._tiling(512, 3072, 2048) == (512, 1536, 512)
+    # no two whole lane tiles, or no longer than one, or two full ones:
+    # the kernel's own cut
+    assert gm._tiling(128, 2688, 1856)[1] == 2048
+    assert gm._tiling(128, 1536, 2048)[1] == 1536
+    assert gm._tiling(128, 4096, 512)[1] == 2048
 
 
 def _sorted_case(sizes, n, k):
@@ -281,6 +301,222 @@ def test_no_gather_of_every_slot_is_left_beside_the_kernels(layer):
     for name in ("moe_rows_fwd", "moe_rows_back"):
         assert _count(jaxpr, lambda e: e.primitive.name == "pallas_call"
                       and e.params["name"] == name) == 2
+
+
+def _three_products(xs, w_gate, w_up, w_down, sizes, act, out_dtype):
+    """The expert FFN as `routed_experts` wrote it until PR 50: three
+    grouped products with the activation between them as XLA's, and jax's
+    own differentiation."""
+    cd = xs.dtype
+    u = grouped_matmul(xs, w_up, sizes, cd)
+    if w_gate is None:
+        h = act(u.astype(out_dtype)).astype(cd)
+    else:
+        g = grouped_matmul(xs, w_gate, sizes, cd)
+        h = (act(g.astype(out_dtype)) * u.astype(out_dtype)).astype(cd)
+    return grouped_matmul(h, w_down, sizes, out_dtype)
+
+
+def _ffn_case(gated, dtype, m=96, d=16, f=24, groups=3):
+    k = jax.random.split(jax.random.PRNGKey(13), 5)
+    w = lambda key, *shape: 0.3 * jax.random.normal(key, shape, jnp.float32)
+    return (jax.random.normal(k[0], (m, d), jnp.float32).astype(dtype),
+            w(k[1], groups, d, f) if gated else None, w(k[2], groups, d, f),
+            w(k[3], groups, f, d), jax.random.normal(k[4], (m, d),
+                                                     jnp.float32))
+
+
+_EXPERTS = {"gated-silu": (True, "silu"), "ungated-relu2": (False, "relu2")}
+# 96 slots in tiles of 32: nothing here, rows in one tile with an empty
+# group, every slot
+_SIZES = {"none": (0, 0, 0), "one-tile": (17, 0, 10), "all": (40, 16, 40)}
+
+
+@pytest.mark.parametrize("sizes", _SIZES.values(), ids=_SIZES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("experts", _EXPERTS.values(), ids=_EXPERTS)
+def test_the_expert_ffn_is_the_three_products_with_the_activation_between(
+        monkeypatch, experts, dtype, sizes):
+    """`ops/expert_ffn.py` against the expression it replaced: the
+    forward's bits on every row inside a group, and the four gradients
+    (the input's now summed over gate and up in the product's float32
+    accumulator and rounded once, where two rounded results were added)."""
+    monkeypatch.setattr(ffn, "_TILE", 32)
+    gated, act = experts[0], activations.get(experts[1])
+    xs, w_gate, w_up, w_down, cot = _ffn_case(gated, dtype)
+    sz, r = jnp.asarray(sizes, jnp.int32), sum(sizes)
+    inside = (jnp.arange(xs.shape[0]) < r)[:, None]
+
+    def through(fn):
+        def loss(xs, w_gate, w_up, w_down):
+            ys = fn(xs, w_gate, w_up, w_down, sz, act, jnp.float32)
+            return jnp.sum(jnp.where(inside, ys, 0) * cot), ys
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3) if gated
+                                  else (0, 2, 3), has_aux=True)
+
+    (_, ys), mine = through(ffn.expert_ffn)(xs, w_gate, w_up, w_down)
+    (_, want_ys), want = through(_three_products)(xs, w_gate, w_up, w_down)
+    assert ys.dtype == jnp.float32 and ys.shape == xs.shape
+    np.testing.assert_array_equal(np.asarray(ys[:r]), np.asarray(want_ys[:r]))
+    tol = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
+    for got, ref_ in zip(mine, want):
+        assert got.dtype == ref_.dtype and got.shape == ref_.shape
+        if got.shape == xs.shape:                     # dx: the rows held
+            got, ref_ = got[:r], ref_[:r]
+        got, ref_ = (np.asarray(a, np.float32) for a in (got, ref_))
+        assert np.all(np.isfinite(got))
+        np.testing.assert_allclose(
+            got, ref_, rtol=tol, atol=tol * max(1.0, np.abs(ref_).max(
+                initial=0.0)))
+    if r == 0:
+        assert all(float(jnp.abs(g).max()) == 0.0 for g in mine[1:])
+    elif sizes[1] == 0:                               # the empty group
+        assert all(float(jnp.abs(g[1]).max()) == 0.0 for g in mine[1:])
+
+
+@pytest.mark.parametrize("sizes", [(0, 0, 0), (17, 0, 10), (25, 30, 10)],
+                         ids=["none", "one-tile", "boundary-inside-a-tile"])
+@pytest.mark.parametrize("experts", _EXPERTS.values(), ids=_EXPERTS)
+def test_nothing_past_the_rows_held_reaches_the_expert_ffns_results(
+        monkeypatch, experts, sizes):
+    """NaN in every row of the pre-activations and of `h`'s gradient from
+    the count on, planted where the two activation kernels take their
+    operands: the result's rows inside the groups and all four gradients
+    are the unplanted run's to the bit."""
+    monkeypatch.setattr(ffn, "_TILE", 32)
+    gated, act = experts[0], activations.get(experts[1])
+    xs, w_gate, w_up, w_down, cot = _ffn_case(gated, jnp.float32)
+    sz, r = jnp.asarray(sizes, jnp.int32), sum(sizes)
+    inside = (jnp.arange(xs.shape[0]) < r)[:, None]
+
+    def run():
+        ys, back = jax.vjp(lambda *a: ffn.expert_ffn(
+            *a, sz, act, jnp.float32), xs, w_gate, w_up, w_down)
+        grads = back(jnp.where(inside, cot, jnp.nan))
+        return [ys[:r], grads[0][:r], *(g for g in grads[1:]
+                                        if g is not None)]
+
+    clean = run()
+    kernels, calls = ffn._act_call, []
+
+    def planted(kernel, name, r_, operands, widths, **kw):
+        calls.append(name)
+        return kernels(kernel, name, r_, [jnp.where(inside, a, jnp.nan)
+                                          for a in operands], widths, **kw)
+
+    monkeypatch.setattr(ffn, "_act_call", planted)
+    dirty = run()
+    assert calls == ["moe_act_fwd", "moe_act_bwd"]
+    for a, b in zip(clean, dirty):
+        assert np.all(np.isfinite(b))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _names_slots(jaxpr, shapes, found):
+    """Into ``found``: the primitive of every equation outside a kernel
+    that takes or gives an array of one of ``shapes`` and is neither a
+    kernel nor a call that only hands its operands on."""
+    passes_on = ("pallas_call", "pjit", "jit", "custom_vjp_call",
+                 "custom_jvp_call", "checkpoint", "remat", "closed_call")
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name not in passes_on and any(
+                getattr(v.aval, "shape", None) in shapes
+                for v in (*eqn.invars, *eqn.outvars)):
+            found.append(eqn.primitive.name)
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _names_slots(sub, shapes, found)
+    return found
+
+
+@pytest.mark.parametrize("recompute", [False, True],
+                         ids=["kept", "recomputed"])
+@pytest.mark.parametrize("experts", _EXPERTS.values(), ids=_EXPERTS)
+def test_nothing_under_the_experts_walks_every_slot(layer, experts,
+                                                    recompute):
+    """The guard of ISSUE 50's mechanism: forward and backward of the
+    routed layer hold no operation outside a kernel on an [N k, f],
+    [N k, 2 f] or [N k, d] array but the axis swap that `tgmm` swaps back
+    (no activation pass, no cast, no sum of two input gradients), and the
+    activation is the two kernels, once each (the forward's twice where
+    the layer is made again in the backward pass)."""
+    gated, act = experts[0], activations.get(experts[1])
+    u, bias, held = layer["u"], layer["bias"], (2, 6)
+    share = _share(layer["p"], *held)
+    if not gated:
+        share = {**share, "e_w1": None}
+
+    def routed(u, share):
+        return moe.routed_experts(
+            u, share["w_r"], share["e_w1"], share["e_w3"], share["e_w2"],
+            bias, top_k=K, held=held, scale=1.0, act=act)[0]
+
+    if recompute:
+        routed = jax.checkpoint(routed)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda u, share: jnp.sum(routed(u, share) ** 2), argnums=(0, 1)))(
+            u, share).jaxpr
+    slots = {(N * K, F), (N * K, 2 * F), (N * K, D)}
+    assert set(_names_slots(jaxpr, slots, [])) <= {"transpose"}
+    for name, times in (("moe_act_fwd", 1 + recompute), ("moe_act_bwd", 1)):
+        assert _count(jaxpr, lambda e: e.primitive.name == "pallas_call"
+                      and e.params["name"] == name) == times
+
+
+def _moe_block(**kw):
+    return L.TransformerBlock(
+        n_out=D, mixer=None, activation="silu", norm="rms", norm_eps=1e-6,
+        bias=False, ffn="moe", ffn_width=F, n_experts=E, top_k=K,
+        experts_held=(2, 6), router="softmax", **kw)
+
+
+def test_a_block_that_makes_its_experts_again_gives_the_same_gradients():
+    """`recompute_moe` through the expert FFN's hand-written backward:
+    `jax.checkpoint` runs its forward rule twice and keeps nothing of it,
+    and the block's result and gradients are those of the block that
+    keeps the residuals."""
+    it = I.RecurrentType(D, 8)
+    kept, again = _moe_block(), _moe_block(recompute_moe=True)
+    p = kept.init(jax.random.PRNGKey(0), it)
+    state = kept.init_state(it)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, D), jnp.float32)
+
+    def through(block):
+        def loss(p, x):
+            y, s = block.apply(p, state, x, train=True)
+            return jnp.sum(y ** 2), s["moe_load"]
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, x)
+
+    (v0, load0), g0 = through(kept)
+    (v1, load1), g1 = through(again)
+    assert float(load0.sum()) > 0
+    np.testing.assert_array_equal(np.asarray(load0), np.asarray(load1))
+    np.testing.assert_allclose(v0, v1, rtol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(g0),
+                    jax.tree_util.tree_leaves(g1)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("ffn_kind", ["gated", "mlp"])
+def test_a_dense_block_lowers_none_of_the_experts_kernels(ffn_kind):
+    """The dense cells' blocks take nothing of ISSUE 50's change: forward
+    and backward hold no kernel at all, so no `moe_act_*` one."""
+    block = L.TransformerBlock(n_out=D, mixer=None, activation="silu",
+                               norm="rms", bias=False, ffn=ffn_kind,
+                               ffn_width=F)
+    p = block.init(jax.random.PRNGKey(0), I.RecurrentType(D, 8))
+    x = jnp.ones((2, 8, D), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda p, x: jnp.sum(block.apply(p, {}, x)[0] ** 2)))(p, x).jaxpr
+    assert _count(jaxpr, lambda e: e.primitive.name == "pallas_call") == 0
+    routed, it = _moe_block(), I.RecurrentType(D, 8)
+    with_experts = jax.make_jaxpr(
+        lambda p, x: routed.apply(p, routed.init_state(it), x)[0])(
+        routed.init(jax.random.PRNGKey(0), it), x)
+    assert _count(with_experts.jaxpr,
+                  lambda e: e.primitive.name == "pallas_call"
+                  and e.params["name"] == "moe_act_fwd") == 1
 
 
 def test_short_conv_is_causal_and_starts_from_zeros():

@@ -159,3 +159,46 @@ def test_a_layer_keeps_one_rounded_copy_of_its_heads(
                if re.search(r"= bf16" + re.escape(head) + r"\S* convert\(", ln)
                and "flash_attn.bwd" in ln]
     assert len(rounded) <= 1, rounded
+
+
+@pytest.mark.parametrize("m,d,f,groups,rows,act", [
+    (65536, 2048, 768, 16, 512, "silu"),      # sdar-train-bd4-t4096
+    (40960, 2048, 512, 16, 80, "silu"),       # qwen3next-train-t4096
+    (32768, 2048, 1536, 8, 512, "silu"),      # lfm2-train-t8192
+    (16384, 2048, 1536, 8, 256, "silu"),      # glm47flash-train-t4096
+    (24576, 2688, 1856, 16, 192, None),       # nemotron3nano: no gate, ReLU^2
+], ids=["sdar", "qwen3next", "lfm2", "glm47flash", "nemotron3nano"])
+def test_the_expert_ffn_compiles_for_the_chip(
+        one_chip, no_compile_cache, monkeypatch, m, d, f, groups, rows, act):
+    """Forward and backward of a cell's expert FFN (ISSUE 50): Mosaic takes
+    both activation kernels at the tile and in the VMEM ``act_vmem_bytes``
+    counts, an f that is no whole number of lane tiles included (1856),
+    and the compiled text holds no operation over every slot of a sorted
+    buffer outside a kernel."""
+    from deeplearning4j_tpu.nn import activations
+    from deeplearning4j_tpu.ops import expert_ffn
+    monkeypatch.setattr(attention_pallas, "backend_is_tpu", lambda: True)
+    gated = act is not None
+    fn = activations.get(act or "relu2")
+    shape = lambda dims, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+
+    def loss(xs, w_gate, w_up, w_down, sizes, cot):
+        return jnp.sum(cot * expert_ffn.expert_ffn(
+            xs, w_gate, w_up, w_down, sizes, fn, jnp.float32, rows))
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 2, 3))).lower(
+            shape((m, d), jnp.bfloat16),
+            shape((groups, d, f)) if gated else None, shape((groups, d, f)),
+            shape((groups, f, d)), shape((groups,), jnp.int32),
+            shape((m, d))).compile().as_text()
+    for kernel in ("moe_act_fwd", "moe_act_bwd"):
+        assert kernel in text
+    assert text.count("tpu_custom_call") >= 8
+    tile = expert_ffn._act_tile(m, f, gated, 2)
+    assert tile in (256, 512)
+    assert expert_ffn.act_vmem_bytes(tile, f, gated, 2) <= expert_ffn._VMEM
+    # what XLA is left with between the kernels: nothing on [m, f | 2 f]
+    assert not re.search(
+        rf"= (?:bf16|f32)\[{m},(?:{f}|{2 * f})\][^=]* fusion\(", text)
