@@ -690,9 +690,10 @@ def _rows_inside(rows, r):
     return jnp.where((jnp.arange(rows.shape[0]) < r)[:, None], rows, 0)
 
 
-def _gather_back(ys, w, order, inv, r):
+def _gather_back(ys, w, order, w_sorted, r):
     """``moe._combine`` as plain gathers of every slot under autodiff."""
     import jax.numpy as jnp
+    inv = jnp.argsort(order)
     w = jnp.where((inv < r).reshape(w.shape), w, 0)
     return jnp.sum(_rows_inside(ys, r)[inv].reshape(*w.shape, -1)
                    * w[..., None].astype(ys.dtype), axis=1)

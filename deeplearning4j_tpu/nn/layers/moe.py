@@ -76,34 +76,40 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(ys, w, order, inv, r):
-    """``y[t] = sum_j w[t, j] ys[inv[t k + j]]`` over the assignments held
-    here (``inv[a] < r``): the sorted rows' results [N k, d] back at their
-    tokens, weighted, summed in float32. ``w`` [N, k] is read in sorted
-    order and only below ``r``, so an assignment computed elsewhere adds
+def _combine(ys, w, order, w_sorted, r):
+    """``y[t] = sum_j w[t, j] ys[p]``, ``p`` the slot of assignment
+    ``t k + j`` (``order[p] = t k + j``), over the assignments held here
+    (``p < r``): the sorted rows' results [N k, d] back at their tokens,
+    weighted, summed in float32. The weights are read as ``w_sorted``,
+    ``w.reshape(-1)[order]`` as the sort that made ``order`` carried it,
+    and only below ``r``; ``w`` [N, k] is there for its gradient, which the
+    inverse sort carries back: an assignment computed elsewhere adds
     nothing and gets a zero gradient."""
-    return _combine_fwd(ys, w, order, inv, r)[0]
+    return _combine_fwd(ys, w, order, w_sorted, r)[0]
 
 
-def _combine_fwd(ys, w, order, inv, r):
+def _combine_fwd(ys, w, order, w_sorted, r):
     cd, ad = _dtypes.compute_dtypes_for(ys.dtype)
     n, k = w.shape
-    tok = order // k
-    w_sorted = w.reshape(-1)[order]
-    y, ys_kept = rows_back(ys, tok, r, n, w_sorted, ad, keep=cd)
-    return y.astype(ys.dtype), (ys_kept, w_sorted, tok, inv, r)
+    y, ys_kept = rows_back(ys, order // k, r, n, w_sorted, ad, keep=cd)
+    return y.astype(ys.dtype), (ys_kept, w_sorted, order, r)
 
 
 def _combine_bwd(res, dy):
-    ys, w_sorted, tok, inv, r = res
+    ys, w_sorted, order, r = res
+    n, slots = dy.shape[0], order.shape[0]
     # the rows this brings into sorted order serve both gradients: the
     # weights' is taken there and goes back as N k scalars. The rows'
     # own leaves in the dtype the grouped product's backward rounds its
     # operand to anyway: the same bits, and no float32 buffer between
-    dys, dw_sorted = rows_into_order(dy, tok, r, ys.dtype, scale=w_sorted,
-                                     other=ys)
-    dw = jnp.where(inv < r, dw_sorted[inv], 0).reshape(dy.shape[0], -1)
-    return dys, dw.astype(w_sorted.dtype), None, None, None
+    dys, dw_sorted = rows_into_order(dy, order // (slots // n), r, ys.dtype,
+                                     scale=w_sorted, other=ys)
+    # sorting by ``order``, a permutation, puts slot p's value at
+    # assignment order[p]: the gather ``dw_sorted[argsort(order)]`` without
+    # indexing a scalar at a time
+    held = jax.lax.iota(jnp.int32, slots) < r
+    _, dw = jax.lax.sort((order, jnp.where(held, dw_sorted, 0)), num_keys=1)
+    return dys, dw.reshape(n, -1).astype(w_sorted.dtype), None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -153,9 +159,16 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
     2 f`` whose float32 accumulator does the sum that two products left to
     a pass over all ``N k`` rows of [N k, d]. Slots from the tile after
     ``r`` on are left unwritten in every sorted buffer and nothing reads
-    them. What still walks all ``N k`` slots is small, and none of it is
-    under ``moe_experts``: the two sorts, and the weights in sorted order
-    with their gradient back (scalars). What ``moe_experts`` holds beside
+    them. What still walks all ``N k`` slots is none of it under
+    ``moe_experts``, and from PR 51 none of it indexes scalars one at a
+    time (XLA:TPU walks a gather or a scatter of scalars at 8.6 ns an
+    element: the four that stood here were 19 ms of a 244 ms step): one
+    stable sort of ``(local, iota, w)`` gives ``order`` with the weights
+    in sorted order as its payload, one sort by ``order`` in the backward
+    pass carries their gradient back, the counts are a comparison summed
+    over the assignments, and the selected scores a comparison with the
+    experts' numbers summed over ``E``, whose gradient autodiff makes a
+    select summed over ``k``. What ``moe_experts`` holds beside
     its kernels is the weights' own traffic, the float32 leaves rounded
     (and gate joined with up) once a pass that reads them and the float32
     gradients written.
@@ -171,19 +184,27 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
                             precision=jax.lax.Precision.HIGHEST)
         if score == "softmax":
             s = jax.nn.softmax(logits, axis=-1)
-            w, sel = jax.lax.top_k(s, top_k)
-            w = w / jnp.sum(w, axis=-1, keepdims=True) * scale
+            ranked, eps = s, 0.0
         else:
             s = jax.nn.sigmoid(logits)
-            _, sel = jax.lax.top_k(s + expert_bias.astype(ad), top_k)
-            w = jnp.take_along_axis(s, sel, axis=-1)
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + ROUTER_EPS) * scale
+            ranked, eps = s + expert_bias.astype(ad), ROUTER_EPS
+        _, sel = jax.lax.top_k(jax.lax.stop_gradient(ranked), top_k)
+        # no gather or scatter of scalars from here on (the docstring has
+        # why). An expert is selected at most once a token, so one term of
+        # the sum over E is not zero and the bits are the gather's
+        experts = jax.lax.iota(jnp.int32, s.shape[1])
+        w = jnp.sum(jnp.where(sel[..., None] == experts, s[:, None, :], 0),
+                    axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps) * scale
         local = sel.reshape(-1).astype(jnp.int32) - first
         here = (local >= 0) & (local < n_held)
         local = jnp.where(here, local, n_held)
-        order = jnp.argsort(local, stable=True).astype(jnp.int32)
-        inv = jnp.argsort(order).astype(jnp.int32)
-        counts = jnp.bincount(local, length=n_held + 1).astype(jnp.int32)
+        _, order, w_sorted = jax.lax.sort(
+            (local, jax.lax.iota(jnp.int32, local.shape[0]),
+             jax.lax.stop_gradient(w).reshape(-1)), num_keys=1,
+            is_stable=True)
+        bins = jax.lax.iota(jnp.int32, n_held + 1)
+        counts = jnp.sum(local == bins[:, None], axis=1, dtype=jnp.int32)
         sizes = counts[:n_held]
         r = jnp.sum(sizes)
         with jax.named_scope("moe_permute"):
@@ -193,7 +214,7 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, expert_bias, *,
     with jax.named_scope("moe_experts"):
         ys = expert_ffn(xs, w_gate, w_up, w_down, sizes, act, ad, rows)
     with jax.named_scope("moe_route"), jax.named_scope("moe_permute"):
-        y = _combine(ys, w, order, inv, r)
+        y = _combine(ys, w, order, w_sorted, r)
     return y.astype(x.dtype), sizes, counts[n_held:]
 
 

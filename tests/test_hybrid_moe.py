@@ -51,10 +51,10 @@ def _share(p, first, end):
                                 for n in ("e_w1", "e_w3", "e_w2")}}
 
 
-def _system(u, p, bias, held, top_k=K):
+def _system(u, p, bias, held, top_k=K, score="sigmoid"):
     return moe.routed_experts(
         u, p["w_r"], p["e_w1"], p["e_w3"], p["e_w2"], bias, top_k=top_k,
-        held=held, scale=1.0, act=jax.nn.silu)
+        held=held, scale=1.0, act=jax.nn.silu, score=score)
 
 
 def test_the_shares_add_up_to_the_uncut_layer(layer):
@@ -222,7 +222,8 @@ def test_the_row_movement_reads_and_moves_only_the_rows_inside_the_groups(
     want_dx, = jax.vjp(lambda x: x[tok], x)[1](jnp.where(inside, dxs, 0))
     np.testing.assert_allclose(dx, want_dx, rtol=1e-6, atol=1e-6)
 
-    y, back = jax.vjp(lambda ys, w: moe._combine(ys, w, order, inv, r),
+    w_sorted = w.reshape(-1)[order]
+    y, back = jax.vjp(lambda ys, w: moe._combine(ys, w, order, w_sorted, r),
                       planted(ys), w)
     want_y, want_back = jax.vjp(take_and_sum, ys, w)
     np.testing.assert_allclose(y, want_y, rtol=1e-6, atol=1e-6)
@@ -301,6 +302,149 @@ def test_no_gather_of_every_slot_is_left_beside_the_kernels(layer):
     for name in ("moe_rows_fwd", "moe_rows_back"):
         assert _count(jaxpr, lambda e: e.primitive.name == "pallas_call"
                       and e.params["name"] == name) == 2
+
+
+@jax.custom_vjp
+def _indexed_combine(ys, w, order, inv, r):
+    """``moe._combine`` as it stood until ISSUE 51: the weights brought
+    into sorted order, and their gradient back, an index at a time."""
+    return _indexed_combine_fwd(ys, w, order, inv, r)[0]
+
+
+def _indexed_combine_fwd(ys, w, order, inv, r):
+    tok, w_sorted = order // w.shape[1], w.reshape(-1)[order]
+    y, kept = moe_rows.rows_back(ys, tok, r, w.shape[0], w_sorted, w.dtype,
+                                 keep=ys.dtype)
+    return y.astype(ys.dtype), (kept, w_sorted, tok, inv, r)
+
+
+def _indexed_combine_bwd(res, dy):
+    ys, w_sorted, tok, inv, r = res
+    dys, dw_sorted = moe_rows.rows_into_order(dy, tok, r, ys.dtype,
+                                              scale=w_sorted, other=ys)
+    dw = jnp.where(inv < r, dw_sorted[inv], 0).reshape(dy.shape[0], -1)
+    return dys, dw.astype(w_sorted.dtype), None, None, None
+
+
+_indexed_combine.defvjp(_indexed_combine_fwd, _indexed_combine_bwd)
+
+
+def _indexed_system(x, p, bias, held, score, seen):
+    """`_system` with the four indexed expressions that ISSUE 51 replaced:
+    ``bincount`` for the counts, ``w[order]`` and ``dw_sorted[inv]`` around
+    the combine, and the selected scores, with their gradient, through
+    ``top_k`` / ``take_along_axis``. The kernels are the layer's own.
+    ``seen`` takes what the layer hands its combine."""
+    first, end = held
+    n_held, top_k = end - first, K
+    logits = jnp.matmul(x, p["w_r"], precision=jax.lax.Precision.HIGHEST)
+    if score == "softmax":
+        w, sel = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    else:
+        s = jax.nn.sigmoid(logits)
+        _, sel = jax.lax.top_k(s + bias, top_k)
+        w = jnp.take_along_axis(s, sel, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + moe.ROUTER_EPS)
+    local = sel.reshape(-1).astype(jnp.int32) - first
+    local = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    counts = jnp.bincount(local, length=n_held + 1).astype(jnp.int32)
+    sizes = counts[:n_held]
+    r = jnp.sum(sizes)
+    seen.update(order=order, r=r,
+                w_sorted=jax.lax.stop_gradient(w).reshape(-1)[order])
+    xs = moe._dispatch(top_k, x.dtype, x, order // top_k, r)
+    ys = ffn.expert_ffn(xs, p["e_w1"], p["e_w3"], p["e_w2"], sizes,
+                        jax.nn.silu, x.dtype, xs.shape[0] // E)
+    return _indexed_combine(ys, w, order, inv, r), sizes, counts[n_held:]
+
+
+# the router's first input is a constant 1, so its first row of weights
+# adds a number an expert to every token's logits under either score
+# function (the sigmoid's bias follows it): every assignment here, none,
+# and a held expert that gets no row
+_PUSHED = {"as-drawn": ({}, None), "all-here": ({2: 12.0, 3: 12.0}, N * K),
+           "none-here": ({2: -12.0, 3: -12.0, 4: -12.0, 5: -12.0}, 0),
+           "an-empty-held-expert": ({3: -12.0}, None)}
+
+
+@pytest.mark.parametrize("pushed,rows_here", _PUSHED.values(), ids=_PUSHED)
+@pytest.mark.parametrize("score", ["softmax", "sigmoid"])
+def test_the_routing_without_indexing_gives_the_indexed_routings_bits(
+        monkeypatch, layer, score, pushed, rows_here):
+    """ISSUE 51's four expressions against the indexed ones they replace,
+    through the whole layer and its gradients, operation by operation (no
+    `jit`: a fusion may round otherwise): the counts, the stable order (16
+    slots a tile, so equal keys lie across tile boundaries), the weights
+    in sorted order, the result, and the gradients of the tokens, of the
+    router (the weights' gradient back, then the selected scores') and of
+    the experts, all `np.array_equal`."""
+    monkeypatch.setattr(moe_rows, "_TILE", 16)
+    held, bias = (2, 6), (None if score == "softmax" else layer["bias"])
+    u = layer["u"].at[:, 0].set(1.0)
+    share = _share(layer["p"], *held)
+    for expert, push in pushed.items():
+        share["w_r"] = share["w_r"].at[0, expert].set(push)
+        if bias is not None:
+            bias = bias.at[expert].set(float(np.sign(push)))
+    handed, seen = {}, {}
+    real = moe._combine
+
+    def spy(ys, w, order, w_sorted, r):
+        handed.update(order=order, w_sorted=w_sorted, r=r)
+        return real(ys, w, order, w_sorted, r)
+
+    monkeypatch.setattr(moe, "_combine", spy)
+
+    def run(system, *more):
+        def loss(u, share):
+            y, here, away = system(u, share, bias, held, *more)
+            return jnp.sum(y * jnp.cos(y)), (y, here, away)
+
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            u, share)
+
+    got = run(_system, K, score)
+    want = run(_indexed_system, score, seen)
+    here, rows = want[0][1][1], int(seen["r"])
+    assert rows == int(handed["r"]) == int(here.sum())
+    if rows_here is None:
+        assert 0 < rows < N * K and (not pushed or int(here[1]) == 0)
+    else:
+        assert rows == rows_here
+    for name in ("order", "w_sorted"):
+        np.testing.assert_array_equal(handed[name], seen[name])
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype and np.all(np.isfinite(a))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert (float(jnp.abs(got[1][1]["w_r"]).max()) > 0) == (rows > 0)
+
+
+@pytest.mark.parametrize("score", ["softmax", "sigmoid"])
+def test_nothing_beside_the_kernels_indexes_the_assignments(layer, score):
+    """The guard of ISSUE 51's mechanism: forward and backward of the
+    routed layer hold no ``gather``, ``scatter`` or ``scatter-add`` outside
+    the kernels on anything as large as the tokens (XLA:TPU walks such an
+    operation an index at a time; the parent held five: ``bincount``,
+    ``w[order]``, ``dw_sorted[inv]`` and the selected scores with their
+    gradient). What is left of those primitives is the grouped products'
+    own bookkeeping, a few entries a group."""
+    u, held = layer["u"], (2, 6)
+    bias = None if score == "softmax" else layer["bias"]
+
+    def loss(u, share):
+        return jnp.sum(_system(u, share, bias, held, score=score)[0] ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(
+        u, _share(layer["p"], *held)).jaxpr
+    indexed = lambda e: e.primitive.name in (
+        "gather", "scatter", "scatter-add") and any(
+            v.aval.size >= N for v in (*e.invars, *e.outvars))
+    assert _count(jaxpr, indexed) == 0
+    assert _count(jaxpr, lambda e: e.primitive.name == "sort") == 2
 
 
 def _three_products(xs, w_gate, w_up, w_down, sizes, act, out_dtype):
