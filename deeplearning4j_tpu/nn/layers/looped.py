@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn import initializers as _init
+from deeplearning4j_tpu.nn import losses as _losses
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
 from deeplearning4j_tpu.nn.layers.base import Layer, ParamLayer
 from deeplearning4j_tpu.nn.layers.core import matmul
@@ -114,10 +115,15 @@ class LoopedLMOutputLayer(ParamLayer):
         loss  = mean over tokens of [ sum_r p_r CE(z_r, y) - beta H(p) ]
 
     Log-softmax is taken from the logits (no clip of probabilities) and the
-    exit distribution is kept in logs. Each pass's head and cross-entropy
-    run under ``jax.checkpoint``: one [B*T, n_out] logits buffer is live at
-    a time and none is kept for the backward pass. ``apply`` (inference)
-    gives ``softmax(z_R)``: the last pass never exits early."""
+    exit distribution is kept in logs. The exit distribution is made first
+    (it reads the gates alone), then all R passes' heads and cross-entropies
+    are ONE call of ``losses.head_xent`` on their R*B*T rows with the weight
+    ``p_r / (B T)`` a row: it walks the rows by blocks, so no [B*T, n_out]
+    logits array exists, makes the gradient where the logits are, so none
+    are made again in the backward pass, and sums the head's weight
+    gradient over the passes in one accumulator. The gate's gradient
+    reaches it through that weight. ``apply`` (inference) gives
+    ``softmax(z_R)``: the last pass never exits early."""
 
     n_out: int = 0
     beta: float = 0.1
@@ -145,15 +151,6 @@ class LoopedLMOutputLayer(ParamLayer):
         z = matmul(x[-1].reshape(b * t, f), params["W"])
         return jax.nn.softmax(z, axis=-1).reshape(b, t, self.n_out), state
 
-    @staticmethod
-    @jax.checkpoint
-    def _pass_ce(w, s, y):
-        """Per-token cross-entropy [N] of one pass's states [N, F]."""
-        with jax.named_scope("exit_head"):
-            z = matmul(s, w)
-            picked = jnp.take_along_axis(z, y[:, None], axis=-1)[:, 0]
-            return jax.nn.logsumexp(z, axis=-1) - picked
-
     def exit_log_probs(self, params, feats):
         """log p_r per token, [R, N], from states [R, N, F]."""
         r, n, f = feats.shape
@@ -171,21 +168,22 @@ class LoopedLMOutputLayer(ParamLayer):
         if not jnp.issubdtype(labels.dtype, jnp.integer):
             raise TypeError("LoopedLMOutputLayer takes integer labels "
                             f"[B, T], got {labels.dtype} {labels.shape}")
-        feats = feats.reshape(r, b * t, f)
-        y = labels.reshape(b * t)
-        ce = jnp.stack([self._pass_ce(params["W"], feats[i], y)
-                        for i in range(r)])
+        n = b * t
+        feats = feats.reshape(r, n, f)
         with jax.named_scope("exit_gate"):
-            if r > 1:
-                log_p = self.exit_log_probs(params, feats)
-                p = jnp.exp(log_p)
-                per = jnp.sum(p * ce, axis=0) \
-                    + self.beta * jnp.sum(p * log_p, axis=0)
-            else:
-                per = ce[0]
+            # the last pass has no gate, so one pass leaves there whole
+            log_p = self.exit_log_probs(params, feats) if r > 1 \
+                else jnp.zeros((1, n), feats.dtype)
+            p = jnp.exp(log_p)
             if mask is None:
-                loss = jnp.mean(per)
+                weight = jnp.full((n,), 1.0 / n, p.dtype)
             else:
-                w = mask.reshape(b * t).astype(per.dtype)
-                loss = jnp.sum(per * w) / jnp.maximum(jnp.sum(w), 1.0)
+                weight = mask.reshape(n).astype(p.dtype)
+                weight = weight / jnp.maximum(jnp.sum(weight), 1.0)
+            c = (p * weight).reshape(r * n)
+        with jax.named_scope("exit_head"):
+            loss, _ = _losses.head_xent(feats.reshape(r * n, f), params["W"],
+                                        jnp.tile(labels.reshape(n), r), c)
+        with jax.named_scope("exit_gate"):
+            loss = loss + self.beta * jnp.sum(p * log_p * weight)
         return loss, None, state

@@ -23,8 +23,10 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from deeplearning4j_tpu.nn import activations as _act
+from deeplearning4j_tpu.utils import dtypes as _dtypes
 
 _EPS = 1e-8
 _LOG_EPS = math.log(_EPS)
@@ -176,6 +178,128 @@ def _softmax_xent_bwd(sparse, res, g):
 
 
 _softmax_xent.defvjp(_softmax_xent_fwd, _softmax_xent_bwd)
+
+
+# head_xent walks its rows in blocks of _HEAD_ROWS, the logits of one block
+# live at a time, and takes the weight gradient of _HEAD_GROUP blocks as one
+# product (ouro-train-t2048 on a v5e, PERF.md section 6, PR 52: a 100 MB
+# block of float32 logits lies in VMEM; a product into the [F, V] float32
+# accumulator a block is bound by reading and writing the accumulator)
+_HEAD_ROWS = 512
+_HEAD_GROUP = 4
+
+
+def head_xent(s, w, y, c):
+    """A language-model head and its cross-entropy as one op: features
+    ``s`` [M, F], the head's weight ``w`` [F, V], integer labels ``y`` [M]
+    and a weight a row ``c`` [M] give
+
+        total = sum_m c_m ce_m,    ce_m = logsumexp(s_m w) - (s_m w)[y_m]
+
+    and ``ce`` [M], which is for reading (it carries no gradient; the
+    gradient to ``c`` is ``ce``). The products are ``core.matmul``'s: the
+    compute dtype in, the accumulation dtype out.
+
+    The rows are walked ``_HEAD_ROWS`` at a time, so no [M, V] array exists
+    in either pass, and under differentiation the gradient is made where
+    the logits are: ``c`` is an input, not a cotangent, so each block forms
+    ``dz = c (softmax(z) - onehot(y))`` while its logits are live, rounds it
+    to the compute dtype as the product's transpose would, and takes
+    ``ds = dz w^T``; the ``dz`` of ``_HEAD_GROUP`` blocks is kept for one
+    product ``dw += s^T dz`` into ONE accumulator for all rows. The backward
+    pass multiplies ``ds``, ``dw`` and ``ce`` by the scalar cotangent and
+    makes no logits again. Several uses of one head (the passes of a looped
+    model) are one call on their rows laid end to end.
+
+    Both rules' operations carry the scopes of the call site (jax keeps
+    them for a ``custom_vjp``'s backward rule), so a caller names the scope
+    its readings go by around the call."""
+    total, ce = _head_xent(s, w, y.astype(jnp.int32), c)
+    return total, lax.stop_gradient(ce)
+
+
+def _head_blocks(s, y, c):
+    """s, y, c as [groups, blocks, rows, ...], padded with rows of weight
+    0."""
+    m = s.shape[0]
+    rows = min(_HEAD_ROWS, m)
+    blocks = min(_HEAD_GROUP, -(-m // rows))
+    groups = -(-m // (blocks * rows))
+    pad = [(0, groups * blocks * rows - m)]
+    if pad[0][1]:
+        s, y, c = jnp.pad(s, pad + [(0, 0)]), jnp.pad(y, pad), jnp.pad(c, pad)
+    lead = (groups, blocks, rows)
+    return s.reshape(*lead, -1), y.reshape(lead), c.reshape(lead)
+
+
+def _head_block_ce(sc, wc, y, accum):
+    """One block's cross-entropy [rows], its softmax and its one-hot
+    labels [rows, V], from the block's features and the weight, both in
+    the compute dtype."""
+    z = lax.dot(sc, wc, preferred_element_type=accum)
+    hot = lax.broadcasted_iota(jnp.int32, z.shape, 1) == y[:, None]
+    top = jnp.max(z, axis=-1, keepdims=True)
+    e = jnp.exp(z - top)
+    norm = jnp.sum(e, axis=-1, keepdims=True)
+    ce = (jnp.log(norm) + top)[:, 0] - jnp.sum(jnp.where(hot, z, 0.0), axis=-1)
+    return ce, e / norm, hot
+
+
+@jax.custom_vjp
+def _head_xent(s, w, y, c):
+    with jax.named_scope("head_xent"):
+        cd, ad = _dtypes.compute_dtypes_for(s.dtype)
+        wc = w.astype(cd)
+
+        def block(_, xs):
+            s_i, y_i = xs
+            return None, _head_block_ce(s_i.astype(cd), wc, y_i, ad)[0]
+
+        s_b, y_b, _ = _head_blocks(s, y, c)
+        rows = s_b.shape[2]
+        _, ce = lax.scan(block, None, (s_b.reshape(-1, rows, s.shape[1]),
+                                       y_b.reshape(-1, rows)))
+        ce = ce.reshape(-1)[:s.shape[0]]
+        return jnp.sum(c * ce), ce
+
+
+def _head_xent_fwd(s, w, y, c):
+    with jax.named_scope("head_xent"):
+        cd, ad = _dtypes.compute_dtypes_for(s.dtype)
+        wc = w.astype(cd)
+
+        def block(_, xs):
+            s_i, y_i, c_i = xs
+            ce, p, hot = _head_block_ce(s_i.astype(cd), wc, y_i, ad)
+            dz = (c_i[:, None].astype(ad) * (p - hot.astype(ad))).astype(cd)
+            ds = lax.dot_general(dz, wc, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=ad)
+            return None, (dz, ds.astype(s.dtype), ce)
+
+        def group(dw, xs):
+            _, (dz, ds, ce) = lax.scan(block, None, xs)
+            s_g = xs[0].reshape(-1, s.shape[1]).astype(cd)
+            dw = dw + lax.dot_general(s_g, dz.reshape(s_g.shape[0], -1),
+                                      (((0,), (0,)), ((), ())),
+                                      preferred_element_type=ad)
+            return dw, (ds, ce)
+
+        dw, (ds, ce) = lax.scan(group, jnp.zeros(w.shape, ad),
+                                _head_blocks(s, y, c))
+        m = s.shape[0]
+        ds, ce = ds.reshape(-1, s.shape[1])[:m], ce.reshape(-1)[:m]
+        return (jnp.sum(c * ce), ce), (ds, dw.astype(w.dtype), ce)
+
+
+def _head_xent_bwd(res, g):
+    ds, dw, ce = res
+    with jax.named_scope("head_xent"):
+        g = g[0]
+        return (g.astype(ds.dtype) * ds, g.astype(dw.dtype) * dw, None,
+                g.astype(ce.dtype) * ce)
+
+
+_head_xent.defvjp(_head_xent_fwd, _head_xent_bwd)
 
 
 def hinge(pred, labels, mask=None, weights=None):

@@ -7,9 +7,10 @@
     call under `moe_route` by operation. `--root` names another checkout
     (the parent's, from `git archive`) whose package is traced instead.
 
-`python3 scripts/moe_route_split.py trace DIR`
+`python3 scripts/moe_route_split.py trace DIR [--scope NAME]`
     the same split of a traced benchmark run (`python3 -m benchmark.run
-    --trace 1` leaves `.bench_out/trace-<cell>`), a `fit.step` span.
+    --trace 1` leaves `.bench_out/trace-<cell>`), a `fit.step` span;
+    `--scope exit_head` parts another of the program's scopes the same way.
 
 Both need the chip (`chiprun -- python3 scripts/moe_route_split.py ...`):
 a CPU trace has no device plane, and the split then prints nothing.
@@ -37,14 +38,15 @@ CELLS = {
 }
 
 
-def split(view, steps, t0=float("-inf"), t1=float("inf"), out=sys.stdout):
-    """Self time a step of the device operations under `moe_route` inside
+def split(view, steps, t0=float("-inf"), t1=float("inf"), out=sys.stdout,
+          scope=SCOPE):
+    """Self time a step of the device operations under `scope` inside
     [t0, t1], by pass, opcode and result shape, largest first."""
     from benchmark.readers.trace_scope_ms import window_self_times
     rows, chips = window_self_times(view, t0, t1)
     by_kind = collections.defaultdict(lambda: [0.0, 0, "", ""])
     for op_name, label, self_ns, _ in rows:
-        if SCOPE in op_name:
+        if scope in op_name:
             name, _, kind = label.partition(" ")
             kind = re.sub(r"\{[^}]*\}", "", kind)  # the layouts
             row = by_kind["bwd " + kind if "transpose(" in op_name
@@ -54,7 +56,7 @@ def split(view, steps, t0=float("-inf"), t1=float("inf"), out=sys.stdout):
             row[2], row[3] = name, op_name
     per = lambda ns: ns / max(chips, 1) / steps * 1e-6
     print(f"{per(sum(r[2] for r in rows)):9.4f} ms a step busy, "
-          f"{per(sum(r[0] for r in by_kind.values())):9.4f} under {SCOPE} "
+          f"{per(sum(r[0] for r in by_kind.values())):9.4f} under {scope} "
           f"({steps} steps)", file=out)
     for kind, (ns, n, name, op_name) in sorted(by_kind.items(),
                                                key=lambda kv: -kv[1][0]):
@@ -120,6 +122,7 @@ def main(argv=None):
     one.add_argument("--cells", default=",".join(CELLS))
     step = sub.add_parser("trace")
     step.add_argument("dir")
+    step.add_argument("--scope", default=SCOPE)
     args = ap.parse_args(argv)
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, os.path.abspath(getattr(args, "root", None) or here))
@@ -134,7 +137,7 @@ def main(argv=None):
         print(f"no device trace under {args.dir}")
         return 1
     steps = len(view.host_spans([STEP_SPAN], window.t0, window.t1))
-    split(view, steps or 1, window.t0, window.t1)
+    split(view, steps or 1, window.t0, window.t1, scope=args.scope)
     return 0
 
 

@@ -10,10 +10,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.test_util import check_grads
 
 from deeplearning4j_tpu import models, telemetry
 from deeplearning4j_tpu.nn import initializers as _init
 from deeplearning4j_tpu.nn import layers as L
+from deeplearning4j_tpu.nn import losses
 from deeplearning4j_tpu.nn.conf import inputs as I
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers import attention as A
@@ -297,6 +299,107 @@ def test_a_label_mask_weights_the_tokens():
     part, _, _ = head.loss_from_features(p, {}, feats[:, :1, :5],
                                          jnp.asarray(y[:1, :5]))
     assert float(masked) == pytest.approx(float(part), rel=1e-5)
+
+
+def _plain_loss(head, p, feats, y, mask=None):
+    """The objective with every pass's head and cross-entropy as the two
+    plain lines under autodiff (what `losses.head_xent` replaces)."""
+    r, b, t, f = feats.shape
+    s = feats.reshape(r, b * t, f)
+    z = matmul(s.reshape(r * b * t, f), p["W"]).reshape(r, b * t, -1)
+    ce = jax.nn.logsumexp(z, axis=-1) - jnp.take_along_axis(
+        z, jnp.broadcast_to(y.reshape(1, b * t, 1), (r, b * t, 1)), -1)[..., 0]
+    if r > 1:
+        log_p = head.exit_log_probs(p, s)
+        per = jnp.sum(jnp.exp(log_p) * (ce + head.beta * log_p), axis=0)
+    else:
+        per = ce[0]
+    w = jnp.ones_like(per) if mask is None else mask.reshape(b * t)
+    return jnp.sum(per * w) / jnp.maximum(jnp.sum(w), 1.0)
+
+
+def _head_case(passes, masked, dtype=jnp.float32):
+    head, p, _ = _head_and_states()
+    feats = jax.random.normal(jax.random.PRNGKey(7), (passes, 2, T, D), dtype)
+    p, (_, y) = jax.tree_util.tree_map(lambda a: a.astype(dtype), p), \
+        _tokens(b=2)
+    mask = None
+    if masked:
+        mask = jnp.asarray(np.random.RandomState(3).rand(2, T) > 0.3, dtype)
+    return head, p, feats, jnp.asarray(y), mask
+
+
+def _assert_leaves_close(got, want, rtol, atol):
+    """Leaf by leaf, `atol` as a share of the wanted leaf's largest entry."""
+    flat = jax.tree_util.tree_leaves_with_path
+    for (path, a), (_, b) in zip(flat(got), flat(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype, path
+        np.testing.assert_allclose(
+            a, b, rtol=rtol, atol=atol * float(jnp.max(jnp.abs(b)) + 1e-30),
+            err_msg=str(path))
+
+
+# 2 T = 64 rows a pass: in blocks of 24 (groups of 96) neither the blocks
+# nor the groups end where the passes do, and the last group is padded
+@pytest.mark.parametrize("rows", [512, 24])
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("passes", [1, 4])
+def test_the_heads_loss_and_gradients_are_the_plain_lines(passes, masked,
+                                                          rows, monkeypatch):
+    monkeypatch.setattr(losses, "_HEAD_ROWS", rows)
+    head, p, feats, y, mask = _head_case(passes, masked)
+    want, g_want = jax.value_and_grad(
+        lambda p, s: _plain_loss(head, p, s, y, mask), argnums=(0, 1))(
+            p, feats)
+    got, g_got = jax.value_and_grad(
+        lambda p, s: head.loss_from_features(p, {}, s, y, mask)[0],
+        argnums=(0, 1))(p, feats)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    if passes == 1:  # one pass has no gate
+        assert not np.any(np.asarray(g_got[0]["gate_W"]))
+    _assert_leaves_close(g_got, g_want, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("passes", [1, 4])
+def test_the_heads_gradients_pass_a_float64_check(passes, masked,
+                                                  monkeypatch):
+    monkeypatch.setattr(losses, "_HEAD_ROWS", 24)
+    head, p, feats, y, mask = _head_case(passes, masked, dtype=jnp.float64)
+    check_grads(lambda p, s: head.loss_from_features(p, {}, s, y, mask)[0],
+                (p, feats), order=1, modes=["rev"], atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("passes", [1, 4])
+def test_the_heads_loss_and_gradients_are_the_float32_references(
+        passes, monkeypatch):
+    """`benchmark/reference/ouro.py`'s objective on the same states: its
+    own head, gates and exit distribution (float32 under precision
+    "highest", a sequence at a time), every leaf's gradient and the
+    states'."""
+    from benchmark.reference import ouro
+    head, p, feats, y, _ = _head_case(passes, False)
+    # the reference keeps the exit distribution in probabilities, not in
+    # logs: gates within its range
+    p["gate_W"] = p["gate_W"] / 10.0
+    model = {"total_ut_steps": passes, "exit_entropy_beta": head.beta}
+
+    def reference(p, feats):
+        leaves = {"head_w": p["W"], "gate_w": p["gate_W"],
+                  "gate_b": p["gate_b"]}
+        total = 0.0
+        for i in range(feats.shape[1]):
+            monkeypatch.setattr(ouro, "states_one",
+                                lambda *a, i=i: list(feats[:, i]))
+            total += ouro.loss_sum_one(leaves, None, y[i], model)
+        return total / y.size
+
+    want, g_want = jax.value_and_grad(reference, argnums=(0, 1))(p, feats)
+    got, g_got = jax.value_and_grad(
+        lambda p, s: head.loss_from_features(p, {}, s, y)[0],
+        argnums=(0, 1))(p, feats)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    _assert_leaves_close(g_got, g_want, rtol=1e-3, atol=1e-5)
 
 
 def test_output_is_the_last_passes_softmax():

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax._src.ad_checkpoint import saved_residuals
+from jax.test_util import check_grads
 
 from deeplearning4j_tpu import models
 from deeplearning4j_tpu.nn import layers as L
@@ -19,6 +20,7 @@ from deeplearning4j_tpu.nn import updaters as U
 from deeplearning4j_tpu.nn.conf import inputs as I
 from deeplearning4j_tpu.nn.conf.network import NeuralNetConfig
 from deeplearning4j_tpu.nn.graph import ComputationGraph, GraphBuilder
+from deeplearning4j_tpu.nn.layers.core import matmul
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 
 V = 7
@@ -225,3 +227,132 @@ def test_the_train_step_makes_no_probability_tensor():
     wide = re.compile(r"stablehlo\.divide\b.*tensor<(24x37|3x8x37)x")
     assert not [ln for ln in text.splitlines() if wide.search(ln)]
     assert re.search(r"stablehlo\.exponential\b.*tensor<24x37x", text)
+
+
+# ---- head_xent: the head's product and its cross-entropy as one op ----
+
+HF, HV = 12, 37  # features and vocabulary of the head cases
+
+
+def _head_case(m, dtype, seed=0):
+    r = np.random.default_rng(seed)
+    s = jnp.asarray(r.normal(size=(m, HF)), dtype)
+    w = jnp.asarray(r.normal(size=(HF, HV)) * 0.5, dtype)
+    y = jnp.asarray(r.integers(0, HV, size=m), jnp.int32)
+    c = jnp.asarray(r.random(size=m) / m, dtype)
+    return s, w, y, c
+
+
+def _plain_head(s, w, y, c):
+    """The two lines the op replaces, under autodiff."""
+    z = matmul(s, w)
+    ce = jax.nn.logsumexp(z, axis=-1) \
+        - jnp.take_along_axis(z, y[:, None], axis=-1)[:, 0]
+    return jnp.sum(c * ce), ce
+
+
+# rows against blocks of 8 in groups of 4: one group of whole blocks, a padded
+# last block, one short block, three groups with the last one padded
+@pytest.mark.parametrize("rows", [24, 21, 5, 70])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
+def test_head_xent_is_autodiff_of_the_plain_lines(rows, dtype, monkeypatch):
+    monkeypatch.setattr(losses, "_HEAD_ROWS", 8)
+    s, w, y, c = _head_case(rows, dtype)
+    tol = 1e-5 if dtype == jnp.float32 else 1e-12
+    (want, ce_want), g_want = jax.value_and_grad(
+        _plain_head, argnums=(0, 1, 3), has_aux=True)(s, w, y, c)
+    (got, ce_got), g_got = jax.value_and_grad(
+        losses.head_xent, argnums=(0, 1, 3), has_aux=True)(s, w, y, c)
+    assert got.dtype == want.dtype and ce_got.shape == (rows,)
+    np.testing.assert_allclose(got, want, rtol=tol)
+    np.testing.assert_allclose(ce_got, ce_want, rtol=tol, atol=tol)
+    for a, b, name in zip(g_got, g_want, ("ds", "dW", "dc")):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, rtol=tol, atol=tol, err_msg=name)
+    # the primal, a score without a gradient, walks the same blocks
+    alone, ce_alone = jax.jit(losses.head_xent)(s, w, y, c)
+    np.testing.assert_allclose(alone, want, rtol=tol)
+    np.testing.assert_allclose(ce_alone, ce_want, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("rows", [16, 11])
+def test_head_xent_passes_a_float64_gradient_check(rows, monkeypatch):
+    monkeypatch.setattr(losses, "_HEAD_ROWS", 8)
+    s, w, y, c = _head_case(rows, jnp.float64, seed=1)
+    # a cotangent other than 1 reaches ds, dW and dc alike
+    f = lambda s, w, c: 1.7 * losses.head_xent(s, w, y, c)[0]  # noqa: E731
+    check_grads(f, (s, w, c), order=1, modes=["rev"], atol=1e-6, rtol=1e-6)
+
+
+def test_head_xent_rounds_as_the_bfloat16_products_do():
+    """Under the bfloat16 policy the op's gradient is the plain lines'
+    to the products' rounding: dz is rounded once, with the row's weight
+    inside, where the product's transpose rounds it."""
+    from deeplearning4j_tpu.utils import dtypes
+    s, w, y, c = _head_case(64, jnp.float32, seed=2)
+    old = dtypes.get_policy()
+    dtypes.bf16_policy()
+    try:
+        want, g_want = jax.value_and_grad(
+            lambda *a: _plain_head(*a)[0], argnums=(0, 1, 3))(s, w, y, c)
+        got, g_got = jax.value_and_grad(
+            lambda *a: losses.head_xent(*a)[0], argnums=(0, 1, 3))(s, w, y, c)
+    finally:
+        dtypes.set_policy(old.param_dtype, old.compute_dtype, old.accum_dtype)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for a, b in zip(g_got, g_want):
+        assert a.dtype == jnp.float32
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, b, rtol=0, atol=2e-2 * scale)
+
+
+def _avals(jaxpr):
+    """Every value's aval of a jaxpr, the loops' bodies included."""
+    for eqn in jaxpr.eqns:
+        yield from ((eqn.primitive.name, v.aval) for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _avals(sub)
+
+
+@pytest.mark.parametrize("what", ["value", "value_and_grad"])
+def test_head_xent_makes_no_array_of_all_rows_logits(what, monkeypatch):
+    """No [M, V] array in either pass: a block's logits, and under
+    differentiation a group's dz. Two products a block (the logits, ds),
+    one a group (dW) and none in the backward rule."""
+    monkeypatch.setattr(losses, "_HEAD_ROWS", 8)
+    s, w, y, c = _head_case(64, jnp.float32)
+    f = lambda s, w, c: losses.head_xent(s, w, y, c)[0]  # noqa: E731
+    if what == "value_and_grad":
+        f = jax.value_and_grad(f, argnums=(0, 1, 2))
+    found = list(_avals(jax.make_jaxpr(f)(s, w, c).jaxpr))
+    wide = {a.shape[0] for _, a in found
+            if len(a.shape) == 2 and a.shape[1] == HV}
+    assert wide == ({8, 32, HF} if what == "value_and_grad" else {8})
+    dots = [a.shape for name, a in found if name == "dot_general"]
+    assert sorted(dots) == sorted(
+        [(8, HV), (8, HF), (HF, HV)] if what == "value_and_grad"
+        else [(8, HV)])
+
+
+def test_both_rules_of_head_xent_carry_the_call_sites_scopes():
+    """jax keeps the call site's scopes for a `custom_vjp`'s backward rule
+    too, so a caller names the scope its readings go by around the call.
+    Read from the compiled text's `op_name`s, which is what a trace shows
+    (the lowered text names a loop body's operations without the caller's
+    scopes): every product and exponential of the gradient's program, and
+    the backward rule's work under `transpose(`."""
+    s, w, y, c = _head_case(24, jnp.float32)
+
+    def f(s, w, c):  # a cotangent of 1 would leave the backward rule empty
+        with jax.named_scope("my_head"):
+            return 1.7 * losses.head_xent(s, w, y, c)[0]
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
+        s, w, c).compile().as_text()
+    paths = set(re.findall(r'op_name="(jit\(f\)[^"]+)"', text))
+    made = [p for p in paths if re.search(r"dot_general|exp", p)]
+    held = re.compile(r"[/(]my_head[/)].*head_xent/")  # jax writes jvp(my_head)
+    assert made and all(held.search(p) for p in made), \
+        [p for p in made if not held.search(p)]
+    assert any("dot_general" in p for p in made)
+    assert any("transpose(" in p and held.search(p) for p in paths)
