@@ -436,6 +436,55 @@ def _flash_backward_time(name, *, b, t, h, d, interpret, iters=20):
             "wall_s": round(time.perf_counter() - t0, 1)}
 
 
+def _flash_steps_time(name, *, bh, t, d, block_diffusion=None, block=512,
+                      interpret=False, iters=50):
+    """Milliseconds a call of the forward kernel and of the backward, each
+    alone on folded [BH, T, D] operands as the custom_vjp rules hand them
+    (bfloat16 q, k, v; float32 out and cotangent; the backward with the
+    delta it computes beside its kernel), causal or under
+    ``BlockDiffusion(*block_diffusion)``, beside the grid steps a head
+    walks (``live``) and the tiles of its rectangle: a dead tile's turn
+    cost 0.10-0.42 us by the head width while the grid was the rectangle
+    (PERF.md section 6, PR 53), and this is the call that reads a step's
+    cost again. The host's clock around ``iters`` calls."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops import attention_pallas as _ap
+
+    t0 = time.perf_counter()
+    geometry = block_diffusion and _ap.BlockDiffusion(*block_diffusion)
+    causal, scale = geometry is None, d ** -0.5
+    block_q, block_k, t_pad = _ap._geometry(t, block, block)
+    steps = _ap.step_list(causal, geometry, t_pad // block_q,
+                          t_pad // block_k, block_q, block_k)
+    q, k, v, g = (jax.random.normal(key, (bh, t, d), jnp.float32)
+                  for key in jax.random.split(jax.random.PRNGKey(t + d), 4))
+    q, k, v = _ap._as_operands(interpret, q, k, v)
+    fwd = jax.jit(lambda q, k, v: _ap._run_fwd(
+        q, k, v, None, 1, causal, scale, block, block, interpret,
+        jnp.float32, geometry))
+    bwd = jax.jit(lambda q, k, v, out, lse, g: _ap._run_bwd(
+        (q, k, v, None, out, lse), g, None, 1, causal, scale, block, block,
+        interpret, geometry))
+
+    def ms(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t1 = time.perf_counter()
+        for _ in range(iters):
+            got = fn(*args)
+        jax.block_until_ready(got)
+        return (time.perf_counter() - t1) / iters * 1e3, got
+    fwd_ms, (out, lse) = ms(fwd, q, k, v)
+    bwd_ms, grads = ms(bwd, q, k, v, out, lse, g)
+    for leaf in (out, *grads):
+        _expect(bool(jnp.isfinite(leaf).all()), f"{name}: non-finite value")
+    return {"kernel": name, "fwd_ms": float(f"{fwd_ms:.4g}"),
+            "bwd_ms": float(f"{bwd_ms:.4g}"), "live": steps.live,
+            "rectangle": steps.rectangle,
+            "wall_s": round(time.perf_counter() - t0, 1)}
+
+
 def _flash_rounded_once_case(name, *, b, t, h, d, interpret):
     """Both flash kernels read q, k and v rounded once to bfloat16
     (``attention_pallas._operand_dtype``), which is what the matrix units
@@ -980,6 +1029,14 @@ def kernels_phase(*, interpret, tol):
                                  h=16, d=128, interpret=False),
             _flash_backward_time("flash_bwd_t4096_h20_d256_f32", b=1, t=4096,
                                  h=20, d=256, interpret=False),
+            # each kernel alone with the steps it walks: sdar's call
+            # (80 live of 256), lfm2's (136 of 256), glm47flash's (36 of 64)
+            _flash_steps_time("flash_steps_bd4_t8192_h32_d128", bh=32,
+                              t=8192, d=128, block_diffusion=(4096, 4)),
+            _flash_steps_time("flash_steps_causal_t8192_h32_d64", bh=32,
+                              t=8192, d=64),
+            _flash_steps_time("flash_steps_causal_t4096_h20_d256", bh=20,
+                              t=4096, d=256),
             # glm47flash's call and gpt2m's: [20, 4096, 256], [64, 1024, 64]
             _flash_rounded_once_case("flash_rounded_once_t4096_h20_d256",
                                      b=1, t=4096, h=20, d=256,

@@ -11,11 +11,30 @@ Kernel design (FlashAttention-style online softmax, TPU-first):
   head's own width D (no pad to the 128 lanes: at D 64 half of every DMA,
   every MXU operand and the accumulator used to be zeros); the sequence
   pads to a common multiple of the block sizes.
-* Grid = (BH, T/Bq, T/Bk) with the KEY dimension innermost: each (bh, iq)
-  pair's query block stays VMEM-resident while key/value blocks [Bk, D]
-  stream through, carried by the running (max, sum, acc) online-softmax
-  recurrence held in VMEM scratch — VMEM use is O(Bq*D + Bk*D), so
-  sequence length is bounded by HBM, not VMEM.
+* Grid = (BH, live steps): a head after a head, and within a head the
+  LIVE [Bq, Bk] tiles of its mask alone, from a list made at trace time
+  (``step_list``: query block, key block, and whether the step opens or
+  closes its outer block) that the kernel and the index maps read
+  scalar-prefetched. No grid step is spent on a tile the mask kills, and
+  a mask with no dead tile walks the whole rectangle: one construction of
+  the grid for every mask and every kernel. The list is in the order of a
+  (T/Bq, T/Bk) grid with the KEY dimension innermost: each query block
+  stays VMEM-resident while its key/value blocks [Bk, D] stream through,
+  carried by the running (max, sum, acc) online-softmax recurrence held in
+  VMEM scratch — VMEM use is O(Bq*D + Bk*D), so sequence length is
+  bounded by HBM, not VMEM. Live steps of a head's rectangle in 512 x 512
+  blocks, at the benchmark cells' calls (tests/test_attention_steps.py):
+
+      sdar-train-bd4-t4096    BlockDiffusion(4096, 4), T 8192    80 / 256
+      lfm2-train-t8192        causal, T 8192                    136 / 256
+      glm47flash-train-t4096, nemotron3nano-train-packed,
+      qwen3next-train-t4096   causal, T 4096                     36 / 64
+      ouro-train-t2048        causal, T 2048                     10 / 16
+      gpt2m-train-t1024       causal, T 1024                      3 / 4
+
+  A dead tile's turn in a rectangular grid cost 0.10-0.42 us by the head
+  width (PERF.md section 6, PR 53): 0.167 at sdar's 128, a quarter of its
+  forward call.
 * Inside a grid step the [Bq, Bk] tile is walked in [128, 128] pieces: a
   float32 score piece is 16 vector registers of the file's 64 (a whole
   512 x 512 tile is 256, and every softmax step went out to VMEM and
@@ -26,22 +45,26 @@ Kernel design (FlashAttention-style online softmax, TPU-first):
   the accumulator is out^T): the softmax state of a query is one lane of
   a [1, Bq] row, reductions over keys are elementwise across registers,
   and nothing is broadcast across lanes inside the walk.
-* Causal masking: key blocks entirely above the diagonal skip their
-  compute via pl.when (and are not fetched); a tile under the diagonal
-  takes no mask at all; on a diagonal tile of equal blocks the dead, cut
-  and whole pieces are told apart at trace time. The length mask applies
-  only where T is padded and the tile holds the tail; the key padding
-  mask on every tile of a masked call.
+* Causal masking: key blocks entirely above the diagonal are not in the
+  list; a tile under the diagonal takes no mask at all; on a diagonal tile
+  of equal blocks the dead, cut and whole pieces are told apart at trace
+  time. The length mask applies only where T is padded and the tile holds
+  the tail; the key padding mask on every tile of a masked call: neither
+  makes a tile dead. ``_walk_tiles`` is the one statement of which tile
+  takes which body: the list is that walk run on integers, and the kernel
+  runs it again on the step's entries to choose the body.
 * Block diffusion (``BlockDiffusion(seq_len, block_len)``, the training
   mask of BD3-LM, arXiv:2503.09573): the sequence is a noised copy of
   ``seq_len`` tokens followed by a clean copy, and three quarters of the
   [2T, 2T] square is dead by whole tiles. ``_walk_block_diffusion`` tells
-  a tile dead (skipped, not fetched), whole (no mask) or cut (on its
-  copy's diagonal: blocks compared on the diagonal pieces alone) from the
-  grid position, as the causal walk does from the diagonal.
+  a tile dead (not in the list), whole (no mask) or cut (on its copy's
+  diagonal: blocks compared on the diagonal pieces alone) from its
+  position, as the causal walk does from the diagonal.
 * The kernel also emits the log-sum-exp per row. Backward is a
-  jax.custom_vjp over a second kernel in the same layout and with the same
-  causal skipping (``_bwd_kernel``): a piece's probabilities are recomputed
+  jax.custom_vjp over a second kernel in the same layout and on the same
+  live tiles (``_bwd_kernel``; key block by key block with the query
+  blocks innermost, but query-major in the "dq" form): a piece's
+  probabilities are recomputed
   from (q, k, v, lse) in VMEM, so the gradient costs no [BH, T, Bk]
   temporary in HBM. One kernel a head with dq^T resident in VMEM (five
   products a piece) where what it holds there, counted from the shape and
@@ -71,6 +94,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
@@ -247,6 +271,13 @@ def _not(pred):
     return (not pred) if isinstance(pred, bool) else jnp.logical_not(pred)
 
 
+def _select(pred, a, b):
+    """``a`` where ``pred`` else ``b``, for a Python bool (the step list's
+    walk on integers) or a traced scalar (the kernel's)."""
+    return (a if pred else b) if isinstance(pred, bool) \
+        else jnp.where(pred, a, b)
+
+
 def _when(pred, fn):
     if pred is True:
         fn()
@@ -324,7 +355,7 @@ def _piece_valid(causal_mask, t_true, mask_ref, iq, j, block_q, block_k, r0,
 def _walk_block_diffusion(tile, geometry, iq, j, block):
     """``_walk_tiles`` under a ``BlockDiffusion`` geometry (square blocks,
     each copy ``half`` whole tiles, no key mask and no padding:
-    ``BlockDiffusion.fits``). Dead, and so neither run nor fetched: every
+    ``BlockDiffusion.fits``). Dead, and so no step of the grid: every
     clean-query x noised-key tile, the noised x noised tiles off the
     diagonal, and the tiles above its copy's diagonal in the two quarters
     with clean keys. Whole: the clean-key tiles under that diagonal. Cut,
@@ -335,42 +366,25 @@ def _walk_block_diffusion(tile, geometry, iq, j, block):
     half = geometry.seq_len // block
     shift = geometry.block_len.bit_length() - 1
     noised_q = iq < half
-    ii = jnp.where(noised_q, iq, iq - half)    # the tile's place in its copy
+    ii = _select(noised_q, iq, iq - half)     # the tile's place in its copy
     jj = j - half
     _when(_all(j >= half, jj < ii), tile(None, False))
     _when(_all(noised_q, j == iq), tile(("same", shift), False))
     _when(_all(j >= half, jj == ii),
-          tile(("under", shift, jnp.where(noised_q, 0, 1)), False))
-
-
-def _block_diffusion_key_block(geometry, block, i, j):
-    """The key block that step ``j`` of query block ``i`` names: ``j``
-    where the tile is live, else the live one nearest before it (or the
-    first), so that nothing is fetched for a dead step."""
-    half = geometry.seq_len // block
-    ii = jnp.where(i < half, i, i - half)
-    return jnp.where(j < half, jnp.where(i < half, i, half),
-                     jnp.minimum(j, half + ii))
-
-
-def _block_diffusion_query_block(geometry, block, j, i):
-    """The query block that step ``i`` of key block ``j`` names, as
-    ``_block_diffusion_key_block``: a noised key block meets its own
-    query block alone; a clean one the noised query blocks from its place
-    on and the clean ones from its own on."""
-    half = geometry.seq_len // block
-    return jnp.where(j < half, j, jnp.where(
-        i < half, jnp.maximum(i, j - half), jnp.maximum(i, j)))
+          tile(("under", shift, _select(noised_q, 0, 1)), False))
 
 
 def _walk_tiles(tile, causal, ragged, has_mask, iq, j, block_q, block_k,
                 t_true, geometry=None):
-    """Run the grid step (query block ``iq``, key block ``j``) as the one
-    ``tile(causal_mask, key_masks)`` body it needs, or none above the
-    diagonal: masks only where a tile needs them, the length mask where
-    the call pads T and this key block holds the tail, the key-padding
-    mask on every tile of a masked call, the causal mask on the diagonal.
-    Shared by the forward and the backward kernel."""
+    """Run the tile (query block ``iq``, key block ``j``) as the one
+    ``tile(causal_mask, key_masks)`` body it needs, or none where the mask
+    kills it whole (above the diagonal): masks only where a tile needs
+    them, the length mask where the call pads T and this key block holds
+    the tail, the key-padding mask on every tile of a masked call, the
+    causal mask on the diagonal. The one statement of a mask's geometry:
+    the forward and the backward kernel run it on the step's traced
+    entries, where it chooses the body, and ``step_list`` on every
+    position as Python integers, where a tile with a body is a step."""
     if geometry is not None:
         return _walk_block_diffusion(tile, geometry, iq, j, block_q)
     tail = _all(ragged, (j + 1) * block_k > t_true)
@@ -389,8 +403,103 @@ def _walk_tiles(tile, causal, ragged, has_mask, iq, j, block_q, block_k,
         _when(_all(live, _not(under)), tile("iota", has_mask or ragged))
 
 
+class Steps(NamedTuple):
+    """The grid steps of one head (``step_list``): step ``s`` works on
+    query block ``qi[s]`` and key block ``kj[s]``; bit 0 of ``edge[s]``
+    says that it opens its outer block (the first of its steps: the
+    accumulators start), bit 1 that it closes it (the last: the block is
+    written). ``rectangle`` is what a grid over every tile would walk."""
+
+    qi: np.ndarray
+    kj: np.ndarray
+    edge: np.ndarray
+    rectangle: int
+
+    @property
+    def live(self):
+        return len(self.qi)
+
+
+@functools.lru_cache(maxsize=None)
+def step_list(causal, geometry, nq, nk, block_q, block_k, key_major=False):
+    """The live tiles of a mask over ``nq`` x ``nk`` blocks, in the order a
+    rectangular grid meets them: query block by query block with the key
+    blocks innermost (the forward and the "dq" backward), or, ``key_major``,
+    key block by key block with the query blocks innermost ("fused",
+    "dkv"). Numpy, at trace time; the kernels read it scalar-prefetched.
+
+    There is one statement of a mask's geometry, ``_walk_tiles``: this
+    runs it with Python integers for the grid position, and a tile is live
+    where the walk runs a body. (The kernels run the same walk on the
+    entries they read, which then chooses the body alone.) The length and
+    key-padding masks choose among bodies and never between one and none,
+    so they are left out here. With neither ``causal`` nor a ``geometry``
+    the list is the whole rectangle.
+
+    Every outer block has a live step under every mask the kernels take
+    (a query sees itself), so every output block is opened, written and
+    closed once: asserted here. The three arrays lie in SMEM for the whole
+    call (1 MiB on the v5e): a causal call in 512 x 512 blocks compiles
+    for the chip to T 131,072 (32,896 steps) and is refused at 262,144."""
+    pairs = []
+    outer, inner = (nk, nq) if key_major else (nq, nk)
+    for o in range(outer):
+        for i in range(inner):
+            at = (i, o) if key_major else (o, i)
+            _walk_tiles(lambda *kind, at=at: lambda: pairs.append(at),
+                        causal, False, False, *at, block_q, block_k, 0,
+                        geometry)
+    assert len(set(pairs)) == len(pairs), "a tile ran two bodies"
+    qi, kj = (np.asarray(x, np.int32) for x in zip(*pairs))
+    of = kj if key_major else qi
+    assert np.array_equal(np.unique(of), np.arange(outer)), \
+        "an outer block with no live step would never be written"
+    turn = np.flatnonzero(np.diff(of)) + 1
+    edge = np.zeros(len(of), np.int32)
+    edge[np.r_[0, turn]] |= 1
+    edge[np.r_[turn - 1, len(of) - 1]] |= 2
+    for array in (qi, kj, edge):            # kept: nobody writes into them
+        array.flags.writeable = False
+    return Steps(qi, kj, edge, nq * nk)
+
+
+def _spec(shape, at, lanes=False, heads=1):
+    """The BlockSpec of a ``shape`` block at the step's query block (``at``
+    "q") or key block ("k"), read from the step list: along the rows of a
+    [BH, T, D] array or, ``lanes``, along the last dimension of a
+    [BH, rows, T] one. ``heads``: the array has one entry a batch element
+    (the key mask), shared by its heads."""
+    def index(b, s, qi, kj, edge):
+        lead = b if heads == 1 else b // heads
+        block = (qi if at == "q" else kj)[s]
+        return (lead, 0, block) if lanes else (lead, block, 0)
+    return pl.BlockSpec(shape, index)
+
+
+def _stepped_call(kernel, steps, operands, in_specs, out_specs, scratch,
+                  **kw):
+    """``kernel`` on the one grid of every flash kernel: a head after a
+    head (``operands[0]`` is [BH, T, D]), and within a head the live steps
+    of ``steps`` in its order, which the kernel and the index maps
+    (``_spec``) read scalar-prefetched."""
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(operands[0].shape[0], steps.live),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        **kw)(*map(jnp.asarray, steps[:3]), *operands)
+
+
+def _step(qi_ref, kj_ref, edge_ref):
+    """(query block, key block, opens, closes) of the grid step at hand."""
+    s = pl.program_id(1)
+    edge = edge_ref[s]
+    return qi_ref[s], kj_ref[s], (edge & 1) != 0, (edge & 2) != 0
+
+
 def _attn_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask,
-                 q_ref, k_ref, v_ref, *rest, geometry=None):
+                 qi_ref, kj_ref, edge_ref, q_ref, k_ref, v_ref, *rest,
+                 geometry=None):
     """Keys run down the sublanes and queries along the lanes: the score
     piece is k @ q^T, [sub_k, sub_q], so a query's running max and sum are
     one lane of a [1, block_q] row (a reduction over keys is elementwise
@@ -402,15 +511,13 @@ def _attn_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask,
     else:
         mask_ref = None
         o_ref, lse_ref, m_s, l_s, acc_s = rest
-    iq = pl.program_id(1)
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
+    iq, j, opens, closes = _step(qi_ref, kj_ref, edge_ref)
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     # a power-of-two scale (1/8 at head width 64) multiplies the query
     # exactly in any float dtype; any other stays on the float32 scores
     fold = math.frexp(scale)[0] == 0.5
 
-    @pl.when(j == 0)
+    @pl.when(opens)
     def _():
         m_s[:] = jnp.full_like(m_s, _NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
@@ -480,7 +587,7 @@ def _attn_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask,
     _walk_tiles(tile, causal, ragged, has_mask, iq, j, block_q, block_k,
                 t_true, geometry)
 
-    @pl.when(j == nk - 1)
+    @pl.when(closes)
     def _():
         l_safe = jnp.maximum(l_s[:], 1e-30)  # fully-masked padding rows
         o_ref[0] = (acc_s[:] / l_safe).T.astype(o_ref.dtype)
@@ -549,21 +656,15 @@ def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
     # blocks carry the head's own width (a block's last dimension may equal
     # the array's): no pad to the 128 lanes, no slice of the result
     qp, kp, vp = (_pad_to(x, t_pad, 1) for x in (q, k, v))
-    grid = (bh, t_pad // block_q, t_pad // block_k)
+    steps = step_list(causal, geometry, t_pad // block_q, t_pad // block_k,
+                      block_q, block_k)
     sub_q, sub_k = _sub_tile(block_q), _sub_tile(block_k)
     kernel = functools.partial(_attn_kernel, t, t_pad != t, causal, scale,
                                sub_q, sub_k, mask is not None)
     if geometry is not None:
         kernel = functools.partial(kernel, geometry=geometry)
-
-    def kv_block(i, j):
-        # a key block above the diagonal is skipped by the kernel; naming
-        # the last live block again keeps the pipeline from fetching it
-        if geometry is not None:
-            return _block_diffusion_key_block(geometry, block_q, i, j)
-        return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k) \
-            if causal else j
-
+    q_spec = _spec((1, block_q, d), "q")
+    k_spec = _spec((1, block_k, d), "k")
     scratch = [pltpu.VMEM((1, block_q), jnp.float32),
                pltpu.VMEM((1, block_q), jnp.float32),
                pltpu.VMEM((d, block_q), jnp.float32)]
@@ -573,37 +674,25 @@ def _run_fwd_local(q, k, v, mask, h, causal, scale, block_q, block_k,
     name, under = ("flash_attn_fwd", contextlib.nullcontext()) \
         if geometry is None else (
             "flash_attn_bd_fwd", jax.named_scope("flash_attn_fwd"))
+    operands, in_specs = [qp, kp, vp], [q_spec, k_spec, k_spec]
+    if mask is not None:
+        # mask rides in as [B, 8, t_pad] f32 — the 8-sublane broadcast
+        # satisfies the TPU (8, 128) tile rule like the lse output block
+        operands.append(jnp.broadcast_to(
+            _pad_to(mask.astype(jnp.float32), t_pad, 1)[:, None, :],
+            (bh // h, 8, t_pad)))
+        in_specs.append(_spec((1, 8, block_k), "k", lanes=True, heads=h))
     with under:
-        out, lse = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_k, d),
-                             lambda b, i, j: (b, kv_block(i, j), 0)),
-                pl.BlockSpec((1, block_k, d),
-                             lambda b, i, j: (b, kv_block(i, j), 0)),
-            ] + ([
-                # mask rides in as [B, 8, t_pad] f32 — the 8-sublane
-                # broadcast satisfies the TPU (8, 128) tile rule like the
-                # lse output block
-                pl.BlockSpec((1, 8, block_k),
-                             lambda b, i, j: (b // h, 0, kv_block(i, j))),
-            ] if mask is not None else []),
-            out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-            ],
+        out, lse = _stepped_call(
+            kernel, steps, operands, in_specs,
+            out_specs=[q_spec, _spec((1, 8, block_q), "q", lanes=True)],
+            scratch=scratch,
             out_shape=[
                 jax.ShapeDtypeStruct((bh, t_pad, d), out_dtype),
                 jax.ShapeDtypeStruct((bh, 8, t_pad), jnp.float32),
             ],
-            scratch_shapes=scratch,
             interpret=interpret,
-            name=name,
-        )(qp, kp, vp, *(() if mask is None else (
-            jnp.broadcast_to(_pad_to(mask.astype(jnp.float32), t_pad, 1)
-                             [:, None, :], (bh // h, 8, t_pad)),)))
+            name=name)
     return out[:, :t], lse[:, 0, :t]
 
 
@@ -647,7 +736,8 @@ def _attention_fwd(q, k, v, mask, causal, scale, block_q, block_k,
 
 
 def _bwd_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask, form,
-                q_ref, k_ref, v_ref, g_ref, st_ref, *rest, geometry=None):
+                qi_ref, kj_ref, edge_ref, q_ref, k_ref, v_ref, g_ref, st_ref,
+                *rest, geometry=None):
     """The backward in the forward's orientation: a piece's probabilities
     are recomputed as p^T = exp(k q^T * scale - lse), [sub_k, sub_q], from
     the residuals, so ``lse`` and ``delta`` (``st_ref`` rows 0 and 1) are
@@ -667,26 +757,27 @@ def _bwd_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask, form,
         dq_ref, dq_s = outs.pop(0), scratch.pop(0)
     if with_dkv:
         (dk_ref, dv_ref), (dk_s, dv_s) = outs, scratch
-    if form == "dq":
-        iq, j = pl.program_id(1), pl.program_id(2)
-    else:
-        j, iq = pl.program_id(1), pl.program_id(2)
-    # the innermost axis' last step closes the block the outer axis names
-    last = pl.program_id(2) == pl.num_programs(2) - 1
+    # the list's outer block (``step_list``) is the key block, or the
+    # query block in the "dq" form: ``opens`` and ``closes`` are its
+    iq, j, opens, closes = _step(qi_ref, kj_ref, edge_ref)
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     fold = math.frexp(scale)[0] == 0.5       # as the forward's
 
     if with_dkv:
-        @pl.when(iq == 0)
+        @pl.when(opens)
         def _():
             dk_s[:] = jnp.zeros_like(dk_s)
             dv_s[:] = jnp.zeros_like(dv_s)
     if with_dq:
-        # "fused": [nq, D, block_q], one slab a query block, zeroed as the
-        # head opens; "dq": [1, D, block_q], zeroed as the query block opens
+        # "fused": [nq, D, block_q], one slab a query block, zeroed at the
+        # head's first step and written at its last; "dq": [1, D, block_q],
+        # zeroed as the query block opens and written as it closes
         dq_i = iq if form == "fused" else 0
+        dq_opens, dq_closes = (opens, closes) if form == "dq" else (
+            pl.program_id(1) == 0,
+            pl.program_id(1) == pl.num_programs(1) - 1)
 
-        @pl.when(_all(j == 0, True if form == "dq" else iq == 0))
+        @pl.when(dq_opens)
         def _():
             dq_s[:] = jnp.zeros_like(dq_s)
 
@@ -758,14 +849,13 @@ def _bwd_kernel(t_true, ragged, causal, scale, sub_q, sub_k, has_mask, form,
     # rows meet lanes once, as a block closes. dk took the scale with q
     # where it folds; dq always owes it
     if with_dkv:
-        @pl.when(last)
+        @pl.when(closes)
         def _():
             dk = dk_s[:] if fold else dk_s[:] * scale
             dk_ref[0] = dk.T.astype(dk_ref.dtype)
             dv_ref[0] = dv_s[:].T.astype(dv_ref.dtype)
     if with_dq:
-        @pl.when(_all(last, True if form == "dq"
-                      else j == pl.num_programs(1) - 1))
+        @pl.when(dq_closes)
         def _():
             for i in range(dq_s.shape[0]):
                 dq_ref[0, i * block_q:(i + 1) * block_q, :] = (
@@ -897,58 +987,31 @@ def _run_bwd_local(q, k, v, out, lse, g, g_lse, mask, h, causal, scale,
             _sub_tile(block_k), mask is not None, form)
         if geometry is not None:
             kernel = functools.partial(kernel, geometry=geometry)
-        # a step above the diagonal names the nearest live block again, so
-        # nothing is fetched for it
-        if form == "dq":
-            grid = (bh, nq, nk)
-
-            def q_at(b, i, j):
-                return i
-
-            def k_at(b, i, j):
-                if geometry is not None:
-                    return _block_diffusion_key_block(geometry, block_q, i, j)
-                return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k) \
-                    if causal else j
-        else:
-            grid = (bh, nk, nq)
-
-            def q_at(b, j, i):
-                if geometry is not None:
-                    return _block_diffusion_query_block(geometry, block_q,
-                                                        j, i)
-                return jnp.maximum(i, (j * block_k) // block_q) \
-                    if causal else i
-
-            def k_at(b, j, i):
-                return j
-        q_spec = pl.BlockSpec((1, block_q, d),
-                              lambda *ix: (ix[0], q_at(*ix), 0))
-        k_spec = pl.BlockSpec((1, block_k, d),
-                              lambda *ix: (ix[0], k_at(*ix), 0))
+        q_spec = _spec((1, block_q, d), "q")
+        k_spec = _spec((1, block_k, d), "k")
         in_specs = [q_spec, k_spec, k_spec, q_spec,
-                    pl.BlockSpec((1, 2, block_q),
-                                 lambda *ix: (ix[0], 0, q_at(*ix)))]
+                    _spec((1, 2, block_q), "q", lanes=True)]
         if mask is not None:
-            in_specs.append(pl.BlockSpec(
-                (1, 8, block_k), lambda *ix: (ix[0] // h, 0, k_at(*ix))))
+            in_specs.append(_spec((1, 8, block_k), "k", lanes=True, heads=h))
         dkv_scratch = [pltpu.VMEM((d, block_k), f32)] * 2
         if form == "fused":
             out_specs = [pl.BlockSpec((1, t_pad, d),
-                                      lambda *ix: (ix[0], 0, 0)),
+                                      lambda b, *_: (b, 0, 0)),
                          k_spec, k_spec]
             scratch = [pltpu.VMEM((nq, d, block_q), f32)] + dkv_scratch
         elif form == "dkv":
             out_specs, scratch = [k_spec, k_spec], dkv_scratch
         else:
             out_specs, scratch = [q_spec], [pltpu.VMEM((1, d, block_q), f32)]
-        return pl.pallas_call(
-            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        return _stepped_call(
+            kernel, step_list(causal, geometry, nq, nk, block_q, block_k,
+                              key_major=form != "dq"),
+            operands, in_specs, out_specs, scratch,
             out_shape=[jax.ShapeDtypeStruct((bh, t_pad, d), g.dtype)]
-            * len(out_specs), scratch_shapes=scratch, interpret=interpret,
+            * len(out_specs), interpret=interpret,
             name=("flash_attn_bwd_" if geometry is None
                   else "flash_attn_bd_bwd_") + form,
-            compiler_params=asked)(*operands)
+            compiler_params=asked)
 
     if form == "fused":
         dq, dk, dv = call("fused")

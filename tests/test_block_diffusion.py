@@ -68,6 +68,40 @@ def test_the_split_backward_agrees_with_the_fused_one():
     assert float(jnp.abs(fused[0]).max()) > 0.1
 
 
+@pytest.mark.parametrize("form", ["fused", "split"])
+@pytest.mark.parametrize("t,block_len,block", [(256, 4, 128), (512, 32, 256),
+                                               (128, 8, 128)])
+def test_every_backward_form_agrees_with_the_dense_mask(t, block_len, block,
+                                                        form):
+    """ISSUE 53: the three backward kernels on the step list, key-major
+    ("fused", "dkv": a noised key block's ONE live step opens and closes
+    it) and query-major ("dq"), with a cotangent on the log-sum-exp too
+    (`flash_attention_block`'s path), against autodiff of the dense-mask
+    softmax. At (128, 8, 128) each copy is one tile: three steps a head."""
+    g = ap.BlockDiffusion(t, block_len)
+    q, k, v, ct = (ap._fold_heads(x) for x in _qkv(2 * t, 2, 128))
+    ct_lse = jax.random.normal(jax.random.PRNGKey(9), (2, 2 * t), q.dtype)
+    scale = 128 ** -0.5
+
+    def dense(q, k, v):
+        s = jnp.where(g.dense()[None], jnp.einsum("bqd,bkd->bqk", q, k)
+                      * scale, -jnp.inf)
+        return (jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, -1), v),
+                jax.nn.logsumexp(s, -1))
+    (out, lse), pullback = jax.vjp(dense, q, k, v)
+    got_out, got_lse = ap._run_fwd(q, k, v, None, 2, False, scale, block,
+                                   block, True, geometry=g)
+    np.testing.assert_allclose(np.asarray(got_out), np.asarray(out),
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got_lse), np.asarray(lse),
+                               atol=2e-5)
+    got = ap._run_bwd_local(q, k, v, got_out, got_lse, ct, ct_lse, None, 2,
+                            False, scale, block, block, True, form, g)
+    for a, b, name in zip(got, pullback((ct, ct_lse)), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   err_msg=name)
+
+
 def _walk(monkeypatch, geometry, block):
     """{(iq, j): the tile's kind} of the steps that issue a product, by
     running the kernels' own walk with Python integers for the grid."""
@@ -130,27 +164,33 @@ def test_no_dead_tile_is_visited_at_the_cells_length(monkeypatch):
     (T^2 + 2 T x 128) / 128^2 grid steps a head issue a product, of the
     (2T / 128)^2 the grid has."""
     t = 4096
+    steps = ap.step_list(False, ap.BlockDiffusion(t, 4), 64, 64, 128, 128)
     ran = _walk(monkeypatch, ap.BlockDiffusion(t, 4), 128)
     assert len(ran) == (t * t + 2 * t * 128) // 128 ** 2 == 1088
     assert (2 * t // 128) ** 2 == 4096
+    # the grid a head walks is the list: 1,088 steps, not 4,096
+    assert (steps.live, steps.rectangle) == (1088, 4096)
 
 
 @pytest.mark.parametrize("t,block", [(512, 128), (1024, 512)])
-def test_a_dead_step_names_a_live_block(monkeypatch, t, block):
-    """The index maps: a live step fetches its own block; a dead one
-    names a block some live step of the same row (or column) fetches, so
-    the pipeline brings nothing in for it."""
+def test_the_list_holds_the_live_steps_and_no_other(monkeypatch, t, block):
+    """The grid is the step list (ISSUE 53: the index maps that named a
+    live block again for a dead step went with the dead steps): in both
+    orders it holds the tiles the walk runs, each once, an outer block's
+    steps together, the first of them opening it and the last closing."""
     g = ap.BlockDiffusion(t, 4)
-    ran = _walk(monkeypatch, g, block)
     n = 2 * t // block
-    for i in range(n):
-        keys = {j for (iq, j) in ran if iq == i}
-        queries = {iq for (iq, j) in ran if j == i}
-        for s in range(n):
-            kb = int(ap._block_diffusion_key_block(g, block, i, s))
-            qb = int(ap._block_diffusion_query_block(g, block, i, s))
-            assert kb == s if s in keys else kb in keys, (i, s, kb)
-            assert qb == s if s in queries else qb in queries, (i, s, qb)
+    lists = [ap.step_list(False, g, n, n, block, block, key_major=km)
+             for km in (False, True)]
+    ran = _walk(monkeypatch, g, block)
+    for steps, outer in zip(lists, (0, 1)):
+        pairs = list(zip(steps.qi.tolist(), steps.kj.tolist()))
+        assert sorted(pairs) == sorted(ran) and steps.rectangle == n * n
+        assert pairs == sorted(pairs, key=lambda p: (p[outer], p[1 - outer]))
+        of = [p[outer] for p in pairs]
+        for s, edge in enumerate(steps.edge.tolist()):
+            assert bool(edge & 1) == (s == 0 or of[s - 1] != of[s])
+            assert bool(edge & 2) == (s == len(of) - 1 or of[s + 1] != of[s])
 
 
 def test_a_geometry_that_does_not_fit_is_refused_or_left_to_xla(
@@ -180,16 +220,18 @@ def test_a_geometry_that_does_not_fit_is_refused_or_left_to_xla(
                                     ap.BlockDiffusion(1024, 4)) is None
 
 
-#: sha256 of `str(make_jaxpr(grad(causal flash_attention)))` at two shapes,
-#: taken on the parent commit of PR 49 (8c8f25e) and equal on PR 49: the
-#: causal call's kernels, forward and fused backward, as they were before
-#: the kernels learned a geometry. A PR that edits the causal kernels on
+#: sha256 of `str(make_jaxpr(grad(causal flash_attention)))` at two shapes:
+#: the causal call's kernels, forward and fused backward. Taken anew in PR
+#: 53, which edited them on purpose (the grid is the step list: three
+#: scalar-prefetched operands, the opening and closing conditions read
+#: from it; the hashes of PR 49, equal from 8c8f25e to PR 52, were
+#: 09631d0f... and 7c12fea2...). A PR that edits the causal kernels on
 #: purpose takes the hashes anew and says so.
 CAUSAL_JAXPRS = {
     (512, 64, 256):
-        "09631d0fee43744b070be3f120c4d72b996ec2bab851843ad4bb2ae55888975c",
+        "2de49a0b91bb7ee2ff149db031ac33aab28c39d9f2231a200d80d48c87c9cb43",
     (300, 128, 128):
-        "7c12fea21ac5e3ed09419859904a332178d425c8063518c723cde8b4e600d1b0"}
+        "569143fdc77f1c4cbefc650c73e1ef1ba9e2634a8bc856066cb5830a9653045b"}
 
 
 @pytest.mark.parametrize("shape", sorted(CAUSAL_JAXPRS))

@@ -73,6 +73,19 @@ def test_kernels_phase_in_interpret_mode(kernel_dispatch):
             "lstm_resident_peephole_masked", "lstm_tiled_masked"} == names
 
 
+@pytest.mark.parametrize("kw,live,rectangle", [
+    (dict(bh=2, t=512, d=128, block_diffusion=(256, 4)), 8, 16),
+    (dict(bh=2, t=300, d=64), 6, 9)], ids=["block_diffusion", "causal"])
+def test_flash_steps_time_at_toy_size(kw, live, rectangle):
+    """The step that reads a dead turn's cost again on the chip: here its
+    plumbing (both kernels alone on folded operands, the counts beside the
+    two times, which on the CPU are the interpreter's and mean nothing)."""
+    r = smoke._flash_steps_time("toy", block=128, interpret=True, iters=1,
+                                **kw)
+    assert (r["live"], r["rectangle"]) == (live, rectangle)
+    assert r["fwd_ms"] > 0 and r["bwd_ms"] > 0
+
+
 def test_looped_block_case_at_toy_size():
     """Off the chip both sides take the naive branch: the case's own
     plumbing (the block's fields, shapes, the comparison) is what runs."""

@@ -664,7 +664,14 @@ class TestFlashKernelGeometry:
         # compared on every piece) at width 64
         for d, bq in ((64, 256), (128, 256), (80, 256), (64, 128))
         for causal in (False, True) for masked in (False, True)
-        for t in (512, 300)])      # whole blocks; a ragged tail
+        for t in (512, 300)] + [   # whole blocks; a ragged tail
+        # the step list's own cases (ISSUE 53): one step that opens and
+        # closes its query block and is the head's whole grid; unequal
+        # blocks over a padded T, so that a query block's last live key
+        # block is not the rectangle's last
+        (64, True, False, 128, 128, 128), (64, True, True, 100, 128, 128),
+        (64, True, False, 700, 256, 128), (64, True, True, 700, 128, 256),
+        (128, False, True, 700, 256, 128)])
     def test_forward_and_lse_match_naive(self, d, causal, masked, t,
                                          block_q, block_k):
         q, k, v, mask = self._inputs(t, d, masked, causal, seed=d + t)
@@ -807,7 +814,20 @@ class TestFlashBackwardKernel:
         (256, True, masked, t, 256, 256, form)
         for masked, form in ((False, "fused"), (True, "fused"),
                              (False, "split"))
-        for t in (512, 300)])
+        for t in (512, 300)] + [
+        # the step list's own cases (ISSUE 53). One tile a head: its one
+        # step opens and closes the key block (dkv), the query block (dq)
+        # and the head's dq (fused) at once
+        (64, True, False, 128, 128, 128, "fused"),
+        (64, True, True, 100, 128, 128, "split"),
+        # unequal blocks over a padded T (768): under causal the first
+        # query block's only key block, and a key block's first live query
+        # block, are not the rectangle's first or last
+        (64, True, False, 700, 256, 128, "fused"),
+        (64, True, True, 700, 256, 128, "split"),
+        (64, True, True, 700, 128, 256, "fused"),
+        (64, True, False, 700, 128, 256, "split"),
+        (128, False, True, 700, 256, 128, "fused")])
     def test_gradients_match_naive(self, d, causal, masked, t, block_q,
                                    block_k, form):
         args, want, empty = self._case(d, causal, masked, t, seed=d + t)
@@ -1195,6 +1215,14 @@ def _lower_for_tpu(fn, *args, **jit_kw):
             lowering_platforms=("tpu",))
 
 
+def _after_the_step_list(operands, live):
+    """A flash kernel's operands behind the three ``int32[live]`` arrays of
+    its step list (``attention_pallas.step_list``), which lead them."""
+    lists = ", ".join([f"tensor<{live}xi32>"] * 3) + ", "
+    assert operands.startswith(lists), operands
+    return operands[len(lists):]
+
+
 def _lstm_loss(peephole, masked):
     def loss(xz, wh, h0, c0, wp, mask):
         hs, (_, cT) = lstm_pallas.fused_sequence_padded(
@@ -1259,7 +1287,10 @@ class TestDefaultDispatchKernelsLowerForTpu:
         call = next(ln for ln in text.splitlines()
                     if 'kernel_name = "flash_attn_fwd"' in ln)
         t_pad = -(-t // 512) * 512
-        operands = call.split(" : (")[1].split(") -> ")[0]
+        operands = _after_the_step_list(
+            call.split(" : (")[1].split(") -> ")[0],
+            attention_pallas.step_list(causal, None, t_pad // 512,
+                                       t_pad // 512, 512, 512).live)
         assert operands.startswith(
             ", ".join([f"tensor<{b * h}x{t_pad}x{d}xbf16>"] * 3)), operands
 
@@ -1299,6 +1330,9 @@ class TestDefaultDispatchKernelsLowerForTpu:
             call = next(ln for ln in text.splitlines()
                         if f'kernel_name = "flash_attn_bwd_{k}"' in ln)
             operands, results = call.split(" : (")[1].split(") -> ")
+            operands = _after_the_step_list(
+                operands, attention_pallas.step_list(
+                    True, None, t // 512, t // 512, 512, 512).live)
             assert operands.startswith(
                 ", ".join([f"tensor<{b * h}x{t}x{d}xbf16>"] * 4)), operands
             assert "bf16" not in results and f"x{d}xf32>" in results, results
@@ -1338,7 +1372,10 @@ class TestDefaultDispatchKernelsLowerForTpu:
         for ln in text.splitlines():
             m = re.search(r'kernel_name = "(flash_attn_[a-z_]+)"', ln)
             if m:
-                calls[m.group(1)] = ln.split(" : (")[1].split(") -> ")
+                operands, results = ln.split(" : (")[1].split(") -> ")
+                # T 1,024 causal in 512 x 512 blocks: 3 live steps of 4
+                calls[m.group(1)] = (_after_the_step_list(operands, 3),
+                                     results)
         assert sorted(calls) == ["flash_attn_bwd_fused", "flash_attn_fwd"]
         operands, results = calls["flash_attn_fwd"]
         assert operands == ", ".join([head + "bf16>"] * 3), operands
@@ -1373,11 +1410,15 @@ class TestDefaultDispatchKernelsLowerForTpu:
                  if e.primitive.name == "pallas_call"}
         assert sorted(calls) == ["flash_attn_bwd_fused", "flash_attn_fwd"]
         fwd, bwd = calls["flash_attn_fwd"], calls["flash_attn_bwd_fused"]
-        assert [str(a.aval.dtype) for a in fwd.invars] == [operand] * 3
-        assert [str(a.aval.dtype) for a in bwd.invars[:4]] == [operand] * 4
+        # the step list's three int32 arrays lead every call's operands
+        for call in (fwd, bwd):
+            assert [str(a.aval.dtype) for a in call.invars[:3]] \
+                == ["int32"] * 3
+        assert [str(a.aval.dtype) for a in fwd.invars[3:]] == [operand] * 3
+        assert [str(a.aval.dtype) for a in bwd.invars[3:7]] == [operand] * 4
         # the same arrays, not a second copy: the backward's q, k, v are
         # the forward's operands
-        assert bwd.invars[:3] == fwd.invars
+        assert bwd.invars[3:6] == fwd.invars[3:]
         assert str(fwd.outvars[0].aval.dtype) == dtype
         assert [str(a.aval.dtype) for a in bwd.outvars] == [dtype] * 3
 
@@ -1462,6 +1503,7 @@ class TestDefaultDispatchKernelsLowerForTpu:
         call = next(ln for ln in text.splitlines()
                     if 'kernel_name = "flash_attn_bwd_fused"' in ln)
         operands, results = call.split(" : (")[1].split(") -> ")
+        operands = _after_the_step_list(operands, 3)
         # one batch element of four heads a device; its [1, 8, T] mask
         assert operands.startswith(
             ", ".join(["tensor<4x1024x64xbf16>"] * 4)), operands

@@ -48,25 +48,27 @@ def no_compile_cache():
     jcc.reset_cache()
 
 
-def _compiled_backward(one_chip, bh, t, d, dtype, form=None):
-    """The compiled text of one causal backward for a caller in ``dtype``
-    at the dispatch's blocks, in the form ``_run_bwd`` chooses or, with
-    ``form``, in that one. The residuals q, k, v are what the forward
-    kernel read (``_operand_dtype``); out and g are the caller's."""
+def _compiled_backward(one_chip, bh, t, d, dtype, form=None, geometry=None):
+    """The compiled text of one backward (causal, or under ``geometry``)
+    for a caller in ``dtype`` at the dispatch's blocks, in the form
+    ``_run_bwd`` chooses or, with ``form``, in that one. The residuals q,
+    k, v are what the forward kernel read (``_operand_dtype``); out and g
+    are the caller's."""
     r = jax.ShapeDtypeStruct(
         (bh, t, d), attention_pallas._operand_dtype(dtype, False),
         sharding=one_chip)
     x = jax.ShapeDtypeStruct((bh, t, d), dtype, sharding=one_chip)
     lse = jax.ShapeDtypeStruct((bh, t), jnp.float32, sharding=one_chip)
+    causal = geometry is None
 
     def bwd(q, k, v, out, lse, g):
         if form is None:
             return attention_pallas._run_bwd(
-                (q, k, v, None, out, lse), g, None, 1, True, d ** -0.5, 512,
-                512, False)
+                (q, k, v, None, out, lse), g, None, 1, causal, d ** -0.5,
+                512, 512, False, geometry)
         return attention_pallas._run_bwd_local(
-            q, k, v, out, lse, g, None, None, 1, True, d ** -0.5, 512, 512,
-            False, form)
+            q, k, v, out, lse, g, None, None, 1, causal, d ** -0.5, 512, 512,
+            False, form, geometry)
     # the chip runs with 32-bit defaults; conftest's float64 mode would put
     # f64 constants into the kernel body, which Mosaic refuses to cast
     with jax.enable_x64(False):
@@ -92,6 +94,38 @@ def test_the_chosen_backward_compiles_for_the_chip(
     assert text.count("tpu_custom_call") >= len(kernels)
     for form in ("fused", "dkv", "dq"):
         assert ("flash_attn_bwd_" + form in text) == (form in kernels), form
+
+
+@pytest.mark.parametrize("bh,t,d,geometry,live", [
+    # sdar-train-bd4-t4096's call: 80 of a head's 256 tiles
+    (32, 8192, 128, attention_pallas.BlockDiffusion(4096, 4), 80),
+    # glm47flash's: the backward asks Mosaic for VMEM beside the list
+    (20, 4096, 256, None, 36),
+    (32, 8192, 64, None, 136),                    # lfm2-train-t8192's
+], ids=["sdar", "glm47flash", "lfm2"])
+def test_both_kernels_compile_under_the_step_list(
+        one_chip, no_compile_cache, bh, t, d, geometry, live):
+    """ISSUE 53: the forward and the fused backward on a grid of (heads,
+    live steps), the list scalar-prefetched, through Mosaic and XLA:TPU at
+    the cells' shapes."""
+    r = jax.ShapeDtypeStruct((bh, t, d), jnp.bfloat16, sharding=one_chip)
+    steps = attention_pallas.step_list(
+        geometry is None, geometry, t // 512, t // 512, 512, 512)
+    assert (steps.live, steps.rectangle) == (live, (t // 512) ** 2)
+
+    def fwd(q, k, v):
+        return attention_pallas._run_fwd(
+            q, k, v, None, 1, geometry is None, d ** -0.5, 512, 512, False,
+            jnp.float32, geometry)
+    with jax.enable_x64(False):
+        text = jax.jit(fwd).lower(r, r, r).compile().as_text()
+    name = "flash_attn_fwd" if geometry is None else "flash_attn_bd_fwd"
+    assert "tpu_custom_call" in text and name in text
+    text = _compiled_backward(one_chip, bh, t, d, jnp.float32,
+                              geometry=geometry)
+    assert ("flash_attn_bwd_fused" if geometry is None
+            else "flash_attn_bd_bwd_fused") in text
+    assert "tpu_custom_call" in text
 
 
 def test_mosaic_refuses_the_width_256_backward_at_its_default(
