@@ -653,9 +653,9 @@ class StepDriver:
                 self._hm.flush(apply_policy=apply_policy)
             if self.instrumented and self._reg.enabled:
                 # the routed-experts layers' counts of the last step, and
-                # the terms a head keeps apart of its loss
-                _tm.note_routing(self.net.state)
-                _tm.note_loss_terms(self.net.state)
+                # the terms a head keeps apart of its loss: one walk of
+                # the state and one fetch for both
+                _tm.note_step_state(self.net.state)
 
     def checkpoint(self, path, *, buckets=None, save_updater=True):
         """``sync()`` then write one resumable ``save_bundle`` unit —

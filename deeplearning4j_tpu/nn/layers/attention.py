@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn import initializers as _init
+from deeplearning4j_tpu.nn import scopes as _scopes
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
 from deeplearning4j_tpu.nn.layers.base import ParamLayer
 from deeplearning4j_tpu.nn.layers.core import matmul
@@ -237,18 +238,21 @@ class MultiHeadAttention(ParamLayer):
         h, d = self.n_heads, self._head_dim()
         kv = self._grouped()
         gate = None
+        x2 = x.reshape(b * t, -1)
         if kv is None:
-            qkv = matmul(x.reshape(b * t, -1), params["Wqkv"])
-            if self.bias:
-                qkv = qkv + params["bqkv"]
+            with jax.named_scope(_scopes.MIX_IN):
+                qkv = matmul(x2, params["Wqkv"])
+                if self.bias:
+                    qkv = qkv + params["bqkv"]
             qkv = qkv.reshape(b, t, 3, h, d)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         else:
-            x2 = x.reshape(b * t, -1)
-            q = matmul(x2, params["Wq"]).reshape(b, t, h, -1)
+            with jax.named_scope(_scopes.MIX_IN):
+                q = matmul(x2, params["Wq"]).reshape(b, t, h, -1)
             if self.gate:
                 q, gate = q[..., :d], q[..., d:]
-            k_v = matmul(x2, params["Wkv"]).reshape(b, t, 2, kv, d)
+            with jax.named_scope(_scopes.MIX_IN):
+                k_v = matmul(x2, params["Wkv"]).reshape(b, t, 2, kv, d)
             k, v = k_v[:, :, 0], k_v[:, :, 1]
         if self.qk_norm:
             norm = RMSNorm(eps=self.qk_norm_eps,
@@ -262,28 +266,31 @@ class MultiHeadAttention(ParamLayer):
         if kv is not None and kv != h:
             # each key/value head serves its group of query heads; autodiff
             # sums the group's gradients back onto the one head
-            k = jnp.repeat(k, h // kv, axis=2)
-            v = jnp.repeat(v, h // kv, axis=2)
+            with jax.named_scope(_scopes.KV_REPEAT):
+                k = jnp.repeat(k, h // kv, axis=2)
+                v = jnp.repeat(v, h // kv, axis=2)
         return q, k, v, gate
 
     def out_proj(self, params, attn):
         b, t, h, d = attn.shape
-        y = matmul(attn.reshape(b * t, h * d), params["Wo"])
-        if self.bias:
-            y = y + params["bo"]
+        with jax.named_scope(_scopes.MIX_OUT):
+            y = matmul(attn.reshape(b * t, h * d), params["Wo"])
+            if self.bias:
+                y = y + params["bo"]
         return y.reshape(b, t, self.n_out)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        geometry = self._geometry(x.shape[1], train)
-        q, k, v, gate = self.heads(params, x, geometry)
-        attn = dot_product_attention(
-            q, k, v, mask=mask, causal=self.causal and geometry is None,
-            geometry=geometry)
-        if gate is not None:
-            with jax.named_scope("attn_gate"):
-                attn = attn * jax.nn.sigmoid(gate).astype(attn.dtype)
-        y = self.out_proj(params, attn)
-        if mask is not None:
-            y = y * mask[..., None].astype(y.dtype)
-        return y, state
+        with jax.named_scope(_scopes.MHA):
+            geometry = self._geometry(x.shape[1], train)
+            q, k, v, gate = self.heads(params, x, geometry)
+            attn = dot_product_attention(
+                q, k, v, mask=mask, causal=self.causal and geometry is None,
+                geometry=geometry)
+            if gate is not None:
+                with jax.named_scope("attn_gate"):
+                    attn = attn * jax.nn.sigmoid(gate).astype(attn.dtype)
+            y = self.out_proj(params, attn)
+            if mask is not None:
+                y = y * mask[..., None].astype(y.dtype)
+            return y, state
 

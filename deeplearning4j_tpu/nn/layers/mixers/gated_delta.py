@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn import initializers as _init
+from deeplearning4j_tpu.nn import scopes as _scopes
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
 from deeplearning4j_tpu.nn.layers.base import ParamLayer
 from deeplearning4j_tpu.nn.layers.core import matmul
@@ -99,8 +100,10 @@ class GatedDeltaNet(ParamLayer):
             hk, hv = self.k_heads, self.v_heads
             _, ad = _dtypes.compute_dtypes_for(x.dtype)
             x2 = x.reshape(b * t, -1)
-            qkvz = matmul(x2, params["W_qkvz"]).reshape(b, t, -1)
-            ba = matmul(x2, params["W_ba"]).reshape(b, t, 2, hv).astype(ad)
+            with jax.named_scope(_scopes.MIX_IN):
+                qkvz = matmul(x2, params["W_qkvz"]).reshape(b, t, -1)
+                ba = matmul(x2, params["W_ba"]).reshape(b, t, 2,
+                                                        hv).astype(ad)
             with jax.named_scope("gdn_conv"):
                 (q, k, v), z = causal_conv(qkvz, params["conv_w"],
                                            activation=True,
@@ -123,7 +126,8 @@ class GatedDeltaNet(ParamLayer):
             o, _ = RMSNorm(eps=self.norm_eps).apply(
                 {"gamma": params["norm_w"]}, {}, o)
             o = o * jax.nn.silu(z.reshape(o.shape))
-            y = matmul(o.reshape(b * t, vw), params["W_out"])
+            with jax.named_scope(_scopes.MIX_OUT):
+                y = matmul(o.reshape(b * t, vw), params["W_out"])
             y = y.reshape(b, t, self.n_out)
             if mask is not None:
                 y = y * mask[..., None].astype(y.dtype)

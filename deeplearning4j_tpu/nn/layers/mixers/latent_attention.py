@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn import initializers as _init
+from deeplearning4j_tpu.nn import scopes as _scopes
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
 from deeplearning4j_tpu.nn.layers.attention import (dot_product_attention,
                                                     rope)
@@ -96,21 +97,29 @@ class LatentAttention(ParamLayer):
         h, dn, dr = self.n_heads, self.nope_dim, self.rope_dim
         norm = RMSNorm(eps=self.norm_eps)
         x2 = x.reshape(b * t, -1)
-        c_q, _ = norm.apply({"gamma": params["q_gamma"]}, {},
-                            matmul(x2, params["W_qa"]))
-        q = matmul(c_q, params["W_qb"]).reshape(b, t, h, dn + dr)
-        kva = matmul(x2, params["W_kva"])
+        with jax.named_scope(_scopes.MIX_IN):
+            q_a = matmul(x2, params["W_qa"])
+        c_q, _ = norm.apply({"gamma": params["q_gamma"]}, {}, q_a)
+        with jax.named_scope(_scopes.MIX_IN):
+            q = matmul(c_q, params["W_qb"]).reshape(b, t, h, dn + dr)
+            kva = matmul(x2, params["W_kva"])
         c_kv, _ = norm.apply({"gamma": params["kv_gamma"]}, {},
                              kva[:, :self.kv_rank])
-        kv = matmul(c_kv, params["W_kvb"]).reshape(b, t, h, dn + self.v_dim)
+        with jax.named_scope(_scopes.MIX_IN):
+            kv = matmul(c_kv, params["W_kvb"]).reshape(b, t, h,
+                                                       dn + self.v_dim)
         # one rotary key a token: every head reads it, and autodiff sums
         # the heads' gradients back onto it
         kr = rope(kva[:, self.kv_rank:].reshape(b, t, 1, dr),
                   self.rope_theta)
-        q = jnp.concatenate([q[..., :dn],
-                             rope(q[..., dn:], self.rope_theta)], axis=-1)
-        k = jnp.concatenate([kv[..., :dn],
-                             jnp.broadcast_to(kr, (b, t, h, dr))], axis=-1)
+        with jax.named_scope(_scopes.HEAD_JOIN):
+            qn, qr = q[..., :dn], q[..., dn:]
+        qr = rope(qr, self.rope_theta)
+        with jax.named_scope(_scopes.HEAD_JOIN):
+            q = jnp.concatenate([qn, qr], axis=-1)
+            k = jnp.concatenate([kv[..., :dn],
+                                 jnp.broadcast_to(kr, (b, t, h, dr))],
+                                axis=-1)
         return q, k, kv[..., dn:]
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
@@ -119,7 +128,8 @@ class LatentAttention(ParamLayer):
             q, k, v = self.heads(params, x)
             attn = dot_product_attention(q, k, v, mask=mask,
                                          causal=self.causal)
-            y = matmul(attn.reshape(b * t, -1), params["Wo"])
+            with jax.named_scope(_scopes.MIX_OUT):
+                y = matmul(attn.reshape(b * t, -1), params["Wo"])
             y = y.reshape(b, t, self.n_out)
             if mask is not None:
                 y = y * mask[..., None].astype(y.dtype)

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn import initializers as _init
+from deeplearning4j_tpu.nn import scopes as _scopes
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
 from deeplearning4j_tpu.nn.layers.base import ParamLayer
 from deeplearning4j_tpu.nn.layers.core import matmul
@@ -124,9 +125,11 @@ class Mamba2Mixer(ParamLayer):
             _, ad = _dtypes.compute_dtypes_for(x.dtype)
             x2 = x.reshape(b * t, -1)
             w_in = params["W_in"]
-            z = matmul(x2, w_in[:, :inner]).reshape(b, t, inner)
-            xbc = matmul(x2, w_in[:, inner:inner + conv]).reshape(b, t, conv)
-            dt = matmul(x2, w_in[:, inner + conv:]).reshape(b, t, h)
+            with jax.named_scope(_scopes.MIX_IN):
+                z = matmul(x2, w_in[:, :inner]).reshape(b, t, inner)
+                xbc = matmul(x2, w_in[:, inner:inner + conv]).reshape(
+                    b, t, conv)
+                dt = matmul(x2, w_in[:, inner + conv:]).reshape(b, t, h)
             with jax.named_scope("ssm_conv"):
                 (xs, bs, cs), _ = causal_conv(
                     xbc, params["conv_w"], params["conv_b"], activation=True,
@@ -138,7 +141,8 @@ class Mamba2Mixer(ParamLayer):
                     params["D"], chunk=self.chunk)
             y = gated_group_norm(y.reshape(b, t, inner), z, params["norm_w"],
                                  g, self.norm_eps)
-            y = matmul(y.reshape(b * t, inner), params["W_out"])
+            with jax.named_scope(_scopes.MIX_OUT):
+                y = matmul(y.reshape(b * t, inner), params["W_out"])
             y = y.reshape(b, t, self.n_out)
             if mask is not None:
                 y = y * mask[..., None].astype(y.dtype)

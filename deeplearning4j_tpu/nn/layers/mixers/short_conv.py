@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn import initializers as _init
+from deeplearning4j_tpu.nn import scopes as _scopes
 from deeplearning4j_tpu.nn.conf import inputs as _inputs
 from deeplearning4j_tpu.nn.layers.base import ParamLayer
 from deeplearning4j_tpu.nn.layers.core import matmul
@@ -60,11 +61,13 @@ class ShortConv(ParamLayer):
         with jax.named_scope("short_conv"):
             b, t, _ = x.shape
             d = self.n_out
-            bcx = matmul(x.reshape(b * t, -1), params["W_in"])
+            with jax.named_scope(_scopes.MIX_IN):
+                bcx = matmul(x.reshape(b * t, -1), params["W_in"])
             gated, _ = causal_conv(bcx.reshape(b, t, 3 * d),
                                    params["conv_w"], gate_before=True,
                                    gate_after=True)
-            y = matmul(gated.reshape(b * t, d), params["W_out"])
+            with jax.named_scope(_scopes.MIX_OUT):
+                y = matmul(gated.reshape(b * t, d), params["W_out"])
             y = y.reshape(b, t, d)
             if mask is not None:
                 y = y * mask[..., None].astype(y.dtype)

@@ -49,7 +49,7 @@ class MultiTokenLMOutputLayer(ParamLayer):
     loss and gradients those of ``RMSNorm`` + ``RnnOutputLayer``. Both
     heads' [B T, n_out] logits are kept for the backward pass.
     The two terms of the last step's loss stay in the state under
-    ``loss_terms`` (``main``, ``mtp``) for ``telemetry.note_loss_terms``.
+    ``loss_terms`` (``main``, ``mtp``) for ``telemetry.note_step_state``.
     ``apply`` (inference) gives ``softmax(z)``."""
 
     n_out: int = 0

@@ -28,6 +28,31 @@ product and cross-entropy, and `mtp` around its module: the block's own
 `updater`, `health`, and
 `<kernel>.fwd` / `<kernel>.bwd` around the Pallas kernels. Anything
 outside `[A-Za-z0-9_.-]` in a name becomes `_`.
+
+**Inside every mixer the same four parts** (grammar `s2`; the names are
+the constants below and are spelled nowhere else in the program).
+Directly inside the mixer's own scope (`MHA` around all of
+`MultiHeadAttention.apply`; `mla`, `gdn`, `ssm`, `short_conv`):
+`MIX_IN`, every product that reads the mixer's input or a latent made
+from it, with its bias, before the core (`Wqkv` or `Wq` and `Wkv`;
+`W_qa`, `W_qb`, `W_kva`, `W_kvb`, the latent norms between them outside
+it; `W_qkvz` and `W_ba`; the three products from Mamba-2's `W_in`; the
+short convolution's `W_in`); the core under the scopes it had
+(`flash_attn.fwd` / `.bwd`, `gdn_conv`, `gdn_core`, `ssm_conv`,
+`ssd_core`, the `causal_conv_*` kernels of `short_conv`); `MIX_OUT`, the
+out-projection and its bias; `KV_REPEAT` around the repeat of grouped
+key/value heads to the query heads' number (autodiff's sum over a group
+lands under `transpose(` of the same scope) and `HEAD_JOIN` around latent
+attention's concatenations of a head's two parts with the shared rotary
+key's broadcast. What is left directly under the mixer's scope is its
+glue (norms, `rope`, gates, decays, relayouts, the mask's product) and is
+read by subtraction. A mixer added later names its products so, or
+`tests/test_mixer_scopes.py` fails. In the routed layer `MOE_WEIGHTS`,
+inside `moe_experts`, holds the work that touches the held experts'
+weights and no row: the float32 leaves rounded to the compute dtype and
+gate joined with up, in the forward rule, in the forward made again
+under `recompute_moe` and in the backward rule; the grouped kernels, the
+two activation kernels and the weight gradients stay outside it.
 """
 
 from __future__ import annotations
@@ -47,7 +72,17 @@ _UNSAFE = re.compile(r"[^A-Za-z0-9_.-]")
 #: key. Raise this when a scope is added, renamed or moved: every step
 #: then compiles anew once, and profiles read the names of the code that
 #: runs.
-GRAMMAR = "s1"
+GRAMMAR = "s2"
+
+#: The parts a mixer names inside its own scope, plain attention's own
+#: scope, and the routed experts' weights' traffic (the docstring has what
+#: each holds): `with jax.named_scope(scopes.MIX_IN):` at the call site.
+MHA = "mha"
+MIX_IN = "mix_in"
+MIX_OUT = "mix_out"
+KV_REPEAT = "kv_repeat"
+HEAD_JOIN = "head_join"
+MOE_WEIGHTS = "moe_weights"
 
 
 def safe(name):

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.nn import scopes as _scopes
 from deeplearning4j_tpu.ops import attention_pallas as _ap
 from deeplearning4j_tpu.ops.grouped_matmul import (
     grouped_matmul, input_gradient, weight_gradient)
@@ -158,15 +159,19 @@ def _act(kernel, name, sizes, operands, widths, act, gated):
                      gated=gated, interpret=not _ap.backend_is_tpu())
 
 
-def _weights_in(w_gate, w_up):
-    """The first product's weights [G, d, f] or, gated, ``Wg ‖ Wu``
-    [G, d, 2 f]: the leaves joined as they are, which the product rounds as
-    it rounds any weights (made in the forward and again in the backward
-    from the float32 leaves, as ``grouped_matmul`` rounds its weights in
-    both: a copy kept between them would be 100 MB a layer)."""
-    if w_gate is None:
-        return w_up
-    return jnp.concatenate([w_gate, w_up], axis=-1)
+def _read_at(dtype, w_up, w_gate=None):
+    """The weights as a grouped product reads them: the float32 leaves
+    rounded to ``dtype`` here, so that ``grouped_matmul``'s own rounding
+    finds nothing to do, and the first product's ``Wg ‖ Wu`` [G, d, 2 f]
+    joined before it. A pass over the held experts' weights and no row,
+    named apart from the kernel it feeds (``moe_weights_ms.*`` reads the
+    name), written in both rules from the leaves: XLA merges the two and
+    keeps the copy unless ``recompute_moe`` stands between, where a copy
+    kept by hand would be 100 MB a layer."""
+    with jax.named_scope(_scopes.MOE_WEIGHTS):
+        if w_gate is not None:
+            w_up = jnp.concatenate([w_gate, w_up], axis=-1)
+        return w_up.astype(dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -176,9 +181,10 @@ def _ffn(xs, w_gate, w_up, w_down, sizes, act, out_dtype, rows):
 
 def _ffn_fwd(xs, w_gate, w_up, w_down, sizes, act, out_dtype, rows):
     gated, f = w_gate is not None, w_down.shape[1]
-    pre = grouped_matmul(xs, _weights_in(w_gate, w_up), sizes, xs.dtype, rows)
+    pre = grouped_matmul(xs, _read_at(xs.dtype, w_up, w_gate), sizes,
+                         xs.dtype, rows)
     h, = _act(_fwd_kernel, "moe_act_fwd", sizes, [pre], (f,), act, gated)
-    ys = grouped_matmul(h, w_down, sizes, out_dtype, rows)
+    ys = grouped_matmul(h, _read_at(h.dtype, w_down), sizes, out_dtype, rows)
     return ys, (xs, pre, w_gate, w_up, w_down, sizes)
 
 
@@ -187,12 +193,13 @@ def _ffn_bwd(act, out_dtype, rows, res, dys):
     ``h`` made again between the two halves, then for the joined one."""
     xs, pre, w_gate, w_up, w_down, sizes = res
     gated, f = w_gate is not None, w_down.shape[1]
-    dh = input_gradient(dys, w_down, sizes, xs.dtype, rows)
+    dh = input_gradient(dys, _read_at(xs.dtype, w_down), sizes, xs.dtype,
+                        rows)
     dpre, h = _act(_bwd_kernel, "moe_act_bwd", sizes, [dh, pre],
                    (pre.shape[1], f), act, gated)
     dw_down = weight_gradient(h, dys, sizes, w_down.dtype, rows)
-    dxs = input_gradient(dpre, _weights_in(w_gate, w_up), sizes, xs.dtype,
-                         rows)
+    dxs = input_gradient(dpre, _read_at(xs.dtype, w_up, w_gate), sizes,
+                         xs.dtype, rows)
     dw_in = weight_gradient(xs, dpre, sizes, w_up.dtype, rows)
     if not gated:
         return dxs, None, dw_in, dw_down, None

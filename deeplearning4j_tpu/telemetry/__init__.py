@@ -79,7 +79,7 @@ from deeplearning4j_tpu.telemetry.tracectx import TraceContext
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry", "Tracer",
            "DEFAULT_BUCKETS", "get_registry", "get_tracer", "span",
            "write_jsonl", "enable", "disable", "enabled", "reset",
-           "series_map", "note_routing", "note_loss_terms",
+           "series_map", "note_step_state",
            "health", "devices", "flight", "scorepipe", "ScorePipeline",
            "NumericsError", "tracectx", "TraceContext",
            "federate", "timeline", "profiling", "slo", "goodput",
@@ -159,76 +159,78 @@ def train_metrics():
             reg.gauge("train_score", "last training score (loss)"))
 
 
-def _states_holding(state, key):
-    """The dicts that hold ``key`` in a net's state tree (a list a layer,
-    a dict a vertex, a layer's own dicts nested inside): ``moe_load`` marks
-    a routed-experts layer's state, ``loss_terms`` a head that keeps the
-    parts of its loss apart."""
+def _states_holding(state, keys):
+    """``(key, dict)`` for every dict that holds one of ``keys`` in a net's
+    state tree (a list a layer, a dict a vertex, a layer's own dicts nested
+    inside), in one walk: ``moe_load`` marks a routed-experts layer's
+    state, ``loss_terms`` a head that keeps the parts of its loss apart. A
+    key found is sought no deeper, the others are: a multi-token head holds
+    ``loss_terms`` beside its module's block, whose routed layer holds
+    ``moe_load``."""
     if isinstance(state, dict):
-        if key in state:
-            yield state
-        else:
-            for v in state.values():
-                yield from _states_holding(v, key)
-    elif isinstance(state, (list, tuple)):
+        for k in keys:
+            if k in state:
+                yield k, state
+        keys = tuple(k for k in keys if k not in state)
+        state = state.values()
+    elif not isinstance(state, (list, tuple)):
+        return
+    if keys:
         for v in state:
-            yield from _states_holding(v, key)
+            yield from _states_holding(v, keys)
 
 
-def note_routing(state):
-    """The last step's routing counts, from the state the step left on
-    the net (``moe_load``: rows per held expert; ``moe_elsewhere``:
-    assignments routed to experts not held), into the registry: the
+def note_step_state(state):
+    """What the last step left in the net's state for the registry, in
+    ONE walk of the tree and ONE small fetch; the fit loop calls it where
+    it already waits for the device (``StepDriver.sync``), and only while
+    the registry records.
+
+    The routing counts (``moe_load``: rows per held expert;
+    ``moe_elsewhere``: assignments routed to experts not held): the
     counters ``moe_rows_here_sampled_total`` and
     ``moe_assignments_sampled_total`` grow by that ONE step's counts summed
     over the expert layers (a sample of a round's steps, one a call: their
     ratio is a share, neither is a total of the run), the gauges
     ``moe_load_hottest_rows`` and ``moe_load_mean_rows`` hold each layer's
-    hottest and mean held expert, summed over the layers. One small fetch;
-    the fit loop calls it where it already waits for the device
-    (``StepDriver.sync``), and only while the registry records."""
+    hottest and mean held expert, summed over the layers. The terms of the
+    loss that a layer left apart (``loss_terms``: a multi-token head's
+    ``main`` and ``mtp``): the gauges ``train_loss_term_<name>``."""
     reg = get_registry()
     if not reg.enabled:
         return
-    found = [(s["moe_load"], s["moe_elsewhere"])
-             for s in _states_holding(state, "moe_load")]
-    if not found:
+    routed, terms = [], []
+    for key, s in _states_holding(state, ("moe_load", "loss_terms")):
+        if key == "moe_load":
+            routed.append((s["moe_load"], s["moe_elsewhere"]))
+        else:
+            terms.append(s["loss_terms"])
+    if not (routed or terms):
         return
     import jax
-    here = hottest = mean = elsewhere = 0.0
-    for load, away in jax.device_get(found):
-        here += float(load.sum())
-        hottest += float(load.max())
-        mean += float(load.mean())
-        elsewhere += float(away.sum())
-    reg.counter("moe_rows_here_sampled_total",
-                "assignments computed by the experts held here, over the "
-                "sampled steps alone (one a fit.sync)").inc(here)
-    reg.counter("moe_assignments_sampled_total",
-                "assignments routed, here and elsewhere, over the sampled "
-                "steps alone (one a fit.sync)").inc(here + elsewhere)
-    reg.gauge("moe_load_hottest_rows",
-              "rows of each layer's hottest held expert, summed over the "
-              "expert layers, last sampled step").set(hottest)
-    reg.gauge("moe_load_mean_rows",
-              "mean rows a held expert, summed over the expert layers, "
-              "last sampled step").set(mean)
-
-
-def note_loss_terms(state):
-    """The terms of the last step's loss that a layer left apart in its
-    state (``loss_terms``: a multi-token head's ``main`` and ``mtp``), as
-    the gauges ``train_loss_term_<name>``. One small fetch, beside
-    ``note_routing``'s and under the same condition."""
-    reg = get_registry()
-    if not reg.enabled:
-        return
-    found = [s["loss_terms"] for s in _states_holding(state, "loss_terms")]
-    if not found:
-        return
-    import jax
-    for terms in jax.device_get(found):
-        for name, value in terms.items():
+    routed, terms = jax.device_get((routed, terms))
+    if routed:
+        here = hottest = mean = elsewhere = 0.0
+        for load, away in routed:
+            here += float(load.sum())
+            hottest += float(load.max())
+            mean += float(load.mean())
+            elsewhere += float(away.sum())
+        reg.counter("moe_rows_here_sampled_total",
+                    "assignments computed by the experts held here, over "
+                    "the sampled steps alone (one a fit.sync)").inc(here)
+        reg.counter("moe_assignments_sampled_total",
+                    "assignments routed, here and elsewhere, over the "
+                    "sampled steps alone (one a fit.sync)").inc(
+                        here + elsewhere)
+        reg.gauge("moe_load_hottest_rows",
+                  "rows of each layer's hottest held expert, summed over "
+                  "the expert layers, last sampled step").set(hottest)
+        reg.gauge("moe_load_mean_rows",
+                  "mean rows a held expert, summed over the expert layers, "
+                  "last sampled step").set(mean)
+    for layer_terms in terms:
+        for name, value in layer_terms.items():
             reg.gauge(f"train_loss_term_{name}",
                       f"the term {name!r} of the last sampled step's "
                       "loss, before its weight").set(float(value))

@@ -432,8 +432,78 @@ def test_the_two_terms_reach_the_registry_through_the_fit_loop():
         assert mtp == pytest.approx(float(net.state[-1]["loss_terms"]["mtp"]))
         assert float(net.score_value) == pytest.approx(main + 0.3 * mtp,
                                                        rel=1e-5)
-        # the module's mixture is among the routed layers the loop samples
+        # the module's mixture is among the routed layers the loop samples:
+        # the head's state holds `loss_terms` BESIDE the block's under
+        # `mtp`, and the one walk goes on past the first into the second
         assert reg.get("moe_assignments_sampled_total") is not None
+        routed = [s for s in net.state if "moe_load" in s]
+        routed.append(net.state[-1]["mtp"])
+        assert len(routed) >= 2
+        assert telemetry.series_map("moe_assignments_sampled_total")[""] == \
+            sum(float(s["moe_load"].sum() + s["moe_elsewhere"].sum())
+                for s in routed)
+        assert telemetry.series_map("moe_load_hottest_rows")[""] == \
+            sum(float(s["moe_load"].max()) for s in routed)
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+
+
+def _two_walks(state):
+    """What `note_routing` and `note_loss_terms` read until PR 54 made
+    them one: each key's own walk, which stops at a dict that holds it."""
+    def holding(state, key):
+        if isinstance(state, dict):
+            if key in state:
+                yield state
+            else:
+                for v in state.values():
+                    yield from holding(v, key)
+        elif isinstance(state, (list, tuple)):
+            for v in state:
+                yield from holding(v, key)
+    return ([(s["moe_load"], s["moe_elsewhere"])
+             for s in holding(state, "moe_load")],
+            [s["loss_terms"] for s in holding(state, "loss_terms")])
+
+
+@pytest.mark.parametrize("state", [
+    # a multi-token head: the terms beside the module's routed block
+    [{}, {"moe_load": np.array([3., 1.]), "moe_elsewhere": np.array([2.])},
+     {"mtp": {"moe_load": np.array([5., 0.]),
+              "moe_elsewhere": np.array([1.])},
+      "loss_terms": {"main": np.float32(2.0), "mtp": np.float32(3.0)}}],
+    # a graph's vertices, a routed layer inside a layer's own dicts
+    {"a": [{"mlp": {"moe_load": np.array([4., 4.]),
+                    "moe_elsewhere": np.array([0.])}}],
+     "head": {"loss_terms": {"main": np.float32(1.5)}}},
+    # routed layers and no head that keeps terms; neither
+    [{"moe_load": np.array([1., 2.]), "moe_elsewhere": np.array([3.])}],
+    [{}, {"running_mean": np.zeros(3)}],
+], ids=["mtp_head", "graph", "routed_only", "neither"])
+def test_one_walk_of_the_state_reads_what_the_two_walks_read(state):
+    routed, terms = _two_walks(state)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        telemetry.note_step_state(state)
+        value = lambda name: telemetry.series_map(name).get("")
+        here = sum(float(load.sum()) for load, _ in routed)
+        away = sum(float(a.sum()) for _, a in routed)
+        want = {
+            "moe_rows_here_sampled_total": here,
+            "moe_assignments_sampled_total": here + away,
+            "moe_load_hottest_rows": sum(float(l.max()) for l, _ in routed),
+            "moe_load_mean_rows": sum(float(l.mean()) for l, _ in routed),
+        } if routed else dict.fromkeys((
+            "moe_rows_here_sampled_total", "moe_assignments_sampled_total",
+            "moe_load_hottest_rows", "moe_load_mean_rows"))
+        for t in terms:
+            want.update({f"train_loss_term_{k}": float(v)
+                         for k, v in t.items()})
+        assert {name: value(name) for name in want} == want
+        if not terms:
+            assert value("train_loss_term_main") is None
     finally:
         telemetry.disable()
         telemetry.reset()

@@ -513,7 +513,7 @@ def test_serde_and_a_fit():
     telemetry.reset()
     telemetry.enable()
     try:
-        telemetry.note_routing(net.state)
+        telemetry.note_step_state(net.state)
         snap = telemetry.get_registry().snapshot()
     finally:
         telemetry.disable()

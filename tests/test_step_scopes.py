@@ -193,15 +193,20 @@ def test_scope_names_are_made_safe(raw, want):
     assert scopes.safe(raw) == want
 
 
+@pytest.mark.parametrize("make", [_mln, _cg])
 @pytest.mark.parametrize("builder,name", [
     ("plain", "train_step"), ("tbptt", "tbptt_step"), ("fused", "steps_fn")])
-def test_the_jitted_step_is_named_with_the_grammars_version(builder, name):
+def test_the_jitted_step_is_named_with_the_grammars_version(builder, name,
+                                                            make):
     """jax leaves op_name out of the persistent cache's key but keeps the
     function's name in it: the stamp is what makes a change of the
     grammar compile anew instead of loading an executable with the old
-    names."""
-    net, x, y = _mln(recurrent=builder == "tbptt")
+    names. `s2` from PR 54, whose scopes inside the mixers and the routed
+    layer change no computation: without the stamp a warm cache would
+    serve both nets' steps with `s1`'s names."""
+    net, x, y = make(recurrent=builder == "tbptt")
     text = _lower(net, x, y, builder).as_text()
+    assert scopes.GRAMMAR == "s2"
     assert f"module @jit_{name}_{scopes.GRAMMAR} " in text
 
 
